@@ -83,8 +83,11 @@ class BeamformBlock(_StageBlock):
             # sequence, published via the gemm_gops_per_s perf key
             self._gemm_ops = stage.engine.ops_per_frame(
                 nfreq, npol) * int(gulp)
-        except Exception:
-            pass
+        except Exception as e:
+            # probing is best-effort — the traced default works — but
+            # a refusal is never dropped without a word
+            from ..ops import mprobe
+            mprobe.refused('beamform', 'prewarm', e)
 
 
 def beamform(iring, weights, accuracy='f32', impl=None, *args,

@@ -206,8 +206,8 @@ class CorrelateBlock(TransformBlock):
             try:
                 fns[name] = self._build_mesh(tuple(shape), dtype, reim,
                                              acc_is_none=True, plan=name)
-            except Exception:
-                pass
+            except Exception as e:
+                mprobe.refused('corner_turn', name, e)
         if len(fns) < 2:
             return 'psum'
         winner, _ms, _err = mprobe.select(
@@ -252,8 +252,11 @@ class CorrelateBlock(TransformBlock):
                         xcorr_prewarm(t_eff, f, sr * p, n)
                         return
             self.engine.prewarm(t_eff, f_eff, n, int_input=int_input)
-        except Exception:
-            pass    # probing is best-effort; the traced default works
+        except Exception as e:
+            # probing is best-effort — the traced default works — but
+            # a refusal is never dropped without a word
+            from ..ops import mprobe
+            mprobe.refused('xengine', 'prewarm', e)
 
     def _local_vis_fn(self, reim):
         engine = self.engine
@@ -283,7 +286,6 @@ class CorrelateBlock(TransformBlock):
         import jax
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P
-        from ..parallel.ops import _shard_map
         local_vis = self._local_vis_fn(reim)
         mesh = self.mesh
         geo = self._mesh_geometry(shape)
@@ -330,19 +332,12 @@ class CorrelateBlock(TransformBlock):
         in_spec = P(*spec)
         in_sharding = NamedSharding(mesh, in_spec)
         acc_spec = out_spec
-        shard_map = _shard_map()
+        from jax import shard_map
         kw = {}
         if plan.startswith('corner'):
             # replication of the all_gathered rows can't be statically
-            # inferred through the corner-turn collective; disable the
-            # check under either shard_map API generation (scope.py
-            # frame_local_plan idiom)
-            import inspect as _inspect
-            params = _inspect.signature(shard_map).parameters
-            if 'check_vma' in params:
-                kw['check_vma'] = False
-            elif 'check_rep' in params:
-                kw['check_rep'] = False
+            # inferred through the corner-turn collective
+            kw['check_vma'] = False
         if acc_is_none:
             sharded = jax.jit(shard_map(
                 lambda x: local_fn(x, None), mesh=mesh,
@@ -377,9 +372,11 @@ class CorrelateBlock(TransformBlock):
             try:
                 return self._build_mesh(shape, dtype, reim,
                                         acc_is_none, plan)
-            except Exception:
+            except Exception as e:
                 if plan != 'psum':      # measured plan failed to
-                    self._mesh_plan = 'psum'   # build: fall back
+                    from ..ops import mprobe    # build: fall back
+                    mprobe.refused('corner_turn', plan, e)
+                    self._mesh_plan = 'psum'
                     return self._build_mesh(shape, dtype, reim,
                                             acc_is_none, 'psum')
                 raise
@@ -452,8 +449,11 @@ class CorrelateStageBlock(_StageBlock):
             self._stage.engine.prewarm(
                 self._stage.nframe_per_vis, f, s * p,
                 int_input=(dt.kind == 'ci' and dt.nbits == 8))
-        except Exception:
-            pass    # probing is best-effort; the traced default works
+        except Exception as e:
+            # probing is best-effort — the traced default works — but
+            # a refusal is never dropped without a word
+            from ..ops import mprobe
+            mprobe.refused('xengine', 'prewarm', e)
         gulp_actual = self.gulp_nframe or iseq.header['gulp_nframe']
         self._gemm_ops = 8 * gulp_actual * f * (s * p) ** 2
         return ohdr

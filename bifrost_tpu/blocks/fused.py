@@ -408,6 +408,19 @@ class FusedBlock(TransformBlock):
         executed one may claim the ProcLog record."""
         self._last_built_impl = dict(info)
 
+    def _record_impl(self, key, info):
+        """Remember the impl record of the plan just built for
+        ``key``, together with every candidate the automatic selection
+        tried and the backend refused (ops.mprobe.refused): a kernel
+        the compiler turned down shows in the published record, not
+        only in a warning."""
+        if info is not None:
+            from ..ops import mprobe
+            refused = mprobe.refusals()
+            if refused:
+                info = dict(info, refused=refused)
+        self._plan_impls[key] = info
+
     def _publish_impl(self, info, key=None):
         """Publish the EXECUTED plan's configuration.  Republishes
         whenever the executed PATH differs from the last published one
@@ -447,7 +460,7 @@ class FusedBlock(TransformBlock):
             self._last_built_impl = None
             plan = self._build_plan(x.shape, x.dtype, donate=donate)
             self._plans[key] = plan
-            self._plan_impls[key] = self._last_built_impl
+            self._record_impl(key, self._last_built_impl)
             self._depot_store(key)
         info = self._plan_impls.get(key)
         if info is not None:
@@ -567,7 +580,7 @@ class FusedBlock(TransformBlock):
                     fn, donate_argnums=dargs), None
             plan = (fn, shard_taxis)
             self._plans[key] = plan
-            self._plan_impls[key] = info
+            self._record_impl(key, info)
             self._depot_store(key)
         info = self._plan_impls.get(key)
         if info is not None:

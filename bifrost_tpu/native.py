@@ -3,7 +3,9 @@
 The reference generates its Python bindings from the C headers with
 ctypesgen (reference: python/Makefile.in:23-30); here the ABI is small
 enough to declare by hand.  The library is built on demand with
-``make -C native`` the first time it's needed.
+``make -C native`` the first time it's needed; a build or load that
+fails warns once with the cause (``make``'s stderr) before host rings
+take the pure-Python core.
 
 Set ``BF_NO_NATIVE=1`` to force the pure-Python ring core.
 """
@@ -14,6 +16,7 @@ import ctypes
 import os
 import subprocess
 import threading
+import warnings
 
 __all__ = ['load', 'available', 'BFT_OK', 'BFT_END_OF_DATA',
            'BFT_WOULD_BLOCK', 'NativeError']
@@ -149,7 +152,9 @@ def _build():
 
 
 def load():
-    """Load (building if needed) the native library; None on failure."""
+    """Load (building if needed) the native library.  On failure warns
+    once with the cause and returns None (host rings then run the
+    pure-Python core)."""
     global _lib, _tried
     with _lock:
         if _lib is not None or _tried:
@@ -161,7 +166,7 @@ def load():
         try:
             srcs = [os.path.join(_repo_root(), 'native', f)
                     for f in ('ring.cpp', 'capture.cpp',
-                              'selftest.cpp')]
+                              'selftest.cpp', 'util.cpp')]
             stale = (not os.path.exists(path) or
                      any(os.path.exists(src) and
                          os.path.getmtime(src) > os.path.getmtime(path)
@@ -172,8 +177,17 @@ def load():
                 _build()
             _lib = _declare(ctypes.CDLL(path))
         except (OSError, AttributeError,
-                subprocess.CalledProcessError):
-            _lib = None   # fall back to the pure-Python core
+                subprocess.CalledProcessError) as e:
+            _lib = None
+            detail = getattr(e, 'stderr', None)
+            if isinstance(detail, bytes):
+                detail = detail.decode('utf-8', 'replace')
+            warnings.warn(
+                'native library %s could not be built or loaded; host '
+                'rings fall back to the pure-Python core.  %s: %s%s'
+                % (path, type(e).__name__, e,
+                   '\n' + detail.strip()[-2000:] if detail else ''),
+                RuntimeWarning, stacklevel=2)
         return _lib
 
 

@@ -395,27 +395,12 @@ class Beamformer(object):
 
     def _gate(self, names, npol, make_args):
         """(keep, had_errors): candidates within the class rtol of the
-        XLA baseline at the actual shape.  Same contract as
-        LinAlg._accuracy_gate; the forced path bypasses this."""
-        import jax.numpy as jnp
-        args = make_args()
-        outs = {}
-        had_errors = False
-        for name in names:
-            try:
-                outs[name] = self._jit(name, npol)(*args)
-            except Exception:
-                had_errors = True
-        if 'xla' not in outs:
-            return [n for n in outs if n not in _LOSSY], had_errors
-        ref = outs['xla']
-        scale = float(jnp.max(jnp.abs(ref))) or 1.0
-        rtol = beam_class_rtol(self.accuracy)
-        keep = []
-        for name, y in outs.items():
-            if float(jnp.max(jnp.abs(y - ref))) / scale <= rtol:
-                keep.append(name)
-        return keep, had_errors
+        XLA baseline at the actual shape (mprobe.accuracy_gate); the
+        forced path bypasses this."""
+        from . import mprobe
+        return mprobe.accuracy_gate(
+            'beamform', {n: self._jit(n, npol) for n in names},
+            make_args, beam_class_rtol(self.accuracy), lossy=_LOSSY)
 
     def _select(self, shape, dtype, int_input, make_args):
         """Measured winner for voltage planes of this shape/dtype —
@@ -559,7 +544,9 @@ _fused_probe = {}
 
 def fused_usable(engine, t, f, rfactor):
     """True when the fused kernel compiles AND runs on this backend at
-    the exact shape match_beamformer would substitute."""
+    the exact shape match_beamformer would substitute.  A refusal is
+    reported through ``mprobe.refused`` under BF_BEAM_FUSED=auto and
+    RAISES under ``force``."""
     key = (engine.nbeam, engine.nstand, t, f, rfactor)
     hit = _fused_probe.get(key)
     if hit is not None:
@@ -569,6 +556,11 @@ def fused_usable(engine, t, f, rfactor):
         x = jnp.zeros((t, f, engine.nstand, 2, 2), jnp.int8)
         np.asarray(fused_detect(engine, x, rfactor))
         _fused_probe[key] = True
-    except Exception:
+    except Exception as e:
+        if fused_mode() == 'force':
+            raise
+        from . import mprobe
+        mprobe.refused('beamform_fused', 'pallas[t=%d,f=%d,r=%d]'
+                       % (t, f, rfactor), e)
         _fused_probe[key] = False
     return _fused_probe[key]

@@ -442,13 +442,14 @@ class Fdmt(object):
             try:
                 fn = jax.jit(factory())
                 y = np.asarray(fn(xj))
-                if float(np.max(np.abs(y - ref))) / scale <= rtol:
-                    fns[name] = fn
-            except Exception:
-                # a transient compile blip must not freeze a ranking
-                # that excludes the possibly-faster core (ADVICE r4):
-                # race without it this session, don't persist
+            except Exception as e:
+                # race without the refused core this session and do
+                # not persist a ranking that excludes it
+                mprobe.refused('fdmt', name, e)
                 had_errors = True
+                continue
+            if float(np.max(np.abs(y - ref))) / scale <= rtol:
+                fns[name] = fn
         if not fns:
             return 'none'
         winner, ms, _err = mprobe.select('fdmt', key, fns,

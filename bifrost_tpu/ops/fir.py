@@ -90,7 +90,7 @@ class Fir(object):
     def _build_sharded(self, in_shape, in_dtype):
         import jax
         from jax.sharding import PartitionSpec as P
-        from ..parallel.ops import _shard_map, _local_fir_stateful
+        from ..parallel.ops import _local_fir_stateful
         from ..parallel.scope import time_axis_name
         mesh = self._mesh
         tname = time_axis_name(mesh)
@@ -103,7 +103,7 @@ class Fir(object):
         def local(x, state):
             return _local_fir_stateful(x, coeffs, state, tname, decim)
 
-        return jax.jit(_shard_map()(
+        return jax.jit(jax.shard_map(
             local, mesh=mesh,
             in_specs=(x_spec, rep), out_specs=(x_spec, rep)))
 

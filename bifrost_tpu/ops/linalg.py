@@ -445,36 +445,15 @@ class LinAlg(object):
             return LinAlg._GATE_RTOL
 
     @staticmethod
-    def _accuracy_gate(impls, make_args, base='xla'):
+    def _accuracy_gate(impls, make_args):
         """(keep, had_errors): candidates whose on-device deviation
         from the XLA baseline at the actual shape stays inside
-        _gate_rtol() relative.  Runs once per (family, shape) — only
-        when no cached winner exists.  ``had_errors`` is True when any
-        candidate raised (e.g. a transient OOM): the caller must not
-        freeze a winner chosen from the reduced field to disk.  If the
-        baseline itself raised, no accuracy evaluation is possible —
-        lossy candidates are dropped rather than admitted unchecked."""
-        import jax.numpy as jnp
-        args = make_args()
-        outs = {}
-        had_errors = False
-        for name, fn in impls.items():
-            try:
-                outs[name] = fn(*args)
-            except Exception:
-                had_errors = True
-        if base not in outs:
-            return [n for n in outs if n not in LinAlg._LOSSY], \
-                had_errors
-        ref = outs[base]
-        scale = float(jnp.max(jnp.abs(ref))) or 1.0
-        rtol = LinAlg._gate_rtol()
-        keep = []
-        for name, y in outs.items():
-            err = float(jnp.max(jnp.abs(y - ref))) / scale
-            if err <= rtol:
-                keep.append(name)
-        return keep, had_errors
+        _gate_rtol() relative (mprobe.accuracy_gate).  Runs once per
+        (family, shape) — only when no cached winner exists."""
+        from . import mprobe
+        return mprobe.accuracy_gate('linalg', impls, make_args,
+                                    LinAlg._gate_rtol(),
+                                    lossy=LinAlg._LOSSY)
 
     # -- public API ---------------------------------------------------------
 
@@ -912,27 +891,11 @@ class XEngine(object):
 
     def _gate(self, names, make_args):
         """(keep, had_errors): candidates within the class rtol of the
-        XLA baseline at the actual shape (Beamformer._gate contract)."""
-        import jax.numpy as jnp
-        args = make_args()
-        outs = {}
-        had_errors = False
-        for name in names:
-            try:
-                outs[name] = self._jit(name)(*args)
-            except Exception:
-                had_errors = True
-        if 'xla' not in outs:
-            return [n for n in outs if n not in _XENGINE_LOSSY], \
-                had_errors
-        ref = outs['xla']
-        scale = float(jnp.max(jnp.abs(ref))) or 1.0
-        rtol = xcorr_class_rtol(self.accuracy)
-        keep = []
-        for name, y in outs.items():
-            if float(jnp.max(jnp.abs(y - ref))) / scale <= rtol:
-                keep.append(name)
-        return keep, had_errors
+        XLA baseline at the actual shape (mprobe.accuracy_gate)."""
+        from . import mprobe
+        return mprobe.accuracy_gate(
+            'xengine', {n: self._jit(n) for n in names}, make_args,
+            xcorr_class_rtol(self.accuracy), lossy=_XENGINE_LOSSY)
 
     def _select(self, shape, dtype, int_input, make_args):
         key = self._key(shape, dtype, int_input)

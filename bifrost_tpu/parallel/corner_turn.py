@@ -32,7 +32,7 @@ from __future__ import annotations
 
 __all__ = ['corner_turn_local', 'corner_turn']
 
-from .ops import _shard_map, _P, axis_size as _axis_size
+from .ops import _P
 
 
 def _ppermute_shift(x, axis_name, ndev):
@@ -85,7 +85,7 @@ def corner_turn_local(x, axis_name, impl='xla', ndev=None):
     from jax import lax
     if impl in ('pallas', 'ring'):
         if ndev is None:
-            ndev = _axis_size(axis_name)
+            ndev = lax.axis_size(axis_name)
         if not isinstance(ndev, int):
             raise ValueError('ring corner turn needs a static device '
                              'count; pass ndev=')
@@ -106,7 +106,7 @@ def corner_turn(mesh, axis_name, impl='xla', stacked=False):
     returns (D, T, F/D, ...) with slot d = device d's post-turn shard,
     comparable against the transpose oracle
     ``x[:, d*F/D:(d+1)*F/D]``."""
-    shard_map = _shard_map()
+    from jax import shard_map
     ndev = int(mesh.shape[axis_name])
 
     def call(x):

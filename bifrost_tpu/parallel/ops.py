@@ -24,24 +24,6 @@ __all__ = ['sharded_spectrometer', 'sharded_beamform', 'sharded_correlate',
            'sharded_fir', 'spectrometer_step']
 
 
-def _shard_map():
-    import jax
-    if hasattr(jax, 'shard_map'):
-        return jax.shard_map
-    from jax.experimental.shard_map import shard_map
-    return shard_map
-
-
-def axis_size(axis_name):
-    """Size of a named mesh axis from inside a shard_map/pmap body.
-    ``jax.lax.axis_size`` only exists on newer jax; the psum-of-one
-    fallback is constant-folded to the same static int everywhere."""
-    import jax
-    if hasattr(jax.lax, 'axis_size'):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 def _P(*args):
     from jax.sharding import PartitionSpec
     return PartitionSpec(*args)
@@ -65,7 +47,7 @@ def _local_fir_stateful(x, coeffs, state, axis_name, decim=1):
     if ntap == 1:
         y = coeffs[0] * x
         return (y[::decim] if decim > 1 else y), state
-    axis_size_ = axis_size(axis_name)
+    axis_size_ = jax.lax.axis_size(axis_name)
     halo = x[-(ntap - 1):]
     perm = [(i, (i + 1) % axis_size_) for i in range(axis_size_)]
     left = jax.lax.ppermute(halo, axis_name, perm)
@@ -139,7 +121,7 @@ def sharded_spectrometer(mesh, time_axis_name='sp'):
     integrated over all time shards (psum over the time axis)."""
     import jax
     import jax.numpy as jnp
-    shard_map = _shard_map()
+    from jax import shard_map
 
     def local_step(v):
         s = jnp.fft.fft(v, axis=-1)
@@ -153,7 +135,7 @@ def sharded_spectrometer(mesh, time_axis_name='sp'):
 
 def sharded_beamform(mesh, ant_axis_name='tp'):
     """Tensor-parallel beamforming GEMM over a sharded antenna axis."""
-    shard_map = _shard_map()
+    from jax import shard_map
 
     def local_step(w, v):
         return _local_beamform(w, v, ant_axis_name)
@@ -166,7 +148,7 @@ def sharded_beamform(mesh, ant_axis_name='tp'):
 
 def sharded_correlate(mesh, ant_axis_name='tp', time_axis_name='sp'):
     """Cross-correlation (visibilities) with antennas and time sharded."""
-    shard_map = _shard_map()
+    from jax import shard_map
 
     def local_step(v):
         return _local_correlate(v, ant_axis_name, time_axis_name)
@@ -179,7 +161,7 @@ def sharded_correlate(mesh, ant_axis_name='tp', time_axis_name='sp'):
 def sharded_fir(mesh, coeffs, time_axis_name='sp'):
     """FIR along a time axis sharded across chips (halo via ppermute)."""
     import jax.numpy as jnp
-    shard_map = _shard_map()
+    from jax import shard_map
     coeffs = jnp.asarray(coeffs)
 
     def local_step(x):
@@ -210,7 +192,7 @@ def sharded_fdmt(mesh, plan, time_axis_name='sp',
     """
     import jax
     import jax.numpy as jnp
-    shard_map = _shard_map()
+    from jax import shard_map
     H = int(plan.max_delay)
     n = int(mesh.shape[time_axis_name])
     if core is None:
@@ -255,7 +237,7 @@ def spectrometer_step(mesh):
     """
     import jax
     import jax.numpy as jnp
-    shard_map = _shard_map()
+    from jax import shard_map
 
     def local_step(volt, weights, coeffs):
         # volt: (T/sp, A/tp, F, 2) int8;  weights: (B, A/tp) complex

@@ -172,10 +172,8 @@ def frame_local_plan(mesh, build_local, shape, dtype, taxis_in,
     Returns ``(jitted, in_sharding, out_sharding)`` or None when the
     frame axis does not divide the mesh or the local build fails
     (caller falls back to a GSPMD plan)."""
-    import inspect
     import jax
     from jax.sharding import NamedSharding, PartitionSpec
-    from .ops import _shard_map
     nsh = time_axis_size(mesh)
     if shape[taxis_in] % nsh:
         return None
@@ -192,29 +190,24 @@ def frame_local_plan(mesh, build_local, shape, dtype, taxis_in,
                                   for i in range(len(shape))])
         spec_out = PartitionSpec(*[aname if i == taxis_out else None
                                    for i in range(out_l.ndim)])
-        sm = _shard_map()
         # bodies may carry no varying-mesh-axis metadata (pallas
-        # kernels); disable the check under either API generation
-        params = inspect.signature(sm).parameters
-        kw = {}
-        if 'check_vma' in params:
-            kw['check_vma'] = False
-        elif 'check_rep' in params:
-            kw['check_rep'] = False
-        sharded = sm(body, mesh=mesh, in_specs=spec_in,
-                     out_specs=spec_out, **kw)
+        # kernels), so the check is off
+        sharded = jax.shard_map(body, mesh=mesh, in_specs=spec_in,
+                                out_specs=spec_out, check_vma=False)
         in_sh = NamedSharding(mesh, spec_in)
         out_sh = NamedSharding(mesh, spec_out)
         from ..ops.common import donating_jit
         jitted = donating_jit(sharded, donate_argnums=donate_argnums,
                               in_shardings=in_sh, out_shardings=out_sh)
-    except Exception:
+    except Exception as e:
         # the caller degrades to GSPMD — which on some partitioners
         # (CPU) re-introduces the collectives this path exists to
         # preclude; make that degradation visible like every other
         # fallback (the divisibility early-return above is an expected
         # geometry case and is not counted)
+        from ..ops import mprobe
         from ..telemetry import counters
+        mprobe.refused('mesh', 'frame_local', e)
         counters.inc('mesh.frame_local_fallback')
         return None
     return jitted, in_sh, out_sh

@@ -1103,7 +1103,10 @@ def match_spectrometer(stages, headers, shape, dtype):
     ReduceStage('freq', r, 'sum') on ci8 dual-pol input — and return
     the fused Pallas kernel (ops/spectrometer.py) as a callable
     :class:`SpectrometerPlan` when the active BF_SPEC_IMPL mode admits
-    it, else None.
+    it, else None.  Under ``auto`` a kernel the backend refuses is
+    reported (ops.mprobe.refused: one warning, an entry in the
+    published impl record) and the XLA chain runs; under the forced
+    ``pallas`` mode the refusal raises.
 
     This is the TPU equivalent of the reference wiring cuFFT load/store
     callbacks into the transform (reference: src/fft_kernels.cu
@@ -1147,9 +1150,7 @@ def match_spectrometer(stages, headers, shape, dtype):
         tile = 16
     if tile < 1:
         tile = 16
-    trans = os.environ.get('BF_SPEC_TRANSPOSE', 'kernel').strip().lower()
-    if trans not in ('kernel', 'epilogue'):
-        trans = 'kernel'
+    trans = spec.resolve_transpose('auto', nfft, r.factor)
     # the EFFECTIVE tile after fused_spectrometer's shrink-to-divisor
     # (shape[0] is the frame count the kernel will actually see — the
     # per-shard count under a mesh)

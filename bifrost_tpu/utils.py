@@ -69,22 +69,24 @@ class ObjectCache(object):
             self._items.clear()
 
 
-def enable_compilation_cache(path=None):
+def enable_compilation_cache():
     """Persist XLA compilations to disk (the analogue of the
     reference's on-disk map-kernel cache, src/map.cpp DiskCacheMgr):
     restarting a pipeline reuses compiled programs instead of paying
-    first-compile latency again.  ``path`` defaults to $BF_CACHE_DIR or
-    ~/.cache/bifrost_tpu/xla.  Safe to call more than once."""
-    import os
-    path = path or os.environ.get('BF_CACHE_DIR') or \
-        os.path.join(os.path.expanduser('~'), '.cache', 'bifrost_tpu',
-                     'xla')
-    os.makedirs(path, exist_ok=True)
+    first-compile latency again.  This is the ONE place the cache
+    directory is decided: where ``JAX_COMPILATION_CACHE_DIR`` is set,
+    JAX reads it itself and nothing here sets a directory; where it is
+    not, the cache is ``<checkout>/.jax_cache`` — a fixed path beside
+    the package (the path is part of the cache key, so a directory
+    that moves never hits).  Returns the directory in use.  Safe to
+    call more than once."""
     import jax
-    jax.config.update('jax_compilation_cache_dir', path)
-    try:
-        jax.config.update('jax_persistent_cache_min_compile_time_secs',
-                          0.5)
-    except Exception:
-        pass
+    path = os.environ.get('JAX_COMPILATION_CACHE_DIR')
+    if not path:
+        path = os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            '.jax_cache')
+        os.makedirs(path, exist_ok=True)
+        jax.config.update('jax_compilation_cache_dir', path)
+    jax.config.update('jax_persistent_cache_min_compile_time_secs', 0.5)
     return path

@@ -21,7 +21,7 @@ def _fat_history(n=120):
     """A probe history big enough to defeat any naive inlining."""
     return [{'t': '2026-08-0%dT00:00:00Z' % (i % 9 + 1),
              'rc': 'timeout' if i % 3 else 1,
-             'error': 'tunnel reset mid-handshake while probing the '
+             'error': 'connection reset mid-handshake while probing the '
                       'accelerator backend attempt %d ' % i + 'x' * 200}
             for i in range(n)]
 

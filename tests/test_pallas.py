@@ -1,8 +1,8 @@
 """Pallas kernel tests.  Where Pallas does not compile natively (e.g.
 the CPU test backend) the kernels run in interpret mode — same program,
 emulated execution — so the math is verified everywhere and only the
-Mosaic lowering is left to the on-hardware smoke gate
-(bench.py --pallas-smoke)."""
+Mosaic lowering is left to the on-chip smoke
+(chip_smoke.py phase B)."""
 
 import numpy as np
 import pytest  # noqa: F401
@@ -34,7 +34,7 @@ def test_stokes_detect_matches_jnp():
 def test_xcorr_herm_exact_interpret():
     """Fused Hermitian int8 correlation kernel vs the integer oracle
     at a lane-aligned shape (interpret mode; the on-chip compile is
-    gated by bench.py --pallas-smoke)."""
+    checked by chip_smoke.py phase B)."""
     import jax.numpy as jnp
     rng = np.random.RandomState(1)
     T, F, n = 16, 3, 256

@@ -1,7 +1,8 @@
 """Compiled pipeline segments (bifrost_tpu.segments; docs/perf.md
 "Compiled pipeline segments"): fusing a device-block chain into ONE
-XLA program must be byte-identical to the unfused chain, elide the
-interior rings completely (0 member dispatches, 0 ring traffic), keep
+XLA program must give the unfused chain's answer (each held to the
+float64 oracle; integer and same-program comparisons stay exact),
+elide the interior rings completely (0 member dispatches, 0 ring traffic), keep
 observability alive through synthesis, refuse every unprovable
 boundary with the exact BF-I190 reason, and support the auto-tuner's
 split/re-fuse knob."""
@@ -15,6 +16,7 @@ import bifrost_tpu as bf
 from bifrost_tpu import segments as bseg
 from bifrost_tpu.blocks.fft import _StageBlock
 from bifrost_tpu.macro import split_ranges
+from bifrost_tpu.ops.spectrometer import spectrometer_oracle
 from bifrost_tpu.stages import DetectStage
 from bifrost_tpu.telemetry import counters, histograms
 from tests.util import NumpySourceBlock, GatherSink, simple_header
@@ -37,6 +39,27 @@ def _volts(ngulp, seed=3):
 def _hdr():
     return simple_header([-1, NP, NF], 'ci8',
                          labels=['time', 'pol', 'fine_time'])
+
+
+#: The fused segment and the unfused chain are DIFFERENT XLA programs,
+#: and XLA promises no bit-identity between programs: under jax 0.9.0
+#: the one-program FFT->detect->reduce differs from the three-program
+#: chain in the last float32 bit.  So each is held to the float64
+#: oracle.  A 64-point f32 FFT of int8 voltages, Stokes squares and a
+#: sum of 4 measure 1.4e-7 of the peak here; 2e-6 leaves an order of
+#: magnitude and is a thousand times tighter than a bf16 path (2^-9 =
+#: 2e-3) could meet.  Exact equality stays for integer paths and for
+#: two runs of ONE program (test_segment_is_deterministic).
+ORACLE_RTOL = 2e-6
+
+
+def _assert_matches_oracle(out, ngulp=6):
+    volt = np.concatenate([np.stack([g['re'], g['im']], axis=-1)
+                           for g in _volts(ngulp)])
+    want = spectrometer_oracle(volt, rfactor=RF)
+    assert out.shape == want.shape
+    rel = np.max(np.abs(out - want)) / np.max(np.abs(want))
+    assert rel <= ORACLE_RTOL, rel
 
 
 def _run_chain(segments=None, gulp_batch=1, ngulp=6, donate=None,
@@ -85,10 +108,11 @@ def _i190(diags):
 # fusion correctness + elision
 # ---------------------------------------------------------------------------
 
-def test_segment_fuses_byte_identical_and_elides():
+def test_segment_fuses_and_elides():
     base, p0, _ = _run_chain(None)
     out, p1, snap = _run_chain('auto')
-    assert np.array_equal(base, out)
+    _assert_matches_oracle(base)
+    _assert_matches_oracle(out)
     # 7 blocks -> 5: fft/detect/reduce replaced by one SegmentBlock
     assert len(p0.blocks) == 7
     assert len(p1.blocks) == 5
@@ -117,10 +141,18 @@ def test_segment_fuses_byte_identical_and_elides():
         assert h is not None and h.count == 6
 
 
+def test_segment_is_deterministic():
+    """Two runs of ONE program agree to the bit."""
+    first, _, _ = _run_chain('auto')
+    again, _, _ = _run_chain('auto')
+    assert np.array_equal(first, again)
+
+
 def test_segment_composes_with_macro_gulp():
     base, _, _ = _run_chain(None, ngulp=8)
     out, p, snap = _run_chain('auto', gulp_batch=4, ngulp=8)
-    assert np.array_equal(base, out)
+    _assert_matches_oracle(base, 8)
+    _assert_matches_oracle(out, 8)
     # one dispatch per K-gulp span: 8 gulps at K=4 = 2 dispatches
     assert snap['segment.dispatches'] == 2
     assert snap['segment.gulps'] == 8
@@ -132,7 +164,8 @@ def test_segment_threads_donation_through_interiors():
     base, _, _ = _run_chain(None, ngulp=8)
     out, _, snap = _run_chain('auto', gulp_batch=4, ngulp=8,
                               donate=True)
-    assert np.array_equal(base, out)
+    _assert_matches_oracle(base, 8)
+    _assert_matches_oracle(out, 8)
     assert snap.get('donation.hits', 0) > 0
 
 
@@ -152,13 +185,14 @@ def test_force_mode_raises_without_a_fusable_chain():
 def test_force_mode_runs_when_a_segment_forms():
     base, _, _ = _run_chain(None)
     out, p, _ = _run_chain('force')
-    assert np.array_equal(base, out)
+    _assert_matches_oracle(base)
+    _assert_matches_oracle(out)
     assert len(p._segments) == 1
 
 
 # ---------------------------------------------------------------------------
 # fusion-breaking boundaries: exact BF-I190 reason + unfused-but-
-# byte-identical execution
+# correct execution
 # ---------------------------------------------------------------------------
 
 def test_boundary_multi_reader():
@@ -177,8 +211,9 @@ def test_boundary_multi_reader():
         assert ('FftBlock', 'multi_reader') in _reasons(p)
         p.run()
     # ...but detect->reduce still fuses (the safe sub-chain), and the
-    # stream is byte-identical to the fully unfused run
-    assert np.array_equal(base, sink.result())
+    # stream is the fully unfused run's, to the oracle's tolerance
+    _assert_matches_oracle(base)
+    _assert_matches_oracle(sink.result())
     assert counters.get('segment.compiled') == 1
     assert counters.get('segment.elided_rings') == 1
     assert len(p._segments) == 1 and len(p._segments[0]._members) == 2
@@ -198,7 +233,8 @@ def test_boundary_tap_via_ring_view():
         sink = GatherSink(bf.blocks.copy(r, space='system'))
         assert ('FftBlock', 'tap') in _reasons(p)
         p.run()
-    assert np.array_equal(base, sink.result())
+    _assert_matches_oracle(base)
+    _assert_matches_oracle(sink.result())
     # detect->reduce still fused behind the tap
     assert counters.get('segment.compiled') == 1
 
@@ -319,7 +355,8 @@ def test_ringcheck_sees_no_traffic_on_elided_interiors(monkeypatch):
     out, p, snap = _run_chain('auto')
     monkeypatch.delenv('BF_RINGCHECK')
     ringcheck.reconfigure()
-    assert np.array_equal(base, out)
+    _assert_matches_oracle(base)
+    _assert_matches_oracle(out)
     assert snap.get('ringcheck.violations', 0) == 0
     for ring in p._segments[0]._elided:
         assert counters.get('ring.%s.gulps' % ring) == 0

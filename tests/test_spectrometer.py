@@ -246,9 +246,11 @@ def test_matcher_probes_usability(monkeypatch):
     assert match_spectrometer(st, headers, (8, 2, 256, 2),
                               'int8') is None
     # tile is the EFFECTIVE one after shrink-to-divisor vs the real
-    # frame count (8 here), not the raw BF_SPEC_TILE default
+    # frame count (8 here), not the raw BF_SPEC_TILE default; the
+    # transpose is the shape's (resolve_transpose): 256 = 16 x 16 with
+    # rfactor 4 leaves a minor dim of 4, not lane-native -> epilogue
     assert seen == {'nfft': 256, 'rfactor': 4, 'tile': 8,
-                    'prec': None, 'trans': 'kernel'}
+                    'prec': None, 'trans': 'epilogue'}
 
 
 def test_split_override(monkeypatch):

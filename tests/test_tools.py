@@ -47,6 +47,32 @@ def test_like_top_once():
     assert 'CopyBlock' in res.stdout
 
 
+def test_like_top_device_pane_reads_proclogs():
+    """The accelerator-memory pane comes from the ``devices/<n>``
+    ProcLogs of the process that owns the chip (MetricsPublisher); the
+    monitor starts no process and never initialises JAX — a chip
+    belongs to one process at a time."""
+    from bifrost_tpu.proclog import ProcLog
+    sys.path.insert(0, TOOLS)
+    try:
+        import like_top
+    finally:
+        sys.path.remove(TOOLS)
+    assert 'subprocess' not in open(like_top.__file__).read()
+    ProcLog('devices/0').update({'platform': 'tpu',
+                                 'bytes_limit': 16 << 30,
+                                 'bytes_in_use': 3 << 30}, force=True)
+    devs = {}
+    like_top.collect_blocks(pids=[os.getpid()], devices=devs)
+    pane = like_top.device_memory_usage(devs)
+    assert pane == {'devCount': 1, 'memTotal': (16 << 30) // 1024,
+                    'memUsed': (3 << 30) // 1024,
+                    'memFree': (13 << 30) // 1024}
+    res = _tool('like_top.py', '--once')
+    assert res.returncode == 0, res.stderr
+    assert 'Dev(s):' in res.stdout and '1 device(s)' in res.stdout
+
+
 def test_like_ps():
     """like_ps lists process details, rings with space/size, and block
     ring wiring (reference: tools/like_ps.py:120-196)."""

@@ -62,7 +62,7 @@ def run_config20(timeout=900):
                 'BF_AUTOTUNE', 'BF_SERVE_MAX_TENANTS',
                 'BF_SERVE_WARM', 'BF_SERVE_QUOTA_BURST',
                 'BF_GULP_BATCH', 'BF_SYNC_DEPTH', 'BF_SEGMENTS',
-                'BF_COMPILE_CACHE', 'BF_FABRIC_STATE',
+                'BF_FABRIC_STATE',
                 'BF_FABRIC_IDENTITY', 'BF_FABRIC_HEARTBEAT_SECS',
                 'BF_FABRIC_DEADLINE_SECS', 'BF_SCHED_REBALANCE_SECS',
                 'BF_SCHED_DISPLACE_QUOTA_FRAC',

@@ -51,14 +51,11 @@ def run_config18(timeout=900):
     env = dict(os.environ, JAX_PLATFORMS='cpu')
     # configured fault/quota/tuning knobs would skew the scripted drill
     # BF_SEGMENTS would replace the warm chain's FusedBlocks with
-    # fresh SegmentBlocks (no plan depot -> spurious recompiles) and
-    # an ambient BF_COMPILE_CACHE would collapse the cold-start
-    # latency the warm speedup is measured against
+    # fresh SegmentBlocks (no plan depot -> spurious recompiles)
     for var in ('BF_FAULTS', 'BF_OVERLOAD_POLICY', 'BF_SLO_MS',
                 'BF_AUTOTUNE', 'BF_SERVE_MAX_TENANTS',
                 'BF_SERVE_WARM', 'BF_SERVE_QUOTA_BURST',
-                'BF_GULP_BATCH', 'BF_SYNC_DEPTH', 'BF_SEGMENTS',
-                'BF_COMPILE_CACHE'):
+                'BF_GULP_BATCH', 'BF_SYNC_DEPTH', 'BF_SEGMENTS'):
         env.pop(var, None)
     out = subprocess.run(
         [sys.executable, os.path.join(ROOT, 'bench_suite.py'),
