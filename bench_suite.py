@@ -1505,7 +1505,7 @@ def bench_e2e_observability(reps=8, ngulp=96):
     trace_tmp = os.path.join(tmpdir, 'overhead_trace.json')
 
     knobs = ('BF_TRACE_FILE', 'BF_TRACE_CONTEXT', 'BF_SLO_MS',
-             'BF_TRACE', 'BF_METRICS_FILE', 'BF_WATCHDOG_SECS')
+             'BF_METRICS_FILE', 'BF_WATCHDOG_SECS')
     saved = {k: os.environ.get(k) for k in knobs}
 
     def arm_env(on):

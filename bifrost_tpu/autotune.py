@@ -49,7 +49,7 @@ constraint and skips the gate.
 incremented so the counter equals the value; ``autotune.retunes`` /
 ``autotune.reverts`` / ``autotune.rejected`` count decisions),
 the ``analysis/autotune`` ProcLog carries the live knob panel
-``tools/like_top.py`` renders, and span recording (BF_TRACE_FILE)
+``tools/like_top.py`` renders, and the span recorder
 gets one ``autotune.retune`` event per change so the Chrome trace
 shows the controller acting on the same timeline as the gulps.
 
@@ -984,19 +984,18 @@ class AutoTuner(threading.Thread):
         """The single choke point every knob change goes through:
         applies, counts, spans, and proclogs the decision."""
         from .telemetry import spans
-        t0 = spans.now_us() if spans.enabled() else None
+        t0 = spans.now_us()
         knob.write(value)
         self.retunes += 1
         self._count('autotune.retunes')
         if kind == 'revert':
             self._count('autotune.reverts')
         self._publish_value(knob, knob.read())
-        if t0 is not None:
-            args = {'knob': knob.name, 'to': value, 'kind': kind}
-            if isinstance(signal, (int, float)):
-                args['signal'] = round(float(signal), 6)
-            spans.record('autotune.retune', 'autotune', t0,
-                         spans.now_us() - t0, args)
+        args = {'knob': knob.name, 'to': value, 'kind': kind}
+        if isinstance(signal, (int, float)):
+            args['signal'] = round(float(signal), 6)
+        spans.record('autotune.retune', 'autotune', t0,
+                     spans.now_us() - t0, args)
         self._publish_panel(last='%s %s -> %s'
                             % (kind, knob.name, value))
 

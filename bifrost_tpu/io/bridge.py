@@ -1340,23 +1340,22 @@ class RingSender(object):
         crc = _lane_crc(lanes) if self.crc else 0
         ngulps = max(1, -(-span.nframe // max(gulp, 1)))
         spans_mod = _spans()
-        t0 = spans_mod.now_us() if spans_mod.enabled() else None
+        t0 = spans_mod.now_us()
         ack_info = None
         if self.on_span_acked is not None:
             ack_info = (self._cur_seq_name, span.frame_offset,
                         span.nframe, nbyte)
         self._emit(MSG_SPAN, span=span, lanes=lanes,
                    meta=_SPAN2.pack(ngulps, crc), ack=ack_info)
-        if t0 is not None:
-            # tx span under the stream's trace identity: the same
-            # (trace, seq, gulp) triple the receiving host records,
-            # so the merged timeline shows the hop itself
-            spans_mod.record('bridge.tx.%s' % self.name, 'bridge', t0,
-                             spans_mod.now_us() - t0,
-                             {'trace': self._cur_trace,
-                              'seq': self._cur_seq,
-                              'gulp': span.frame_offset // max(gulp, 1),
-                              'gulps': ngulps, 'bytes': nbyte})
+        # tx span under the stream's trace identity: the same
+        # (trace, seq, gulp) triple the receiving host records, so
+        # the merged timeline shows the hop itself
+        spans_mod.record('bridge.tx.%s' % self.name, 'bridge', t0,
+                         spans_mod.now_us() - t0,
+                         {'trace': self._cur_trace,
+                          'seq': self._cur_seq,
+                          'gulp': span.frame_offset // max(gulp, 1),
+                          'gulps': ngulps, 'bytes': nbyte})
         if self.heartbeat is not None:
             self.heartbeat()
 
@@ -1878,7 +1877,7 @@ class RingReceiver(object):
         """Striped / v1 path: payload already in host memory; scatter
         into the reserved span."""
         spans_mod = _spans()
-        t0 = spans_mod.now_us() if spans_mod.enabled() else None
+        t0 = spans_mod.now_us()
         if crc is not None and self._crc:
             got = zlib.crc32(payload) & 0xffffffff
             if got != crc:
@@ -1904,15 +1903,13 @@ class RingReceiver(object):
             raise
         span.close()
         self._note_committed(nframe)
-        if t0 is not None:
-            self._record_rx_span(t0, len(payload), ngulps,
-                                 frame_offset)
+        self._record_rx_span(t0, len(payload), ngulps, frame_offset)
 
     def _recv_span_into_ring(self, sock, payload_nbyte, ngulps, crc):
         """Single-stream zero-copy path: ``recv_into`` straight into
         the reserved span's lane views (no intermediate buffer)."""
         spans_mod = _spans()
-        t0 = spans_mod.now_us() if spans_mod.enabled() else None
+        t0 = spans_mod.now_us()
         span, nframe = self._reserve(payload_nbyte)
         frame_offset = span.frame_offset
         try:
@@ -1941,9 +1938,7 @@ class RingReceiver(object):
             raise
         span.close()
         self._note_committed(nframe)
-        if t0 is not None:
-            self._record_rx_span(t0, payload_nbyte, ngulps,
-                                 frame_offset)
+        self._record_rx_span(t0, payload_nbyte, ngulps, frame_offset)
 
     def _crc_mismatch(self, want, got):
         self._rx_crc_errors += 1

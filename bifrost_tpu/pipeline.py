@@ -30,7 +30,7 @@ import traceback
 import warnings
 import queue as queue_mod
 from collections import defaultdict, deque
-from contextlib import ExitStack, nullcontext
+from contextlib import ExitStack
 from copy import copy
 
 from . import affinity, device, memory
@@ -40,7 +40,6 @@ from .telemetry import exporter as _metrics_exporter
 from .telemetry import histograms as _histograms
 from .telemetry import slo as _slo
 from .telemetry import spans as _spans
-from .trace import ScopedTracer, tracing_enabled as _tracing
 from .ring import Ring, ring_view, EndOfDataStop, RingPoisonedError
 from .ndarray import memset_array
 from .proclog import ProcLog
@@ -579,6 +578,7 @@ class Pipeline(BlockScope):
         # export / flight record is not contaminated by earlier runs
         _spans.reconfigure()
         _spans.prune_dead_buffers()
+        _spans.watch_jax()       # jit.compile spans from here on
         _slo.reset_budget()
         # honor BF_RINGCHECK toggles between runs the same way
         # (bifrost_tpu.analysis.ringcheck; docs/analysis.md)
@@ -848,15 +848,13 @@ class Block(BlockScope):
         blocks by its (sequence, gulp_index) args — and, when the
         stream carries a trace context, across PIPELINES AND HOSTS by
         the stream-unique trace id (tools/trace_merge.py joins on the
-        (trace, seq, gulp) triple).  Free when span recording is
-        off."""
-        if _spans.enabled():
-            kwargs = {'seq': seq, 'gulp': gulp}
-            if self._trace_ctx is not None:
-                kwargs['trace'] = self._trace_ctx.get('id')
-            return _spans.span(self.name + '.on_data', 'compute',
-                               **kwargs)
-        return nullcontext()
+        (trace, seq, gulp) triple)."""
+        if self._trace_ctx is not None:
+            return _spans.timed(self.name + '.on_data', 'compute',
+                                seq=seq, gulp=gulp,
+                                trace=self._trace_ctx.get('id'))
+        return _spans.timed(self.name + '.on_data', 'compute',
+                            seq=seq, gulp=gulp)
 
     def _observe_exit_age(self, iheader, frame_end):
         """Capture->pipeline-exit SLO observation (sink blocks: the
@@ -1179,20 +1177,26 @@ class Block(BlockScope):
                 return [a for a in gulp
                         if not getattr(a, 'is_deleted',
                                        lambda: False)()]
+
+            def hard_wait(arrs):
+                # where a device-bound block spends its time: waiting
+                # for the device inside its own call, not in a ring
+                counters.inc('pipeline.sync_waits')
+                with _spans.timed(self.name + '.sync_wait', 'wait',
+                                  'block.%s.sync_wait_s' % self.name):
+                    wait(*arrs)
             if device.execution_in_order():
                 # newest popped gulp with anything left to wait on
                 for gulp in reversed(popped):
                     arrs = live(gulp)
                     if arrs:
-                        counters.inc('pipeline.sync_waits')
-                        wait(*arrs)
+                        hard_wait(arrs)
                         break
             else:
                 for gulp in popped:
                     arrs = live(gulp)
                     if arrs:
-                        counters.inc('pipeline.sync_waits')
-                        wait(*arrs)
+                        hard_wait(arrs)
         # retire completed async D2H transfers without blocking
         xfer.engine().drain()
 
@@ -1285,10 +1289,10 @@ class SourceBlock(Block):
                     oseq_stack, orings, oheaders,
                     igulp_nframes=[], istride_nframes=[])
                 while not self.shutdown_event.is_set():
-                    t0 = time.time()
+                    t0 = time.perf_counter()
                     with ExitStack() as ospan_stack:
                         ospans = self.reserve_spans(ospan_stack, oseqs)
-                        t1 = time.time()
+                        t1 = time.perf_counter()
                         faults.fire('block.on_data', self.name)
                         with self._compute_span(seq_id, gulp_index):
                             ostrides = self.on_data(ireader, ospans)
@@ -1297,7 +1301,7 @@ class SourceBlock(Block):
                                           ogulp_overlaps)
                         if any(o == 0 for o in ostrides):
                             break
-                    t2 = time.time()
+                    t2 = time.perf_counter()
                     gulp_index += 1
                     self._observe_gulp(0.0, t1 - t0, t2 - t1)
                     self._observe_dispatch(1)
@@ -1589,7 +1593,7 @@ class MultiTransformBlock(Block):
                 igulp_nframes, istride_nframes, batch=batch)
             if self.shutdown_event.is_set():
                 return False
-            prev_time = time.time()
+            prev_time = time.perf_counter()
             for ispans in izip(*[iseq.read(igulp, istride, iframe0)
                                  for iseq, igulp, istride, iframe0
                                  in zip(iseqs, igulp_nframes,
@@ -1638,7 +1642,7 @@ class MultiTransformBlock(Block):
                 if all(ispan.nframe == 0 for ispan in ispans):
                     continue
 
-                cur_time = time.time()
+                cur_time = time.perf_counter()
                 acquire_time = cur_time - prev_time
                 prev_time = cur_time
 
@@ -1646,21 +1650,14 @@ class MultiTransformBlock(Block):
                     cur_igulps = [ispan.nframe for ispan in ispans]
                     ospans = self.reserve_spans(ospan_stack, oseqs,
                                                 cur_igulps)
-                    cur_time = time.time()
+                    cur_time = time.perf_counter()
                     reserve_time = cur_time - prev_time
                     prev_time = cur_time
 
                     if not force_skip:
                         faults.fire('block.on_data', self.name)
                         with self._compute_span(seq_id, gulp_index):
-                            if _tracing():
-                                with ScopedTracer(self.name +
-                                                  '/on_data'):
-                                    ostrides = self._on_data(ispans,
-                                                             ospans)
-                            else:
-                                ostrides = self._on_data(ispans,
-                                                         ospans)
+                            ostrides = self._on_data(ispans, ospans)
                         self._sync_gulp(ospans)
 
                     any_overwritten = any(ispan.nframe_overwritten
@@ -1691,7 +1688,7 @@ class MultiTransformBlock(Block):
                     for ospan in ospans:
                         ospan._ngulps = ngulps
                     self.commit_spans(ospans, ostrides, ogulp_overlaps)
-                cur_time = time.time()
+                cur_time = time.perf_counter()
                 process_time = cur_time - prev_time
                 prev_time = cur_time
                 gulp_index += 1
@@ -1784,12 +1781,12 @@ class TransformBlock(MultiTransformBlock):
         which dispatches ran N chips wide."""
         from .telemetry import profiling
         thunk = lambda: fn(*args)               # noqa: E731
-        if _spans.enabled() and self._shards_active > 1:
+        if self._shards_active > 1:
             span_args = {'shards': int(self._shards_active)}
             if self._trace_ctx is not None:
                 span_args['trace'] = self._trace_ctx.get('id')
-            with _spans.span('%s.dispatch' % self.name, 'mesh',
-                             **span_args):
+            with _spans.timed('%s.dispatch' % self.name, 'mesh',
+                              **span_args):
                 return profiling.profiled_dispatch(thunk)
         return profiling.profiled_dispatch(thunk)
 

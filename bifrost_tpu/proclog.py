@@ -195,13 +195,17 @@ class ProcLog(object):
             text = ''.join('%s : %s\n' % (k, v) for k, v in contents.items())
         else:
             text = str(contents)
-        try:
-            tmp = self.path + '.tmp'
-            with open(tmp, 'w') as f:
-                f.write(text)
-            os.replace(tmp, self.path)
-        except OSError:
-            pass
+        # a span: on a slow filesystem (anything but the default
+        # tmpfs) this is milliseconds on a block's own thread
+        from .telemetry import spans
+        with spans.timed('proclog.write', 'host', 'proclog.write_s'):
+            try:
+                tmp = self.path + '.tmp'
+                with open(tmp, 'w') as f:
+                    f.write(text)
+                os.replace(tmp, self.path)
+            except OSError:
+                pass
 
     def close(self):
         pass

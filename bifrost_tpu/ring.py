@@ -42,7 +42,6 @@ from __future__ import annotations
 import json
 import string
 import threading
-import time
 import weakref
 from copy import copy, deepcopy
 from functools import reduce
@@ -1748,31 +1747,39 @@ class WriteSpan(_SpanAPI):
         policy = getattr(ring, 'overload_policy', 'block')
         if nonblocking:
             policy = 'block'
-        t0 = time.perf_counter()
+        if ring._h_reserve is None:
+            ring._h_reserve = hist.get_or_create(
+                'ring.%s.reserve_s' % ring.name, unit='s')
         shed_nbyte = 0
-        try:
-            if policy == 'drop_oldest':
-                self._begin, shed_nbyte = ring._reserve_span_shed(
-                    self._nbyte, sequence.tensor['frame_nbyte'],
-                    span=self)
-            elif policy == 'drop_newest':
-                try:
-                    self._begin = ring._reserve_span(
-                        self._nbyte, True, span=self)
-                except WouldBlock:
-                    # shed THIS gulp: the writer computes into scratch
-                    # and the commit is counted instead of published
-                    self._shed = True
-                    self._begin = None
-            else:
-                self._begin = ring._reserve_span(self._nbyte,
-                                                 nonblocking,
-                                                 span=self)
-        except BaseException:
-            if rc is not None:
-                rc.reserve_abort(rc_tok)
-            raise
-        dt = time.perf_counter() - t0
+        with spans_.timed('%s.reserve' % ring.name, 'ring',
+                          ring._h_reserve) as tm:
+            try:
+                if policy == 'drop_oldest':
+                    self._begin, shed_nbyte = ring._reserve_span_shed(
+                        self._nbyte, sequence.tensor['frame_nbyte'],
+                        span=self)
+                elif policy == 'drop_newest':
+                    try:
+                        self._begin = ring._reserve_span(
+                            self._nbyte, True, span=self)
+                    except WouldBlock:
+                        # shed THIS gulp: the writer computes into
+                        # scratch and the commit is counted instead of
+                        # published
+                        self._shed = True
+                        self._begin = None
+                else:
+                    self._begin = ring._reserve_span(self._nbyte,
+                                                     nonblocking,
+                                                     span=self)
+            except BaseException:
+                if rc is not None:
+                    rc.reserve_abort(rc_tok)
+                raise
+            if not self._shed:
+                # identity across threads: the gulp follows from the
+                # span's first frame in its sequence
+                tm.args = {'frame': self.frame_offset}
         if self._shed:
             if rc is not None:
                 rc.reserve_abort(rc_tok)
@@ -1812,11 +1819,6 @@ class WriteSpan(_SpanAPI):
                                 (self._begin + self._nbyte -
                                  ring.total_span -
                                  sequence._seq.begin) // fb, 0))
-        if ring._h_reserve is None:
-            ring._h_reserve = hist.get_or_create(
-                'ring.%s.reserve_s' % ring.name, unit='s')
-        ring._h_reserve.record(dt)
-        spans_.record_elapsed('%s.reserve' % ring.name, 'ring', dt)
         with ring._lock:
             ring._open_wspans.append(self)
             ring._nwrite_open += 1
@@ -1974,15 +1976,20 @@ class ReadSpan(_SpanAPI):
         rc_tok = rc.acquire_enter(
             sequence, sequence._seq.begin + frame_offset * fb) \
             if rc is not None else None
-        t0 = time.perf_counter()
-        try:
-            begin, nbyte = self._ring._acquire_span(
-                sequence, frame_offset * fb, nframe * fb, fb)
-        except BaseException:
-            if rc is not None:
-                rc.acquire_abort(rc_tok)
-            raise
-        dt = time.perf_counter() - t0
+        ring = self._ring
+        if ring._h_acquire is None:
+            ring._h_acquire = hist.get_or_create(
+                'ring.%s.acquire_s' % ring.name, unit='s')
+        with spans_.timed('%s.acquire' % ring.name, 'ring',
+                          ring._h_acquire) as tm:
+            try:
+                begin, nbyte = ring._acquire_span(
+                    sequence, frame_offset * fb, nframe * fb, fb)
+            except BaseException:
+                if rc is not None:
+                    rc.acquire_abort(rc_tok)
+                raise
+            tm.args = {'frame': (begin - sequence._seq.begin) // fb}
         if rc is not None:
             rc_nbyte = nbyte
             if faults.armed('ring.corrupt.acquire_uncommitted',
@@ -1999,12 +2006,6 @@ class ReadSpan(_SpanAPI):
             # watermark bug) — the checker catches the overwriting
             # reserve the core now admits
             self._ring._corrupt_guarantee_jump(sequence)
-        ring = self._ring
-        if ring._h_acquire is None:
-            ring._h_acquire = hist.get_or_create(
-                'ring.%s.acquire_s' % ring.name, unit='s')
-        ring._h_acquire.record(dt)
-        spans_.record_elapsed('%s.acquire' % ring.name, 'ring', dt)
         self._begin, self._nbyte = begin, nbyte
         self.requested_frame_offset = frame_offset
         self.nframe_skipped = min(self.frame_offset - frame_offset, nframe)

@@ -418,12 +418,6 @@ class Supervisor(object):
         if not secs or secs <= 0:
             return None
         escalate = os.environ.get('BF_WATCHDOG_ESCALATE', '0') == '1'
-        # an armed watchdog turns on the span flight recorder (even
-        # without BF_TRACE_FILE): a stall report then carries the
-        # timeline of what was happening BEFORE everything stopped,
-        # not just where each thread is parked now
-        from .telemetry import spans
-        spans.enable_flight_recorder()
         self._watchdog = _Watchdog(self, float(secs), escalate)
         self._watchdog.start()
         return self._watchdog
@@ -432,10 +426,6 @@ class Supervisor(object):
         if self._watchdog is not None:
             self._watchdog.stop()
             self._watchdog = None
-            # release this run's flight-recorder hold (refcounted, so
-            # a concurrently armed pipeline keeps recording)
-            from .telemetry import spans
-            spans.disable_flight_recorder()
 
 
 class HealthMonitor(threading.Thread):

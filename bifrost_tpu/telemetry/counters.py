@@ -207,6 +207,13 @@ Distributed-observability counters (docs/observability.md
                                            — synthesized into
                                            ``telemetry.snapshot()``
                                            from the live buffers
+- ``jit.compiles``                         compilations and loads from
+                                           the persistent cache
+                                           (jax.monitoring
+                                           backend-compile events,
+                                           telemetry.spans.watch_jax);
+                                           one inside a steady window
+                                           is a stall to explain
 - ``jaxprof.captures``                     one-shot BF_JAX_PROFILE
                                            gulp captures taken
                                            (telemetry.profiling)

@@ -10,9 +10,9 @@ live view:
   CUMULATIVE (last-value semantics) and a FULL snapshot is re-sent
   every ``BF_FLEET_FULL_EVERY`` publishes, so a restarted collector
   re-adopts a live publisher without double-counting anything.  The
-  publisher arms the span flight recorder while it runs and answers
-  two collector requests on its own socket: ``need_full`` (resync)
-  and ``flight_request`` (incident capture).
+  publisher answers two collector requests on its own socket:
+  ``need_full`` (resync) and ``flight_request`` (incident capture,
+  from the always-on span recorder).
 
 - :class:`FleetCollector` — binds one UDP port (the same control-port
   plumbing the fabric heartbeats use), maintains a per-host rollup
@@ -226,15 +226,9 @@ class FleetPublisher(threading.Thread):
         self._last_counters = {}
         self._last_hist_counts = {}
         self._need_full = True
-        self._flight_armed = False
 
     # -- lifecycle ---------------------------------------------------------
     def start(self):
-        # the fleet plane wants a flight record from every member, so
-        # publishing arms the span recorder (refcounted — paired in
-        # stop(); a configured BF_TRACE_FILE keeps its own hold)
-        spans.enable_flight_recorder()
-        self._flight_armed = True
         # health escalations stream as immediate out-of-band events
         # (the collector's incident trigger), not at snapshot cadence
         try:
@@ -263,9 +257,6 @@ class FleetPublisher(threading.Thread):
             self.publish(full=True, final=True)
         except Exception:
             pass
-        if self._flight_armed:
-            self._flight_armed = False
-            spans.disable_flight_recorder()
         if getattr(self, '_escalation_watch', False):
             try:
                 from .. import supervision

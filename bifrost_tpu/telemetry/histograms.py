@@ -22,14 +22,31 @@ and operators may add their own):
                                  control (acquire + reserve)
 - ``ring.<ring>.reserve_s``      writer-side span reservation time
 - ``ring.<ring>.acquire_s``      reader-side span acquisition time
-- ``xfer.h2d_s`` / ``xfer.d2h_wait_s``  host-side transfer time
+- ``block.<block>.sync_wait_s``   the dispatch-ahead wait for the device
+- ``xfer.h2d_s`` / ``xfer.d2h_wait_s``  host-side transfer time, and its
+                                 parts: ``xfer.h2d_stage_s`` (staging
+                                 copy), ``xfer.h2d_put_s`` (device_put);
+                                 ``xfer.d2h_ready_s`` (device and DMA
+                                 remainder), ``xfer.d2h_asarray_s``,
+                                 ``xfer.d2h_convert_s``
+- ``xfer.d2h_fill_s``            the copy of a product into its host
+                                 ring span (deferred fills)
+- ``xfer.d2h_peer_wait_s``       waiting for a peer thread to finish
+                                 the same transfer
 - ``xfer.h2d_nbytes`` / ``xfer.d2h_nbytes``  transfer sizes
+- ``jit.compile_s``              compilations and persistent-cache
+                                 loads (jax.monitoring backend-compile
+                                 events)
 - ``slo.<block>.commit_age_s``   capture -> block-commit data age
                                  (telemetry.slo; needs a trace-context
                                  origin in the sequence header)
 - ``slo.<block>.exit_age_s`` / ``slo.exit_age_s``  capture ->
                                  pipeline-exit age per sink / merged
                                  (the capture-to-commit SLO p50/p99)
+
+Every duration above but the two ``block.<block>`` loop times is
+recorded by the span of the same site, from the same two stamps
+(``spans.timed``; docs/observability.md has the table).
 
 Percentiles are bucket UPPER bounds clamped to the observed min/max:
 an estimate, monotone in ``p`` by construction (the exporter tests

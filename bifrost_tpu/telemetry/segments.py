@@ -48,13 +48,13 @@ def note_dispatch(segment, members, ndispatches, ngulps, t0_us,
     (``ndispatches`` > 1 when the auto-tuner split the segment into
     sequential sub-programs) and synthesize the members' telemetry
     from it.  Called from ``SegmentBlock.on_data`` — must stay cheap:
-    a handful of counter increments, plus span/SLO work only when
-    those layers are armed."""
+    a handful of counter increments and one span per member, plus
+    SLO work only when the header carries an origin."""
     counters.inc('segment.dispatches', ndispatches)
     counters.inc('segment.gulps', ngulps)
     for m in members:
         counters.inc('block.%s.gulps' % m, ngulps)
-    if members and spans.enabled():
+    if members:
         slot = dur_us / len(members)
         for i, m in enumerate(members):
             args = {'seq': seq, 'gulp': gulp, 'segment': segment,

@@ -1,76 +1,93 @@
-"""Gulp-span tracing: per-thread event buffers, Chrome trace-event
-export, and the watchdog flight recorder.
+"""Gulp-span tracing: one always-on recorder of per-thread event
+buffers, the Chrome trace-event export, and the flight recorder.
 
 The reference answers "where does a gulp spend its time?" with NVTX
 ranges rendered by nsight (reference: src/trace.hpp ScopedTracer); this
-module is the portable equivalent.  Every instrumented operation —
-block compute (``pipeline.py``), ring reserve/acquire blocked time
-(``ring.py``, both cores), H2D/D2H transfer time (``xfer.py``) —
-records one COMPLETE span (name, category, start, duration, args) into
-a bounded per-thread buffer: recording takes no lock (the buffer is
-``threading.local``), so tracing stays cheap enough for the gulp hot
-path (see the overhead gate in ``tools/watch_and_bench.sh``).
+module is the portable equivalent and the package's ONE timing
+mechanism.  Every instrumented operation -- block compute and the
+dispatch-ahead wait (``pipeline.py``), ring reserve/acquire blocked
+time (``ring.py``, both cores), the parts of H2D and D2H
+(``xfer.py``), compilations and full garbage collections (this
+module) -- records one COMPLETE span (name, category, start, duration,
+args) into a bounded per-thread buffer.  Recording has no switch: the
+site already takes two ``perf_counter`` stamps for its always-on
+histogram, and appending one tuple to the thread's ``deque`` (no lock:
+the buffer is ``threading.local``) is the whole added cost, 1-2 us.
 
-Two consumers share the buffers:
+One call feeds both sinks::
 
-- **Chrome trace export** — ``BF_TRACE_FILE=trace.json`` makes
+    with spans.timed('h2d.put', 'xfer', hist='xfer.h2d_put_s'):
+        ...
+
+takes the two stamps once and records the histogram and the span with
+the same duration, so a site's span durations sum to its histogram's
+sum.  Parentage is by nesting on the thread (a child lies inside its
+parent's interval; self time = duration - children); across threads a
+gulp is followed by ``seq``/``gulp`` (compute spans) and ``frame``
+(ring spans: first frame of the span in its sequence).
+
+Consumers of the buffers:
+
+- **Chrome trace export** -- ``BF_TRACE_FILE=trace.json`` makes
   ``Pipeline.run`` write a Chrome trace-event JSON on exit (one track
   per block thread), loadable in Perfetto / ``chrome://tracing``.
-  Compute spans carry ``{'seq': sequence, 'gulp': index}`` args, so a
-  gulp can be followed across blocks.
+- **flight recorder** -- on a stall the watchdog dumps the most recent
+  spans of every thread as a text timeline next to the thread stacks
+  (supervision.py); the fleet publisher ships the same
+  (``flight_events``).  They always have history.
+- **the benchmark** -- ``perfbench/progspans.py`` reads :func:`events`
+  after a run and lays them over the device trace.
 
-- **flight recorder** — when the stall watchdog is armed the buffers
-  record even without a trace file; on a stall the watchdog dumps the
-  most recent spans of every thread as a text timeline next to the
-  thread stacks (supervision.py), so a stall report shows WHAT was
-  happening before everything stopped, not just where each thread is
-  parked now.
+The clock is ``time.perf_counter()`` less :func:`origin_s`, in
+microseconds; the export writes the origin under
+``otherData.bf_clock.origin_s``, so one ``perf_counter`` stamp tied to
+a ``jax.profiler`` trace (an anchor program, as ``perfbench`` does)
+lays this trace over that one.
 
-``BF_SPAN_BUFFER`` bounds events kept per thread (default 65536; the
-buffer is a ring — oldest events fall off, which is exactly the flight
-recorder semantic).  Timestamps are microseconds on the
-``time.perf_counter`` clock, relative to process start.
+``BF_SPAN_BUFFER`` bounds events kept per thread (default 16384; the
+buffer is a ring -- oldest events fall off and are counted, which is
+the flight-recorder semantic).  It applies to threads that record
+their first span after it is read (``Pipeline.run`` re-reads it before
+it starts the block threads).
 """
 
 from __future__ import annotations
 
+import gc
 import json
 import os
 import threading
 import time
 from collections import deque
 
-__all__ = ['enabled', 'trace_file', 'span', 'record',
-           'record_elapsed', 'now_us', 'configure', 'reconfigure',
-           'enable_flight_recorder', 'disable_flight_recorder',
+from . import counters, histograms
+
+__all__ = ['trace_file', 'timed', 'record', 'now_us', 'origin_s',
+           'configure', 'reconfigure', 'watch_jax',
            'export', 'export_if_configured', 'flight_record',
            'flight_events', 'prune_dead_buffers', 'reset', 'events',
-           'dropped_spans',
+           'dropped_spans', 'dropped_by_thread',
            'note_peer_clock', 'clock_info']
 
-DEFAULT_BUFFER = 65536
-#: per-thread buffer size in flight-recorder-only mode (no trace
-#: file): the only consumer reads the last ~32 spans per thread, so a
-#: full-size export buffer would be pure waste
-FLIGHT_BUFFER = 256
+#: a 30 s window of the busiest benchmark cell needs about 8 k on its
+#: one thread; 65536 always-on would hold hundreds of MB over many
+#: threads
+DEFAULT_BUFFER = 16384
 #: dead-thread buffers kept for export before the oldest are pruned
 MAX_BUFFERS = 512
 
-_t0 = time.perf_counter()
+_perf_counter = time.perf_counter
+_t0 = _perf_counter()
 
 _config_lock = threading.Lock()
 _configured = False
 _trace_file = None
 _buf_cap = DEFAULT_BUFFER
-_flight = 0              # recorder-only refcount (armed watchdogs)
-_enabled = False
-#: configuration generation — bumped on every (re)configure and
-#: flight-recorder toggle so live threads rebuild their buffers with
-#: the current capacity instead of keeping a stale maxlen forever
-_gen = 0
 
 _tls = threading.local()
-_buffers_lock = threading.Lock()
+#: RLock: the gc callback below records from inside whatever the
+#: collecting thread was doing, which may be a locked region here
+_buffers_lock = threading.RLock()
 _buffers = []            # [(threading.Thread, deque, drops:[int])]
 #: drop counts inherited from PRUNED (dead-thread) buffers, so
 #: ``dropped_spans`` stays monotonic across Pipeline.run's
@@ -86,15 +103,22 @@ _clock_lock = threading.Lock()
 _sessions = {}           # session -> {'role', 'offset_us', 'rtt_us'}
 
 
+def origin_s():
+    """The ``time.perf_counter()`` value at span time 0: a span's
+    ``ts_us * 1e-6 + origin_s()`` is its start on the clock every
+    ``perf_counter`` stamp in the process shares."""
+    return _t0
+
+
 def now_us():
-    """Microseconds since process start on the span clock."""
-    return (time.perf_counter() - _t0) * 1e6
+    """Microseconds since :func:`origin_s` on the span clock."""
+    return (_perf_counter() - _t0) * 1e6
 
 
 def configure():
     """Read ``BF_TRACE_FILE`` / ``BF_SPAN_BUFFER`` (first call only;
     use :func:`reconfigure` to force a re-read)."""
-    global _configured, _trace_file, _buf_cap, _enabled, _gen
+    global _configured, _trace_file, _buf_cap
     with _config_lock:
         if _configured:
             return
@@ -104,50 +128,17 @@ def configure():
                                or DEFAULT_BUFFER), 16)
         except ValueError:
             _buf_cap = DEFAULT_BUFFER
-        _enabled = bool(_trace_file) or _flight > 0
-        _gen += 1
         _configured = True
 
 
 def reconfigure():
     """Re-read the environment (tests / long-lived operator processes
-    toggling tracing without a restart — also reached via
-    ``bifrost_tpu.trace.reset()``)."""
+    pointing the export elsewhere without a restart — also reached
+    via ``bifrost_tpu.trace.reset()``)."""
     global _configured
     with _config_lock:
         _configured = False
     configure()
-
-
-def enable_flight_recorder():
-    """Turn span recording on without a trace file (the watchdog's
-    flight recorder — supervision.Supervisor.start_watchdog).
-    Refcounted: pair every call with :func:`disable_flight_recorder`
-    so a long-lived process is not left recording forever after one
-    watchdog-armed run."""
-    global _flight, _enabled, _gen
-    with _config_lock:
-        _flight += 1
-        _enabled = True
-        _gen += 1
-
-
-def disable_flight_recorder():
-    """Drop one flight-recorder hold (supervision.stop_watchdog);
-    recording stays on while any watchdog is armed or a trace file is
-    configured.  Already-buffered events remain readable."""
-    global _flight, _enabled, _gen
-    with _config_lock:
-        _flight = max(_flight - 1, 0)
-        _enabled = bool(_trace_file) or _flight > 0
-        _gen += 1
-
-
-def enabled():
-    """Whether spans are being recorded (cheap hot-path check)."""
-    if not _configured:
-        configure()
-    return _enabled
 
 
 def trace_file():
@@ -157,31 +148,19 @@ def trace_file():
 
 
 def _buf():
-    old = getattr(_tls, 'buf', None)
-    if old is not None and getattr(_tls, 'gen', None) == _gen:
-        return old, _tls.drops
-    # (re)build this thread's buffer at the CURRENT capacity: flight-
-    # recorder-only mode needs just the recent tail, a configured
-    # trace file gets the full export buffer — and a reconfigure must
-    # apply to threads that outlive it (the long-lived-process toggle
-    # flow), so stale-generation buffers are migrated, keeping their
-    # newest events
-    cap = _buf_cap if _trace_file else min(_buf_cap, FLIGHT_BUFFER)
-    b = deque(old if old is not None else (), maxlen=cap)
-    drops = getattr(_tls, 'drops', None)
-    if drops is None:
-        # a one-int list, shared by reference with the registry so the
-        # owning thread bumps it lock-free and readers see it
-        drops = [0]
+    b = getattr(_tls, 'buf', None)
+    if b is not None:
+        return b, _tls.drops
+    if not _configured:
+        configure()
+    b = deque(maxlen=_buf_cap)
+    # a one-int list, shared by reference with the registry so the
+    # owning thread bumps it lock-free and readers see it
+    drops = [0]
     _tls.buf = b
-    _tls.gen = _gen
     _tls.drops = drops
     t = threading.current_thread()
     with _buffers_lock:
-        if old is not None:
-            # same thread's buffer migrating to a new capacity: its
-            # drops list is carried over, so no retired accumulation
-            _buffers[:] = [e for e in _buffers if e[1] is not old]
         if len(_buffers) >= MAX_BUFFERS:
             # prune every dead thread's buffer so a long-lived
             # process running many pipelines cannot accumulate
@@ -209,7 +188,7 @@ def _append(ev):
     as 'nothing happened before this' (the ``trace.dropped_spans``
     counter in ``telemetry.snapshot()`` says otherwise)."""
     b, drops = _buf()
-    if b.maxlen is not None and len(b) >= b.maxlen:
+    if len(b) >= b.maxlen:
         drops[0] += 1
     b.append(ev)
 
@@ -222,6 +201,17 @@ def dropped_spans():
     often when this grows)."""
     with _buffers_lock:
         return _dropped_retired + sum(e[2][0] for e in _buffers)
+
+
+def dropped_by_thread():
+    """``{thread name: spans its live buffer has evicted}``, for a
+    reader that must know whether a thread's oldest history is whole
+    (``perfbench/progspans.py``)."""
+    with _buffers_lock:
+        out = {}
+        for t, _b, d in _buffers:
+            out[t.name] = out.get(t.name, 0) + d[0]
+        return out
 
 
 def _drain(buf):
@@ -244,21 +234,11 @@ def _drain(buf):
 
 
 def record(name, cat, ts_us, dur_us, args=None):
-    """Record one complete span (timestamps from :func:`now_us`).
-    No-op when recording is disabled."""
-    if not enabled():
-        return
+    """Record one complete span from span-clock stamps
+    (:func:`now_us`): for events whose interval is known only after
+    the fact (a listener's callback, a synthesized member span).
+    Instrumentation sites use :class:`timed`."""
     _append((name, cat, ts_us, dur_us, args))
-
-
-def record_elapsed(name, cat, dt_s, **args):
-    """Record a span that ends NOW and lasted ``dt_s`` seconds — the
-    one-liner for instrumentation sites that already timed an
-    operation with ``time.perf_counter`` (ring waits, transfers)."""
-    if not enabled():
-        return
-    dur = dt_s * 1e6
-    _append((name, cat, now_us() - dur, dur, args or None))
 
 
 def prune_dead_buffers():
@@ -311,44 +291,55 @@ def note_peer_clock(session, role, offset_us=None, rtt_us=None,
 
 def clock_info():
     """This process's clock-correlation metadata for the trace export:
-    host/pid plus every bridge session seen (and, sender side, the
-    offset estimate)."""
+    host/pid, the span clock's origin (:func:`origin_s`) plus every
+    bridge session seen (and, sender side, the offset estimate)."""
     import socket as socket_mod
     with _clock_lock:
         sessions = {k: dict(v) for k, v in _sessions.items()}
     return {'host': socket_mod.gethostname(), 'pid': os.getpid(),
-            'sessions': sessions}
+            'origin_s': _t0, 'sessions': sessions}
 
 
-class span(object):
-    """With-block recording one complete span::
+class timed(object):
+    """The site API: a with-block that takes two ``perf_counter``
+    stamps and feeds both sinks with the one duration::
 
-        with spans.span('fft.on_data', 'compute', seq=0, gulp=3):
+        with spans.timed('fft.on_data', 'compute', seq=0, gulp=3):
+            ...
+        with spans.timed('d2h.fill', 'xfer', hist='xfer.d2h_fill_s',
+                         bytes=n):
             ...
 
-    The span closes (and is recorded) on ANY exit — exceptions from
-    fault injection or real failures still produce a complete,
-    correctly nested event, which is what makes the flight recorder
-    trustworthy around crashes."""
+    ``hist`` is a histogram's name or the :class:`Histogram` itself (a
+    hot path caches it); None records the span alone.  Both are
+    recorded on ANY exit -- exceptions from fault injection or real
+    failures still produce a complete, correctly nested event, which
+    is what makes the flight recorder trustworthy around crashes.
+    ``args`` may be set or added to inside the block (a ring span
+    learns its ``frame`` only once the call returns)."""
 
-    __slots__ = ('name', 'cat', 'args', 't0')
+    __slots__ = ('name', 'cat', 'hist', 'args', 't0')
 
-    def __init__(self, name, cat='', **args):
+    def __init__(self, name, cat='', hist=None, **args):
         self.name = name
         self.cat = cat
+        self.hist = hist
         self.args = args or None
-        self.t0 = None
 
     def __enter__(self):
-        if enabled():
-            self.t0 = now_us()
+        self.t0 = _perf_counter()
         return self
 
     def __exit__(self, *exc):
-        if self.t0 is not None:
-            t1 = now_us()
-            _append((self.name, self.cat, self.t0,
-                     t1 - self.t0, self.args))
+        t0 = self.t0
+        dt = _perf_counter() - t0
+        hist = self.hist
+        if hist is not None:
+            if hist.__class__ is str:
+                hist = histograms.get_or_create(hist, unit='s')
+            hist.record(dt)
+        _append((self.name, self.cat, (t0 - _t0) * 1e6, dt * 1e6,
+                 self.args))
         return False
 
 
@@ -449,8 +440,8 @@ def flight_record(per_thread=32):
         for ev in _drain(buf)[-per_thread:]:
             merged.append((ev[2], tname, ev))
     if not merged:
-        return ('=== flight recorder: no spans recorded '
-                '(tracing/flight recording was off) ===')
+        return '=== flight recorder: no spans recorded ==='
+
     merged.sort(key=lambda e: e[0])
     lines = ['=== flight recorder: last %d span(s)/thread, '
              'oldest first ===' % per_thread]
@@ -487,6 +478,73 @@ def flight_events(per_thread=64):
                         round(ts, 3), round(dur, 3), args])
     out.sort(key=lambda e: e[3])
     return out
+
+
+# ---------------------------------------------------------------------------
+# what the process does behind the program's back: compiling, collecting
+# ---------------------------------------------------------------------------
+
+#: the jax.monitoring duration events kept, under their span names.
+#: The backend-compile event brackets the whole of compile-or-load, so
+#: it alone feeds ``jit.compile_s`` / ``jit.compiles``; a persistent
+#: cache hit's retrieval event nests inside it as a span of its own
+_JIT_EVENTS = {
+    '/jax/core/compile/backend_compile_duration': 'backend_compile',
+    '/jax/compilation_cache/cache_retrieval_time_sec': 'cache_retrieval',
+}
+_jax_watched = False
+
+
+def _on_jax_duration(event, duration_secs, **kwargs):
+    what = _JIT_EVENTS.get(event)
+    if what is None:
+        return
+    # the callback runs on the compiling thread as the work ends
+    dur = duration_secs * 1e6
+    args = {'event': what}
+    if kwargs.get('fun_name'):
+        args['fun'] = str(kwargs['fun_name'])
+    if what == 'backend_compile':
+        histograms.get_or_create('jit.compile_s', unit='s') \
+            .record(duration_secs)
+        counters.inc('jit.compiles')
+    _append(('jit.compile', 'jit', now_us() - dur, dur, args))
+
+
+def watch_jax():
+    """Register the one ``jax.monitoring`` listener behind the
+    ``jit.compile`` spans (idempotent).  Called where the package
+    first needs JAX (``Pipeline.run``, the transfer engine): importing
+    the package alone must not import JAX."""
+    global _jax_watched
+    with _config_lock:
+        if _jax_watched:
+            return
+        from jax import monitoring
+        monitoring.register_event_duration_secs_listener(
+            _on_jax_duration)
+        _jax_watched = True
+
+
+_gc_tls = threading.local()
+
+
+def _on_gc(phase, info):
+    """``gc.callbacks`` entry: a full (generation 2) collection holds
+    the interpreter lock for tens of ms in a process holding many
+    objects: a ``host.gc`` span on the thread that triggered it."""
+    if info.get('generation') != 2:
+        return
+    if phase == 'start':
+        _gc_tls.t0 = now_us()
+    else:
+        t0 = getattr(_gc_tls, 't0', None)
+        if t0 is not None:
+            _gc_tls.t0 = None
+            _append(('host.gc', 'host', t0, now_us() - t0, {'gen': 2}))
+
+
+gc.callbacks.append(_on_gc)
 
 
 def reset():

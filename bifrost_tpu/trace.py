@@ -1,91 +1,22 @@
-"""Tracing/profiling hooks.
+"""Re-reading the tracing configuration without a restart.
 
 The reference wraps NVTX ranges around block operations so nsight shows
-per-op spans (reference: src/trace.hpp:48-179, --enable-trace).  The
-TPU-native equivalents are jax.profiler trace annotations (visible in
-xprof/TensorBoard) plus simple wall-clock scopes; enable by setting
-``BF_TRACE=1`` (mirrors the reference's compile-time flag with an env
-var).
+per-op spans (reference: src/trace.hpp:48-179, --enable-trace).  Here
+that is :mod:`bifrost_tpu.telemetry.spans`, the one span recorder,
+always on; this module keeps the documented entry point that tests and
+long-lived operator processes use to apply a changed environment.
 """
 
 from __future__ import annotations
 
-import os
-import time
-from contextlib import contextmanager
-
-__all__ = ['tracing_enabled', 'reset', 'ScopedTracer', 'trace_scope',
-           'start_profile', 'stop_profile']
-
-_enabled = None
-
-
-def tracing_enabled():
-    global _enabled
-    if _enabled is None:
-        _enabled = bool(int(os.environ.get('BF_TRACE', '0') or 0))
-    return _enabled
+__all__ = ['reset']
 
 
 def reset():
-    """Forget the cached ``BF_TRACE`` state so the next
-    :func:`tracing_enabled` re-reads the environment, and re-read the
-    gulp-span configuration (``BF_TRACE_FILE`` / ``BF_SPAN_BUFFER`` —
-    :mod:`bifrost_tpu.telemetry.spans`) plus the ``BF_SLO_MS`` latency
-    budget (:mod:`bifrost_tpu.telemetry.slo`).  Lets tests and
-    long-lived operator processes toggle tracing without a restart;
-    ``Pipeline.run`` re-reads the span config on every run anyway."""
-    global _enabled
-    _enabled = None
-    try:
-        from .telemetry import spans, slo
-        spans.reconfigure()
-        slo.reset_budget()
-    except Exception:
-        pass
-
-
-class ScopedTracer(object):
-    """With-block trace range (reference: ScopedTracer,
-    src/trace.hpp:126-179)."""
-
-    def __init__(self, name):
-        self.name = name
-        self._ctx = None
-        self.t0 = None
-        self.elapsed = None
-
-    def __enter__(self):
-        self.t0 = time.perf_counter()
-        if tracing_enabled():
-            try:
-                import jax.profiler
-                self._ctx = jax.profiler.TraceAnnotation(self.name)
-                self._ctx.__enter__()
-            except Exception:
-                self._ctx = None
-        return self
-
-    def __exit__(self, *exc):
-        self.elapsed = time.perf_counter() - self.t0
-        if self._ctx is not None:
-            self._ctx.__exit__(*exc)
-        return False
-
-
-@contextmanager
-def trace_scope(name):
-    with ScopedTracer(name) as t:
-        yield t
-
-
-def start_profile(logdir='/tmp/bifrost_tpu_profile'):
-    """Start an xprof capture (view with TensorBoard)."""
-    import jax.profiler
-    jax.profiler.start_trace(logdir)
-    return logdir
-
-
-def stop_profile():
-    import jax.profiler
-    jax.profiler.stop_trace()
+    """Re-read the gulp-span configuration (``BF_TRACE_FILE`` /
+    ``BF_SPAN_BUFFER`` — :mod:`bifrost_tpu.telemetry.spans`) and the
+    ``BF_SLO_MS`` latency budget (:mod:`bifrost_tpu.telemetry.slo`).
+    ``Pipeline.run`` re-reads both on every run anyway."""
+    from .telemetry import spans, slo
+    spans.reconfigure()
+    slo.reset_budget()

@@ -58,9 +58,9 @@ from __future__ import annotations
 
 import os
 import threading
-import time
 import weakref
 from collections import deque
+from contextlib import contextmanager
 
 import numpy as np
 
@@ -110,6 +110,33 @@ def _obs():
         from .telemetry import histograms, spans
         _obs_mods = (histograms, spans)
     return _obs_mods
+
+
+def _timed(name, cat, hist=None, **args):
+    """``spans.timed``: one span and one histogram observation from
+    the same two stamps (telemetry/spans.py)."""
+    return _obs()[1].timed(name, cat, hist, **args)
+
+
+def _first(host):
+    """The identity conversion of a one-array readback."""
+    return host[0]
+
+
+@contextmanager
+def _held(lock):
+    """``with lock``, for a transfer's lock.  Where a peer thread holds
+    it, it is completing this very transfer (a reader, the depth bound
+    and every block's per-gulp drain race for the same fill), and this
+    thread sits out the whole of it: a span, so that the wait is
+    nobody's unexplained stall."""
+    if not lock.acquire(False):
+        with _timed('d2h.peer_wait', 'wait', 'xfer.d2h_peer_wait_s'):
+            lock.acquire()
+    try:
+        yield
+    finally:
+        lock.release()
 
 
 def _env_int(name, default):
@@ -343,32 +370,41 @@ class TransferFuture(object):
             return True            # invalid: result() will raise
 
     def result(self):
-        with self._lock:
+        with _held(self._lock):
             if self._done:
                 if self._error is not None:
                     raise self._error
                 return self._result
-            hist, spans = _obs()
-            t0 = time.perf_counter()
-            try:
-                faults.fire('xfer.result')
-                if not all(a.is_deleted() or a.is_ready()
-                           for a in self._arrays):
-                    _counters().inc('xfer.sync_waits')
-                host = [np.asarray(a) for a in self._arrays]
-                self._result = self._convert(host)
-            except Exception as exc:
-                self._error = exc
-                self._done = True
-                self._arrays = []
-                _counters().inc('xfer.errors')
-                raise
-            # D2H completion time as seen by the host (residual wait on
-            # the in-flight remainder + conversion)
-            dt = time.perf_counter() - t0
-            hist.observe('xfer.d2h_wait_s', dt)
-            spans.record_elapsed('d2h', 'xfer', dt,
-                                 bytes=self._nbytes)
+            from jax import block_until_ready
+            # D2H completion as the host sees it, in its parts: the
+            # wait for the device and the DMA's remainder, the copy
+            # out of the runtime's buffer, the conversion
+            with _timed('d2h', 'xfer', 'xfer.d2h_wait_s',
+                        bytes=self._nbytes):
+                try:
+                    faults.fire('xfer.result')
+                    live = [a for a in self._arrays
+                            if not a.is_deleted()]
+                    if not all(a.is_ready() for a in live):
+                        _counters().inc('xfer.sync_waits')
+                    with _timed('d2h.ready', 'wait',
+                                'xfer.d2h_ready_s'):
+                        block_until_ready(live)
+                    with _timed('d2h.asarray', 'xfer',
+                                'xfer.d2h_asarray_s'):
+                        host = [np.asarray(a) for a in self._arrays]
+                    if self._convert is _first:
+                        self._result = host[0]
+                    else:
+                        with _timed('d2h.convert', 'xfer',
+                                    'xfer.d2h_convert_s'):
+                            self._result = self._convert(host)
+                except Exception as exc:
+                    self._error = exc
+                    self._done = True
+                    self._arrays = []
+                    _counters().inc('xfer.errors')
+                    raise
             self._done = True
             self._arrays = []      # drop device refs promptly
             return self._result
@@ -440,7 +476,7 @@ class HostFill(object):
         """Complete the fill: block on the transfer, convert into the
         span's host view, then redo the ghost mirror for wrapped
         spans (the commit-time mirror ran before the bytes landed)."""
-        with self._lock:
+        with _held(self._lock):
             if self.done:
                 if self.error is not None:
                     raise self.error
@@ -448,10 +484,14 @@ class HostFill(object):
             try:
                 host = self.future.result()
                 from .devrep import from_device_rep
-                from_device_rep(host, self.dtype, self.out)
-                if self._storage is not None and self.nbyte:
-                    self._storage.fill_ghost_mirror(self.begin,
-                                                    self.nbyte)
+                # the copy into the ring span: paid by whichever
+                # thread needs the bytes first
+                with _timed('d2h.fill', 'xfer', 'xfer.d2h_fill_s',
+                            bytes=int(getattr(self.out, 'nbytes', 0))):
+                    from_device_rep(host, self.dtype, self.out)
+                    if self._storage is not None and self.nbyte:
+                        self._storage.fill_ghost_mirror(self.begin,
+                                                        self.nbyte)
             except Exception as exc:
                 self.done = True
                 self.error = exc
@@ -482,6 +522,7 @@ class TransferEngine(object):
         self._fills = deque()       # HostFills (host_fill)
         self._lock = threading.Lock()
         _tune_allocator()
+        _obs()[1].watch_jax()
 
     def _is_zero_copy(self):
         if self._zero_copy is not None:
@@ -490,11 +531,15 @@ class TransferEngine(object):
 
     # -- H2D ---------------------------------------------------------------
     def _put(self, arr, device):
+        """``device_put`` until it returns: the runtime's own host-side
+        work (its layout conversion, on the caller's thread or its
+        own) ends somewhere after."""
         import jax
         import jax.numpy as jnp
-        if device is not None:
-            return jax.device_put(arr, device)
-        return jnp.asarray(arr)
+        with _timed('h2d.put', 'xfer', 'xfer.h2d_put_s'):
+            if device is not None:
+                return jax.device_put(arr, device)
+            return jnp.asarray(arr)
 
     def _stage_ship(self, shape, dtype, nbytes, fill, device):
         """The ONE copy of the staging-slot ship protocol (shared by
@@ -513,7 +558,9 @@ class TransferEngine(object):
             slot = self._pool.acquire(shape, dtype)
         if slot is not None:
             try:
-                fill(slot.buf)
+                with _timed('h2d.stage', 'xfer', 'xfer.h2d_stage_s',
+                            staged=1):
+                    fill(slot.buf)
                 out = self._put(slot.buf, device)
             except Exception:
                 # no device array ever saw the buffer: return the slot
@@ -522,8 +569,12 @@ class TransferEngine(object):
             self._pool.bind(slot, out)
             c.inc('xfer.h2d_staged')
         else:
-            staged = _alloc_aligned(shape, dtype)
-            fill(staged)
+            # the pool was exhausted (acquire never waits), or the
+            # backend aliases host memory: a fresh buffer
+            with _timed('h2d.stage', 'xfer', 'xfer.h2d_stage_s',
+                        staged=0):
+                staged = _alloc_aligned(shape, dtype)
+                fill(staged)
             out = self._put(staged, device)
             c.inc('xfer.h2d_unstaged')
         c.inc('xfer.h2d_issued')
@@ -588,13 +639,18 @@ class TransferEngine(object):
                     # one; the flag records whether this slot's DMA
                     # was ever issued
                     slots.append([slot, False])
-                    np.copyto(slot.buf, piece, casting='no')
+                    with _timed('h2d.stage', 'xfer',
+                                'xfer.h2d_stage_s', staged=1):
+                        np.copyto(slot.buf, piece, casting='no')
                     shard_arrays.append(self._put(slot.buf, dev))
                     slots[-1][1] = True
                     c.inc('xfer.h2d_staged')
                 else:
-                    staged = _alloc_aligned(piece.shape, piece.dtype)
-                    np.copyto(staged, piece, casting='no')
+                    with _timed('h2d.stage', 'xfer',
+                                'xfer.h2d_stage_s', staged=0):
+                        staged = _alloc_aligned(piece.shape,
+                                                piece.dtype)
+                        np.copyto(staged, piece, casting='no')
                     shard_arrays.append(self._put(staged, dev))
                     c.inc('xfer.h2d_unstaged')
                 c.inc('xfer.h2d_issued')
@@ -617,7 +673,6 @@ class TransferEngine(object):
             self._pool.bind(slot, out)
         c.inc('xfer.h2d_sharded')
         c.inc('xfer.h2d_shard_bytes', shard_bytes)
-        _obs()[0].observe('xfer.h2d_shard_nbytes', shard_bytes)
         return out
 
     def _stage_real(self, arr, device):
@@ -664,27 +719,27 @@ class TransferEngine(object):
             from .device import get_bound_device
             device = get_bound_device()
         arr = np.asarray(arr)
-        hist, spans = _obs()
-        t0 = time.perf_counter()
-        if np.iscomplexobj(arr):
-            ft = np.float64 if arr.dtype == np.complex128 else np.float32
-            # plane extraction copies into fresh buffers the caller
-            # never sees — already alias-safe without staging
-            re = np.ascontiguousarray(arr.real, dtype=ft)
-            im = np.ascontiguousarray(arr.imag, dtype=ft)
+        # host-side transfer time (staging copy + async device_put
+        # issue) and transfer-size distribution
+        _obs()[0].observe('xfer.h2d_nbytes', int(arr.nbytes))
+        with _timed('h2d', 'xfer', 'xfer.h2d_s', bytes=int(arr.nbytes)):
+            if not np.iscomplexobj(arr):
+                return self._stage_real(arr, device)
+            re, im = self._planes(arr)
             c = _counters()
             c.inc('xfer.h2d_issued')
             c.inc('xfer.h2d_bytes', int(arr.nbytes))
-            out = _combine(self._put(re, device), self._put(im, device))
-        else:
-            out = self._stage_real(arr, device)
-        # host-side transfer time (staging copy + async device_put
-        # issue) and transfer-size distribution
-        dt = time.perf_counter() - t0
-        hist.observe('xfer.h2d_s', dt)
-        hist.observe('xfer.h2d_nbytes', int(arr.nbytes))
-        spans.record_elapsed('h2d', 'xfer', dt, bytes=int(arr.nbytes))
-        return out
+            return _combine(self._put(re, device), self._put(im, device))
+
+    @staticmethod
+    def _planes(arr):
+        """(re, im) float planes of a complex array: the extraction
+        copies into fresh buffers the caller never sees -- already
+        alias-safe without staging."""
+        ft = np.float64 if arr.dtype == np.complex128 else np.float32
+        with _timed('h2d.stage', 'xfer', 'xfer.h2d_stage_s', staged=0):
+            return (np.ascontiguousarray(arr.real, dtype=ft),
+                    np.ascontiguousarray(arr.imag, dtype=ft))
 
     def _to_device_sharded(self, arr, sharding):
         """Sharded H2D (see :meth:`to_device`).  Complex crosses as
@@ -693,29 +748,21 @@ class TransferEngine(object):
         One transfer observation regardless of plane count (matching
         the single-device complex path), so the sharded and
         single-device arms of config 11 read comparable histograms."""
-        hist, spans = _obs()
-        t0 = time.perf_counter()
-        faults.fire('xfer.h2d')
-        if np.iscomplexobj(arr):
-            ft = np.float64 if arr.dtype == np.complex128 else np.float32
-            re = np.ascontiguousarray(arr.real, dtype=ft)
-            im = np.ascontiguousarray(arr.imag, dtype=ft)
-            out = _combine(self._ship_sharded_real(re, sharding),
-                           self._ship_sharded_real(im, sharding))
-        else:
-            out = self._ship_sharded_real(arr, sharding)
-        dt = time.perf_counter() - t0
-        hist.observe('xfer.h2d_s', dt)
-        hist.observe('xfer.h2d_nbytes', int(arr.nbytes))
         try:
             ndev = len(sharding.device_set)
         except Exception:
             ndev = 1
+        _obs()[0].observe('xfer.h2d_nbytes', int(arr.nbytes))
         # the shard count distinguishes mesh placements from
         # single-device ships in the trace (mesh observability)
-        spans.record_elapsed('h2d', 'xfer', dt, bytes=int(arr.nbytes),
-                             shards=ndev)
-        return out
+        with _timed('h2d', 'xfer', 'xfer.h2d_s', bytes=int(arr.nbytes),
+                    shards=ndev):
+            faults.fire('xfer.h2d')
+            if not np.iscomplexobj(arr):
+                return self._ship_sharded_real(arr, sharding)
+            re, im = self._planes(arr)
+            return _combine(self._ship_sharded_real(re, sharding),
+                            self._ship_sharded_real(im, sharding))
 
     def _ship_sharded_real(self, arr, sharding):
         """One real-valued sharded placement: per-shard staged shards
@@ -775,8 +822,6 @@ class TransferEngine(object):
             _counters().inc('xfer.h2d_batched', len(arrs))
             return self.to_device(np.stack(arrs), device)
         faults.fire('xfer.h2d')
-        hist, spans = _obs()
-        t0 = time.perf_counter()
         k = len(arrs)
         bshape = (k,) + tuple(shape)
         nbytes = int(np.dtype(dtype).itemsize * np.prod(bshape))
@@ -785,12 +830,10 @@ class TransferEngine(object):
             for i, a in enumerate(arrs):
                 np.copyto(buf[i], a, casting='no')
 
-        out = self._stage_ship(bshape, dtype, nbytes, fill, device)
+        _obs()[0].observe('xfer.h2d_nbytes', nbytes)
+        with _timed('h2d', 'xfer', 'xfer.h2d_s', bytes=nbytes):
+            out = self._stage_ship(bshape, dtype, nbytes, fill, device)
         _counters().inc('xfer.h2d_batched', k)
-        dt = time.perf_counter() - t0
-        hist.observe('xfer.h2d_s', dt)
-        hist.observe('xfer.h2d_nbytes', nbytes)
-        spans.record_elapsed('h2d', 'xfer', dt, bytes=nbytes)
         return out
 
     # -- D2H ---------------------------------------------------------------
@@ -830,7 +873,7 @@ class TransferEngine(object):
                 return (host[0].astype(ft) + 1j * host[1]).astype(ct)
             return TransferFuture([re, im], convert)
         self._start_readback((arr,))
-        return TransferFuture([arr], lambda host: host[0])
+        return TransferFuture([arr], _first)
 
     def to_host(self, arr):
         """array -> numpy; blocks until the value is ready (the D2H
@@ -857,16 +900,23 @@ class TransferEngine(object):
             while len(self._pending) > self.depth:
                 over.append(self._pending.popleft())
         for old in over:
-            if not old.done and not old.ready():
-                # a real hard wait: the depth bound forced a drain
-                # before the transfer finished on its own (ready()
-                # distinguishes finished-but-unharvested futures —
-                # done only flips once result() runs).  The
-                # closed-loop auto-tuner reads this rate as part of
-                # its sync-depth trigger (docs/autotune.md).
-                _counters().inc('xfer.depth_waits')
-            old.result()
+            self._retire(old, old.ready, old.result)
         return fut
+
+    @staticmethod
+    def _retire(old, ready, wait):
+        """Complete the transfer the depth bound pushed out (a future
+        or a fill).  It is a real hard wait only where the transfer
+        has not finished on its own (``ready`` tells finished-but-
+        unharvested ones apart: ``done`` flips only once the result is
+        taken); the closed-loop auto-tuner reads that rate as part of
+        its sync-depth trigger (docs/autotune.md)."""
+        if old.done:
+            return wait()          # re-raises a recorded failure
+        if not ready():
+            _counters().inc('xfer.depth_waits')
+        with _timed('d2h.depth_wait', 'wait'):
+            wait()
 
     def host_fill(self, dev_arr, dtype, out_view):
         """A :class:`HostFill` materializing ``dev_arr`` (device
@@ -884,12 +934,7 @@ class TransferEngine(object):
             while len(self._fills) > self.depth:
                 over.append(self._fills.popleft())
         for old in over:
-            # same finished-but-unharvested exclusion as the future
-            # drain above: HostFill.done only flips inside wait(), so
-            # poll the underlying transfer before charging a hard wait
-            if not old.done and not old.future.ready():
-                _counters().inc('xfer.depth_waits')
-            old.wait()
+            self._retire(old, old.future.ready, old.wait)
         return fill
 
     def drain(self, block=False):

@@ -44,7 +44,7 @@ def run_config12(timeout=1800):
     env = dict(os.environ, JAX_PLATFORMS='cpu')
     # a configured observability environment would contaminate the
     # arms (the config manages these knobs itself)
-    for var in ('BF_TRACE_FILE', 'BF_TRACE', 'BF_TRACE_CONTEXT',
+    for var in ('BF_TRACE_FILE', 'BF_TRACE_CONTEXT',
                 'BF_SLO_MS', 'BF_METRICS_FILE', 'BF_WATCHDOG_SECS',
                 'BF_JAX_PROFILE'):
         env.pop(var, None)

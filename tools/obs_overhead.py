@@ -3,8 +3,9 @@
 
 Runs bench_suite config 8 (the async-transfer gulp loop — the hottest
 host-side path in the framework) in fresh subprocesses, ``--reps``
-interleaved repetitions per arm: span recording OFF (the default) vs
-ON (``BF_TRACE_FILE`` set), then asserts the traced arm's best
+interleaved repetitions per arm: the Chrome-trace export OFF (the
+default) vs ON (``BF_TRACE_FILE`` set; span recording itself has no
+switch and runs in both arms), then asserts the exporting arm's best
 per-gulp time regressed by less than ``--threshold`` percent (default
 5).  Two noise defenses, both necessary in practice: the arms compare
 per-arm MINIMA (run-to-run spread on a busy host is 2x — far larger
@@ -68,7 +69,7 @@ def run_chain(armed, timeout=1800, stack='ringcheck',
     FleetCollector in THIS process) at a 4Hz publish interval — the
     streaming-publish bound of docs/observability.md "Fleet plane"."""
     env = dict(os.environ)
-    for knob in ('BF_TRACE_FILE', 'BF_TRACE', 'BF_WATCHDOG_SECS',
+    for knob in ('BF_TRACE_FILE', 'BF_WATCHDOG_SECS',
                  'BF_WATCHDOG_ESCALATE', 'BF_METRICS_FILE',
                  'BF_SLO_MS', 'BF_JAX_PROFILE', 'BF_RINGCHECK',
                  'BF_AUTOTUNE', 'BF_AUTOTUNE_PROFILE',
@@ -135,7 +136,7 @@ def run_config8(trace_file=None, timeout=1800, full_stack=False):
     # work, so the baseline arm is genuinely instrumentation-off (an
     # inherited BF_WATCHDOG_SECS would arm the flight recorder and
     # make the gate compare on-vs-on)
-    for knob in ('BF_TRACE_FILE', 'BF_TRACE', 'BF_WATCHDOG_SECS',
+    for knob in ('BF_TRACE_FILE', 'BF_WATCHDOG_SECS',
                  'BF_WATCHDOG_ESCALATE', 'BF_METRICS_FILE',
                  'BF_SLO_MS', 'BF_TRACE_CONTEXT', 'BF_JAX_PROFILE'):
         env.pop(knob, None)
