@@ -19,6 +19,15 @@ Counter names used by the framework:
                                            (copy_to_host_async + queue)
 - ``xfer.sync_waits``                      hard host blocks inside a
                                            transfer (result not ready)
+- ``xfer.fills_by_worker`` /
+  ``xfer.fills_by_caller``                 deferred ring fills completed
+                                           by one of the engine's
+                                           completion threads / by the
+                                           thread that needed the bytes
+                                           (a reader, a writer, the
+                                           depth bound, a synchronous
+                                           fill); their sum is the fills
+                                           completed
 - ``pipeline.sync_waits``                  dispatch-ahead drain waits in
                                            Block._sync_gulp
 - ``pipeline.gulps``                       gulps processed through

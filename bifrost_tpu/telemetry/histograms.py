@@ -31,8 +31,9 @@ and operators may add their own):
                                  ``xfer.d2h_convert_s``
 - ``xfer.d2h_fill_s``            the copy of a product into its host
                                  ring span (deferred fills)
-- ``xfer.d2h_peer_wait_s``       waiting for a peer thread to finish
-                                 the same transfer
+- ``xfer.d2h_peer_wait_s``       waiting for the thread that claimed
+                                 the same transfer (a completion
+                                 thread as a rule) to land it
 - ``xfer.h2d_nbytes`` / ``xfer.d2h_nbytes``  transfer sizes
 - ``jit.compile_s``              compilations and persistent-cache
                                  loads (jax.monitoring backend-compile

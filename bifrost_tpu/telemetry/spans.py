@@ -517,10 +517,14 @@ def watch_jax():
     first needs JAX (``Pipeline.run``, the transfer engine): importing
     the package alone must not import JAX."""
     global _jax_watched
+    if _jax_watched:
+        return
+    # outside the lock: a full collection during the import records a
+    # ``host.gc`` span, and a thread's first span takes the lock
+    from jax import monitoring
     with _config_lock:
         if _jax_watched:
             return
-        from jax import monitoring
         monitoring.register_event_duration_secs_listener(
             _on_jax_duration)
         _jax_watched = True

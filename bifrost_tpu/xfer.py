@@ -35,7 +35,16 @@ analogue of bifrost's per-block CUDA streams + async memcpy
 - **deferred ring fills** — :class:`HostFill` lets a block commit a
   host ring span whose bytes are still in flight; the ring gates
   readers on the fill (see ring.py), so the writer thread never blocks
-  on D2H and the consumer pays only the residual wait.
+  on D2H.  The engine's own completion threads (``xfer-d2h-<n>``,
+  ``_D2H_WORKERS`` of them) take the transfer's result and copy it
+  into the span, oldest fill first; a fill is claimed once, by
+  whoever completes it.  A reader (or a wrapping writer, a resize, the depth
+  bound) that needs a fill nobody has claimed yet completes it
+  itself; one that finds it claimed waits for it to land
+  (``d2h.peer_wait``).  A block's per-gulp ``drain()`` neither
+  completes a fill nor waits for one.  A large product crosses in
+  pieces small enough for the allocator to keep between products
+  (``_D2H_PIECE_BYTES``; docs/transfer.md).
 
 Complex data crosses the host boundary as (re, im) float planes,
 split on one side and recombined under jit on the other.  The local
@@ -56,6 +65,7 @@ Tunables (environment):
 
 from __future__ import annotations
 
+import atexit
 import os
 import threading
 import weakref
@@ -72,6 +82,28 @@ __all__ = ['to_device', 'to_device_batch', 'to_host', 'to_host_async',
            'HostFill']
 
 _ALIGN = 128
+
+#: completion threads an engine starts with its first deferred fill.
+#: The runtime takes one transfer at a time (two concurrent
+#: ``np.asarray`` of ready products: 1.8 times one, tools/d2h_probe.py)
+#: and one thread keeps up with the served cell, at work for 93 % of
+#: its window there: the cell reads the same with one, two and four
+#: to a hundredth, and 6 % less with none (PERF.md section 6, PR 27).
+#: A fill that finds the thread busy is not held up by it: whoever
+#: needs it first completes it (:class:`HostFill`).
+_D2H_WORKERS = 1
+
+#: a product on its way into a host ring span crosses in pieces of at
+#: most this many bytes (split along its leading axis, on the device),
+#: once it is larger than twice this.  The runtime lands every
+#: transfer in a fresh numpy buffer, and glibc serves a request over
+#: 32 MiB with an mmap of its own that it unmaps at free(), whatever
+#: ``mallopt`` says once a process has threads: a 268 MB product then
+#: first-touches 65536 pages, 0.8 CPU-seconds and most of its
+#: ``np.asarray`` on the v5e host.  Pieces of 16 MiB come from the
+#: heap and are found again there by the next product's
+#: (tools/d2h_probe.py; PERF.md section 6, PR 27).
+_D2H_PIECE_BYTES = 16 << 20
 
 _combine_fn = None
 _split_fn = None
@@ -92,6 +124,39 @@ def _split(arr):
         import jax.numpy as jnp
         _split_fn = jax.jit(lambda c: (jnp.real(c), jnp.imag(c)))
     return _split_fn(arr)
+
+
+_pieces_fn = None
+
+
+class _Pieces(list):
+    """The host copies of a product that crossed in pieces, in order
+    along its leading axis (a :class:`TransferFuture`'s result, for
+    :class:`HostFill` alone)."""
+
+
+def _in_pieces(arr):
+    """``arr`` split along its leading axis into the fewest equal
+    pieces of at most ``_D2H_PIECE_BYTES`` and at least half that (one
+    program on the device), or None where it is small enough to cross
+    whole, lies on more than one device, or does not divide so."""
+    nbytes = int(arr.nbytes)
+    if nbytes <= 2 * _D2H_PIECE_BYTES or not arr.ndim or \
+            len(arr.sharding.device_set) != 1:
+        return None
+    rows = arr.shape[0]
+    least = -(-nbytes // _D2H_PIECE_BYTES)
+    n = next((n for n in range(least, min(2 * least, rows) + 1)
+              if rows % n == 0), None)
+    if n is None:
+        return None
+    global _pieces_fn
+    if _pieces_fn is None:
+        import jax
+        import jax.numpy as jnp
+        _pieces_fn = jax.jit(
+            lambda x, n: tuple(jnp.split(x, n, axis=0)), static_argnums=1)
+    return _pieces_fn(arr, n)
 
 
 def _counters():
@@ -123,15 +188,21 @@ def _first(host):
     return host[0]
 
 
+def _peer_wait():
+    """The span of a thread that needs a transfer which a peer (a
+    completion thread, or a caller that claimed it first) is
+    completing: a span, so that the wait is nobody's unexplained
+    stall."""
+    return _timed('d2h.peer_wait', 'wait', 'xfer.d2h_peer_wait_s')
+
+
 @contextmanager
 def _held(lock):
-    """``with lock``, for a transfer's lock.  Where a peer thread holds
-    it, it is completing this very transfer (a reader, the depth bound
-    and every block's per-gulp drain race for the same fill), and this
-    thread sits out the whole of it: a span, so that the wait is
-    nobody's unexplained stall."""
+    """``with lock``, for a future's lock.  Where a peer thread holds
+    it, it is completing this very transfer, and this thread sits out
+    the rest of it (:func:`_peer_wait`)."""
     if not lock.acquire(False):
-        with _timed('d2h.peer_wait', 'wait', 'xfer.d2h_peer_wait_s'):
+        with _peer_wait():
             lock.acquire()
     try:
         yield
@@ -284,17 +355,24 @@ class _StagingPool(object):
                 slot.recycled = True
                 self._free.setdefault(slot.key, []).append(slot.buf)
 
-    def acquire(self, shape, dtype):
-        """A staging buffer for (shape, dtype), or None when the pool
-        for that key is exhausted."""
-        key = (tuple(shape), str(np.dtype(dtype)))
+    def reclaim(self):
+        """Return to the free list every slot whose transfer is
+        observed done (the device then owns a copy).  A DELETED array
+        (donated downstream) proves nothing about the DMA — donation
+        deletes at dispatch time — and polling is_ready() on it
+        crashes the runtime: such slots are dropped, not reused (same
+        policy as _on_array_death).
+
+        Called by :meth:`acquire`, and by the engine's per-gulp
+        ``drain()`` on every block thread: at a gulp every 60 ms the
+        next ``acquire`` alone comes too late for some arrays (their
+        consumer has let go of them by then), and each slot lost so is
+        replaced by a first touch of fresh pages, 0.5-0.7 s for a
+        268 MB slot on the v5e host against 16 ms for the copy into a
+        reused one: ``h2d.stage`` read 103 ms a gulp in the mean, and
+        the served cell 1180 Msamples/s against 2960 with this
+        (PERF.md section 6, PR 27)."""
         with self._lock:
-            # reclaim slots whose transfer is observed done (the device
-            # then owns a copy).  A DELETED array (donated downstream)
-            # proves nothing about the DMA — donation deletes at
-            # dispatch time — and polling is_ready() on it crashes the
-            # runtime: drop such slots instead of reusing them (same
-            # policy as _on_array_death).
             for slot in list(self._busy):
                 if slot.recycled:
                     continue
@@ -310,6 +388,13 @@ class _StagingPool(object):
                         self._busy.remove(slot)
                     except ValueError:
                         pass
+
+    def acquire(self, shape, dtype):
+        """A staging buffer for (shape, dtype), or None when the pool
+        for that key is exhausted."""
+        key = (tuple(shape), str(np.dtype(dtype)))
+        with self._lock:
+            self.reclaim()
             free = self._free.get(key)
             if free:
                 return _Slot(free.pop(), key)
@@ -371,43 +456,60 @@ class TransferFuture(object):
 
     def result(self):
         with _held(self._lock):
-            if self._done:
-                if self._error is not None:
-                    raise self._error
-                return self._result
-            from jax import block_until_ready
-            # D2H completion as the host sees it, in its parts: the
-            # wait for the device and the DMA's remainder, the copy
-            # out of the runtime's buffer, the conversion
-            with _timed('d2h', 'xfer', 'xfer.d2h_wait_s',
-                        bytes=self._nbytes):
-                try:
-                    faults.fire('xfer.result')
-                    live = [a for a in self._arrays
-                            if not a.is_deleted()]
-                    if not all(a.is_ready() for a in live):
-                        _counters().inc('xfer.sync_waits')
-                    with _timed('d2h.ready', 'wait',
-                                'xfer.d2h_ready_s'):
-                        block_until_ready(live)
-                    with _timed('d2h.asarray', 'xfer',
-                                'xfer.d2h_asarray_s'):
-                        host = [np.asarray(a) for a in self._arrays]
-                    if self._convert is _first:
-                        self._result = host[0]
-                    else:
-                        with _timed('d2h.convert', 'xfer',
-                                    'xfer.d2h_convert_s'):
-                            self._result = self._convert(host)
-                except Exception as exc:
-                    self._error = exc
-                    self._done = True
-                    self._arrays = []
-                    _counters().inc('xfer.errors')
-                    raise
-            self._done = True
-            self._arrays = []      # drop device refs promptly
+            return self._take()
+
+    def poll(self):
+        """Harvest the transfer if it has finished on its own and no
+        peer is completing it; never waits, for the device or for a
+        peer.  True once done; a recorded failure raises."""
+        if not self._done and self.ready() and self._lock.acquire(False):
+            try:
+                self._take()
+            finally:
+                self._lock.release()
+        if self._done and self._error is not None:
+            raise self._error
+        return self._done
+
+    def _take(self):
+        # under self._lock
+        if self._done:
+            if self._error is not None:
+                raise self._error
             return self._result
+        from jax import block_until_ready
+        # D2H completion as the host sees it, in its parts: the wait
+        # for the device and the DMA's remainder, the copy out of the
+        # runtime's buffer, the conversion
+        with _timed('d2h', 'xfer', 'xfer.d2h_wait_s',
+                    bytes=self._nbytes):
+            try:
+                faults.fire('xfer.result')
+                live = [a for a in self._arrays if not a.is_deleted()]
+                if not all(a.is_ready() for a in live):
+                    _counters().inc('xfer.sync_waits')
+                with _timed('d2h.ready', 'wait', 'xfer.d2h_ready_s'):
+                    block_until_ready(live)
+                with _timed('d2h.asarray', 'xfer',
+                            'xfer.d2h_asarray_s'):
+                    host = [np.asarray(a) for a in self._arrays]
+                if self._convert is _first:
+                    self._result = host[0]
+                elif self._convert is _Pieces:
+                    self._result = _Pieces(host)
+                else:
+                    with _timed('d2h.convert', 'xfer',
+                                'xfer.d2h_convert_s'):
+                        self._result = self._convert(host)
+            except Exception as exc:
+                self._error = exc
+                self._done = True
+                self._arrays = []
+                _counters().inc('xfer.errors')
+                raise
+        self._done = True
+        self._arrays = []      # drop device refs promptly
+        return self._result
 
     @property
     def error(self):
@@ -423,19 +525,26 @@ class HostFill(object):
     D2H transfer.
 
     The writing block registers the fill on the ring instead of
-    blocking; readers acquiring any overlapping span call
-    :meth:`wait` first (ring.py), so data is materialized exactly when
-    first needed — by which time the DMA has usually finished.
-    ``wait`` is idempotent and thread-safe (multiple readers may race
-    to complete the same fill).
+    blocking, and readers acquiring any overlapping span call
+    :meth:`wait` first (ring.py).  A fill is CLAIMED once, by whoever
+    completes it: one of the engine's completion threads (the usual
+    case: they take fills oldest first, so by the time a reader needs
+    the bytes they have landed), or the first :meth:`wait` to find it
+    unclaimed, which then does the work itself and so never waits for
+    a thread that is busy elsewhere.  Every other ``wait`` waits for
+    the fill to land.  Fills of different spans complete side by side;
+    the ring gates each reader on the fills overlapping its span, so
+    delivery stays once and in order.
 
-    A FAILED transfer is not swallowed: the first ``wait`` records the
+    A FAILED transfer is not swallowed: the claimant records the
     error, POISONS the target ring (waking every reader/writer with
     ``RingPoisonedError`` instead of handing them a span of garbage
-    bytes), and re-raises; later waits re-raise the same error."""
+    bytes) and, where it is a ``wait``, re-raises; later waits, and
+    the engine's next ``drain()``, raise the same error."""
 
     __slots__ = ('future', 'dtype', 'out', 'begin', 'nbyte',
-                 '_storage', '_ring', 'done', 'error', '_lock')
+                 '_storage', '_ring', 'done', 'error', '_lock',
+                 '_claimed', '_landed')
 
     def __init__(self, future, dtype, out_view):
         self.future = future
@@ -447,62 +556,131 @@ class HostFill(object):
         self._ring = None
         self.done = False
         self.error = None
+        #: guards the claim, and ``done`` against :meth:`attach` (who
+        #: of the two runs second mirrors the ghost region)
         self._lock = threading.Lock()
+        self._claimed = False
+        self._landed = threading.Event()
 
     def attach(self, ring, begin, nbyte):
         """Bind the fill to its committed byte range so ghost-region
         maintenance can run after the data lands (called by
-        WriteSpan.close).  The fill may already have completed — the
-        engine's per-gulp drain (another block thread) or synchronous
-        mode can run wait() before the span closes — in which case the
-        deferred ghost mirror runs here instead; no reader can have
-        acquired the span yet (commit happens after attach)."""
-        self._storage = ring._storage
-        self._ring = ring
-        self.begin = begin
-        self.nbyte = nbyte
+        WriteSpan.close).  The fill may already have completed — a
+        completion thread may be done with it before the span closes,
+        and synchronous mode always is — in which case the deferred
+        ghost mirror runs here instead (and a recorded failure poisons
+        the ring here); no reader can have acquired the span yet
+        (commit happens after attach)."""
         with self._lock:
+            self._storage = ring._storage
+            self._ring = ring
+            self.begin = begin
+            self.nbyte = nbyte
             if self.done and self.error is None and nbyte:
                 self._storage.fill_ghost_mirror(begin, nbyte)
+            failed = self.error
+        if failed is not None:
+            self._poison(ring, failed)
+
+    def _claim(self):
+        """True for exactly one caller: the one that completes (or
+        cancels) the fill."""
+        with self._lock:
+            if self._claimed:
+                return False
+            self._claimed = True
+            return True
 
     def cancel(self):
         """Abandon the fill without writing (its span committed no
         bytes — the reservation rolled back and the target region may
-        be re-reserved; a late write would corrupt the next span)."""
-        with self._lock:
+        be re-reserved; a late write would corrupt the next span).
+        Where a peer has claimed it, its write is under way or over:
+        returns once it is over."""
+        if self._claim():
             self.done = True
+            self._landed.set()
+        elif not self.done:
+            with _peer_wait():
+                self._landed.wait()
 
     def wait(self):
-        """Complete the fill: block on the transfer, convert into the
-        span's host view, then redo the ghost mirror for wrapped
-        spans (the commit-time mirror ran before the bytes landed)."""
-        with _held(self._lock):
-            if self.done:
-                if self.error is not None:
-                    raise self.error
-                return
-            try:
-                host = self.future.result()
-                from .devrep import from_device_rep
-                # the copy into the ring span: paid by whichever
-                # thread needs the bytes first
-                with _timed('d2h.fill', 'xfer', 'xfer.d2h_fill_s',
-                            bytes=int(getattr(self.out, 'nbytes', 0))):
+        """Return once the span's bytes have landed: complete the fill
+        here if nobody has claimed it, else wait for its claimant."""
+        if not self.done:
+            if self._claim():
+                self.complete('xfer.fills_by_caller')
+            else:
+                with _peer_wait():
+                    self._landed.wait()
+        if self.error is not None:
+            raise self.error
+
+    def complete(self, who):
+        """The claimant's work: block on the transfer, convert into
+        the span's host view, then redo the ghost mirror for wrapped
+        spans (the commit-time mirror ran before the bytes landed).
+        A failure is recorded, not raised (an interrupt is both).
+        ``who`` is the counter that says which side did it."""
+        try:
+            host = self.future.result()
+            from .devrep import from_device_rep
+            # the second pass over the product: into the ring span
+            with _timed('d2h.fill', 'xfer', 'xfer.d2h_fill_s',
+                        bytes=int(getattr(self.out, 'nbytes', 0))):
+                if isinstance(host, _Pieces):
+                    row = 0
+                    for piece in host:
+                        rows = piece.shape[0]
+                        from_device_rep(piece, self.dtype,
+                                        self.out[row:row + rows])
+                        row += rows
+                else:
                     from_device_rep(host, self.dtype, self.out)
+                with self._lock:
                     if self._storage is not None and self.nbyte:
                         self._storage.fill_ghost_mirror(self.begin,
                                                         self.nbyte)
-            except Exception as exc:
-                self.done = True
+                    self.done = True
+        except BaseException as exc:
+            with self._lock:
                 self.error = exc
-                _counters().inc('xfer.fill_errors')
-                if self._ring is not None:
-                    try:
-                        self._ring.poison(exc)
-                    except Exception:
-                        pass
+                self.done = True
+                ring = self._ring
+            _counters().inc('xfer.fill_errors')
+            self._poison(ring, exc)
+            if not isinstance(exc, Exception):
                 raise
-            self.done = True
+        finally:
+            _counters().inc(who)
+            self._landed.set()
+
+    @staticmethod
+    def _poison(ring, exc):
+        if ring is not None:
+            try:
+                ring.poison(exc)
+            except Exception:
+                pass
+
+
+def _complete_fills(work, fills, stop):
+    """Body of a completion thread: claim the oldest unclaimed fill of
+    the engine's queue and complete it, until told to stop.  It holds
+    the engine's condition, queue and stop flag, not the engine, so an
+    engine nobody refers to any more can be collected (and stops its
+    threads from ``__del__``)."""
+    while True:
+        with work:
+            while True:
+                if stop.is_set():
+                    return
+                fill = next((f for f in fills if f._claim()), None)
+                if fill is not None:
+                    break
+                work.wait()
+        fill.complete('xfer.fills_by_worker')
+        del fill           # hold no product while idle
 
 
 class TransferEngine(object):
@@ -521,6 +699,10 @@ class TransferEngine(object):
         self._pending = deque()     # TransferFutures (to_host_async)
         self._fills = deque()       # HostFills (host_fill)
         self._lock = threading.Lock()
+        #: wakes the completion threads: a fill was queued, or stop
+        self._work = threading.Condition(self._lock)
+        self._stop = threading.Event()
+        self._workers = []
         _tune_allocator()
         _obs()[1].watch_jax()
 
@@ -845,8 +1027,10 @@ class TransferEngine(object):
             except Exception:
                 pass               # optional fast-path hint only
 
-    def _future_for(self, arr):
-        """TransferFuture for a jax array (complex split on device)."""
+    def _future_for(self, arr, pieces=False):
+        """TransferFuture for a jax array (complex split on device).
+        With ``pieces`` a large real-valued array crosses in pieces
+        (:func:`_in_pieces`) and the result is their :class:`_Pieces`."""
         faults.fire('xfer.d2h')
         import jax
         import jax.numpy as jnp
@@ -872,6 +1056,11 @@ class TransferEngine(object):
             def convert(host):
                 return (host[0].astype(ft) + 1j * host[1]).astype(ct)
             return TransferFuture([re, im], convert)
+        parts = _in_pieces(arr) if pieces and \
+            isinstance(arr, jax.Array) else None
+        if parts is not None:
+            self._start_readback(parts)
+            return TransferFuture(parts, _Pieces)
         self._start_readback((arr,))
         return TransferFuture([arr], _first)
 
@@ -920,46 +1109,96 @@ class TransferEngine(object):
 
     def host_fill(self, dev_arr, dtype, out_view):
         """A :class:`HostFill` materializing ``dev_arr`` (device
-        representation of bifrost dtype ``dtype``) into ``out_view``.
-        Bounded like to_host_async; completed synchronously when the
-        engine is disabled."""
-        fill = HostFill(self._future_for(dev_arr), dtype, out_view)
+        representation of bifrost dtype ``dtype``) into ``out_view``,
+        queued for the engine's completion threads.  Bounded like
+        to_host_async; completed on the caller, before returning, when
+        the engine is disabled."""
+        # in pieces where the span's frames are the product's
+        pieces = getattr(dev_arr, 'ndim', 0) > 0 and \
+            getattr(out_view, 'ndim', 0) > 0 and \
+            dev_arr.shape[0] == out_view.shape[0]
+        fill = HostFill(self._future_for(dev_arr, pieces), dtype,
+                        out_view)
         if not async_enabled():
             fill.wait()
             return fill
         _counters().inc('xfer.d2h_async')
-        with self._lock:
+        with self._work:
             self._fills.append(fill)
             over = []
             while len(self._fills) > self.depth:
                 over.append(self._fills.popleft())
+            self._start_workers()
+            self._work.notify()
         for old in over:
             self._retire(old, old.future.ready, old.wait)
         return fill
 
-    def drain(self, block=False):
-        """Retire completed async transfers (non-blocking scan); with
-        ``block=True``, force every outstanding transfer to complete.
-        Returns the number retired.  The pipeline's dispatch-ahead
-        drain calls this once per gulp.
+    def _start_workers(self):
+        # under self._lock
+        if self._workers or self._stop.is_set():
+            return
+        for i in range(_D2H_WORKERS):
+            t = threading.Thread(
+                target=_complete_fills, name='xfer-d2h-%d' % i,
+                args=(self._work, self._fills, self._stop), daemon=True)
+            t.start()
+            self._workers.append(t)
 
-        A failed transfer raises out of the draining thread (after the
-        failure has been recorded on the future/fill, so the queues
-        still retire it) — the block whose gulp loop drained it then
-        applies its failure policy instead of the error vanishing."""
+    def close(self, timeout=None):
+        """Stop the completion threads and wait for them to end (each
+        for at most ``timeout`` seconds); a fill one of them has
+        claimed is completed first.  Fills still queued stay with
+        whoever waits for them (the caller-claims rule).  The engine
+        starts no thread again."""
+        self._stop.set()
+        with self._work:
+            self._work.notify_all()
+            workers, self._workers = self._workers, []
+        for t in workers:
+            if t is not threading.current_thread():
+                t.join(timeout)
+
+    def __del__(self):
+        # tell the threads only: a collection may run on one of them
+        try:
+            self._stop.set()
+            with self._work:
+                self._work.notify_all()
+        except Exception:
+            pass
+
+    def drain(self, block=False):
+        """Retire async transfers that are done; returns the number
+        retired.  The pipeline's dispatch-ahead loop calls this once a
+        gulp on every block thread, so it takes nobody's work and
+        waits for nobody: a future that has finished on its own is
+        harvested if no peer is at it, a fill is left to its claimant.
+        With ``block=True`` (shutdown) every outstanding transfer is
+        completed or waited for.  It also lets the staging pool take
+        back the slots of H2D transfers that have landed
+        (:meth:`_StagingPool.reclaim`).
+
+        A failed transfer raises out of the draining thread (the
+        failure is recorded on the future/fill, so the queues still
+        retire it) — the block whose gulp loop drained it then applies
+        its failure policy instead of the error vanishing."""
         n = 0
         error = None
+        self._pool.reclaim()
         with self._lock:
             pending = list(self._pending)
             fills = list(self._fills)
         for fut in pending:
-            if block or fut.ready():
-                try:
+            try:
+                if block:
                     fut.result()
-                except Exception as exc:
-                    error = error if error is not None else exc
+                else:
+                    fut.poll()
+            except Exception as exc:
+                error = error if error is not None else exc
         for fill in fills:
-            if block or fill.done or fill.future.ready():
+            if block or fill.done:
                 try:
                     fill.wait()
                 except Exception as exc:
@@ -995,7 +1234,9 @@ def engine():
 
 
 def reset_engine():
-    """Drop the process engine (tests: re-read env tunables)."""
+    """Drop the process engine (tests: re-read env tunables): its
+    outstanding transfers are completed, its completion threads
+    joined."""
     global _engine
     with _engine_lock:
         if _engine is not None:
@@ -1003,7 +1244,18 @@ def reset_engine():
                 _engine.drain(block=True)
             except Exception:
                 pass       # failed transfers die with the engine
+            _engine.close()
         _engine = None
+
+
+@atexit.register
+def _close_at_exit():
+    """Join the completion threads before the interpreter goes: a
+    daemon thread inside the runtime while it is torn down is a crash
+    at exit.  Outstanding transfers die with the process, as ever,
+    and so does a thread that a dead device holds past the timeout."""
+    if _engine is not None:
+        _engine.close(timeout=5.0)
 
 
 def to_device(arr, device=None, sharding=None):

@@ -176,7 +176,11 @@ def test_ring_spans_carry_the_first_frame(ring_core):
         assert frames[:NGULP] == [8 * k for k in range(NGULP)], what
 
 
-def test_fill_lands_on_the_reader_that_acquires_first(ring_core):
+def test_fill_lands_on_the_reader_that_acquires_first(ring_core,
+                                                      monkeypatch):
+    """Where no completion thread is free for it (here: the engine has
+    none), the fill is claimed by the reader that needs it first."""
+    monkeypatch.setattr(xfer, '_D2H_WORKERS', 0)
     data = np.arange(8 * 16, dtype=np.float32).reshape(8, 16)
     hdr = simple_header([-1, 16], 'f32', gulp_nframe=8)
     ring = Ring(space='system')

@@ -3,13 +3,16 @@ out-of-order completion drain, deferred D2H ring fills, buffer
 donation bit-exactness, and the sync_strict fallback."""
 
 import gc
+import sys
+import threading
+import time
 
 import numpy as np
 import pytest
 
 import bifrost_tpu as bf
 from bifrost_tpu import xfer
-from bifrost_tpu.telemetry import counters
+from bifrost_tpu.telemetry import counters, histograms, spans
 from tests.util import NumpySourceBlock, GatherSink, simple_header
 
 
@@ -79,6 +82,29 @@ def test_staging_pool_recycles_only_completed_transfers():
     buf_id = id(slot_entry[0].buf)
     free = pool._free.get(((256, 256), 'float32'), [])
     assert all(id(b) != buf_id for b in free)
+
+
+def test_drain_recycles_the_slot_of_a_transfer_it_sees_complete():
+    """A block ships ahead, waits for its arrays and lets go of them
+    before it stages again: the per-gulp drain in between is where the
+    pool sees them complete.  Without it the slot is dropped with the
+    array, and its replacement is a first touch of fresh pages."""
+    key = ((256, 256), 'float32')
+    a = np.ones((256, 256), np.float32)
+    for drains in (True, False):
+        eng = xfer.TransferEngine(staging=2, zero_copy=False)
+        d = eng.to_device(a)
+        d.block_until_ready()
+        (slot,) = eng._pool._busy
+        if drains:
+            eng.drain()
+        del d
+        gc.collect()
+        assert slot.recycled
+        free = eng._pool._free.get(key, [])
+        assert (len(free) == 1 and free[0] is slot.buf) if drains \
+            else not free
+        assert eng._pool._nalloc[key] == (1 if drains else 0)
 
 
 # ---------------------------------------------------------------------------
@@ -316,6 +342,452 @@ def test_host_fill_wraparound_ghost():
     np.testing.assert_allclose(sink.result(),
                                np.concatenate(gulps, axis=0),
                                rtol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# completion threads: who completes a deferred fill, and how many at once
+# ---------------------------------------------------------------------------
+
+#: seconds any one wait of these tests may last: a deadlock fails here,
+#: not at the suite's limit
+SOON = 10.0
+
+
+def within(fn, *args):
+    """``fn(*args)`` on a thread of its own, which has to end SOON;
+    returns its value or raises what it raised."""
+    box = []
+
+    def run():
+        try:
+            box.append((True, fn(*args)))
+        except BaseException as exc:
+            box.append((False, exc))
+
+    t = threading.Thread(target=run, name='within')
+    t.start()
+    t.join(SOON)
+    assert not t.is_alive(), '%s did not return in %g s' % (fn, SOON)
+    ok, value = box[0]
+    if not ok:
+        raise value
+    return value
+
+
+class GatedFuture(object):
+    """A transfer whose ``result()`` waits for the test's word."""
+
+    def __init__(self, value, fail=None):
+        self.value = value
+        self.fail = fail
+        self.gate = threading.Event()
+        self.entered = threading.Event()
+        self.thread = None
+        self.done = False
+        self.error = None
+
+    def ready(self):
+        return self.gate.is_set()
+
+    def result(self):
+        self.thread = threading.current_thread().name
+        self.entered.set()
+        assert self.gate.wait(SOON), 'the gate never opened'
+        self.done = True
+        if self.fail is not None:
+            self.error = self.fail
+            raise self.fail
+        return self.value
+
+
+@pytest.fixture
+def gated(monkeypatch):
+    """An engine with two completion threads whose transfers are
+    GatedFutures, in order of issue; closed (gates opened first) when
+    the test ends."""
+    monkeypatch.setattr(xfer, '_D2H_WORKERS', 2)
+    eng = xfer.TransferEngine(depth=16)
+    eng.futures = []
+
+    def future_for(value, pieces=False):
+        fut = GatedFuture(value)
+        eng.futures.append(fut)
+        return fut
+
+    eng._future_for = future_for
+    yield eng
+    for fut in eng.futures:
+        fut.gate.set()
+    within(eng.close)
+
+
+def _ring_of(nframe_buf, nframe_gulp=8):
+    from bifrost_tpu.ring import Ring
+    ring = Ring(space='system')
+    hdr = simple_header([-1, 16], 'f32', gulp_nframe=nframe_gulp)
+    return ring, hdr
+
+
+def test_two_fills_of_different_spans_complete_at_the_same_time(gated):
+    a, b = (np.full((8, 16), v, np.float32) for v in (1.0, 2.0))
+    out_a, out_b = np.zeros_like(a), np.zeros_like(b)
+    fa = gated.host_fill(a, 'f32', out_a)
+    fb = gated.host_fill(b, 'f32', out_b)
+    # both are inside their transfer before either is let go
+    for fut in gated.futures:
+        assert fut.entered.wait(SOON)
+    assert sorted(f.thread for f in gated.futures) == \
+        ['xfer-d2h-0', 'xfer-d2h-1']
+    assert not fa.done and not fb.done
+    gated.futures[1].gate.set()              # the younger lands first
+    within(fb.wait)
+    assert fb.done and not fa.done
+    gated.futures[0].gate.set()
+    within(fa.wait)
+    assert np.array_equal(out_a, a) and np.array_equal(out_b, b)
+    assert counters.get('xfer.fills_by_worker') == 2
+    assert counters.get('xfer.fills_by_caller') == 0
+
+
+def test_drain_neither_completes_nor_waits_for_a_claimed_fill(gated):
+    data = np.ones((8, 16), np.float32)
+    fill = gated.host_fill(data, 'f32', np.zeros_like(data))
+    assert gated.futures[0].entered.wait(SOON)       # claimed, at work
+    t0 = time.perf_counter()
+    assert within(gated.drain) == 0
+    assert time.perf_counter() - t0 < SOON / 2
+    assert not fill.done and gated.outstanding == 1
+    gated.futures[0].gate.set()
+    within(fill.wait)
+    assert within(gated.drain) == 1 and gated.outstanding == 0
+
+
+def test_drain_leaves_an_unclaimed_fill_alone(gated, monkeypatch):
+    monkeypatch.setattr(xfer, '_D2H_WORKERS', 0)
+    data = np.ones((8, 16), np.float32)
+    fill = gated.host_fill(data, 'f32', np.zeros_like(data))
+    gated.futures[0].gate.set()              # finished on its own
+    assert within(gated.drain) == 0
+    assert not fill.done and not gated.futures[0].entered.is_set()
+    assert within(gated.drain, True) == 1    # block=True completes it
+    assert fill.done and counters.get('xfer.fills_by_caller') == 1
+
+
+def test_reader_that_arrives_first_claims_the_fill(gated):
+    """Both completion threads are held on other transfers; the reader
+    of a wrapped span completes its fill itself, ghost mirror and
+    all, and waits for nobody."""
+    rng = np.random.RandomState(5)
+    data = rng.randn(24, 16).astype(np.float32)
+    ring, hdr = _ring_of(20)
+    with ring.begin_writing() as w:
+        with w.begin_sequence(hdr, 8, 20) as seq:
+            for g0 in (0, 8):
+                with seq.reserve(8) as sp:
+                    sp.set_fill(gated.host_fill(data[g0:g0 + 8], 'f32',
+                                                sp.data.as_numpy()))
+                    sp.commit(8)
+            for fut in gated.futures:            # landed, by the workers
+                fut.gate.set()
+            within(gated.drain, True)
+            busy = [gated.host_fill(data[:8], 'f32', np.zeros((8, 16),
+                                                              np.float32))
+                    for _ in range(2)]
+            for fut in gated.futures[2:]:
+                assert fut.entered.wait(SOON)    # both threads held
+            # [16, 24) wraps: frames 20-23 go through the ghost region
+            with seq.reserve(8) as sp:
+                sp.set_fill(gated.host_fill(data[16:], 'f32',
+                                            sp.data.as_numpy()))
+                sp.commit(8)
+            last = gated.futures[-1]
+            last.gate.set()
+            time.sleep(0.05)
+            assert not last.entered.is_set()     # nobody took it
+
+            def read():
+                with ring.open_earliest_sequence(guarantee=False) as rs:
+                    with rs.acquire(18, 4) as span:
+                        return np.array(span.data.as_numpy(), copy=True)
+
+            histograms.reset()
+            got = within(read)
+    assert last.thread == 'within'
+    assert np.array_equal(got, data[18:22])
+    assert counters.get('xfer.fills_by_caller') == 1
+    assert not any(f.done for f in busy)
+    assert histograms.get_or_create('xfer.d2h_peer_wait_s').count == 0
+
+
+def test_reader_that_arrives_second_waits_in_peer_wait(gated):
+    histograms.reset()
+    spans.reset()
+    data = np.random.RandomState(6).randn(8, 16).astype(np.float32)
+    ring, hdr = _ring_of(24)
+    got = []
+
+    def read():
+        with ring.open_earliest_sequence(guarantee=True) as rs:
+            with rs.acquire(0, 8) as span:
+                got.append(np.array(span.data.as_numpy(), copy=True))
+
+    with ring.begin_writing() as w:
+        with w.begin_sequence(hdr, 8, 24) as seq:
+            with seq.reserve(8) as sp:
+                sp.set_fill(gated.host_fill(data, 'f32',
+                                            sp.data.as_numpy()))
+                sp.commit(8)
+            assert gated.futures[0].entered.wait(SOON)   # a worker has it
+            reader = threading.Thread(target=read, name='the-reader')
+            reader.start()
+            reader.join(0.2)
+            assert reader.is_alive() and not got         # it waits
+            gated.futures[0].gate.set()
+            reader.join(SOON)
+            assert not reader.is_alive()
+    assert np.array_equal(got[0], data)
+    mine = [ev[0] for t, ev in spans.events() if t == 'the-reader']
+    assert 'd2h.peer_wait' in mine and 'd2h.fill' not in mine
+    filled = [t for t, ev in spans.events() if ev[0] == 'd2h.fill']
+    assert filled == [gated.futures[0].thread]
+    assert filled[0].startswith('xfer-d2h-')
+    assert histograms.get_or_create('xfer.d2h_peer_wait_s').count == 1
+    assert counters.get('xfer.fills_by_worker') == 1
+
+
+@pytest.mark.parametrize('fails', ['before_the_span_closes',
+                                   'after_the_span_closed'])
+def test_failure_on_a_worker_poisons_the_ring(gated, fails):
+    boom = RuntimeError('the transfer failed')
+    data = np.ones((8, 16), np.float32)
+    ring, hdr = _ring_of(24)
+    with ring.begin_writing() as w:
+        with w.begin_sequence(hdr, 8, 24) as seq:
+            with seq.reserve(8) as sp:
+                fill = gated.host_fill(data, 'f32', sp.data.as_numpy())
+                gated.futures[0].fail = boom
+                if fails == 'before_the_span_closes':
+                    gated.futures[0].gate.set()
+                    assert fill._landed.wait(SOON)
+                sp.set_fill(fill)
+                sp.commit(8)
+            gated.futures[0].gate.set()
+            assert fill._landed.wait(SOON)
+            assert gated.futures[0].thread.startswith('xfer-d2h-')
+            assert fill.done and fill.error is boom
+            assert ring.poisoned
+            assert counters.get('xfer.fill_errors') == 1
+            # raised on the thread that drains next, once retired
+            with pytest.raises(RuntimeError, match='the transfer failed'):
+                within(gated.drain)
+            assert within(gated.drain) == 0
+            with pytest.raises(RuntimeError, match='the transfer failed'):
+                within(fill.wait)
+
+
+def test_reset_engine_joins_the_completion_threads():
+    eng = xfer.engine()
+    data = np.arange(128, dtype=np.float32).reshape(8, 16)
+    out = np.zeros_like(data)
+    fill = eng.host_fill(eng.to_device(data), 'f32', out)
+    workers = list(eng._workers)
+    assert [t.name for t in workers] == \
+        ['xfer-d2h-%d' % i for i in range(xfer._D2H_WORKERS)]
+    assert all(t.daemon for t in workers)
+    within(xfer.reset_engine)
+    assert fill.done and np.array_equal(out, data)
+    assert not any(t.is_alive() for t in workers)
+    # and those of engines the tests before this one let go of
+    gc.collect()
+    deadline = time.monotonic() + SOON
+    while time.monotonic() < deadline and any(
+            t.name.startswith('xfer-d2h-') for t in threading.enumerate()):
+        time.sleep(0.01)
+    assert not [t.name for t in threading.enumerate()
+                if t.name.startswith('xfer-d2h-')]
+    # a closed engine starts none again: its fills stay with callers
+    before = counters.get('xfer.fills_by_caller')
+    late = eng.host_fill(eng.to_device(data), 'f32', np.zeros_like(data))
+    assert not eng._workers
+    within(late.wait)
+    assert counters.get('xfer.fills_by_caller') == before + 1
+
+
+@pytest.mark.parametrize('var', ['BF_XFER_ASYNC', 'BF_SYNC_STRICT'])
+def test_synchronous_fills_never_reach_a_worker(var, monkeypatch):
+    monkeypatch.setenv(var, '0' if var == 'BF_XFER_ASYNC' else '1')
+    eng = xfer.TransferEngine()
+    data = np.arange(128, dtype=np.float32).reshape(8, 16)
+    out = np.zeros_like(data)
+    fill = eng.host_fill(eng.to_device(data), 'f32', out)
+    assert fill.done and np.array_equal(out, data)
+    assert not eng._workers and not eng._fills
+    assert counters.get('xfer.fills_by_caller') == 1
+    assert counters.get('xfer.fills_by_worker') == 0
+
+
+def test_sync_strict_scope_never_reaches_a_worker():
+    _run_chain(_make_raw(seed=4), ngulp=4, sync_strict=True)
+    assert counters.get('xfer.fills_by_worker') == 0
+    assert counters.get('xfer.fills_by_caller') == 0
+    assert not xfer.engine()._workers
+
+
+def test_every_fill_is_completed_once_by_one_side():
+    """fills_by_worker + fills_by_caller = fills issued, through a real
+    pipeline (which side takes a fill is a race; that one does is
+    not)."""
+    raw = _make_raw(seed=6)
+    out_async, _ = _run_chain(raw, ngulp=8)
+    snap = counters.snapshot()
+    assert snap.get('xfer.d2h_async', 0) == 8
+    assert snap.get('xfer.fills_by_worker', 0) + \
+        snap.get('xfer.fills_by_caller', 0) == 8
+    counters.reset()
+    out_sync, _ = _run_chain(raw, ngulp=8, sync_strict=True)
+    assert np.array_equal(out_async, out_sync)
+
+
+def test_claim_survives_many_racing_threads():
+    """More waiters than cores on every fill, with the completion
+    threads and a drain loop beside them, at a short switch interval:
+    each fill is completed exactly once and every waiter sees its
+    bytes."""
+    nfill, nwaiter = 200, 8
+    eng = xfer.TransferEngine(depth=nfill)
+    rng = np.random.RandomState(9)
+    data = [rng.randn(4, 16).astype(np.float32) for _ in range(nfill)]
+    outs = [np.zeros_like(d) for d in data]
+    fills = []
+    wrong = []
+    go = threading.Event()
+
+    def waiter(seed):
+        go.wait(SOON)
+        for i in np.random.RandomState(seed).permutation(nfill):
+            while i >= len(fills):
+                time.sleep(0)
+            fills[i].wait()
+            if not np.array_equal(outs[i], data[i]):
+                wrong.append(i)
+
+    threads = [threading.Thread(target=waiter, args=(k,))
+               for k in range(nwaiter)]
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        go.set()
+        for d, o in zip(data, outs):
+            fills.append(eng.host_fill(eng.to_device(d), 'f32', o))
+            eng.drain()
+        for t in threads:
+            t.join(3 * SOON)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(old)
+        within(eng.close)
+    assert not wrong
+    assert counters.get('xfer.fills_by_worker') + \
+        counters.get('xfer.fills_by_caller') == nfill
+    assert counters.get('xfer.d2h_issued') == nfill
+    assert histograms.get_or_create('xfer.d2h_fill_s').count >= nfill
+
+
+# ---------------------------------------------------------------------------
+# products that cross in pieces
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize('dtype', ['f32', 'ci8', 'cf32'])
+def test_large_product_crosses_in_pieces(dtype, monkeypatch):
+    """A product over twice the piece size is split on the device
+    along its leading axis and lands piece by piece, bit-exact, in a
+    span that wraps (ghost mirror included); a complex product, a
+    small one and one that does not divide cross whole."""
+    from bifrost_tpu.devrep import to_device_rep
+    from bifrost_tpu.dtype import DataType
+    monkeypatch.setattr(xfer, '_D2H_PIECE_BYTES', 128)
+    rng = np.random.RandomState(11)
+    nframe, nchan = 24, 32
+    if dtype == 'f32':
+        data = rng.randn(nframe, nchan).astype(np.float32)
+    elif dtype == 'cf32':
+        data = (rng.randn(nframe, nchan) +
+                1j * rng.randn(nframe, nchan)).astype(np.complex64)
+    else:
+        data = np.zeros((nframe, nchan), DataType('ci8').as_numpy_dtype())
+        data['re'] = rng.randint(-100, 100, (nframe, nchan))
+        data['im'] = rng.randint(-100, 100, (nframe, nchan))
+    eng = xfer.engine()
+    from bifrost_tpu.ring import Ring
+    ring = Ring(space='system')
+    hdr = simple_header([-1, nchan], dtype, gulp_nframe=8)
+    fills = []
+    with ring.begin_writing() as w:
+        with w.begin_sequence(hdr, 8, 20) as seq:
+            for g0 in (0, 8, 16):            # [16, 24) wraps at 20
+                with seq.reserve(8) as sp:
+                    fill = eng.host_fill(
+                        to_device_rep(data[g0:g0 + 8], dtype), dtype,
+                        sp.data.as_numpy())
+                    sp.set_fill(fill)
+                    sp.commit(8)
+                    fills.append(fill)
+                if g0 == 0:
+                    with ring.open_earliest_sequence(
+                            guarantee=False) as rs:
+                        with rs.acquire(0, 8) as span:
+                            first = np.array(span.data.as_numpy())
+            with ring.open_earliest_sequence(guarantee=False) as rs:
+                with rs.acquire(18, 4) as span:
+                    got = np.array(span.data.as_numpy(), copy=True)
+    assert np.array_equal(first, data[:8])
+    assert np.array_equal(got, data[18:22])
+    for fill in fills:
+        host = fill.future.result()
+        if dtype == 'cf32':
+            assert isinstance(host, np.ndarray)
+        else:
+            # 8 frames of 128 (f32) or 64 (ci8) bytes, in pieces of 128
+            assert isinstance(host, xfer._Pieces)
+            assert sum(h.shape[0] for h in host) == 8
+            assert len(host) == (8 if dtype == 'f32' else 4)
+            assert all(h.nbytes == 128 for h in host)
+
+
+def test_small_or_indivisible_products_cross_whole(monkeypatch):
+    monkeypatch.setattr(xfer, '_D2H_PIECE_BYTES', 256)
+    eng = xfer.engine()
+    for shape in ((4, 16),          # 256 bytes: not over twice a piece
+                  (7, 100)):        # 7 frames of 400 bytes: no split
+        data = np.arange(np.prod(shape), dtype=np.float32).reshape(shape)
+        out = np.zeros_like(data)
+        fill = eng.host_fill(eng.to_device(data), 'f32', out)
+        fill.wait()
+        assert isinstance(fill.future.result(), np.ndarray)
+        assert np.array_equal(out, data)
+    # and a future asked for outside a ring fill is one array
+    big = np.arange(64 * 16, dtype=np.float32).reshape(64, 16)
+    assert np.array_equal(eng.to_host_async(eng.to_device(big)).result(),
+                          big)
+    assert np.array_equal(eng.to_host(eng.to_device(big)), big)
+
+
+def test_product_on_a_mesh_crosses_whole(monkeypatch):
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    if len(jax.devices()) < 2:
+        pytest.skip('one device')
+    monkeypatch.setattr(xfer, '_D2H_PIECE_BYTES', 256)
+    mesh = Mesh(np.array(jax.devices()[:2]), ('t',))
+    data = np.arange(64 * 16, dtype=np.float32).reshape(64, 16)
+    arr = jax.device_put(data, NamedSharding(mesh, P('t')))
+    out = np.zeros_like(data)
+    fill = xfer.engine().host_fill(arr, 'f32', out)
+    fill.wait()
+    assert isinstance(fill.future.result(), np.ndarray)
+    assert np.array_equal(out, data)
 
 
 # ---------------------------------------------------------------------------

@@ -1,0 +1,338 @@
+#!/usr/bin/env python3
+"""What the runtime's device-to-host readback does, with no pipeline.
+
+Five readings on float32 products of the gpuspec cell's own shape,
+``(16384, 4, 1024)`` (268 MB), each made by a jitted program and
+``block_until_ready`` before any clock starts.  One JSON line on
+standard output; imports ``jax`` and ``numpy`` only.
+
+1. ``np.asarray(x)`` cold: seconds and GB/s.
+2. ``x.copy_to_host_async()``, a second's sleep, then ``np.asarray(x)``:
+   milliseconds mean the hint works and the bytes move in the
+   background; the same as 1 means they move inside the call.  And
+   the same with the hint issued while the device is still computing
+   the product, which is when a pipeline's copy block issues it.
+3. 1, 2 and 4 threads, each taking a distinct ready product at the same
+   moment, with and without the hint issued first: wall time of all
+   over wall time of one is the runtime's D2H concurrency.
+4. Eight products hinted back to back, then taken in order: the
+   runtime's sustained D2H rate with nothing else on the host.
+5. 1 again for the same bytes as ``(16384, 4096)`` and as one flat
+   axis: whether a second-minor dimension of 4 costs a padded or
+   strided transfer.
+
+And two beside them, for what a completion worker can buy: one
+thread copies a taken product into a second host buffer (the ring
+fill) while another takes the next product; and a closed loop of
+products, four hinted ahead, taken and then filled on one thread or on
+two, alone and while a third thread stages and ships one 268 MB int8
+gulp to the device for every product taken (the cell's H2D bytes, as
+one flat axis: in the cell's own shape, ``(16384, 2, 4096, 2)``, the
+runtime's host-side copy of two gulps in flight passed the machine's
+40 GiB in two calls of four; PERF.md section 6, PR 27).  The same
+loop once more with each product split on the device into 16 pieces
+of 16 MiB, each hinted and taken by itself: what the fresh pages of
+a 268 MB landing buffer cost, in seconds and in CPU seconds a product
+(``xfer._D2H_PIECE_BYTES``).
+
+    chiprun -- python3 tools/d2h_probe.py
+"""
+
+import json
+import resource
+import sys
+import threading
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+SHAPE = (16384, 4, 1024)
+NBYTES = 4 * int(np.prod(SHAPE))
+
+
+def _maker(shape):
+    n = int(np.prod(shape))
+    return jax.jit(lambda s: (jnp.arange(n, dtype=jnp.float32)
+                              * s).reshape(shape))
+
+
+_made = [0]
+
+
+def products(make, count, wait=True):
+    """``count`` distinct ready products (a jax array caches its host
+    value, so every reading takes fresh ones)."""
+    out = []
+    for _ in range(count):
+        _made[0] += 1
+        out.append(make(jnp.float32(_made[0])))
+    if wait:
+        jax.block_until_ready(out)
+    return out
+
+
+def timed(fn, *args):
+    t0 = time.perf_counter()
+    fn(*args)
+    return time.perf_counter() - t0
+
+
+def take_together(xs):
+    """Wall seconds for len(xs) threads to each ``np.asarray`` one
+    product, released at the same moment."""
+    gate = threading.Barrier(len(xs) + 1)
+    done = []
+
+    def take(x):
+        gate.wait()
+        np.asarray(x)
+        done.append(time.perf_counter())
+
+    threads = [threading.Thread(target=take, args=(x,)) for x in xs]
+    for t in threads:
+        t.start()
+    gate.wait()
+    t0 = time.perf_counter()
+    for t in threads:
+        t.join()
+    return max(done) - t0
+
+
+PIECES = 16
+_in_pieces = jax.jit(lambda x: tuple(jnp.split(x, PIECES, axis=0)))
+
+
+def _cpu_s():
+    r = resource.getrusage(resource.RUSAGE_SELF)
+    return r.ru_utime + r.ru_stime
+
+
+def loop_period(make, split, h2d, seconds=5.0, depth=4, pieces=False):
+    """Seconds, and CPU seconds of the whole process, a product in a
+    closed loop: ``depth`` products hinted ahead, the oldest taken
+    (``np.asarray``) and then copied into a ring slot, on the taking
+    thread or (``split``) on a second one; with ``h2d`` (a shape), a
+    third thread stages and ships one int8 gulp of that shape for
+    every product taken; with ``pieces`` every product is split on the
+    device into ``PIECES`` along its leading axis and crosses piece by
+    piece."""
+    import queue
+    ring = [np.zeros(SHAPE, np.float32) for _ in range(2)]
+    rows = SHAPE[0] // PIECES
+
+    def fill(slot, host):
+        if pieces:
+            for i, piece in enumerate(host):
+                np.copyto(slot[i * rows:(i + 1) * rows], piece)
+        else:
+            np.copyto(slot, host)
+
+    stop = threading.Event()
+    fills = queue.Queue(maxsize=1)
+    ships = threading.Semaphore(0)
+
+    def filler():
+        while True:
+            item = fills.get()
+            if item is None:
+                return
+            fill(ring[item[0] % 2], item[1])
+
+    def shipper():
+        src = np.ones(h2d, np.int8)
+        stage = np.empty_like(src)
+        held = []
+        while True:
+            ships.acquire()
+            if stop.is_set():
+                return
+            np.copyto(stage, src)                  # the source's copy
+            np.copyto(src, stage)                  # the staging copy
+            held.append(jax.device_put(stage))
+            if len(held) > 1:
+                held.pop(0).block_until_ready()
+
+    threads = [threading.Thread(target=filler)] if split else []
+    if h2d:
+        threads.append(threading.Thread(target=shipper))
+    for t in threads:
+        t.start()
+    flight = []
+    taken = 0
+    t0 = t_first = time.perf_counter()
+    cpu_first = _cpu_s()
+    while True:
+        x = products(make, 1, wait=False)[0]
+        x = _in_pieces(x) if pieces else (x,)
+        for piece in x:
+            piece.copy_to_host_async()
+        flight.append(x)
+        if len(flight) <= depth:
+            continue
+        host = [np.asarray(piece) for piece in flight.pop(0)]
+        if not pieces:
+            host = host[0]
+        if split:
+            fills.put((taken, host))
+        else:
+            fill(ring[taken % 2], host)
+        ships.release()
+        taken += 1
+        now = time.perf_counter()
+        if taken == 4:
+            t_first, cpu_first = now, _cpu_s()      # warmed up
+        if now - t0 > seconds:
+            break
+    stop.set()
+    fills.put(None)
+    ships.release()
+    for t in threads:
+        t.join()
+    cpu = _cpu_s() - cpu_first
+    return [(now - t_first) / max(taken - 4, 1), cpu / max(taken - 4, 1)]
+
+
+def gbps(seconds, nbytes=NBYTES):
+    return nbytes / seconds / 1e9
+
+
+def main():
+    dev = jax.devices()[0]
+    make = _maker(SHAPE)
+    products(make, 1)                                    # compile
+    out = {'device': {'platform': dev.platform,
+                      'kind': dev.device_kind},
+           'shape': list(SHAPE), 'nbytes': NBYTES}
+
+    # 1: cold
+    cold = [timed(np.asarray, x) for x in products(make, 3)]
+    out['1_cold_s'] = cold
+    out['1_cold_gbps'] = gbps(min(cold))
+
+    # 1 again with a thread of pure Python beside it: the longest time
+    # that thread went without the interpreter lock says whether the
+    # take holds it while the bytes move
+    gaps = []
+    for x in products(make, 2):
+        stop = threading.Event()
+        worst = [0.0]
+
+        def spin():
+            last = time.perf_counter()
+            while not stop.is_set():
+                now = time.perf_counter()
+                worst[0] = max(worst[0], now - last)
+                last = now
+
+        th = threading.Thread(target=spin)
+        th.start()
+        time.sleep(0.05)
+        worst[0] = 0.0
+        took = timed(np.asarray, x)
+        stop.set()
+        th.join()
+        gaps.append([took, worst[0]])
+    out['1_cold_s_and_longest_python_stall_s'] = gaps
+
+    # 2: hinted, a second later
+    hinted = []
+    for x in products(make, 3):
+        x.copy_to_host_async()
+        time.sleep(1.0)
+        hinted.append(timed(np.asarray, x))
+    out['2_hinted_s'] = hinted
+
+    # 2 again, hinted before the product is ready: a program of some
+    # tens of milliseconds, the hint right behind its dispatch
+    slow = jax.jit(lambda x: jax.lax.fori_loop(
+        0, 100, lambda _i, y: y * 1.0001 + 1.0, x))
+    base = products(make, 1)[0]
+    jax.block_until_ready(slow(base))                    # compile
+    unready = []
+    for _ in range(3):
+        x = slow(base)
+        was_ready = x.is_ready()
+        x.copy_to_host_async()
+        time.sleep(1.0)
+        unready.append([was_ready, timed(np.asarray, x)])
+    out['2_hinted_unready_s'] = unready
+    del base
+
+    # 3: concurrent takes
+    conc = {}
+    for hint in (False, True):
+        for n in (1, 2, 4):
+            walls = []
+            for _ in range(2):
+                xs = products(make, n)
+                if hint:
+                    for x in xs:
+                        x.copy_to_host_async()
+                walls.append(take_together(xs))
+            conc['%s_%d' % ('hint' if hint else 'cold', n)] = min(walls)
+    out['3_wall_s'] = conc
+    out['3_ratio_2_over_1'] = {k: conc[k + '_2'] / conc[k + '_1']
+                               for k in ('cold', 'hint')}
+    out['3_ratio_4_over_1'] = {k: conc[k + '_4'] / conc[k + '_1']
+                               for k in ('cold', 'hint')}
+
+    # 4: eight hinted back to back, taken in order
+    xs = products(make, 8)
+    t0 = time.perf_counter()
+    for x in xs:
+        x.copy_to_host_async()
+    each = [timed(np.asarray, x) for x in xs]
+    wall = time.perf_counter() - t0
+    out['4_each_s'] = each
+    out['4_sustained_gbps'] = gbps(wall, 8 * NBYTES)
+    del xs
+
+    # 5: the same bytes in other shapes
+    shapes = {}
+    for shape in ((16384, 4096), (int(np.prod(SHAPE)),)):
+        mk = _maker(shape)
+        products(mk, 1)
+        shapes['x'.join(map(str, shape))] = min(
+            timed(np.asarray, x) for x in products(mk, 3))
+    out['5_cold_s_by_shape'] = shapes
+
+    # beside them: the ring fill of one product under the take of the
+    # next (what a completion worker overlaps)
+    ring = np.empty(SHAPE, np.float32)
+    ring[...] = 0                                        # pages in
+    a, b = products(make, 2)
+    host_a = np.asarray(a)
+    fill_alone = timed(np.copyto, ring, host_a)
+    t0 = time.perf_counter()
+    th = threading.Thread(target=np.copyto, args=(ring, host_a))
+    th.start()
+    take_b = timed(np.asarray, b)
+    th.join()
+    out['fill_alone_s'] = fill_alone
+    out['take_under_fill_s'] = take_b
+    out['fill_and_take_wall_s'] = time.perf_counter() - t0
+
+    out['loop_period_s_and_cpu_s'] = {}
+    out['loop_peak_rss_gb'] = {}
+    jax.block_until_ready(_in_pieces(products(make, 1)[0]))     # compile
+    for name, h2d, pieces in (('alone', None, False),
+                              ('h2d_flat', (NBYTES,), False),
+                              ('alone_in_pieces', None, True)):
+        for split in (False, True):
+            key = '%s_%s' % ('two_threads' if split else 'one_thread',
+                             name)
+            out['loop_period_s_and_cpu_s'][key] = loop_period(
+                make, split, h2d, pieces=pieces)
+            out['loop_peak_rss_gb'][key] = resource.getrusage(
+                resource.RUSAGE_SELF).ru_maxrss * 1024 / 1e9
+            # the line so far: a kill for memory must not lose it
+            print(json.dumps(out), file=sys.stderr, flush=True)
+
+    print(json.dumps(out))
+    return 0
+
+
+if __name__ == '__main__':
+    sys.exit(main())
