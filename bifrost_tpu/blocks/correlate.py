@@ -13,7 +13,17 @@ systolic matmul does not need).
 Two block forms:
 
 - :class:`CorrelateBlock` — stateful: integrates ``nframe_per_integration``
-  frames ACROSS gulps, one output frame per integration.  Under a mesh
+  frames ACROSS gulps, one output frame per integration.  On one
+  device it integrates IN PLACE: the accumulator is a pair of float32
+  (re, im) planes the block keeps for the whole sequence and donates
+  to the program of every gulp, which takes the channels through the
+  engine a chunk at a time (``_VIS_CHUNK_BYTES``), so that a gulp's
+  visibilities never exist whole beside the accumulator; the first
+  gulp of an integration overwrites the planes, the last is followed
+  by the one program that writes the complex64 product.  The block
+  holds two products' worth of HBM, the one integrating and the one
+  it hands on (the TPU computes complex64 on separate planes: a
+  complex accumulator is copied by every add, donated or not).  Under a mesh
   it runs one of two measured plans: time-parallel partial visibilities
   met in a ``psum``, or the CORNER TURN — redistribute the voltages
   from time-sharded to channel-sharded with an on-chip collective
@@ -65,6 +75,22 @@ def _cross_block(x, xg, reim):
     return vis.reshape(f, sr, p, s, p)
 
 
+#: a gulp's channels go through the engine in the fewest equal chunks
+#: whose complex64 visibilities hold at most this many bytes: the
+#: temporaries of the in-place program are a chunk's, not a product's
+#: (0.5-0.7 GB against 3.2 GB at 1024 channels of 512 inputs, by
+#: libtpu's own memory analysis; PERF.md section 6, PR 28)
+_VIS_CHUNK_BYTES = 1 << 28
+
+
+def _chunk_nchan(f, n):
+    """Channels a chunk: the largest divisor of ``f`` whose (n, n)
+    complex64 matrices fit ``_VIS_CHUNK_BYTES`` (one channel at
+    least)."""
+    fit = max(_VIS_CHUNK_BYTES // (8 * n * n), 1)
+    return next(c for c in range(min(fit, f), 0, -1) if f % c == 0)
+
+
 def _corner_turn_mode():
     """BF_XCORR_CORNER_TURN: 'auto' (default — race the psum and
     corner-turn mesh plans at prewarm where probing is on), 'off'
@@ -83,6 +109,7 @@ class CorrelateBlock(TransformBlock):
         self.engine = XEngine(accuracy=accuracy, impl=impl)
         self.accuracy = self.engine.accuracy
         self._fn = {}
+        self.impl_info = None
         #: mesh plan the measured prewarm selected ('psum' or
         #: 'corner:xla' / 'corner:pallas'); published to ProcLog via
         #: impl_info so monitors read what ran
@@ -251,7 +278,16 @@ class CorrelateBlock(TransformBlock):
                         sr = s // mesh.shape[sname]
                         xcorr_prewarm(t_eff, f, sr * p, n)
                         return
-            self.engine.prewarm(t_eff, f_eff, n, int_input=int_input)
+            if mesh is None:
+                # the in-place program calls the engine a chunk of
+                # channels at a time: that is the shape it is raced at
+                f_eff = _chunk_nchan(f, n)
+            winner = self.engine.prewarm(t_eff, f_eff, n,
+                                         int_input=int_input)
+            #: what ran, for monitors and the benchmark's window line
+            self.impl_info = {'engine': winner, 'mesh_plan':
+                              self._mesh_plan if mesh is not None else None,
+                              'nchan_chunk': f_eff}
         except Exception as e:
             # probing is best-effort — the traced default works — but
             # a refusal is never dropped without a word
@@ -359,6 +395,8 @@ class CorrelateBlock(TransformBlock):
         return mesh_fn
 
     def _build(self, shape, dtype, reim, acc_is_none):
+        """The program of a gulp under a mesh (one device integrates
+        in place: :meth:`_build_in_place`)."""
         import jax
         local_vis = self._local_vis_fn(reim)
 
@@ -366,8 +404,7 @@ class CorrelateBlock(TransformBlock):
             vis = local_vis(x)
             return vis if acc is None else acc + vis
 
-        mesh = self.mesh
-        if mesh is not None and self._mesh_geometry(shape) is not None:
+        if self._mesh_geometry(shape) is not None:
             plan = self._mesh_plan
             try:
                 return self._build_mesh(shape, dtype, reim,
@@ -382,8 +419,6 @@ class CorrelateBlock(TransformBlock):
                 raise
 
         jfn = jax.jit(fn)
-        if mesh is None:
-            return jfn
 
         # mesh fallback (e.g. indivisible partial gulp): carried state
         # may be mesh-committed — reconcile device sets first
@@ -395,11 +430,88 @@ class CorrelateBlock(TransformBlock):
             return jfn(x, acc)
         return plain_fn
 
+    def _build_in_place(self, shape, reim, first):
+        """The one-device program of a gulp: ``fn(x, ar, ai) -> (ar,
+        ai)`` with the float32 accumulator planes, shaped as the
+        product's frame (F, S, P, S, P), donated and updated where
+        they lie; ``first`` overwrites them instead of adding."""
+        import jax.numpy as jnp
+        from jax import lax
+        from ..ops.common import donating_jit
+        local_vis = self._local_vis_fn(reim)
+        _, f, s, p = shape[:4]
+        fc = _chunk_nchan(f, s * p)
+
+        def chunk(k, acc, x):
+            # real() and imag() of the engine's complex64 are its own
+            # two planes again once XLA has simplified the program
+            vis = local_vis(lax.dynamic_slice_in_dim(x, k * fc, fc,
+                                                     axis=1))
+            out = []
+            for plane, a in zip((jnp.real(vis), jnp.imag(vis)), acc):
+                if not first:
+                    plane = plane + lax.dynamic_slice_in_dim(
+                        a, k * fc, fc, axis=0)
+                out.append(lax.dynamic_update_slice_in_dim(
+                    a, plane, k * fc, axis=0))
+            return tuple(out)
+
+        def fn(x, ar, ai):
+            if fc == f:
+                return chunk(0, (ar, ai), x)
+            return lax.fori_loop(0, f // fc,
+                                 lambda k, acc: chunk(k, acc, x),
+                                 (ar, ai))
+        return donating_jit(fn, donate_argnums=(1, 2))
+
+    def _integrate_in_place(self, x, reim):
+        """Add one gulp into the block's own planes (made once a
+        sequence, on the gulp's device, and kept from one integration
+        to the next: the first gulp overwrites them)."""
+        import jax.numpy as jnp
+        from ..telemetry import counters
+        first = self.nframe_integrated == 0
+        key = (tuple(x.shape), str(x.dtype), first)
+        fn = self._fn.get(key)
+        if fn is None:
+            fn = self._fn[key] = self._build_in_place(x.shape, reim,
+                                                      first)
+        if self._acc is None:
+            _, f, s, p = x.shape[:4]
+            dev = next(iter(x.devices()))
+            self._acc = tuple(jnp.zeros((f, s, p, s, p), jnp.float32,
+                                        device=dev) for _ in range(2))
+        self._acc = fn(x, *self._acc)
+        counters.inc('correlate.acc_in_place')
+
+    def _product(self):
+        """The finished integration as the output span's frame: the
+        only complex64 program of the block, and it reads the planes
+        where they lie (no temporary: libtpu's memory analysis)."""
+        fn = self._fn.get('product')
+        if fn is None:
+            import jax
+            from jax import lax
+            fn = self._fn['product'] = jax.jit(
+                lambda ar, ai: lax.complex(ar, ai)[None])
+        return fn(*self._acc)
+
     def on_data(self, ispan, ospan):
         import jax.numpy as jnp
+        from ..telemetry import counters
         x = ispan.data
         reim = ispan.tensor['dtype'].kind == 'ci' and \
             not jnp.issubdtype(x.dtype, jnp.complexfloating)
+        if self.mesh is None:
+            self._integrate_in_place(x, reim)
+            self.nframe_integrated += ispan.nframe
+            assert self.nframe_integrated <= self.nframe_per_integration
+            if self.nframe_integrated < self.nframe_per_integration:
+                return 0
+            self.nframe_integrated = 0
+            ospan.set(self._product())
+            counters.inc('correlate.integrations')
+            return 1
         acc_is_none = self._acc is None
         key = (tuple(x.shape), str(x.dtype), acc_is_none)
         fn = self._fn.get(key)
@@ -414,6 +526,7 @@ class CorrelateBlock(TransformBlock):
             out = self._acc[None]    # add the time axis
             self._acc = None
             ospan.set(out.astype(jnp.complex64))
+            counters.inc('correlate.integrations')
             return 1
         return 0
 

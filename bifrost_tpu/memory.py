@@ -29,6 +29,28 @@ def _alignment_from_env():
 
 ALIGNMENT = _alignment_from_env()
 
+#: Depth follows from bytes (docs/transfer.md, "Depth by bytes"): a
+#: span of this many bytes or more (one gulp in a ring, one product on
+#: its way across the host boundary) is LARGE.  Whatever holds spans in
+#: depth, to overlap the work on either side of it, holds large ones
+#: two deep and no deeper: a ring its reader sizes (``span_depth`` of
+#: the default 3), a block's dispatch-ahead queue, the transfer
+#: engine's fills in flight (``INFLIGHT_BYTES``).  The third of a
+#: 268 MB gulp costs a ring a sixtieth of a chip's memory and buys
+#: slack; the third of a 2.1 GB visibility product costs an eighth.
+LARGE_SPAN_BYTES = 1 << 30
+
+#: spans outstanding behind a caller's back (fills in flight, outputs
+#: the device has not been waited for) hold at most this many bytes,
+#: the newest apart: two spans that are just not large
+INFLIGHT_BYTES = 2 * LARGE_SPAN_BYTES
+
+
+def span_depth(span_nbyte, depth):
+    """Spans of ``span_nbyte`` bytes that a buffer asked for ``depth``
+    of them holds: ``depth``, or 2 at most once a span is large."""
+    return depth if span_nbyte < LARGE_SPAN_BYTES else min(depth, 2)
+
 
 def raw_malloc(size, space='system'):
     """Allocate ``size`` bytes in a host space, returned as a uint8 numpy

@@ -1125,8 +1125,9 @@ class Block(BlockScope):
         serializes the host against the device and halves pipeline
         throughput (measured on the spectroscopy bench: 2.0 -> 3.9
         Gsamples/s).  Peak device memory held by the queue is about
-        ``sync_depth`` gulps of outputs — lower sync_depth for
-        HBM-tight workloads.
+        ``sync_depth`` gulps of outputs and at most
+        ``memory.INFLIGHT_BYTES`` besides the newest: large outputs
+        (a 2.1 GB visibility product) drain every time.
 
         Draining waits only on the newest popped gulp, which is
         sufficient on in-order backends (the TPU single-stream runtime);
@@ -1164,7 +1165,12 @@ class Block(BlockScope):
             # rate (waits per device gulp <= 1/sync_depth steady-state)
             counters.inc('pipeline.gulps_device')
             pend.append(arrays)
-        if len(pend) > depth:
+        # depth by bytes (memory.span_depth's rule): the queue keeps
+        # these outputs alive whatever the ring does with them, so it
+        # drains to the newest once they pass INFLIGHT_BYTES too
+        if len(pend) > depth or (len(pend) > 1 and sum(
+                int(getattr(a, 'nbytes', 0)) for gulp in pend
+                for a in gulp) > memory.INFLIGHT_BYTES):
             popped = [pend.popleft() for _ in range(len(pend) - 1)]
             wait = device.force_completion if strict \
                 else device.stream_synchronize

@@ -698,10 +698,28 @@ class Ring(object):
         self._apply_geometry_locked(size, ghost, nringlet)
         return True
 
+    def _publish_capacity(self, size, ghost, nringlet):
+        """Gauges (docs/observability.md): the bytes this ring may hold
+        at its geometry (on the host its buffer, ghost region and all;
+        on the device the arrays of as many spans as fit), and their
+        sum over the live rings of its space."""
+        from .telemetry import counters
+        if self.space == 'tpu':
+            ghost = 0
+        self._capacity_bytes = (size + ghost) * max(nringlet, 1)
+        counters.set_gauge('ring.%s.capacity_bytes' % self.name,
+                           self._capacity_bytes)
+        counters.set_gauge(
+            'ring.held_bytes.%s' % self.space,
+            sum(getattr(r, '_capacity_bytes', 0) for r in live_rings()
+                if r.space == self.space))
+
     def _write_ring_proclog(self):
         """Record this ring's geometry under rings/<name> for the
         monitor tools (reference: ring_impl.cpp:476-489 'size' log:
-        space/binding/ghost/span/stride/nringlet)."""
+        space/binding/ghost/span/stride/nringlet), and in the capacity
+        gauges."""
+        self._publish_capacity(self._size, self._ghost, self._nringlet)
         try:
             from .proclog import ProcLog
             if getattr(self, '_geom_proclog', None) is None:
@@ -1608,13 +1626,17 @@ class ReadSequence(_SequenceAPI):
                 prev.release()
 
     def resize(self, gulp_nframe, buf_nframe=None, buffer_factor=None):
-        """Reader-side buffering request; default buffer_factor=3 gives the
-        double-buffered async depth (reference: ring2.py:312-319)."""
+        """Reader-side buffering request; the default buffer_factor of
+        3 gives the double-buffered async depth (reference:
+        ring2.py:312-319), and 2 once a gulp is large
+        (:func:`bifrost_tpu.memory.span_depth`: depth by bytes)."""
+        tensor = self.tensor
         if buf_nframe is None:
             if buffer_factor is None:
-                buffer_factor = 3
+                from .memory import span_depth
+                buffer_factor = span_depth(
+                    gulp_nframe * tensor['frame_nbyte'], 3)
             buf_nframe = int(np.ceil(gulp_nframe * buffer_factor))
-        tensor = self.tensor
         return self._ring.resize(gulp_nframe * tensor['frame_nbyte'],
                                  buf_nframe * tensor['frame_nbyte'])
 

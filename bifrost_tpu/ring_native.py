@@ -286,6 +286,8 @@ class NativeRing(Ring):
             native.check(self._lib.bft_ring_geometry(
                 self._handle, None, ctypes.byref(size),
                 ctypes.byref(ghost), ctypes.byref(nringlet)))
+            self._publish_capacity(size.value, ghost.value,
+                                   nringlet.value)
             if getattr(self, '_geom_proclog', None) is None:
                 self._geom_proclog = ProcLog('rings/%s' % self.name)
             self._geom_proclog.update({

@@ -85,6 +85,22 @@ rows via ``<name>_bridge_transmit|capture/stats`` proclogs.)
 Observability counters (docs/observability.md; complemented by
 :mod:`bifrost_tpu.telemetry.histograms` for distributions):
 
+- ``ring.<name>.capacity_bytes``           GAUGE: bytes ring ``<name>``
+                                           may hold at its present
+                                           geometry (span depth plus,
+                                           on the host, the ghost
+                                           region), set at every
+                                           resize
+- ``ring.held_bytes.system`` /
+  ``ring.held_bytes.tpu``                  GAUGE: the sum of those over
+                                           the live rings of a space
+- ``xfer.d2h_piece_bytes``                 bytes of ``xfer.d2h_bytes``
+                                           that crossed in pieces
+                                           (docs/transfer.md)
+- ``correlate.integrations``               integrations a CorrelateBlock
+                                           emitted
+- ``correlate.acc_in_place``               gulps it added into its
+                                           donated accumulator planes
 - ``ring.<name>.gulps``                    LOGICAL gulps committed
                                            through ring ``<name>``
                                            (both cores; a macro-gulp
@@ -317,10 +333,11 @@ from __future__ import annotations
 import threading
 from collections import defaultdict
 
-__all__ = ['inc', 'get', 'snapshot', 'reset']
+__all__ = ['inc', 'get', 'snapshot', 'reset', 'set_gauge', 'gauges']
 
 _lock = threading.Lock()
 _counts = defaultdict(int)
+_gauges = {}
 
 
 def inc(name, n=1):
@@ -342,6 +359,20 @@ def snapshot():
 
 
 def reset():
-    """Zero all counters (tests/benchmarks)."""
+    """Zero all counters and forget all gauges (tests/benchmarks)."""
     with _lock:
         _counts.clear()
+        _gauges.clear()
+
+
+def set_gauge(name, value):
+    """Set gauge ``name``: a level (bytes held, a capacity), which the
+    next ``set_gauge`` replaces and nothing adds to."""
+    with _lock:
+        _gauges[name] = value
+
+
+def gauges():
+    """Copy of all gauges as a plain dict."""
+    with _lock:
+        return dict(_gauges)

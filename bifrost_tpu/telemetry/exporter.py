@@ -249,6 +249,7 @@ def snapshot(pipeline=None, rates=False):
         identity['fabric_role'] = ident[1]
     snap = {
         'counters': counts,
+        'gauges': counters.gauges(),
         'histograms': hists,
         'rings': _ring_occupancy(pipeline),
         'devices': _device_stats(),
@@ -282,6 +283,11 @@ def prometheus_text(snap=None):
     for name in sorted(snap.get('counters', {})):
         lines.append('bifrost_tpu_counter_total{name="%s"} %d'
                      % (_esc(name), snap['counters'][name]))
+    if snap.get('gauges'):
+        lines.append('# TYPE bifrost_tpu_gauge gauge')
+    for name in sorted(snap.get('gauges', {})):
+        lines.append('bifrost_tpu_gauge{name="%s"} %g'
+                     % (_esc(name), snap['gauges'][name]))
     hists = snap.get('histograms', {})
     if hists:
         lines.append('# TYPE bifrost_tpu_hist histogram')

@@ -44,23 +44,26 @@ analogue of bifrost's per-block CUDA streams + async memcpy
   (``d2h.peer_wait``).  A block's per-gulp ``drain()`` neither
   completes a fill nor waits for one.  A large product crosses in
   pieces small enough for the allocator to keep between products
-  (``_D2H_PIECE_BYTES``; docs/transfer.md).
+  (``_D2H_PIECE_BYTES``), whatever its dtype and whichever axis has to
+  be cut, each copied into its place in the span as it arrives
+  (:class:`_PieceFuture`; docs/transfer.md).
 
-Complex data crosses the host boundary as (re, im) float planes,
-split on one side and recombined under jit on the other.  The local
-v5e runtime does transfer complex64 both ways — a device_put /
-np.asarray round trip is bit-exact (chip_smoke.py fact ii, PR 21) — so
-the split is no longer required there; taking it out is ROADMAP D6's.
+Device to host, every dtype crosses as it is: the local v5e runtime
+transfers complex64 bit-exactly both ways (chip_smoke.py fact ii,
+PR 21), and complex128 exists on the CPU backend alone, where
+``np.asarray`` hands it over as it is.  Host to device, complex data
+still goes as (re, im) float planes recombined under jit (ROADMAP D6).
 
 Tunables (environment):
 
 - ``BF_XFER_ASYNC=0``      disable the async engine (legacy blocking
                            behavior; also implied by BF_SYNC_STRICT=1)
-- ``BF_XFER_DEPTH``        max in-flight async D2H transfers (default 4)
+- ``BF_XFER_DEPTH``        max in-flight async D2H transfers (default
+                           4), and never more than
+                           ``memory.INFLIGHT_BYTES`` of them besides
+                           the newest
 - ``BF_XFER_STAGING``      staging slots per (shape, dtype) (default 4)
 - ``BF_XFER_STAGE_MIN``    min bytes to use a staging slot (default 16384)
-- ``BF_XFER_MALLOC_TUNE=0``  skip the glibc mallopt tuning (see
-                           _tune_allocator)
 """
 
 from __future__ import annotations
@@ -94,19 +97,25 @@ _ALIGN = 128
 _D2H_WORKERS = 1
 
 #: a product on its way into a host ring span crosses in pieces of at
-#: most this many bytes (split along its leading axis, on the device),
-#: once it is larger than twice this.  The runtime lands every
-#: transfer in a fresh numpy buffer, and glibc serves a request over
-#: 32 MiB with an mmap of its own that it unmaps at free(), whatever
-#: ``mallopt`` says once a process has threads: a 268 MB product then
-#: first-touches 65536 pages, 0.8 CPU-seconds and most of its
-#: ``np.asarray`` on the v5e host.  Pieces of 16 MiB come from the
+#: most this many bytes (cut on the device along its first axis longer
+#: than one), once it is larger than twice this.  The runtime lands
+#: every transfer in a fresh numpy buffer, and glibc serves a request
+#: over 32 MiB with an mmap of its own that it unmaps at free(),
+#: whatever ``mallopt`` says once a process has threads: a 268 MB
+#: product then first-touches 65536 pages, 0.8 CPU-seconds and most of
+#: its ``np.asarray`` on the v5e host.  Pieces of 16 MiB come from the
 #: heap and are found again there by the next product's
 #: (tools/d2h_probe.py; PERF.md section 6, PR 27).
 _D2H_PIECE_BYTES = 16 << 20
 
+#: pieces of a LARGE product (``memory.LARGE_SPAN_BYTES``) are cut from
+#: it this many at a time, one group on its way while the one before
+#: is copied into the span: the pieces on the device are 2 x 8 x 16 MiB
+#: and never a second product.  A smaller product is cut in one
+#: program, all of it on its way at once (PR 27's 268 MB products).
+_D2H_GROUP = 8
+
 _combine_fn = None
-_split_fn = None
 
 
 def _combine(re, im):
@@ -117,46 +126,52 @@ def _combine(re, im):
     return _combine_fn(re, im)
 
 
-def _split(arr):
-    global _split_fn
-    if _split_fn is None:
-        import jax
-        import jax.numpy as jnp
-        _split_fn = jax.jit(lambda c: (jnp.real(c), jnp.imag(c)))
-    return _split_fn(arr)
-
-
-_pieces_fn = None
-
-
-class _Pieces(list):
-    """The host copies of a product that crossed in pieces, in order
-    along its leading axis (a :class:`TransferFuture`'s result, for
-    :class:`HostFill` alone)."""
-
-
-def _in_pieces(arr):
-    """``arr`` split along its leading axis into the fewest equal
-    pieces of at most ``_D2H_PIECE_BYTES`` and at least half that (one
-    program on the device), or None where it is small enough to cross
-    whole, lies on more than one device, or does not divide so."""
+def _piece_plan(arr):
+    """``(axis, step)``: ``arr`` crosses in pieces of ``step`` indices
+    (the most that fit ``_D2H_PIECE_BYTES``, one at least; the last
+    piece may be shorter) along ``axis``, its first axis longer than
+    one, so that every piece is one stretch of the product's bytes.
+    None where it crosses whole: no larger than two pieces, or on
+    more than one device."""
     nbytes = int(arr.nbytes)
-    if nbytes <= 2 * _D2H_PIECE_BYTES or not arr.ndim or \
+    if nbytes <= 2 * _D2H_PIECE_BYTES or \
             len(arr.sharding.device_set) != 1:
         return None
-    rows = arr.shape[0]
-    least = -(-nbytes // _D2H_PIECE_BYTES)
-    n = next((n for n in range(least, min(2 * least, rows) + 1)
-              if rows % n == 0), None)
-    if n is None:
-        return None
-    global _pieces_fn
-    if _pieces_fn is None:
+    axis = next(i for i, n in enumerate(arr.shape) if n > 1)
+    return axis, max(_D2H_PIECE_BYTES * arr.shape[axis] // nbytes, 1)
+
+
+#: the runtime hands a device array to the host in the device's own
+#: order of axes, which for an array whose last axis is shorter than a
+#: lane is not the host's: a (1, 8, 256, 2, 256, 2) complex64 piece
+#: arrives with strides (.., 8192, 4096, 8, 2048), and copying it into
+#: a span is a gather at 2.5 GB/s where a row-major piece copies at
+#: 10 (my chip runs, PERF.md section 6, PR 28).  Such pieces are cut as
+#: rows, ``(step, everything else)``: the same bytes in the same
+#: order, relaid on the device.
+_LANE = 128
+
+_cut_fn = None
+
+
+def _cut(arr, start, axis, step, count, rows):
+    """``count`` pieces of ``step`` indices along ``axis`` from index
+    ``start`` on, as ``(step, the rest)`` with ``rows``: one program
+    on the device, whose start is an argument, so that one compilation
+    serves every group of every product of a shape."""
+    global _cut_fn
+    if _cut_fn is None:
         import jax
-        import jax.numpy as jnp
-        _pieces_fn = jax.jit(
-            lambda x, n: tuple(jnp.split(x, n, axis=0)), static_argnums=1)
-    return _pieces_fn(arr, n)
+        from jax import lax
+
+        def cut(x, start, axis, step, count, rows):
+            pieces = (lax.dynamic_slice_in_dim(x, start + j * step,
+                                               step, axis)
+                      for j in range(count))
+            return tuple(p.reshape(step, -1) if rows else p
+                         for p in pieces)
+        _cut_fn = jax.jit(cut, static_argnums=(2, 3, 4, 5))
+    return _cut_fn(arr, start, axis, step, count, rows)
 
 
 def _counters():
@@ -186,6 +201,27 @@ def _timed(name, cat, hist=None, **args):
 def _first(host):
     """The identity conversion of a one-array readback."""
     return host[0]
+
+
+def _cross(arrays, nbytes, convert=_first):
+    """Host copies of device ``arrays`` whose readback has been
+    started, converted: D2H completion as the host sees it, in its
+    parts (the wait for the device and the DMA's remainder, the copy
+    out of the runtime's buffer, the conversion where there is one)."""
+    from jax import block_until_ready
+    with _timed('d2h', 'xfer', 'xfer.d2h_wait_s', bytes=nbytes):
+        faults.fire('xfer.result')
+        live = [a for a in arrays if not a.is_deleted()]
+        if not all(a.is_ready() for a in live):
+            _counters().inc('xfer.sync_waits')
+        with _timed('d2h.ready', 'wait', 'xfer.d2h_ready_s'):
+            block_until_ready(live)
+        with _timed('d2h.asarray', 'xfer', 'xfer.d2h_asarray_s'):
+            host = [np.asarray(a) for a in arrays]
+        if convert in (_first, list):      # no conversion: no span
+            return convert(host)
+        with _timed('d2h.convert', 'xfer', 'xfer.d2h_convert_s'):
+            return convert(host)
 
 
 def _peer_wait():
@@ -240,35 +276,6 @@ def _alloc_aligned(shape, dtype):
     raw = np.empty(nbytes + _ALIGN, np.uint8)
     off = (-raw.ctypes.data) % _ALIGN
     return raw[off:off + nbytes].view(dtype).reshape(shape)
-
-
-_allocator_tuned = False
-
-
-def _tune_allocator():
-    """Raise glibc's mmap threshold so gulp-sized staging buffers come
-    from the heap arena instead of per-allocation mmap/munmap.
-
-    On zero-copy backends every transfer needs a fresh buffer (see
-    _StagingPool), and glibc unmaps large free()d chunks immediately —
-    so each gulp would re-fault ~nbytes/4K pages.  Keeping gulp-scale
-    allocations heap-resident removes that churn; this is the CPU
-    analogue of the reference keeping a pinned staging area alive
-    (cudaHostAlloc) instead of re-registering per copy.  Best-effort
-    and glibc-only; BF_XFER_MALLOC_TUNE=0 opts out."""
-    global _allocator_tuned
-    if _allocator_tuned or \
-            os.environ.get('BF_XFER_MALLOC_TUNE', '1') == '0':
-        _allocator_tuned = True
-        return
-    _allocator_tuned = True
-    try:
-        import ctypes
-        libc = ctypes.CDLL('libc.so.6')
-        M_MMAP_THRESHOLD = -3
-        libc.mallopt(M_MMAP_THRESHOLD, 1 << 28)
-    except Exception:
-        pass
 
 
 def _zero_copy_backend():
@@ -477,39 +484,25 @@ class TransferFuture(object):
             if self._error is not None:
                 raise self._error
             return self._result
-        from jax import block_until_ready
-        # D2H completion as the host sees it, in its parts: the wait
-        # for the device and the DMA's remainder, the copy out of the
-        # runtime's buffer, the conversion
-        with _timed('d2h', 'xfer', 'xfer.d2h_wait_s',
-                    bytes=self._nbytes):
-            try:
-                faults.fire('xfer.result')
-                live = [a for a in self._arrays if not a.is_deleted()]
-                if not all(a.is_ready() for a in live):
-                    _counters().inc('xfer.sync_waits')
-                with _timed('d2h.ready', 'wait', 'xfer.d2h_ready_s'):
-                    block_until_ready(live)
-                with _timed('d2h.asarray', 'xfer',
-                            'xfer.d2h_asarray_s'):
-                    host = [np.asarray(a) for a in self._arrays]
-                if self._convert is _first:
-                    self._result = host[0]
-                elif self._convert is _Pieces:
-                    self._result = _Pieces(host)
-                else:
-                    with _timed('d2h.convert', 'xfer',
-                                'xfer.d2h_convert_s'):
-                        self._result = self._convert(host)
-            except Exception as exc:
-                self._error = exc
-                self._done = True
-                self._arrays = []
-                _counters().inc('xfer.errors')
-                raise
+        try:
+            self._result = self._fetch()
+        except Exception as exc:
+            self._error = exc
+            self._done = True
+            self._arrays = []
+            _counters().inc('xfer.errors')
+            raise
         self._done = True
         self._arrays = []      # drop device refs promptly
         return self._result
+
+    def _fetch(self):
+        return _cross(self._arrays, self._nbytes, self._convert)
+
+    @property
+    def nbytes(self):
+        """Bytes of the device arrays this transfer brings over."""
+        return self._nbytes
 
     @property
     def error(self):
@@ -518,6 +511,98 @@ class TransferFuture(object):
     @property
     def done(self):
         return self._done
+
+
+class _PieceFuture(TransferFuture):
+    """The D2H of one large product in pieces (:func:`_piece_plan`),
+    for :class:`HostFill`: :meth:`land` hands each group of host
+    pieces, with the place of each in the product, to the caller's
+    ``put`` as it arrives, so the product is never whole on the host
+    outside its destination.  The first group is cut and on its way
+    when the future is made, on the caller's thread; whoever lands
+    the future cuts each further group while it takes the one before.
+    The product itself is let go with its last cut.  ``result()``
+    lands it into an array of its own."""
+
+    __slots__ = ('_axis', '_step', '_group', '_shape', '_dtype',
+                 '_row', '_ahead')
+
+    def __init__(self, arr, axis, step, group):
+        super(_PieceFuture, self).__init__([arr], None)
+        self._axis, self._step, self._group = axis, step, group
+        self._shape, self._dtype = arr.shape, arr.dtype
+        self._row = 0
+        self._ahead = self._cut_next()
+
+    def _cut_next(self):
+        """[(device piece, its index in the product)] of the next
+        group, readback started; empty once the product is cut up."""
+        if not self._arrays:
+            return []
+        arr, rows = self._arrays[0], self._shape[self._axis]
+        full = (rows - self._row) // self._step
+        step, count = (self._step, min(full, self._group)) if full \
+            else (rows - self._row, 1)
+        pieces = _cut(arr, self._row, self._axis, step, count,
+                      self._shape[-1] < _LANE)
+        TransferEngine._start_readback(pieces)
+        lead = (slice(None),) * self._axis
+        group = [(p, lead + (slice(self._row + j * step,
+                                   self._row + (j + 1) * step),))
+                 for j, p in enumerate(pieces)]
+        self._row += step * count
+        if self._row >= rows:
+            self._arrays = []
+        return group
+
+    def ready(self):
+        if self._done:
+            return True
+        try:
+            return all(p.is_ready() for p, _where in self._ahead)
+        except Exception:
+            return True            # invalid: landing it will raise
+
+    def land(self, put):
+        """Complete the transfer through ``put(group, last)``, called
+        once a group with ``[(host piece, index)]``."""
+        with _held(self._lock):
+            if not self._done:
+                try:
+                    self._take_into(put)
+                except Exception as exc:
+                    self._error = exc
+                    _counters().inc('xfer.errors')
+                finally:
+                    self._done = True
+            if self._error is not None:
+                raise self._error
+
+    def _take_into(self, put):
+        # under self._lock
+        shape = list(self._shape)
+        shape[self._axis] = -1          # rows are pieces again
+        try:
+            group = self._ahead
+            while group:
+                self._ahead = ahead = self._cut_next()
+                host = _cross([p for p, _where in group],
+                              sum(int(p.nbytes) for p, _where in group),
+                              list)
+                put([(h.reshape(shape), where)
+                     for h, (_p, where) in zip(host, group)], not ahead)
+                group = ahead
+        finally:
+            self._arrays, self._ahead = [], []
+
+    def _fetch(self):
+        out = np.empty(self._shape, self._dtype)
+
+        def put(group, last):
+            for host, where in group:
+                out[where] = host
+        self._take_into(put)
+        return out
 
 
 class HostFill(object):
@@ -542,7 +627,7 @@ class HostFill(object):
     bytes) and, where it is a ``wait``, re-raises; later waits, and
     the engine's next ``drain()``, raise the same error."""
 
-    __slots__ = ('future', 'dtype', 'out', 'begin', 'nbyte',
+    __slots__ = ('future', 'dtype', 'out', 'nbytes', 'begin', 'nbyte',
                  '_storage', '_ring', 'done', 'error', '_lock',
                  '_claimed', '_landed')
 
@@ -550,6 +635,8 @@ class HostFill(object):
         self.future = future
         self.dtype = dtype
         self.out = out_view
+        #: what the fill holds in flight: the product's bytes
+        self.nbytes = int(getattr(out_view, 'nbytes', 0))
         self.begin = None
         self.nbyte = 0
         self._storage = None
@@ -618,30 +705,16 @@ class HostFill(object):
 
     def complete(self, who):
         """The claimant's work: block on the transfer, convert into
-        the span's host view, then redo the ghost mirror for wrapped
-        spans (the commit-time mirror ran before the bytes landed).
-        A failure is recorded, not raised (an interrupt is both).
-        ``who`` is the counter that says which side did it."""
+        the span's host view (piece by piece where it crosses so),
+        then redo the ghost mirror for wrapped spans (the commit-time
+        mirror ran before the bytes landed).  A failure is recorded,
+        not raised (an interrupt is both).  ``who`` is the counter
+        that says which side did it."""
         try:
-            host = self.future.result()
-            from .devrep import from_device_rep
-            # the second pass over the product: into the ring span
-            with _timed('d2h.fill', 'xfer', 'xfer.d2h_fill_s',
-                        bytes=int(getattr(self.out, 'nbytes', 0))):
-                if isinstance(host, _Pieces):
-                    row = 0
-                    for piece in host:
-                        rows = piece.shape[0]
-                        from_device_rep(piece, self.dtype,
-                                        self.out[row:row + rows])
-                        row += rows
-                else:
-                    from_device_rep(host, self.dtype, self.out)
-                with self._lock:
-                    if self._storage is not None and self.nbyte:
-                        self._storage.fill_ghost_mirror(self.begin,
-                                                        self.nbyte)
-                    self.done = True
+            if isinstance(self.future, _PieceFuture):
+                self.future.land(self._put)
+            else:
+                self._put([(self.future.result(), Ellipsis)], True)
         except BaseException as exc:
             with self._lock:
                 self.error = exc
@@ -654,6 +727,22 @@ class HostFill(object):
         finally:
             _counters().inc(who)
             self._landed.set()
+
+    def _put(self, group, last):
+        """The second pass over the product: ``[(host array, its
+        index in the product)]`` into the ring span, and after the
+        ``last`` of them the ghost mirror."""
+        from .devrep import from_device_rep
+        with _timed('d2h.fill', 'xfer', 'xfer.d2h_fill_s',
+                    bytes=sum(int(h.nbytes) for h, _where in group)):
+            for host, where in group:
+                from_device_rep(host, self.dtype, self.out[where])
+            if last:
+                with self._lock:
+                    if self._storage is not None and self.nbyte:
+                        self._storage.fill_ghost_mirror(self.begin,
+                                                        self.nbyte)
+                    self.done = True
 
     @staticmethod
     def _poison(ring, exc):
@@ -703,7 +792,6 @@ class TransferEngine(object):
         self._work = threading.Condition(self._lock)
         self._stop = threading.Event()
         self._workers = []
-        _tune_allocator()
         _obs()[1].watch_jax()
 
     def _is_zero_copy(self):
@@ -1027,40 +1115,36 @@ class TransferEngine(object):
             except Exception:
                 pass               # optional fast-path hint only
 
-    def _future_for(self, arr, pieces=False):
-        """TransferFuture for a jax array (complex split on device).
-        With ``pieces`` a large real-valued array crosses in pieces
-        (:func:`_in_pieces`) and the result is their :class:`_Pieces`."""
+    def _future_for(self, arr, out_view=None):
+        """TransferFuture for a jax array, every dtype as it is.  With
+        ``out_view``, the host view it is bound for, a large array
+        crosses in pieces (:class:`_PieceFuture`) where the view has
+        the array's shape down to the axis that is cut."""
         faults.fire('xfer.d2h')
         import jax
-        import jax.numpy as jnp
         if hasattr(arr, 'as_numpy'):       # bifrost_tpu.ndarray
             return TransferFuture([], lambda _h: None,
                                   result=arr.as_numpy(), done=True)
         if isinstance(arr, np.ndarray):
             return TransferFuture([], lambda _h: None,
                                   result=arr, done=True)
+        nbytes = int(getattr(arr, 'nbytes', 0) or 0)
         c = _counters()
         c.inc('xfer.d2h_issued')
-        c.inc('xfer.d2h_bytes', int(getattr(arr, 'nbytes', 0) or 0))
-        _obs()[0].observe('xfer.d2h_nbytes',
-                          int(getattr(arr, 'nbytes', 0) or 0))
-        if isinstance(arr, jax.Array) and \
-                jnp.issubdtype(arr.dtype, jnp.complexfloating):
-            re, im = _split(arr)
-            self._start_readback((re, im))
-            wide = arr.dtype == jnp.complex128
-            ft = np.float64 if wide else np.float32
-            ct = np.complex128 if wide else np.complex64
-
-            def convert(host):
-                return (host[0].astype(ft) + 1j * host[1]).astype(ct)
-            return TransferFuture([re, im], convert)
-        parts = _in_pieces(arr) if pieces and \
+        c.inc('xfer.d2h_bytes', nbytes)
+        _obs()[0].observe('xfer.d2h_nbytes', nbytes)
+        plan = _piece_plan(arr) if out_view is not None and \
             isinstance(arr, jax.Array) else None
-        if parts is not None:
-            self._start_readback(parts)
-            return TransferFuture(parts, _Pieces)
+        if plan is not None:
+            axis, step = plan
+            if tuple(getattr(out_view, 'shape', ())[:axis + 1]) == \
+                    tuple(arr.shape[:axis + 1]):
+                from .memory import LARGE_SPAN_BYTES
+                c.inc('xfer.d2h_piece_bytes', nbytes)
+                count = -(-arr.shape[axis] // step)
+                return _PieceFuture(
+                    arr, axis, step,
+                    count if nbytes < LARGE_SPAN_BYTES else _D2H_GROUP)
         self._start_readback((arr,))
         return TransferFuture([arr], _first)
 
@@ -1085,12 +1169,25 @@ class TransferEngine(object):
         _counters().inc('xfer.d2h_async')
         with self._lock:
             self._pending.append(fut)
-            over = []
-            while len(self._pending) > self.depth:
-                over.append(self._pending.popleft())
+            over = self._past_bound(self._pending)
         for old in over:
             self._retire(old, old.ready, old.result)
         return fut
+
+    def _past_bound(self, queue):
+        """Under the lock: the oldest transfers of ``queue`` that its
+        newest pushes past the bound, popped.  The bound counts
+        transfers (``depth``) and bytes: the unfinished ones hold at
+        most ``memory.INFLIGHT_BYTES``, the newest apart, so that
+        products of 268 MB are in flight four deep and products of
+        2.1 GB one at a time (docs/transfer.md, "Depth by bytes")."""
+        from . import memory
+        over = []
+        while len(queue) > self.depth or (len(queue) > 1 and sum(
+                t.nbytes for t in queue if not t.done)
+                > memory.INFLIGHT_BYTES):
+            over.append(queue.popleft())
+        return over
 
     @staticmethod
     def _retire(old, ready, wait):
@@ -1113,11 +1210,7 @@ class TransferEngine(object):
         queued for the engine's completion threads.  Bounded like
         to_host_async; completed on the caller, before returning, when
         the engine is disabled."""
-        # in pieces where the span's frames are the product's
-        pieces = getattr(dev_arr, 'ndim', 0) > 0 and \
-            getattr(out_view, 'ndim', 0) > 0 and \
-            dev_arr.shape[0] == out_view.shape[0]
-        fill = HostFill(self._future_for(dev_arr, pieces), dtype,
+        fill = HostFill(self._future_for(dev_arr, out_view), dtype,
                         out_view)
         if not async_enabled():
             fill.wait()
@@ -1125,9 +1218,7 @@ class TransferEngine(object):
         _counters().inc('xfer.d2h_async')
         with self._work:
             self._fills.append(fill)
-            over = []
-            while len(self._fills) > self.depth:
-                over.append(self._fills.popleft())
+            over = self._past_bound(self._fills)
             self._start_workers()
             self._work.notify()
         for old in over:

@@ -28,6 +28,7 @@ object: ``{"ok": true, "device": {...}}`` on success.
 
 import argparse
 import contextlib
+import gc
 import json
 import os
 import shutil
@@ -79,6 +80,15 @@ class Failures(object):
 
     def phase(self, name, fn, *args, **kwargs):
         say('\n== phase %s ==' % name)
+        # the pipelines of the phase before are cycles of blocks and
+        # rings, and their device arrays go only with a collection
+        # (6.7 GB after phase A without it, 2.15 GB with: the last
+        # pipeline's rings and queues are still held somewhere).
+        # Phase B's X-engine gate at the BASELINE shape wants more
+        # than is left either way: it has refused since PR 27 at the
+        # latest, on the parent of PR 28 as on PR 28 (CHANGES.md)
+        gc.collect()
+        say('device bytes in use: %s' % device_bytes_in_use())
         t0 = time.time()
         try:
             out = fn(*args, **kwargs)
@@ -89,6 +99,13 @@ class Failures(object):
             out = None
         say('-- phase %s took %.1f s' % (name, time.time() - t0))
         return out
+
+
+def device_bytes_in_use():
+    """``bytes_in_use`` of the first device, or None where the backend
+    keeps no such statistic (the CPU)."""
+    import jax
+    return (jax.devices()[0].memory_stats() or {}).get('bytes_in_use')
 
 
 def parse_args(argv):

@@ -250,13 +250,29 @@ struct Ring {
                        int64_t new_nringlet) {
         uint8_t* nb = nullptr;
         size_t total = (size_t)new_nringlet * (new_size + new_ghost);
+        if (buf && head <= tail) {
+            // nothing to carry over (a reader's first resize of the
+            // ring its writer has just made): let the old buffer go
+            // first, so that the two are never resident together
+            std::free(buf);
+            buf = nullptr;
+            size = ghost = 0;
+        }
         if (posix_memalign(reinterpret_cast<void**>(&nb), ALIGNMENT,
                            total ? total : ALIGNMENT) != 0)
             return BFT_ERR_ALLOC;
         // bind BEFORE first touch: mbind without MPOL_MF_MOVE only
-        // steers future page faults, and memset faults every page
+        // steers future page faults, and memset faults every page of
+        // what spans are written into.  The ghost region is left to
+        // its first use: it is written before it is read (a wrapped
+        // write mirrors it back, a wrapped read refreshes it first),
+        // and a ring whose spans divide its size never wraps, so its
+        // ghost region, a whole span of 2.1 GB behind a correlator,
+        // stays out of the resident set (PERF.md section 6, PR 28).
         numa_bind_to_core(nb, total, bind_core);
-        std::memset(nb, 0, total);
+        for (int64_t lane = 0; lane < new_nringlet; ++lane)
+            std::memset(nb + lane * (new_size + new_ghost), 0,
+                        (size_t)new_size);
         if (buf && head > tail) {
             // preserve [tail, head) across the re-layout, per lane
             int64_t t = tail, h = head;

@@ -1,0 +1,264 @@
+"""Large products through the pipeline (docs/transfer.md, "Depth by
+bytes"; PERF.md section 6, PR 28): ring depth follows from a span's
+bytes, the correlator integrates in place, the dispatch-ahead queue
+and the fills in flight are bounded by bytes.  The sizes here are
+small; the rule's one constant is patched down to them."""
+
+import numpy as np
+import pytest
+
+import bifrost_tpu as bf
+from bifrost_tpu import memory
+from bifrost_tpu.ring import Ring
+from bifrost_tpu.telemetry import counters
+
+from util import NumpySourceBlock, GatherSink, simple_header
+
+
+# ---------------------------------------------------------------------------
+# ring depth from span bytes
+# ---------------------------------------------------------------------------
+
+def test_the_rule_leaves_gulps_under_a_gibibyte_three_deep():
+    gpuspec, xcorr_in, product = 268435456, 536870912, 2147483648
+    assert memory.span_depth(gpuspec, 3) == 3
+    assert memory.span_depth(xcorr_in, 3) == 3
+    assert memory.span_depth(memory.LARGE_SPAN_BYTES - 1, 3) == 3
+    assert memory.span_depth(memory.LARGE_SPAN_BYTES, 3) == 2
+    assert memory.span_depth(product, 3) == 2
+    assert memory.span_depth(product, 1) == 1       # never deeper
+    # four gpuspec products in flight, one visibility product
+    assert 4 * gpuspec <= memory.INFLIGHT_BYTES < 2 * product
+
+
+@pytest.mark.parametrize('space', ['system', 'tpu'])
+@pytest.mark.parametrize('large', [False, True])
+def test_reader_depth_follows_span_bytes(space, large, monkeypatch):
+    """A reader asks for three spans of a small gulp and two of a
+    large one, in either space, and the gauges say what that holds."""
+    counters.reset()
+    span = 8 * 64 * 4                       # 8 frames of 64 float32
+    monkeypatch.setattr(memory, 'LARGE_SPAN_BYTES',
+                        span if large else span + 1)
+    ring = Ring(space=space)
+    hdr = simple_header([-1, 64], 'f32', gulp_nframe=8)
+    with ring.begin_writing() as w:
+        with w.begin_sequence(hdr, 8, 8):
+            assert ring.total_span == span          # the writer's one
+            with ring.open_earliest_sequence(guarantee=True) as rs:
+                rs.resize(8)
+                depth = 2 if large else 3
+                assert ring.total_span == depth * span
+                assert ring.ghost_span == span
+                held = depth * span + (span if space == 'system' else 0)
+                g = counters.gauges()
+                assert g['ring.%s.capacity_bytes' % ring.name] == held
+                assert g['ring.held_bytes.%s' % space] >= held
+                # an explicit factor is the caller's own
+                rs.resize(8, buffer_factor=4)
+                assert ring.total_span == 4 * span
+
+
+# ---------------------------------------------------------------------------
+# the correlator integrates in place
+# ---------------------------------------------------------------------------
+
+def _voltages(nframe, F, S, P, seed):
+    from bifrost_tpu.dtype import ci8 as ci8_dtype
+    rng = np.random.RandomState(seed)
+    raw = np.zeros((nframe, F, S, P), dtype=ci8_dtype)
+    raw['re'] = rng.randint(-64, 64, size=raw.shape)
+    raw['im'] = rng.randint(-64, 64, size=raw.shape)
+    return raw
+
+
+def _oracle(raw):
+    """x @ x^H over the frames of ``raw`` in int64, as complex64."""
+    T, F, S, P = raw.shape
+    r = raw['re'].astype(np.int64).reshape(T, F, S * P)
+    i = raw['im'].astype(np.int64).reshape(T, F, S * P)
+    re = np.einsum('tfi,tfj->fij', r, r) + np.einsum('tfi,tfj->fij', i, i)
+    im = np.einsum('tfi,tfj->fij', i, r) - np.einsum('tfi,tfj->fij', r, i)
+    return (re + 1j * im).astype(np.complex64).reshape(F, S, P, S, P)
+
+
+@pytest.mark.parametrize('chunked', [False, True])
+def test_correlate_block_integrates_in_place(chunked, monkeypatch):
+    """Three integrations of four gulps, exact against int64; every
+    gulp goes into the block's donated planes, the first of an
+    integration by a program that overwrites them (no zero product),
+    with the channels in one chunk or in several."""
+    import importlib
+    C = importlib.import_module('bifrost_tpu.blocks.correlate')
+    G, F, S, P, NINT, GPI = 8, 6, 3, 2, 3, 4
+    if chunked:     # two channels a chunk: three trips of the loop
+        monkeypatch.setattr(C, '_VIS_CHUNK_BYTES', 2 * 8 * (S * P) ** 2)
+    assert C._chunk_nchan(F, S * P) == (2 if chunked else F)
+    counters.reset()
+    raw = _voltages(G * GPI * NINT, F, S, P, seed=7)
+    hdr = simple_header([-1, F, S, P], 'ci8',
+                        labels=['time', 'freq', 'station', 'pol'],
+                        gulp_nframe=G)
+    seen = []
+    integrate = C.CorrelateBlock._integrate_in_place
+
+    def spy(self, x, reim):
+        before = self._acc
+        integrate(self, x, reim)
+        seen.append((self.nframe_integrated == 0, before, self._acc))
+    monkeypatch.setattr(C.CorrelateBlock, '_integrate_in_place', spy)
+    with bf.Pipeline() as p:
+        src = NumpySourceBlock(
+            [raw[k * G:(k + 1) * G] for k in range(GPI * NINT)], hdr,
+            gulp_nframe=G)
+        b = bf.blocks.copy(src, space='tpu')
+        corr = bf.blocks.correlate(b, nframe_per_integration=G * GPI)
+        b = bf.blocks.copy(corr, space='system')
+        sink = GatherSink(b)
+        p.run()
+    out = sink.result()
+    assert out.shape == (NINT, F, S, P, S, P)
+    assert out.dtype == np.complex64
+    for k in range(NINT):
+        np.testing.assert_array_equal(
+            out[k], _oracle(raw[k * G * GPI:(k + 1) * G * GPI]))
+    assert counters.get('correlate.acc_in_place') == GPI * NINT
+    assert counters.get('correlate.integrations') == NINT
+    # two programs a gulp shape (first / add) and the product's, and
+    # the accumulator each gulp was given is gone: it was donated
+    assert sorted(k[2] for k in corr._fn if k != 'product') == \
+        [False, True]
+    assert [first for first, _b, _a in seen] == \
+        ([True] + [False] * (GPI - 1)) * NINT
+    for _first, before, after in seen[1:]:
+        assert all(a.is_deleted() for a in before)
+        assert all(a.dtype == np.float32 and
+                   a.shape == (F, S, P, S, P) for a in after)
+    assert corr.impl_info['nchan_chunk'] == (2 if chunked else F)
+
+
+def test_correlate_block_float_voltages_in_place():
+    """Complex float voltages take the same in-place program."""
+    T, F, S, P = 8, 4, 3, 2
+    rng = np.random.RandomState(1)
+    v = (rng.randn(2 * T, F, S, P) + 1j * rng.randn(2 * T, F, S, P)) \
+        .astype(np.complex64)
+    hdr = simple_header([-1, F, S, P], 'cf32',
+                        labels=['time', 'freq', 'station', 'pol'],
+                        gulp_nframe=4)
+    counters.reset()
+    with bf.Pipeline() as p:
+        src = NumpySourceBlock([v[k:k + 4] for k in range(0, 2 * T, 4)],
+                               hdr, gulp_nframe=4)
+        b = bf.blocks.copy(src, space='tpu')
+        b = bf.blocks.correlate(b, nframe_per_integration=T)
+        b = bf.blocks.copy(b, space='system')
+        sink = GatherSink(b)
+        p.run()
+    out = sink.result()
+    assert out.shape == (2, F, S, P, S, P)
+    for k in range(2):
+        vm = v[k * T:(k + 1) * T].reshape(T, F, S * P)
+        np.testing.assert_allclose(
+            out[k], np.einsum('tfi,tfj->fij', vm, vm.conj())
+            .reshape(F, S, P, S, P), rtol=1e-4, atol=1e-4)
+    assert counters.get('correlate.acc_in_place') == 4
+
+
+# ---------------------------------------------------------------------------
+# the whole served chain with everything large
+# ---------------------------------------------------------------------------
+
+def test_served_chain_with_large_products(monkeypatch):
+    """host source -> copy('tpu') -> correlate -> copy('system') ->
+    sink with the rule's constant below a product: the product rings
+    are two deep on both sides, the input rings three; the products
+    cross in pieces as complex64, group by group, one in flight at a
+    time; the block's dispatch-ahead queue holds one; every
+    visibility is exact."""
+    from bifrost_tpu import xfer
+    from bifrost_tpu.telemetry import spans
+    G, F, S, P, NINT, GPI = 4, 16, 4, 2, 5, 2
+    product = F * (S * P) ** 2 * 8                  # 8192 bytes
+    gulp = G * F * S * P * 2                        # 1024 bytes
+    monkeypatch.setattr(memory, 'LARGE_SPAN_BYTES', product)
+    monkeypatch.setattr(memory, 'INFLIGHT_BYTES', 2 * product - 1)
+    monkeypatch.setattr(xfer, '_D2H_PIECE_BYTES', 1024)
+    monkeypatch.setattr(xfer, '_D2H_GROUP', 2)
+    xfer.reset_engine()
+    counters.reset()
+    spans.reset()
+    raw = _voltages(G * GPI * NINT, F, S, P, seed=3)
+    hdr = simple_header([-1, F, S, P], 'ci8',
+                        labels=['time', 'freq', 'station', 'pol'],
+                        gulp_nframe=G)
+    most = []
+    fill_of = xfer.TransferEngine.host_fill
+
+    def host_fill(self, dev_arr, dtype, out_view):
+        fill = fill_of(self, dev_arr, dtype, out_view)
+        with self._lock:
+            most.append(sum(f.nbytes for f in self._fills
+                            if not f.done and f is not fill))
+        return fill
+    monkeypatch.setattr(xfer.TransferEngine, 'host_fill', host_fill)
+    with bf.Pipeline() as p:
+        src = NumpySourceBlock(
+            [raw[k * G:(k + 1) * G] for k in range(GPI * NINT)], hdr,
+            gulp_nframe=G)
+        h2d = bf.blocks.copy(src, space='tpu')
+        corr = bf.blocks.correlate(h2d, nframe_per_integration=G * GPI)
+        d2h = bf.blocks.copy(corr, space='system')
+        sink = GatherSink(d2h)
+        p.run()
+    try:
+        out = sink.result()
+        for k in range(NINT):
+            np.testing.assert_array_equal(
+                out[k], _oracle(raw[k * G * GPI:(k + 1) * G * GPI]))
+        g = counters.gauges()
+
+        def cap(block):
+            return g['ring.%s.capacity_bytes' % block.orings[0].name]
+        assert cap(src) == 4 * gulp         # three and the ghost region
+        assert cap(h2d) == 3 * gulp
+        assert cap(corr) == 2 * product
+        assert cap(d2h) == 3 * product      # two and the ghost region
+        assert g['ring.held_bytes.system'] >= 4 * gulp + 3 * product
+        assert g['ring.held_bytes.tpu'] >= 3 * gulp + 2 * product
+        # every product in pieces, four groups of two each, as it is
+        assert counters.get('xfer.d2h_piece_bytes') == \
+            counters.get('xfer.d2h_bytes') == NINT * product
+        names = [ev[0] for _t, ev in spans.events()]
+        assert names.count('d2h.fill') == 4 * NINT
+        assert 'd2h.convert' not in names
+        # no product was queued behind an unfinished one
+        assert len(most) == NINT and max(most) == 0
+        assert len(corr._pending_outputs) == 1
+    finally:
+        xfer.reset_engine()
+
+
+def test_dispatch_ahead_queue_is_bounded_by_bytes(monkeypatch):
+    """A device block's queue of outputs it has not waited for drains
+    to the newest once it holds more than INFLIGHT_BYTES, whatever
+    ``sync_depth`` says."""
+    G, NG = 8, 12
+    data = np.arange(G * NG * 16, dtype=np.float32).reshape(G * NG, 16)
+    hdr = simple_header([-1, 16], 'f32', gulp_nframe=G)
+
+    def waits(bound):
+        monkeypatch.setattr(memory, 'INFLIGHT_BYTES', bound)
+        counters.reset()
+        with bf.Pipeline() as p:
+            src = NumpySourceBlock(
+                [data[k * G:(k + 1) * G] for k in range(NG)], hdr,
+                gulp_nframe=G)
+            b = bf.blocks.copy(src, space='tpu')
+            sink = GatherSink(bf.blocks.copy(b, space='system'))
+            p.run()
+        np.testing.assert_array_equal(sink.result(), data)
+        return counters.get('pipeline.sync_waits')
+    deep = waits(1 << 30)               # by count: once in five gulps
+    assert deep <= NG // 4
+    assert waits(G * 16 * 4) >= NG - 2  # by bytes: every gulp but one

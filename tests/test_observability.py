@@ -233,9 +233,14 @@ def test_span_buffer_env_bounds_events(monkeypatch, tmp_path):
 def test_snapshot_merges_counters_histograms_rings():
     p, sink = _run_simple_pipeline(ngulp=5)
     snap = bf.telemetry.snapshot()
-    assert set(snap) == {'counters', 'histograms', 'rings',
+    assert set(snap) == {'counters', 'gauges', 'histograms', 'rings',
                          'devices', 'mesh', 'tenants', 'scheduler',
                          'identity'}
+    # every ring's capacity, and their sum a space (depth by bytes)
+    caps = {k: v for k, v in snap['gauges'].items()
+            if k.endswith('.capacity_bytes')}
+    assert caps and all(v > 0 for v in caps.values())
+    assert snap['gauges']['ring.held_bytes.system'] > 0
     assert snap['identity']['pid'] == os.getpid()
     assert snap['counters'].get('pipeline.gulps', 0) > 0
     assert any(k.startswith('block.') and k.endswith('.gulp_s')

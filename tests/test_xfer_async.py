@@ -699,15 +699,23 @@ def test_claim_survives_many_racing_threads():
 # products that cross in pieces
 # ---------------------------------------------------------------------------
 
+def _span_names():
+    from bifrost_tpu.telemetry import spans
+    return [ev[0] for _t, ev in spans.events()]
+
+
 @pytest.mark.parametrize('dtype', ['f32', 'ci8', 'cf32'])
 def test_large_product_crosses_in_pieces(dtype, monkeypatch):
-    """A product over twice the piece size is split on the device
-    along its leading axis and lands piece by piece, bit-exact, in a
-    span that wraps (ghost mirror included); a complex product, a
-    small one and one that does not divide cross whole."""
+    """A product over twice the piece size is cut on the device and
+    lands piece by piece, bit-exact, in a span that wraps (ghost
+    mirror included), whatever its dtype: complex64 as complex64, with
+    no conversion on the host."""
     from bifrost_tpu.devrep import to_device_rep
     from bifrost_tpu.dtype import DataType
+    from bifrost_tpu.telemetry import spans
     monkeypatch.setattr(xfer, '_D2H_PIECE_BYTES', 128)
+    counters.reset()
+    spans.reset()
     rng = np.random.RandomState(11)
     nframe, nchan = 24, 32
     if dtype == 'f32':
@@ -744,34 +752,119 @@ def test_large_product_crosses_in_pieces(dtype, monkeypatch):
                     got = np.array(span.data.as_numpy(), copy=True)
     assert np.array_equal(first, data[:8])
     assert np.array_equal(got, data[18:22])
-    for fill in fills:
-        host = fill.future.result()
-        if dtype == 'cf32':
-            assert isinstance(host, np.ndarray)
-        else:
-            # 8 frames of 128 (f32) or 64 (ci8) bytes, in pieces of 128
-            assert isinstance(host, xfer._Pieces)
-            assert sum(h.shape[0] for h in host) == 8
-            assert len(host) == (8 if dtype == 'f32' else 4)
-            assert all(h.nbytes == 128 for h in host)
+    # 8 frames of 128 (f32), 64 (ci8) or 256 (cf32) bytes in pieces of
+    # at most 128: one frame a piece, or two
+    assert all(isinstance(f.future, xfer._PieceFuture) for f in fills)
+    assert fills[0].future._step == (2 if dtype == 'ci8' else 1)
+    assert counters.get('xfer.d2h_piece_bytes') == \
+        counters.get('xfer.d2h_bytes') == 3 * fills[0].nbytes
+    assert 'd2h.convert' not in _span_names()
 
 
-def test_small_or_indivisible_products_cross_whole(monkeypatch):
+def test_small_products_cross_whole_and_uneven_ones_in_pieces(
+        monkeypatch):
+    from bifrost_tpu.telemetry import spans
     monkeypatch.setattr(xfer, '_D2H_PIECE_BYTES', 256)
     eng = xfer.engine()
-    for shape in ((4, 16),          # 256 bytes: not over twice a piece
-                  (7, 100)):        # 7 frames of 400 bytes: no split
+    for shape, pieces in (((4, 16), None),   # 256 bytes: under two pieces
+                          ((7, 100), 7),     # a frame over a piece: one each
+                          ((10, 16), 3)):    # four frames a piece: 4, 4, 2
+        counters.reset()
+        spans.reset()
         data = np.arange(np.prod(shape), dtype=np.float32).reshape(shape)
         out = np.zeros_like(data)
         fill = eng.host_fill(eng.to_device(data), 'f32', out)
         fill.wait()
-        assert isinstance(fill.future.result(), np.ndarray)
+        assert isinstance(fill.future, xfer._PieceFuture) == bool(pieces)
         assert np.array_equal(out, data)
+        assert counters.get('xfer.d2h_piece_bytes') == \
+            (data.nbytes if pieces else 0)
+        if pieces:
+            # one group: every piece was on its way before the first
+            # was taken, and the last one is the remainder
+            assert _span_names().count('d2h.fill') == \
+                (1 if shape[0] % fill.future._step == 0 else 2)
     # and a future asked for outside a ring fill is one array
     big = np.arange(64 * 16, dtype=np.float32).reshape(64, 16)
     assert np.array_equal(eng.to_host_async(eng.to_device(big)).result(),
                           big)
     assert np.array_equal(eng.to_host(eng.to_device(big)), big)
+    # as is the result of a piece future nobody landed
+    fut = eng._future_for(eng.to_device(big), np.zeros_like(big))
+    assert isinstance(fut, xfer._PieceFuture)
+    assert np.array_equal(fut.result(), big)
+
+
+def test_single_frame_complex_product_streams_in_groups(monkeypatch):
+    """A product whose leading axis is one frame (an integration of a
+    correlator) is cut along the first axis that can be cut; a LARGE
+    one is cut a group at a time, never a second product's worth of
+    pieces on the device at once."""
+    import jax
+    from bifrost_tpu import memory
+    from bifrost_tpu.telemetry import spans
+    monkeypatch.setattr(xfer, '_D2H_PIECE_BYTES', 512)
+    monkeypatch.setattr(xfer, '_D2H_GROUP', 2)
+    monkeypatch.setattr(memory, 'LARGE_SPAN_BYTES', 4096)
+    counters.reset()
+    spans.reset()
+    rng = np.random.RandomState(5)
+    shape = (1, 12, 4, 2, 4, 2)              # 12 channels of 512 bytes
+    data = (rng.randn(*shape) + 1j * rng.randn(*shape)) \
+        .astype(np.complex64)
+    held = []
+    cut = xfer._cut
+
+    def counting_cut(arr, start, axis, step, count, rows):
+        pieces = cut(arr, start, axis, step, count, rows)
+        # a last axis under a lane: handed over as rows
+        assert rows and all(p.shape == (1, 64) for p in pieces)
+        held.append(sum(int(p.nbytes) for p in pieces))
+        return pieces
+    monkeypatch.setattr(xfer, '_cut', counting_cut)
+    eng = xfer.engine()
+    out = np.zeros_like(data)
+    fill = eng.host_fill(jax.device_put(data), 'cf32', out)
+    fill.wait()
+    assert np.array_equal(out, data)
+    fut = fill.future
+    assert (fut._axis, fut._step, fut._group) == (1, 1, 2)
+    assert held == [1024] * 6            # six groups of two channels
+    names = _span_names()
+    assert names.count('d2h.fill') == 6 and 'd2h.convert' not in names
+    assert counters.get('xfer.d2h_piece_bytes') == data.nbytes
+    assert fut.done and fut._arrays == [] and fut._ahead == []
+
+
+def test_fills_in_flight_are_bounded_by_bytes(gated, monkeypatch):
+    """The depth bound counts bytes as well as fills: the unfinished
+    fills hold at most INFLIGHT_BYTES besides the newest, so a third
+    product of 600 bytes retires the first, and one that is over the
+    bound alone retires every fill before it."""
+    from bifrost_tpu import memory
+    monkeypatch.setattr(memory, 'INFLIGHT_BYTES', 1500)
+    small = [np.full((150,), v, np.float32) for v in (1.0, 2.0, 3.0)]
+    outs = [np.zeros_like(a) for a in small]
+    fills = [gated.host_fill(a, 'f32', o)
+             for a, o in zip(small[:2], outs[:2])]
+    assert gated.outstanding == 2        # 1200 bytes: both in flight
+
+    def third():
+        fills.append(gated.host_fill(small[2], 'f32', outs[2]))
+    t = threading.Thread(target=third)
+    t.start()
+    t.join(0.2)
+    assert t.is_alive()                  # held up by the first fill
+    gated.futures[0].gate.set()
+    t.join(SOON)
+    assert not t.is_alive() and fills[0].done and not fills[1].done
+    assert np.array_equal(outs[0], small[0])
+    big = np.zeros((500,), np.float32)   # 2000 bytes: over it alone
+    for fut in gated.futures[1:3]:
+        fut.gate.set()
+    within(gated.host_fill, big, 'f32', np.zeros_like(big))
+    assert all(f.done for f in fills)
+    assert [len(gated._fills), gated._fills[0].nbytes] == [1, 2000]
 
 
 def test_product_on_a_mesh_crosses_whole(monkeypatch):
@@ -786,7 +879,7 @@ def test_product_on_a_mesh_crosses_whole(monkeypatch):
     out = np.zeros_like(data)
     fill = xfer.engine().host_fill(arr, 'f32', out)
     fill.wait()
-    assert isinstance(fill.future.result(), np.ndarray)
+    assert not isinstance(fill.future, xfer._PieceFuture)
     assert np.array_equal(out, data)
 
 
