@@ -8,7 +8,11 @@
 - cf16 -> complex64
 
 Conversions are bit-exact round trips.  All transfers ride
-:mod:`bifrost_tpu.xfer` (complex never crosses the host boundary).
+:mod:`bifrost_tpu.xfer`: a complex array goes to the device as (re, im)
+float planes, comes back whole as the complex64 it is, and comes back
+in pieces (a large product bound for a ring span) as rows of 32-bit
+words with re and im interleaved, which the host sees as complex with
+a view; ``from_device_rep`` is handed complex either way.
 """
 
 from __future__ import annotations

@@ -97,6 +97,10 @@ Observability counters (docs/observability.md; complemented by
 - ``xfer.d2h_piece_bytes``                 bytes of ``xfer.d2h_bytes``
                                            that crossed in pieces
                                            (docs/transfer.md)
+- ``xfer.d2h_pair_bytes``                  bytes of those that crossed
+                                           as real (re, im) pairs: the
+                                           complex products' (0, and
+                                           there, where none is)
 - ``correlate.integrations``               integrations a CorrelateBlock
                                            emitted
 - ``correlate.acc_in_place``               gulps it added into its

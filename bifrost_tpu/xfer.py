@@ -48,11 +48,17 @@ analogue of bifrost's per-block CUDA streams + async memcpy
   be cut, each copied into its place in the span as it arrives
   (:class:`_PieceFuture`; docs/transfer.md).
 
-Device to host, every dtype crosses as it is: the local v5e runtime
-transfers complex64 bit-exactly both ways (chip_smoke.py fact ii,
-PR 21), and complex128 exists on the CPU backend alone, where
-``np.asarray`` hands it over as it is.  Host to device, complex data
-still goes as (re, im) float planes recombined under jit (ROADMAP D6).
+Device to host, a product that crosses whole crosses as it is: the
+local v5e runtime transfers complex64 bit-exactly both ways
+(chip_smoke.py fact ii, PR 21), and complex128 exists on the CPU
+backend alone, where ``np.asarray`` hands it over as it is.  The
+pieces of a complex product do not: the runtime interleaves a
+complex64 array on the host, one transfer at a time, at 2.5 GB/s, so
+the cut program interleaves on the device and each piece leaves as
+rows of 32-bit words, re and im element by element, which the host
+sees as complex again with a view (:func:`_pairs`; PERF.md section 6,
+PR 29).  Host to device, complex data still goes as (re, im) float
+planes recombined under jit (ROADMAP D6).
 
 Tunables (environment):
 
@@ -109,11 +115,29 @@ _D2H_WORKERS = 1
 _D2H_PIECE_BYTES = 16 << 20
 
 #: pieces of a LARGE product (``memory.LARGE_SPAN_BYTES``) are cut from
-#: it this many at a time, one group on its way while the one before
-#: is copied into the span: the pieces on the device are 2 x 8 x 16 MiB
-#: and never a second product.  A smaller product is cut in one
-#: program, all of it on its way at once (PR 27's 268 MB products).
+#: it this many at a time, ``_D2H_AHEAD`` groups on their way while the
+#: one before them is copied into the span, and never a second
+#: product.  A smaller product is cut in one program, all of it on its
+#: way at once (PR 27's 268 MB products).
 _D2H_GROUP = 8
+
+#: groups of a large product that are cut, and on their way, ahead of
+#: the group being taken.  A cut queues on the one device stream
+#: behind the programs of the block that makes the products (the
+#: correlator's gulps, 51 ms each), so with one group ahead the
+#: completion thread waits for the device 43 % of its time
+#: (``d2h.ready``) once the transfer itself is cheap.  A group in
+#: flight is 128 MiB of pieces on the device, as much again of the
+#: runtime's own, and its landing buffers on the host.  The served
+#: xcorr cell (PERF.md section 6, PR 29) reads 1806 Msamples/s with
+#: one ahead, 2035-2112 with two (peak HBM 11.95 GB of 13, peak RSS
+#: 26.8 GB as at the parent commit), 5 % more with three or four (12.2
+#: and 12.5 GB; 27.3 and 27.6 GB), no more with five; groups of
+#: sixteen halve what the cuts cost the device (every cut program
+#: splits the whole product into planes first, 13 ms) and read
+#: 2275-2384 two ahead, at 12.63 and 28.1 GB.  Two of eight is what
+#: fits both budgets.
+_D2H_AHEAD = 2
 
 _combine_fn = None
 
@@ -158,7 +182,8 @@ def _cut(arr, start, axis, step, count, rows):
     """``count`` pieces of ``step`` indices along ``axis`` from index
     ``start`` on, as ``(step, the rest)`` with ``rows``: one program
     on the device, whose start is an argument, so that one compilation
-    serves every group of every product of a shape."""
+    serves every group of every product of a shape.  A piece of a
+    complex product leaves as real rows (:func:`_pairs`)."""
     global _cut_fn
     if _cut_fn is None:
         import jax
@@ -168,10 +193,44 @@ def _cut(arr, start, axis, step, count, rows):
             pieces = (lax.dynamic_slice_in_dim(x, start + j * step,
                                                step, axis)
                       for j in range(count))
+            if x.dtype.kind == 'c':
+                return tuple(_pairs(p) for p in pieces)
             return tuple(p.reshape(step, -1) if rows else p
                          for p in pieces)
         _cut_fn = jax.jit(cut, static_argnums=(2, 3, 4, 5))
     return _cut_fn(arr, start, axis, step, count, rows)
+
+
+def _pairs(piece):
+    """A complex piece, under jit, as real rows with re and im
+    interleaved element by element: byte for byte what the host calls
+    complex, so that the runtime moves plain 32-bit words and the host
+    takes the piece with a view (``_PieceFuture._take_group``).  Moves
+    alone, never arithmetic, so NaN payloads, -0.0 and infinities
+    arrive as they left: float32 planes are stacked as the words they
+    are, because libtpu's compiler joins two float arrays with a
+    ``maximum`` over ``-inf`` pads, which no unsigned word minds
+    (float64 planes, which only the CPU backend has, as they are:
+    there a stack is a copy).  A row is the fewest trailing axes that
+    fill a lane, each plane folded to ``(rows, those axes)`` before
+    the two are stacked: XLA then interleaves inside the tiles the
+    planes already have (tools/d2h_probe.py on the chip, the sixteen
+    cuts of a (1, 1024, 256, 2, 256, 2) product: 0.215 s, 0.208 of
+    them the split into planes that every cut program starts with;
+    as ``(step, everything else)`` 0.517, as the complex64 rows of
+    before 0.507; stacking the unfolded planes copies the whole
+    product first)."""
+    import jax.numpy as jnp
+    from jax import lax
+    tail = 1
+    while tail < piece.ndim and \
+            int(np.prod(piece.shape[-tail:])) < _LANE:
+        tail += 1
+    tail = piece.shape[-tail:]
+    planes = [p.reshape((-1,) + tail) for p in (piece.real, piece.imag)]
+    if planes[0].dtype == jnp.float32:
+        planes = [lax.bitcast_convert_type(p, jnp.uint32) for p in planes]
+    return jnp.stack(planes, -1).reshape(-1, 2 * int(np.prod(tail)))
 
 
 def _counters():
@@ -518,11 +577,13 @@ class _PieceFuture(TransferFuture):
     for :class:`HostFill`: :meth:`land` hands each group of host
     pieces, with the place of each in the product, to the caller's
     ``put`` as it arrives, so the product is never whole on the host
-    outside its destination.  The first group is cut and on its way
-    when the future is made, on the caller's thread; whoever lands
-    the future cuts each further group while it takes the one before.
-    The product itself is let go with its last cut.  ``result()``
-    lands it into an array of its own."""
+    outside its destination.  The first groups (``_D2H_AHEAD`` of
+    them) are cut and on their way when the future is made, on the
+    caller's thread; whoever lands the future cuts a further group as
+    it starts on each.  The product itself is let go with its last
+    cut.  A complex product's pieces cross as real (re, im) pairs
+    (:func:`_cut`) and are seen as complex again here, with no pass
+    over them.  ``result()`` lands it into an array of its own."""
 
     __slots__ = ('_axis', '_step', '_group', '_shape', '_dtype',
                  '_row', '_ahead')
@@ -530,36 +591,38 @@ class _PieceFuture(TransferFuture):
     def __init__(self, arr, axis, step, group):
         super(_PieceFuture, self).__init__([arr], None)
         self._axis, self._step, self._group = axis, step, group
-        self._shape, self._dtype = arr.shape, arr.dtype
+        self._shape, self._dtype = arr.shape, np.dtype(arr.dtype)
         self._row = 0
-        self._ahead = self._cut_next()
+        self._ahead = deque()
+        self._cut_ahead()
 
-    def _cut_next(self):
-        """[(device piece, its index in the product)] of the next
-        group, readback started; empty once the product is cut up."""
-        if not self._arrays:
-            return []
-        arr, rows = self._arrays[0], self._shape[self._axis]
-        full = (rows - self._row) // self._step
-        step, count = (self._step, min(full, self._group)) if full \
-            else (rows - self._row, 1)
-        pieces = _cut(arr, self._row, self._axis, step, count,
-                      self._shape[-1] < _LANE)
-        TransferEngine._start_readback(pieces)
-        lead = (slice(None),) * self._axis
-        group = [(p, lead + (slice(self._row + j * step,
+    def _cut_ahead(self):
+        """Cut groups, and start their readback, until ``_D2H_AHEAD``
+        are on their way or the product is cut up: each
+        ``[(device piece, its index in the product)]``."""
+        while self._arrays and len(self._ahead) < _D2H_AHEAD:
+            arr, rows = self._arrays[0], self._shape[self._axis]
+            full = (rows - self._row) // self._step
+            step, count = (self._step, min(full, self._group)) if full \
+                else (rows - self._row, 1)
+            pieces = _cut(arr, self._row, self._axis, step, count,
+                          self._shape[-1] < _LANE)
+            TransferEngine._start_readback(pieces)
+            lead = (slice(None),) * self._axis
+            self._ahead.append(
+                [(p, lead + (slice(self._row + j * step,
                                    self._row + (j + 1) * step),))
-                 for j, p in enumerate(pieces)]
-        self._row += step * count
-        if self._row >= rows:
-            self._arrays = []
-        return group
+                 for j, p in enumerate(pieces)])
+            self._row += step * count
+            if self._row >= rows:
+                self._arrays = []
 
     def ready(self):
         if self._done:
             return True
         try:
-            return all(p.is_ready() for p, _where in self._ahead)
+            return all(p.is_ready() for group in self._ahead
+                       for p, _where in group)
         except Exception:
             return True            # invalid: landing it will raise
 
@@ -580,20 +643,23 @@ class _PieceFuture(TransferFuture):
 
     def _take_into(self, put):
         # under self._lock
+        try:
+            while self._ahead:
+                self._take_group(self._ahead.popleft(), put)
+        finally:
+            self._arrays = []
+            self._ahead.clear()
+
+    def _take_group(self, group, put):
+        """One group to ``put``, the next cut first.  Real pairs are
+        complex again by a view: no pass over them."""
+        self._cut_ahead()
         shape = list(self._shape)
         shape[self._axis] = -1          # rows are pieces again
-        try:
-            group = self._ahead
-            while group:
-                self._ahead = ahead = self._cut_next()
-                host = _cross([p for p, _where in group],
-                              sum(int(p.nbytes) for p, _where in group),
-                              list)
-                put([(h.reshape(shape), where)
-                     for h, (_p, where) in zip(host, group)], not ahead)
-                group = ahead
-        finally:
-            self._arrays, self._ahead = [], []
+        host = _cross([p for p, _where in group],
+                      sum(int(p.nbytes) for p, _where in group), list)
+        put([(h.view(self._dtype).reshape(shape), where)
+             for h, (_p, where) in zip(host, group)], not self._ahead)
 
     def _fetch(self):
         out = np.empty(self._shape, self._dtype)
@@ -1141,6 +1207,10 @@ class TransferEngine(object):
                     tuple(arr.shape[:axis + 1]):
                 from .memory import LARGE_SPAN_BYTES
                 c.inc('xfer.d2h_piece_bytes', nbytes)
+                # of those, the ones that cross as real (re, im) pairs
+                # (0 too, so that a reader finds the counter)
+                c.inc('xfer.d2h_pair_bytes',
+                      nbytes if arr.dtype.kind == 'c' else 0)
                 count = -(-arr.shape[axis] // step)
                 return _PieceFuture(
                     arr, axis, step,

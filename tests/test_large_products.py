@@ -169,11 +169,13 @@ def test_correlate_block_float_voltages_in_place():
 # the whole served chain with everything large
 # ---------------------------------------------------------------------------
 
-def test_served_chain_with_large_products(monkeypatch):
+@pytest.mark.parametrize('ahead', [1, 2])
+def test_served_chain_with_large_products(ahead, monkeypatch):
     """host source -> copy('tpu') -> correlate -> copy('system') ->
     sink with the rule's constant below a product: the product rings
     are two deep on both sides, the input rings three; the products
-    cross in pieces as complex64, group by group, one in flight at a
+    cross in pieces as real (re, im) pairs, group by group, ``ahead``
+    groups cut before the one being taken, one product in flight at a
     time; the block's dispatch-ahead queue holds one; every
     visibility is exact."""
     from bifrost_tpu import xfer
@@ -185,6 +187,7 @@ def test_served_chain_with_large_products(monkeypatch):
     monkeypatch.setattr(memory, 'INFLIGHT_BYTES', 2 * product - 1)
     monkeypatch.setattr(xfer, '_D2H_PIECE_BYTES', 1024)
     monkeypatch.setattr(xfer, '_D2H_GROUP', 2)
+    monkeypatch.setattr(xfer, '_D2H_AHEAD', ahead)
     xfer.reset_engine()
     counters.reset()
     spans.reset()
@@ -226,8 +229,9 @@ def test_served_chain_with_large_products(monkeypatch):
         assert cap(d2h) == 3 * product      # two and the ghost region
         assert g['ring.held_bytes.system'] >= 4 * gulp + 3 * product
         assert g['ring.held_bytes.tpu'] >= 3 * gulp + 2 * product
-        # every product in pieces, four groups of two each, as it is
+        # every product in pieces, four groups of two each, as pairs
         assert counters.get('xfer.d2h_piece_bytes') == \
+            counters.get('xfer.d2h_pair_bytes') == \
             counters.get('xfer.d2h_bytes') == NINT * product
         names = [ev[0] for _t, ev in spans.events()]
         assert names.count('d2h.fill') == 4 * NINT

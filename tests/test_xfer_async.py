@@ -704,107 +704,173 @@ def _span_names():
     return [ev[0] for _t, ev in spans.events()]
 
 
-@pytest.mark.parametrize('dtype', ['f32', 'ci8', 'cf32'])
-def test_large_product_crosses_in_pieces(dtype, monkeypatch):
-    """A product over twice the piece size is cut on the device and
-    lands piece by piece, bit-exact, in a span that wraps (ghost
-    mirror included), whatever its dtype: complex64 as complex64, with
-    no conversion on the host."""
+#: float32 words that arithmetic would not bring through: -0.0, both
+#: infinities, quiet and signalling NaNs of several payloads and both
+#: signs, denormals
+_SPECIAL_WORDS = np.array(
+    [0x80000000, 0x7f800000, 0xff800000, 0x7fc00000, 0x7fc00001,
+     0xffc12345, 0x7f800001, 0xff8abcde, 0x7fffffff, 0x00000001,
+     0x807fffff, 0x3f800000], np.uint32)
+
+
+def _product(dtype, nframe, nchan):
+    """(host data of bifrost dtype ``dtype``, how it reaches the
+    device) for a product of ``(nframe, nchan)``."""
+    import jax
     from bifrost_tpu.devrep import to_device_rep
     from bifrost_tpu.dtype import DataType
-    from bifrost_tpu.telemetry import spans
-    monkeypatch.setattr(xfer, '_D2H_PIECE_BYTES', 128)
-    counters.reset()
-    spans.reset()
     rng = np.random.RandomState(11)
-    nframe, nchan = 24, 32
     if dtype == 'f32':
         data = rng.randn(nframe, nchan).astype(np.float32)
     elif dtype == 'cf32':
         data = (rng.randn(nframe, nchan) +
                 1j * rng.randn(nframe, nchan)).astype(np.complex64)
+    elif dtype == 'cf32_special':
+        # H2D recombines complex with arithmetic: these go as they are
+        words = rng.choice(_SPECIAL_WORDS, (nframe, nchan, 2))
+        return words.view(np.complex64)[..., 0], jax.device_put
+    elif dtype == 'cf64':
+        return (rng.randn(nframe, nchan) +
+                1j * rng.randn(nframe, nchan)), jax.device_put
     else:
         data = np.zeros((nframe, nchan), DataType('ci8').as_numpy_dtype())
         data['re'] = rng.randint(-100, 100, (nframe, nchan))
         data['im'] = rng.randint(-100, 100, (nframe, nchan))
+    return data, lambda gulp: to_device_rep(gulp, dtype)
+
+
+def _words(a):
+    """The bytes of ``a``, for a comparison that tells NaNs apart."""
+    return np.ascontiguousarray(a).view(np.uint8)
+
+
+@pytest.mark.parametrize('dtype', ['f32', 'ci8', 'cf32', 'cf32_special',
+                                   'cf64'])
+def test_large_product_crosses_in_pieces(dtype, monkeypatch):
+    """A product over twice the piece size is cut on the device and
+    lands piece by piece, bit for bit, in a span that wraps (ghost
+    mirror included), whatever its dtype: a complex one as real
+    (re, im) pairs that the host sees as complex again, with no
+    conversion of its own (complex128 on the CPU backend alone)."""
+    import jax
+    from bifrost_tpu.telemetry import spans
+    monkeypatch.setattr(xfer, '_D2H_PIECE_BYTES', 128)
+    counters.reset()
+    spans.reset()
+    nframe, nchan = 24, 32
     eng = xfer.engine()
     from bifrost_tpu.ring import Ring
     ring = Ring(space='system')
-    hdr = simple_header([-1, nchan], dtype, gulp_nframe=8)
+    hdr = simple_header([-1, nchan], dtype.split('_')[0], gulp_nframe=8)
     fills = []
-    with ring.begin_writing() as w:
-        with w.begin_sequence(hdr, 8, 20) as seq:
-            for g0 in (0, 8, 16):            # [16, 24) wraps at 20
-                with seq.reserve(8) as sp:
-                    fill = eng.host_fill(
-                        to_device_rep(data[g0:g0 + 8], dtype), dtype,
-                        sp.data.as_numpy())
-                    sp.set_fill(fill)
-                    sp.commit(8)
-                    fills.append(fill)
-                if g0 == 0:
-                    with ring.open_earliest_sequence(
-                            guarantee=False) as rs:
-                        with rs.acquire(0, 8) as span:
-                            first = np.array(span.data.as_numpy())
-            with ring.open_earliest_sequence(guarantee=False) as rs:
-                with rs.acquire(18, 4) as span:
-                    got = np.array(span.data.as_numpy(), copy=True)
-    assert np.array_equal(first, data[:8])
-    assert np.array_equal(got, data[18:22])
-    # 8 frames of 128 (f32), 64 (ci8) or 256 (cf32) bytes in pieces of
-    # at most 128: one frame a piece, or two
+    with jax.enable_x64(dtype == 'cf64'):
+        data, put = _product(dtype, nframe, nchan)
+        with ring.begin_writing() as w:
+            with w.begin_sequence(hdr, 8, 20) as seq:
+                for g0 in (0, 8, 16):            # [16, 24) wraps at 20
+                    with seq.reserve(8) as sp:
+                        fill = eng.host_fill(
+                            put(data[g0:g0 + 8]), hdr['_tensor']['dtype'],
+                            sp.data.as_numpy())
+                        sp.set_fill(fill)
+                        sp.commit(8)
+                        fills.append(fill)
+                    if g0 == 0:
+                        with ring.open_earliest_sequence(
+                                guarantee=False) as rs:
+                            with rs.acquire(0, 8) as span:
+                                first = np.array(span.data.as_numpy())
+                with ring.open_earliest_sequence(guarantee=False) as rs:
+                    with rs.acquire(18, 4) as span:
+                        got = np.array(span.data.as_numpy(), copy=True)
+    assert first.dtype == got.dtype == data.dtype
+    assert np.array_equal(_words(first), _words(data[:8]))
+    assert np.array_equal(_words(got), _words(data[18:22]))
+    # 8 frames of 128 (f32), 64 (ci8), 256 (cf32) or 512 (cf64) bytes in
+    # pieces of at most 128: one frame a piece, or two
     assert all(isinstance(f.future, xfer._PieceFuture) for f in fills)
     assert fills[0].future._step == (2 if dtype == 'ci8' else 1)
     assert counters.get('xfer.d2h_piece_bytes') == \
         counters.get('xfer.d2h_bytes') == 3 * fills[0].nbytes
+    # the complex ones as real pairs; the counter is there for a
+    # reader whatever it counts
+    assert counters.snapshot()['xfer.d2h_pair_bytes'] == \
+        (3 * fills[0].nbytes if dtype.startswith('cf') else 0)
     assert 'd2h.convert' not in _span_names()
 
 
+@pytest.mark.parametrize('ctype', [np.float32, np.complex64])
 def test_small_products_cross_whole_and_uneven_ones_in_pieces(
-        monkeypatch):
+        ctype, monkeypatch):
+    import jax
     from bifrost_tpu.telemetry import spans
-    monkeypatch.setattr(xfer, '_D2H_PIECE_BYTES', 256)
+    item = np.dtype(ctype).itemsize
+    dtype = 'cf32' if item == 8 else 'f32'
+    monkeypatch.setattr(xfer, '_D2H_PIECE_BYTES', 64 * item)
     eng = xfer.engine()
-    for shape, pieces in (((4, 16), None),   # 256 bytes: under two pieces
+
+    def product(shape):
+        real = np.arange(np.prod(shape), dtype=np.float32).reshape(shape)
+        return real if item == 4 else (real - 1j * real).astype(ctype)
+    for shape, pieces in (((4, 16), None),   # 64 items: under two pieces
                           ((7, 100), 7),     # a frame over a piece: one each
                           ((10, 16), 3)):    # four frames a piece: 4, 4, 2
         counters.reset()
         spans.reset()
-        data = np.arange(np.prod(shape), dtype=np.float32).reshape(shape)
+        data = product(shape)
         out = np.zeros_like(data)
-        fill = eng.host_fill(eng.to_device(data), 'f32', out)
+        fill = eng.host_fill(jax.device_put(data), dtype, out)
         fill.wait()
         assert isinstance(fill.future, xfer._PieceFuture) == bool(pieces)
         assert np.array_equal(out, data)
         assert counters.get('xfer.d2h_piece_bytes') == \
             (data.nbytes if pieces else 0)
+        # a complex product under the threshold crosses as it is
+        assert counters.get('xfer.d2h_pair_bytes') == \
+            (data.nbytes if pieces and item == 8 else 0)
         if pieces:
             # one group: every piece was on its way before the first
             # was taken, and the last one is the remainder
             assert _span_names().count('d2h.fill') == \
                 (1 if shape[0] % fill.future._step == 0 else 2)
     # and a future asked for outside a ring fill is one array
-    big = np.arange(64 * 16, dtype=np.float32).reshape(64, 16)
+    big = product((64, 16))
+    whole = []
+    cross = xfer._cross
+
+    def spy(arrays, *args):
+        whole.extend(a.dtype for a in arrays)
+        return cross(arrays, *args)
+    monkeypatch.setattr(xfer, '_cross', spy)
     assert np.array_equal(eng.to_host_async(eng.to_device(big)).result(),
                           big)
     assert np.array_equal(eng.to_host(eng.to_device(big)), big)
-    # as is the result of a piece future nobody landed
-    fut = eng._future_for(eng.to_device(big), np.zeros_like(big))
+    assert whole == [np.dtype(ctype)] * 2        # crossed as they are
+    # as is the result of a piece future nobody landed, whose pieces
+    # crossed as reals (a complex one's as the words of its reals)
+    del whole[:]
+    fut = eng._future_for(jax.device_put(big), np.zeros_like(big))
     assert isinstance(fut, xfer._PieceFuture)
-    assert np.array_equal(fut.result(), big)
+    got = fut.result()
+    assert got.dtype == big.dtype and np.array_equal(got, big)
+    assert set(whole) == {np.dtype(np.uint32 if item == 8
+                                   else np.float32)}
 
 
-def test_single_frame_complex_product_streams_in_groups(monkeypatch):
+@pytest.mark.parametrize('ahead', [1, 2, 3])
+def test_single_frame_complex_product_streams_in_groups(ahead,
+                                                        monkeypatch):
     """A product whose leading axis is one frame (an integration of a
     correlator) is cut along the first axis that can be cut; a LARGE
-    one is cut a group at a time, never a second product's worth of
-    pieces on the device at once."""
+    one is cut a group at a time, ``_D2H_AHEAD`` groups on their way
+    beside the one being taken and never more."""
+    import weakref
     import jax
     from bifrost_tpu import memory
     from bifrost_tpu.telemetry import spans
     monkeypatch.setattr(xfer, '_D2H_PIECE_BYTES', 512)
     monkeypatch.setattr(xfer, '_D2H_GROUP', 2)
+    monkeypatch.setattr(xfer, '_D2H_AHEAD', ahead)
     monkeypatch.setattr(memory, 'LARGE_SPAN_BYTES', 4096)
     counters.reset()
     spans.reset()
@@ -812,28 +878,46 @@ def test_single_frame_complex_product_streams_in_groups(monkeypatch):
     shape = (1, 12, 4, 2, 4, 2)              # 12 channels of 512 bytes
     data = (rng.randn(*shape) + 1j * rng.randn(*shape)) \
         .astype(np.complex64)
-    held = []
+    held, cut_at, alive = [], [], []
     cut = xfer._cut
 
+    def live():
+        gc.collect()
+        return sum(1 for ref in alive if ref() is not None)
+
     def counting_cut(arr, start, axis, step, count, rows):
+        cut_at.append(live())
         pieces = cut(arr, start, axis, step, count, rows)
-        # a last axis under a lane: handed over as rows
-        assert rows and all(p.shape == (1, 64) for p in pieces)
+        # complex: handed over as rows of 32-bit words, re and im
+        # interleaved
+        assert all(p.shape == (1, 128) and p.dtype == np.uint32
+                   for p in pieces)
         held.append(sum(int(p.nbytes) for p in pieces))
+        alive.extend(weakref.ref(p) for p in pieces)
         return pieces
     monkeypatch.setattr(xfer, '_cut', counting_cut)
     eng = xfer.engine()
     out = np.zeros_like(data)
-    fill = eng.host_fill(jax.device_put(data), 'cf32', out)
-    fill.wait()
-    assert np.array_equal(out, data)
-    fut = fill.future
+    fut = eng._future_for(jax.device_put(data), out)
     assert (fut._axis, fut._step, fut._group) == (1, 1, 2)
+    assert len(held) == len(fut._ahead) == ahead     # cut by the caller
+    landed = []
+
+    def put(group, last):
+        landed.append(live())
+        for host, where in group:
+            assert host.dtype == np.complex64
+            out[where] = host
+    fut.land(put)
+    assert np.array_equal(out, data)
     assert held == [1024] * 6            # six groups of two channels
-    names = _span_names()
-    assert names.count('d2h.fill') == 6 and 'd2h.convert' not in names
-    assert counters.get('xfer.d2h_piece_bytes') == data.nbytes
-    assert fut.done and fut._arrays == [] and fut._ahead == []
+    # before a cut: the group being taken and one short of the
+    # look-ahead; while a group lands: it and the look-ahead
+    assert max(cut_at) <= 2 * ahead and max(landed) <= 2 * (1 + ahead)
+    assert landed[0] == 2 * (1 + ahead) and len(landed) == 6
+    assert counters.get('xfer.d2h_piece_bytes') == \
+        counters.get('xfer.d2h_pair_bytes') == data.nbytes
+    assert fut.done and fut._arrays == [] and not fut._ahead
 
 
 def test_fills_in_flight_are_bounded_by_bytes(gated, monkeypatch):

@@ -35,6 +35,38 @@ of 16 MiB, each hinted and taken by itself: what the fresh pages of
 a 268 MB landing buffer cost, in seconds and in CPU seconds a product
 (``xfer._D2H_PIECE_BYTES``).
 
+The complex case (PR 29; ``complex`` on the command line runs it
+alone, ``float32`` the readings above alone, nothing both): one
+2.147 GB complex64 product of the xcorr cell's own shape,
+``(1, 1024, 256, 2, 256, 2)``, made from random bits so that every NaN
+payload, denormal, infinity and -0.0 is in it, crosses into a touched
+host buffer as the engine does it: cut on the device along its channel
+axis into pieces of 16 MiB, eight to a program, each group hinted as
+it is cut, one group ahead of the one being taken.  What differs is
+the form in which a piece leaves the device:
+
+(a) ``a_complex_rows``: complex64 rows ``(step, rest)``, as xfer.py
+    cut them until PR 29; the host copies each into its place.
+(b) float32 rows with re and im interleaved element by element on the
+    device, seen as complex64 on the host (``h.view``) and copied into
+    place.  ``b_pairs`` folds just enough trailing axes into a row to
+    fill a lane; ``b_pairs_words`` is the same with the planes stacked
+    as the uint32 words they are (xfer.py's cut since PR 29: libtpu's
+    compiler joins two float arrays with a ``maximum``, which is
+    arithmetic); ``b_pairs_step_rows`` is ``(step, 2 * rest)``.
+(c) two float32 planes taken apart, interleaved by the host as it
+    writes them into place (two strided stores): ``c_planes_rows`` as
+    rows ``(step, rest)``, ``c_planes_lane_rows`` as lane-filling rows,
+    ``c_planes_as_is`` in the product's own shape, with no program on
+    the device but the slices.
+
+For each: ``cut_s_a_product`` (the sixteen cut programs of a product
+with nothing else on the device and no transfer), and for each of
+two products in turn the seconds inside ``block_until_ready``,
+``np.asarray`` and the copy into place, the wall time, and
+``asarray_gbps`` / ``wall_gbps``; ``exact`` says the host buffer holds
+the product's bits, word for word.
+
     chiprun -- python3 tools/d2h_probe.py
 """
 
@@ -198,13 +230,172 @@ def gbps(seconds, nbytes=NBYTES):
     return nbytes / seconds / 1e9
 
 
+CSHAPE = (1, 1024, 256, 2, 256, 2)          # xcorr's product, complex64
+CSTEP, CGROUP, LANE = 8, 8, 128             # 16 MiB pieces, eight a program
+
+
+def _lane_rows(shape):
+    """The fewest trailing axes of ``shape`` that fill a lane."""
+    tail = 1
+    while tail < len(shape) and int(np.prod(shape[-tail:])) < LANE:
+        tail += 1
+    return tuple(shape[-tail:])
+
+
+def _pairs(p, tail, words=False):
+    planes = [q.reshape((-1,) + tail) for q in (p.real, p.imag)]
+    if words:
+        planes = [jax.lax.bitcast_convert_type(q, jnp.uint32)
+                  for q in planes]
+    return jnp.stack(planes, -1).reshape(-1, 2 * int(np.prod(tail)))
+
+
+def _interleave(host, place):
+    """(re, im) host planes into a complex64 view: two strided
+    stores."""
+    pair = place.view(np.float32).reshape(-1, 2)
+    pair[:, 0] = host[0].reshape(-1)
+    pair[:, 1] = host[1].reshape(-1)
+
+
+def _as_complex(host, place):
+    place[...] = host[0].view(np.complex64).reshape(place.shape)
+
+
+#: name -> (device arrays of one piece, how they land in their place)
+COMPLEX_FORMS = {
+    'a_complex_rows': (
+        lambda p: [p.reshape(CSTEP, -1)],
+        lambda host, place: np.copyto(place,
+                                      host[0].reshape(place.shape))),
+    'b_pairs': (
+        lambda p: [_pairs(p, _lane_rows(p.shape))], _as_complex),
+    'b_pairs_words': (
+        lambda p: [_pairs(p, _lane_rows(p.shape), words=True)],
+        _as_complex),
+    'b_pairs_step_rows': (
+        lambda p: [_pairs(p, (int(np.prod(p.shape)) // CSTEP,))],
+        _as_complex),
+    'c_planes_rows': (
+        lambda p: [p.real.reshape(CSTEP, -1), p.imag.reshape(CSTEP, -1)],
+        _interleave),
+    'c_planes_lane_rows': (
+        lambda p: [q.reshape(-1, int(np.prod(_lane_rows(p.shape))))
+                   for q in (p.real, p.imag)], _interleave),
+    'c_planes_as_is': (lambda p: [p.real, p.imag], _interleave),
+}
+
+
+def _complex_cut(form):
+    def cut(x, start):
+        out = []
+        for j in range(CGROUP):
+            out += form(jax.lax.dynamic_slice_in_dim(
+                x, start + j * CSTEP, CSTEP, 1))
+        return tuple(out)
+    return jax.jit(cut)
+
+
+def _cross_complex(x, cut, land, out):
+    """One product into ``out`` as the engine does it, one group ahead;
+    seconds by part."""
+    took = {'ready_s': 0.0, 'asarray_s': 0.0, 'fill_s': 0.0}
+
+    def hinted(start):
+        group = cut(x, start)
+        for piece in group:
+            piece.copy_to_host_async()
+        return group
+
+    starts = list(range(0, CSHAPE[1], CSTEP * CGROUP))
+    t0 = time.perf_counter()
+    ahead = hinted(starts[0])
+    for k, start in enumerate(starts):
+        group = ahead
+        ahead = hinted(starts[k + 1]) if k + 1 < len(starts) else None
+        t1 = time.perf_counter()
+        jax.block_until_ready(group)
+        t2 = time.perf_counter()
+        host = [np.asarray(piece) for piece in group]
+        t3 = time.perf_counter()
+        each = len(host) // CGROUP
+        for j in range(CGROUP):
+            row = start + j * CSTEP
+            land(host[j * each:(j + 1) * each], out[0, row:row + CSTEP])
+        t4 = time.perf_counter()
+        took['ready_s'] += t2 - t1
+        took['asarray_s'] += t3 - t2
+        took['fill_s'] += t4 - t3
+    took['wall_s'] = time.perf_counter() - t0
+    took['asarray_gbps'] = gbps(took['asarray_s'], out.nbytes)
+    took['wall_gbps'] = gbps(took['wall_s'], out.nbytes)
+    return took
+
+
+def complex_case():
+    """(a), (b), (c) of the module docstring; the line so far goes to
+    standard error after every form."""
+    out = {'shape': list(CSHAPE),
+           'nbytes': 8 * int(np.prod(CSHAPE)), 'forms': {}}
+    bits = jax.jit(lambda k: jax.random.bits(k, CSHAPE, jnp.uint32))
+    fuse = jax.jit(lambda re, im: jax.lax.complex(
+        jax.lax.bitcast_convert_type(re, jnp.float32),
+        jax.lax.bitcast_convert_type(im, jnp.float32)))
+    prods, wants = [], []
+    for k in range(2):
+        key = jax.random.PRNGKey(29 + k)
+        re, im = bits(key), bits(jax.random.fold_in(key, 1))
+        prods.append(fuse(re, im))
+        want = np.empty(CSHAPE + (2,), np.uint32)
+        want[..., 0] = np.asarray(re)
+        want[..., 1] = np.asarray(im)
+        wants.append(want)
+        del re, im
+    jax.block_until_ready(prods)
+    host = np.zeros(CSHAPE, np.complex64)                # touched
+    starts = range(0, CSHAPE[1], CSTEP * CGROUP)
+    for name, (form, land) in COMPLEX_FORMS.items():
+        cut = _complex_cut(form)
+        jax.block_until_ready(cut(prods[0], 0))          # compile
+        cuts = []
+        for _ in range(2):
+            t0 = time.perf_counter()
+            jax.block_until_ready([cut(prods[0], s) for s in starts])
+            cuts.append(time.perf_counter() - t0)
+        got = {'cut_s_a_product': cuts, 'products': []}
+        for x, want in zip(prods, wants):
+            host[...] = 0
+            took = _cross_complex(x, cut, land, host)
+            have = host.view(np.uint32).reshape(want.shape)
+            took['exact'] = bool(np.array_equal(have, want))
+            if not took['exact']:
+                took['words_wrong'] = int((have != want).sum())
+            got['products'].append(took)
+        got['peak_hbm_gb_so_far'] = (
+            jax.devices()[0].memory_stats() or {}).get(
+            'peak_bytes_in_use', 0) / 1e9
+        out['forms'][name] = got
+        print(json.dumps({'complex': out}), file=sys.stderr, flush=True)
+    return out
+
+
 def main():
     dev = jax.devices()[0]
+    cases = sys.argv[1:] or ['float32', 'complex']
+    out = {'device': {'platform': dev.platform,
+                      'kind': dev.device_kind}}
+    if 'complex' in cases:
+        out['complex'] = complex_case()
+    if 'float32' in cases:
+        float32_case(out)
+    print(json.dumps(out))
+    return 0
+
+
+def float32_case(out):
     make = _maker(SHAPE)
     products(make, 1)                                    # compile
-    out = {'device': {'platform': dev.platform,
-                      'kind': dev.device_kind},
-           'shape': list(SHAPE), 'nbytes': NBYTES}
+    out.update({'shape': list(SHAPE), 'nbytes': NBYTES})
 
     # 1: cold
     cold = [timed(np.asarray, x) for x in products(make, 3)]
@@ -329,9 +520,6 @@ def main():
                 resource.RUSAGE_SELF).ru_maxrss * 1024 / 1e9
             # the line so far: a kill for memory must not lose it
             print(json.dumps(out), file=sys.stderr, flush=True)
-
-    print(json.dumps(out))
-    return 0
 
 
 if __name__ == '__main__':
