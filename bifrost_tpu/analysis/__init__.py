@@ -10,7 +10,7 @@ Two halves:
   surface as runtime stalls, gulp-0 exceptions, or silently degraded
   performance.  Exposed as ``Pipeline.validate()``, gated into
   ``Pipeline.run()`` by ``BF_VALIDATE={off,warn,strict}``, and driven
-  standalone by ``tools/bf_lint.py`` / ``tools/verify_gate.py``.
+  standalone by ``tools/bf_lint.py``.
 
 - :mod:`bifrost_tpu.analysis.ringcheck` — the **dynamic ring-protocol
   checker** (``BF_RINGCHECK=1``): a shadow state machine hooked into

@@ -10,7 +10,7 @@ performance.  Exposed three ways:
   ``warn``: diagnostics print to stderr and publish to the
   ``analysis/verify`` ProcLog so ``tools/pipeline2dot.py`` can overlay
   them on the graph; ``strict`` refuses to start on any ``BF-E``);
-- ``tools/bf_lint.py`` / ``tools/verify_gate.py`` drive it standalone
+- ``tools/bf_lint.py`` drives it standalone
   (``BF_LINT=1`` makes ``Pipeline.run()`` validate-and-return without
   launching threads).
 

@@ -58,9 +58,8 @@ then pins the configuration and dumps it as a reusable JSON profile
 (``BF_AUTOTUNE_PROFILE``, default ``autotune_profile.json``).  A
 profile that already exists at startup is applied as the starting
 configuration in every mode — warm-starting a deployment at its last
-converged optimum (bench_suite config 14 gates that a de-tuned cold
-start converges to within ~5% of the hand-tuned optimum and that the
-dumped profile reproduces it).
+converged optimum (tests/test_autotune.py:
+``test_freeze_dumps_profile_and_warm_starts``).
 """
 
 from __future__ import annotations

@@ -53,8 +53,7 @@ MANY of those pipes into a deployable fabric:
   :class:`AckLedger` journals delivered/shed bytes durably
   (``BF_FABRIC_STATE``) so the loss accounting survives the kill:
   produced == delivered + shed holds byte-exact across all surviving
-  ledgers (the chaos gate, bench_suite config 17 /
-  ``tools/fabric_gate.py``).
+  ledgers (tests/test_fabric.py: ``TestRejoin``).
 """
 
 from __future__ import annotations

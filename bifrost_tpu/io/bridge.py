@@ -40,8 +40,8 @@ hop a pipelined transport instead of a synchronous byte pump:
 v1 endpoints negotiate down cleanly: the receiver auto-detects the
 legacy wire (first frame is a bare MSG_HEADER, not MSG_HELLO) and
 ``RingSender(protocol=1)`` emits it.  ``RingSender(naive=True)``
-additionally reproduces the seed implementation's copying send loop —
-the benchmark baseline arm (bench_suite config 10).
+additionally reproduces the seed implementation's copying send loop
+(no caller is left but tests/test_bridge.py; ROADMAP D10).
 
 Wire framing: [u8 type][u64le length][payload]; v2 payloads begin with
 a u64le frame sequence number.  See docs/networking.md for the full
@@ -394,7 +394,7 @@ def _recv_exact(sock, n):
 def _recv_msg_naive(sock):
     """The seed implementation's receive: chunked ``recv`` into fresh
     bytes objects joined with ``b''.join`` — two extra copies per
-    frame vs the recv_into paths.  Baseline arm of bench config 10."""
+    frame vs the recv_into paths (ROADMAP D10)."""
     hdr = _recv_exact(sock, _FRAME.size)
     mtype, length = _FRAME.unpack(hdr)
     if length > _MAX_FRAME:
@@ -939,8 +939,8 @@ class RingSender(object):
 
     def _run_naive(self):
         """The seed implementation: per-span ``ascontiguousarray`` +
-        ``tobytes`` copies and a blocking ``sendall`` per message —
-        kept as the measured baseline arm of bench_suite config 10."""
+        ``tobytes`` copies and a blocking ``sendall`` per message
+        (ROADMAP D10)."""
         sock = self.socks[0]
         seqs = self._seqs
         ok = False
@@ -1611,8 +1611,8 @@ class RingReceiver(object):
         #: sender replays only frames this side never committed.
         self.adopt_sessions = bool(adopt_sessions)
         #: seed-implementation receive loop (chunked recv + b''.join +
-        #: frombuffer scatter — two extra copies per span); kept as
-        #: the measured baseline arm of bench_suite config 10
+        #: frombuffer scatter — two extra copies per span; ROADMAP
+        #: D10)
         self.naive = bool(naive)
 
         self._writer = writer

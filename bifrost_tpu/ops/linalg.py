@@ -518,7 +518,7 @@ class LinAlg(object):
 
 # ---------------------------------------------------------------------------
 # cross-correlation entry point (FX correlator X-step; blocks.correlate
-# and bench config 5 both route here)
+# routes here)
 # ---------------------------------------------------------------------------
 
 def _xcorr_einsum(re_i, im_i, re_j, im_j):
@@ -730,7 +730,7 @@ def xcorr_prewarm(t, f, n_i, n_j=None):
 
 # ---------------------------------------------------------------------------
 # XEngine: the raced, accuracy-classed X-engine (FX correlator X-step;
-# blocks.correlate and bench config 19 route here).  The beamform-side
+# blocks.correlate routes here).  The beamform-side
 # twin is ops.beamform.Beamformer — same selection machinery, but the
 # correlation has NO weight-quantization step: on ci8 voltage planes
 # the int8 candidates are EXACT (pure int32 accumulation, bit-identical

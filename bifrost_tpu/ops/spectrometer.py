@@ -480,8 +480,8 @@ def choose_precision(nfft=4096, rfactor=4):
     mode, or the string 'off' when the XLA chain should run instead.
 
     'auto' only substitutes the kernel when it matches the float64
-    oracle to f32 accuracy (same 1e-5 bar as bench.py's on-hardware
-    correctness gate) at the requested fft length, so enabling it can
+    oracle to f32 accuracy (same 1e-5 bar as chip_smoke.py's phase A
+    on the chip) at the requested fft length, so enabling it can
     never change science output beyond FFT-algorithm noise.
     """
     import os

@@ -225,8 +225,8 @@ def hlo_stats_enabled():
     """Whether mesh plan builds should ALSO compile an analysis copy and
     count the collectives XLA inserted (``mesh.collectives.<kind>``
     counters).  Off by default — it doubles compile time per plan —
-    BF_MESH_HLO_STATS=1 enables (tests and tools/mesh_gate.py use it to
-    assert the zero-reshard property)."""
+    BF_MESH_HLO_STATS=1 enables (tests use it to assert the
+    zero-reshard property)."""
     return os.environ.get('BF_MESH_HLO_STATS', '0') == '1'
 
 

@@ -28,7 +28,7 @@ temporaries XLA reuses in place).  Rings survive only at supervision,
 tap, multi-reader, mesh-reshard, and host boundaries.
 
 Inside a segment: **0 Python dispatches and 0 ring handoffs per
-gulp** (bench_suite config 16, artifact ``BENCH_SEGMENT_cpu.json``).
+gulp** (tests/test_segments.py: ``test_segment_fuses_and_elides``).
 
 Eligibility is decided by ONE planner (:func:`plan`) shared with the
 static verifier: ``analysis.verify`` reports a ``BF-I190`` diagnostic

@@ -1,9 +1,8 @@
 """Multi-tenant streaming service tier (docs/service.md).
 
-The reference framework — and every bench config in this repo until
-now — runs ONE pipeline per process.  The ROADMAP's north star is a
-production service handling heavy traffic from many users; this module
-is the front-end that turns "a pipeline" into "a service": a
+The reference framework runs ONE pipeline per process.  The ROADMAP's
+north star is a production service handling heavy traffic from many
+users; this module is the front-end that turns "a pipeline" into "a service": a
 :class:`JobManager` runs N concurrent tenant pipelines per host from
 declarative :class:`TenantSpec`\\ s, composing the machinery the
 previous layers built —

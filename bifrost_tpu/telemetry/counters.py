@@ -287,9 +287,7 @@ Fleet observability counters (telemetry.fleet — docs/observability.md
 - ``fleet.pub.msgs`` / ``fleet.pub.bytes``  snapshot messages / wire
                                            bytes a FleetPublisher sent
 - ``fleet.pub.busy_us``                    publisher THREAD-CPU time
-                                           spent building+sending (what
-                                           the <2% obs_overhead fleet
-                                           gate binds on)
+                                           spent building+sending
 - ``fleet.pub.errors``                     publish/send/request
                                            failures (never raised)
 - ``fleet.pub.events``                     out-of-band events pushed

@@ -342,22 +342,19 @@ class FleetPublisher(threading.Thread):
 
     def publish(self, full=False, final=False):
         """Build and send one snapshot message; meters its own busy
-        time on ``fleet.pub.busy_us`` (what the <2% overhead gate in
-        tools/obs_overhead.py --stack fleet binds on).
+        time on ``fleet.pub.busy_us``.
 
         Gathers only the sections the wire format carries — NOT
         ``exporter.snapshot()``, whose device section queries the
-        accelerator runtime per call (~ms each; measured 4% of chain
-        wall at a 4Hz publish interval, double the gate's bound, all
-        spent building sections the message then dropped).
+        accelerator runtime per call (~ms each, all spent building
+        sections the message then dropped).
 
         Busy is metered as THREAD CPU time, not wall: against a hot
         pipeline ~80% of a publish's wall-clock is this thread parked
         waiting for the GIL — time the pipeline was productively
         computing, so charging it to the publisher would double-count
         it.  thread_time is the processor cost the stream actually
-        steals (the A/B arm comparison in obs_overhead cross-checks
-        the wall side)."""
+        steals."""
         clock = getattr(time, 'thread_time', time.perf_counter)
         t0 = clock()
         from . import exporter, histograms

@@ -9,9 +9,9 @@ power-of-two buckets: bucket ``i`` holds values in
 finds the bucket and a 64-int walk yields any percentile — no
 sampling, no reservoir, no numpy on the hot path.  Recording is one
 short critical section per observation (a few arithmetic ops under the
-histogram's own lock), which benchmarks at well under a microsecond —
-the <5% overhead gate in ``tools/watch_and_bench.sh`` holds with these
-always on.
+histogram's own lock), so the histograms are always on; what they
+cost on the served path is part of ``host_cpu_s_per_gsample``
+(PERF.md).
 
 Histogram names used by the framework (the registry is open — blocks
 and operators may add their own):

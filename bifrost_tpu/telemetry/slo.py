@@ -34,8 +34,7 @@ above it increments ``slo.violations`` plus a per-block
 reading either.  Ages always record (the histograms are the
 observability); the budget only adds the violation counting.
 
-Cost: one ``time.time()`` plus one histogram record per commit —
-inside the <5% observability overhead gate (``tools/e2e_gate.py``).
+Cost: one ``time.time()`` plus one histogram record per commit.
 Everything is a no-op for sequences without a trace context
 (``BF_TRACE_CONTEXT=0`` or pre-context peers).
 """

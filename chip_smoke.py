@@ -5,8 +5,9 @@ the chip.  One process, no arguments, exit 0 within 1200 s:
     python3 chip_smoke.py
 
 Phase A drives the main path through the public API at the flagship's
-own width (bench.py: 16384 frames x 2 pol x 4096 fine-time ci8, reduce
-4 — 268 MB in, 268 MB of Stokes f32 out, per gulp): a host source of
+own width (16384 frames x 2 pol x 4096 fine-time ci8, reduce 4 — 268
+MB in, 268 MB of Stokes f32 out, per gulp; this file owns the
+flagship chain, below): a host source of
 seeded gulps -> blocks.copy('tpu') -> blocks.fused(flagship_stages())
 -> blocks.copy('system') -> sink.  Sampled frames of EVERY gulp are
 compared with the float64 numpy oracle at rel <= 1e-5, two runs of one
@@ -40,8 +41,13 @@ import warnings
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
-#: rel. error bar against the float64 oracle (bench.py --check and
-#: ops/spectrometer.choose_precision use the same)
+#: the flagship gulp (BASELINE.json config 2, upstream
+#: testbench/gpuspec_simple.py): frames per gulp, polarisations,
+#: fine-time samples (the FFT length) and the frequency reduction
+NTIME, NPOL, NFINE, RFACTOR = 16384, 2, 4096, 4
+
+#: rel. error bar against the float64 oracle
+#: (ops/spectrometer.choose_precision uses the same)
 ORACLE_RTOL = 1e-5
 NGULP_WARM = 2
 NGULP_STEADY = 6
@@ -58,9 +64,24 @@ def first_line(exc):
                        text.splitlines()[0][:400] if text else '')
 
 
+def flagship_header(npol=NPOL, nfine=NFINE):
+    """The flagship gulp's ring header."""
+    return {'name': 'flagship', 'time_tag': 0,
+            '_tensor': {'shape': [-1, npol, nfine], 'dtype': 'ci8',
+                        'labels': ['time', 'pol', 'fine_time'],
+                        'scales': [[0, 1]] * 3, 'units': [None] * 3}}
+
+
+def flagship_stages(rfactor=RFACTOR):
+    """The flagship FFT -> detect -> reduce stage chain."""
+    from bifrost_tpu.stages import FftStage, DetectStage, ReduceStage
+    return [FftStage('fine_time', axis_labels='freq'),
+            DetectStage('stokes', axis='pol'),
+            ReduceStage('freq', rfactor)]
+
+
 def rel_err(got, want):
-    """Max abs error relative to the reference's peak (bench.py
-    --check's measure)."""
+    """Max abs error relative to the reference's peak."""
     import numpy as np
     return float(np.max(np.abs(got - want)) /
                  (np.max(np.abs(want)) or 1.0))
@@ -214,12 +235,8 @@ def run_chain(gulps, ntime, npol, nfine, rfactor, sample_idx, mesh=None):
     import bifrost_tpu as bf
     from bifrost_tpu.pipeline import SourceBlock, SinkBlock
     from bifrost_tpu.telemetry import counters
-    from bench import flagship_stages
 
-    header = {'name': 'chip_smoke', 'time_tag': 0,
-              '_tensor': {'shape': [-1, npol, nfine], 'dtype': 'ci8',
-                          'labels': ['time', 'pol', 'fine_time'],
-                          'scales': [[0, 1]] * 3, 'units': [None] * 3}}
+    header = flagship_header(npol, nfine)
 
     class VoltageSource(SourceBlock):
         """Host source: copies the next seeded gulp into the ring."""
@@ -288,7 +305,7 @@ def run_chain(gulps, ntime, npol, nfine, rfactor, sample_idx, mesh=None):
         src = VoltageSource()
         with scope:
             h2d = bf.blocks.copy(src, space='tpu')
-            fused = bf.blocks.fused(h2d, flagship_stages())
+            fused = bf.blocks.fused(h2d, flagship_stages(rfactor))
             d2h = bf.blocks.copy(fused, space='system')
         if mesh is not None:
             taps = {'h2d': ShardTap(h2d), 'fused': ShardTap(fused)}
@@ -498,8 +515,8 @@ def verdict(build, want, rtol, hard=False):
 
 
 def b_fdmt(seed):
-    """FDMT nchan 256 / max_delay 100 / T 8192 (BASELINE.json config 3;
-    bench_suite.bench_fdmt's plan)."""
+    """FDMT nchan 256 / max_delay 100 / T 8192 (BASELINE.json config
+    3)."""
     import numpy as np
     import jax.numpy as jnp
     from bifrost_tpu.ops.fdmt import Fdmt, fdmt_numpy, fdmt_gate_rtol
@@ -857,7 +874,6 @@ def main(argv=None):
 
     import bifrost_tpu as bf
     from bifrost_tpu import native
-    import bench
     fails = Failures()
     if not native.available():
         fails.add('the native ring core was expected and is not loaded')
@@ -868,8 +884,7 @@ def main(argv=None):
            if os.environ.get('JAX_COMPILATION_CACHE_DIR')
            else 'the checkout default', ncache0))
 
-    sizes = (args.ntime or bench.NTIME, bench.NPOL,
-             args.nfine or bench.NFINE, bench.RFACTOR)
+    sizes = (args.ntime or NTIME, NPOL, args.nfine or NFINE, RFACTOR)
     result = {'device': device, 'rehearsal': rehearsal,
               'seed': args.seed, 'versions': vers}
     with warnings.catch_warnings():

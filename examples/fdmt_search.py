@@ -1,5 +1,4 @@
-"""FDMT FRB-search demo — the bench config-22 chain end to end
-(reference: testbench/test_fdmt.py; bench_suite.bench_fdmt_chain and
+"""FDMT FRB-search demo (reference: testbench/test_fdmt.py;
 docs/perf.md "FDMT FRB search"): synthesize dispersed pulses in a
 filterbank stream, dedisperse with the stage-backed FDMT engine,
 matched-filter across pulse widths, threshold at a fixed false-alarm

@@ -1,6 +1,6 @@
-"""Distributed FX correlator demo — the bench config-19 chain end to
-end (reference architecture: the xGPU-style FX pipeline, arXiv:
-1107.4264; bench_suite.bench_fxcorr and docs/perf.md "FX correlator").
+"""Distributed FX correlator demo (reference architecture: the
+xGPU-style FX pipeline, arXiv:1107.4264; docs/perf.md "FX
+correlator").
 
   synthetic ci8 stations -> copy('tpu') -> FFT(fine -> freq)  [F]
     -> requantize ci8 -> CorrelateStageBlock (raced X-engine)  [X]

@@ -24,6 +24,7 @@ from bifrost_tpu.analysis.verify import (CODES, PipelineValidationError)
 from bifrost_tpu.ring import Ring, RingPoisonedError
 from bifrost_tpu.stages import FftStage, DetectStage, ReduceStage
 from bifrost_tpu.testing import faults
+from tests.topologies import TOPOLOGIES
 from tests.util import NumpySourceBlock, GatherSink, simple_header
 
 pytestmark = pytest.mark.faults
@@ -52,8 +53,8 @@ def _codes(diags):
 
 
 def test_clean_chain_validates_clean():
-    """The config-8 chain (the hot path every bench runs) must verify
-    with zero errors/warnings — the strict gate depends on this.
+    """The fused spectroscopy chain (the benchmark's hot path) must
+    verify with zero errors/warnings.
     Info-level findings are allowed (BF-I190 inventories the unfused
     device-ring boundaries on every chain, by design); anything
     visible in warn mode is not."""
@@ -70,6 +71,19 @@ def test_clean_chain_validates_clean():
     assert visible == [], _codes(visible)
     # the info inventory names each non-fused device-ring boundary
     assert {d.code for d in diags} <= {'BF-I190'}, _codes(diags)
+
+
+@pytest.mark.parametrize('name', sorted(TOPOLOGIES))
+def test_shipped_topologies_validate_clean(name):
+    """Every topology the repo ships (tests/topologies.py) builds and
+    verifies with nothing above info level: no error, and none of the
+    warnings its builder's docstring rules out.  The verifier's rules
+    and the blocks' declarations must not drift apart on any of
+    them."""
+    built = TOPOLOGIES[name]()
+    for p in built if isinstance(built, list) else [built]:
+        visible = [d for d in p.validate() if d.severity != 'info']
+        assert visible == [], '%s: %s' % (p.name, visible)
 
 
 def test_undersized_macro_ring_is_deadlock_error():

@@ -2,9 +2,10 @@
 kernels in ops/pallas_kernels.py, BeamformBlock and the fused
 beamform->detect->integrate substitution in stages.py).
 
-Kernel parity runs in Pallas interpret mode on the CPU test backend;
-the on-hardware timing and the published ops/s-per-chip row come from
-bench_suite config 13 (tools/beam_gate.py -> BENCH_BEAM_cpu.json).
+Kernel parity runs in Pallas interpret mode on the CPU test backend.
+On the chip, chip_smoke.py phase B compiles every candidate at the
+BASELINE.json shape and holds it to the float64 oracle; no cell of the
+benchmark times the beamformer yet (ROADMAP R7).
 """
 
 import os

@@ -209,7 +209,7 @@ from bifrost_tpu.io.bridge import (BridgeListener, BridgeProtocolError,
                                    MSG_END)
 from bifrost_tpu.header_standard import (serialize_header,
                                          deserialize_header)
-from bifrost_tpu.ring import RingPoisonedError
+from bifrost_tpu.ring import RingPoisonedError, _tensor_info
 
 
 def _gather(ring, gulp):
@@ -242,6 +242,17 @@ def _roundtrip(datasets, hdr_fn, gulp, sender_kw=None, receiver_kw=None,
     # pre-barrier prime)
     total_frames = sum(d.shape[hdr_fn(s)['_tensor']['shape'].index(-1)]
                        for s, d in enumerate(datasets))
+    # ... and the destination ring likewise.  RingReceiver opens its
+    # sequences 3 gulps deep, and _gather's guarantee registers only
+    # once it has seen the first of them: until then nothing holds
+    # the receiver back, and on a loaded host it laps the reader (a
+    # pipeline's downstream block is subject to the same rule; its
+    # rings are sized by its readers before data flows).  Rings only
+    # grow, so the receiver's own request leaves this size standing.
+    info = _tensor_info(hdr_fn(0))
+    dst.resize(gulp * info['frame_nbyte'],
+               (total_frames + gulp) * info['frame_nbyte'],
+               info['nringlet'])
 
     def writer():
         with src.begin_writing() as wr:
@@ -289,6 +300,7 @@ def _roundtrip(datasets, hdr_fn, gulp, sender_kw=None, receiver_kw=None,
         t.join(30)
     lst.close()
     assert not errors, errors
+    assert not any(t.is_alive() for t in threads)
     return out
 
 

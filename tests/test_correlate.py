@@ -1,4 +1,4 @@
-"""FX-correlator tests (bench config 19; docs/perf.md "FX
+"""FX-correlator tests (docs/perf.md "FX
 correlator"): the raced X-engine against the exact int64 oracle, the
 accuracy-class admission rules, the fused/macro chain's byte
 stability, the corner-turn collective against the transpose oracle,

@@ -6,7 +6,6 @@ docs/fabric.md)."""
 from __future__ import annotations
 
 import os
-import socket
 import threading
 import time
 
@@ -18,7 +17,8 @@ from bifrost_tpu import fabric, proclog
 from bifrost_tpu.analysis.verify import verify_fabric
 from bifrost_tpu.telemetry import counters, histograms
 
-from util import NumpySourceBlock, GatherSink, simple_header
+from util import (NumpySourceBlock, GatherSink, simple_header,
+                  free_ports, port_block)
 
 NT, NC = 4, 8
 FRAME_NBYTE = NC * 4
@@ -34,49 +34,6 @@ def _fabric_env(tmp_path, monkeypatch):
     monkeypatch.setenv('BF_FABRIC_REJOIN_CAP', '0.05')
     yield
     proclog.set_identity(None)
-
-
-def _free_ports(n):
-    socks, ports = [], []
-    for _ in range(n):
-        s = socket.socket()
-        s.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
-        s.bind(('127.0.0.1', 0))
-        socks.append(s)
-        ports.append(s.getsockname()[1])
-    for s in socks:
-        s.close()
-    return ports
-
-
-def _port_block(n, tries=64):
-    """Base of n CONSECUTIVE free ports (fan endpoints use port+i)."""
-    for _ in range(tries):
-        socks = []
-        try:
-            s0 = socket.socket()
-            s0.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
-            s0.bind(('127.0.0.1', 0))
-            base = s0.getsockname()[1]
-            socks.append(s0)
-            ok = True
-            for i in range(1, n):
-                s = socket.socket()
-                s.setsockopt(socket.SOL_SOCKET,
-                             socket.SO_REUSEADDR, 1)
-                try:
-                    s.bind(('127.0.0.1', base + i))
-                except OSError:
-                    s.close()
-                    ok = False
-                    break
-                socks.append(s)
-            if ok:
-                return base
-        finally:
-            for s in socks:
-                s.close()
-    raise RuntimeError('no consecutive free ports')
 
 
 def _gulps(origin, n, start=0):
@@ -217,11 +174,11 @@ class TestFanOutLoopback:
     NSEQ = 6
 
     def _spec(self, nlegs, policy='block'):
-        base = _port_block(nlegs)        # legs listen at base + i
-        ports = [p for p in _free_ports(1 + nlegs)
+        base = port_block(nlegs)        # legs listen at base + i
+        ports = [p for p in free_ports(1 + nlegs)
                  if p not in range(base, base + nlegs)]
         while len(ports) < 1 + nlegs:
-            ports += [p for p in _free_ports(1)
+            ports += [p for p in free_ports(1)
                       if p not in range(base, base + nlegs)]
         legs = ['leg%d' % i for i in range(nlegs)]
         hosts = {'src': {'address': '127.0.0.1',
@@ -504,7 +461,7 @@ class TestRejoin:
 
 class TestMembership:
     def test_death_and_rejoin(self):
-        ports = _free_ports(2)
+        ports = free_ports(2)
         spec = fabric.FabricSpec('m', hosts={
             'a': {'address': '127.0.0.1', 'control_port': ports[0]},
             'b': {'address': '127.0.0.1', 'control_port': ports[1]},
@@ -598,19 +555,10 @@ class TestAffinityAndIdentity:
 
 
 # ---------------------------------------------------------------------------
-# verify-gate topology + overload stamp merge
+# overload stamp merge
 # ---------------------------------------------------------------------------
 
 class TestIntegration:
-    def test_verify_topology_clean(self):
-        import bench_suite
-        pipelines = bench_suite.build_verify_topologies()[
-            'config17_fabric']()
-        assert len(pipelines) == 4
-        for p in pipelines:
-            errs = [d for d in p.validate() if d.is_error]
-            assert not errs, 'fabric host %s: %s' % (p.name, errs)
-
     def test_overload_stamp_merges_upstream_fields(self):
         """A drop-policy ring's own _overload stamp must MERGE with an
         upstream stamp riding the header (the fan-in's fabric_gapped

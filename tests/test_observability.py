@@ -407,13 +407,6 @@ def test_pipeline2dot_labels_ring_edges_with_flow():
     assert 'gulps' in res.stdout
 
 
-def test_obs_overhead_tool_importable():
-    res = _tool('obs_overhead.py', '--help')
-    assert res.returncode == 0, res.stderr
-    assert '--threshold' in res.stdout
-    assert '--stack' in res.stdout        # full-stack E2E arm option
-
-
 # ---------------------------------------------------------------------------
 # distributed tracing: trace context (header_standard + pipeline)
 # ---------------------------------------------------------------------------

@@ -473,6 +473,35 @@ def test_halo_carry_macro_gulp_byte_identical():
     assert snap['segment.gulps'] == 8
 
 
+@pytest.mark.parametrize('segments,gulp_batch', [
+    (None, 1), ('force', 1), ('force', 4)])
+def test_dm_chain_matches_float64_oracle(segments, gulp_batch):
+    """Every way of running the dedispersion chain (ring overlap
+    between separate blocks, the in-program halo carry, the carry
+    under macro gulps) gives what a sequential float64 reference of
+    the same semantics gives: numpy FDMT, a fixed-order boxcar, the
+    threshold.  Tolerance: the FDMT engine's own oracle gate
+    (float32 sums of at most nchan * ntap terms), relative to the
+    reference's peak.  A cell the reference puts within that
+    tolerance of the threshold may fall on either side and is left
+    out."""
+    from bifrost_tpu.ops.fdmt import fdmt_numpy, fdmt_gate_rtol
+    out, _, _ = _run_dm_chain(segments, gulp_batch=gulp_batch)
+    data = np.random.RandomState(11).randn(F_DM, T_DM)
+    dm = fdmt_numpy(F_DM, MD_DM, 100.0, 1.0, data.astype(np.float32))
+    nvalid = dm.shape[-1] - (NTAP_DM - 1)
+    mf = sum(dm[:, i:i + nvalid] for i in range(NTAP_DM))
+    n = out.shape[-1]
+    assert out.shape[0] == MD_DM and 0 < n <= nvalid
+    mf = mf[:, :n]
+    tol = fdmt_gate_rtol() * np.max(np.abs(mf))
+    want = np.where(mf >= 0.5, mf, 0.0)
+    decided = np.abs(mf - 0.5) > tol
+    assert decided.mean() > 0.99
+    np.testing.assert_allclose(out[decided], want[decided],
+                               rtol=0, atol=tol)
+
+
 def test_boundary_overlap_carried_reason():
     """The planner reports 'overlap_carried' (a FUSING record) for
     derivable stage overlap, and still cuts with 'overlap' when the

@@ -268,7 +268,7 @@ def test_quota_gate_paces_rate():
     assert store['paceq'].result().shape[0] == 512
     elapsed = job.finished_at - job.first_data_at
     achieved = 512 * 32 / elapsed          # bytes/s (32 B per frame)
-    # generous tier-1 bounds; the bench gate holds the 10% bar
+    # generous bounds: a shared CI host's sleeps overshoot
     assert achieved <= 16384 * 1.5, achieved
     assert elapsed >= 0.5, elapsed
 
@@ -609,14 +609,3 @@ def test_bf_serve_validate_cli(tmp_path):
         timeout=180)
     assert out.returncode == 3
     assert 'BF-E210' in out.stdout
-
-
-def test_service_gate_wired():
-    with open(os.path.join(ROOT, 'tools',
-                           'watch_and_bench.sh')) as f:
-        sh = f.read()
-    assert 'BF_SKIP_SERVICE_GATE' in sh
-    assert 'tools/service_gate.py' in sh
-    import bench_suite
-    assert 'config18_service' in bench_suite.build_verify_topologies()
-    assert 18 in bench_suite.ALL

@@ -1,8 +1,9 @@
 """Fused Pallas spectrometer kernel vs the float64 numpy oracle.
 
-Runs in Pallas interpret mode on the CPU test backend; the on-hardware
-equivalence (and the MXU timing) is covered by bench.py's correctness
-gate + the spectrometer entry in the bench suite.
+Runs in Pallas interpret mode on the CPU test backend.  On the chip,
+chip_smoke.py phase A holds the kernel, inside the served chain at full
+width, to the same oracle, phase B compiles it, and every gpuspec cell
+of the benchmark checks its products (`correct`) and times it.
 """
 import numpy as np
 import pytest

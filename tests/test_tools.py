@@ -5,6 +5,7 @@ import subprocess
 import sys
 
 import numpy as np
+import pytest
 
 import bifrost_tpu as bf
 from tests.util import NumpySourceBlock, GatherSink, simple_header
@@ -230,13 +231,22 @@ def test_lint_envvars_invariant():
     assert '0 undocumented, 0 phantom' in res.stdout
 
 
-def test_bf_lint_script_mode():
+EXAMPLES = os.path.join(os.path.dirname(TOOLS), 'examples')
+#: scripts that print their usage and exit when given no argument
+EXAMPLE_ARGS = {'gpuspec_simple.py': ['--demo']}
+
+
+@pytest.mark.parametrize('script', sorted(
+    f for f in os.listdir(EXAMPLES) if f.endswith('.py')))
+def test_bf_lint_script_mode(script):
     """bf_lint lints an example script without running its pipeline
-    and exits 0 under --strict when the topology is clean."""
+    and exits 0 under --strict: every example the repo ships builds a
+    topology the verifier finds no error in."""
     res = _tool('bf_lint.py', '--strict',
-                os.path.join(os.path.dirname(TOOLS),
-                             'examples', 'your_first_block.py'))
+                os.path.join(EXAMPLES, script),
+                *EXAMPLE_ARGS.get(script, []))
     assert res.returncode == 0, res.stdout + res.stderr
+    assert ' 0 error(s)' in res.stdout
     assert 'BF-E' not in res.stdout
 
 

@@ -3,6 +3,8 @@ test/test_pipeline.py:43-113 CallbackBlock)."""
 
 from __future__ import annotations
 
+import socket
+
 import numpy as np
 
 import bifrost_tpu as bf
@@ -122,3 +124,48 @@ def run_pipeline(pipeline=None):
     p = pipeline or bf.get_default_pipeline()
     p.run()
     return p
+
+
+def _bound_socket(port=0):
+    s = socket.socket()
+    s.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
+    try:
+        s.bind(('127.0.0.1', port))
+    except OSError:
+        s.close()
+        raise
+    return s
+
+
+def free_ports(n, exclude=()):
+    """``n`` distinct free loopback ports, reserved briefly."""
+    socks = []
+    try:
+        while len(socks) < n:
+            s = _bound_socket()
+            if s.getsockname()[1] in exclude:
+                s.close()
+            else:
+                socks.append(s)
+        return [s.getsockname()[1] for s in socks]
+    finally:
+        for s in socks:
+            s.close()
+
+
+def port_block(n, tries=64):
+    """Base of ``n`` CONSECUTIVE free ports: fan endpoints derive
+    ``port + i``, so the whole derived range is probed."""
+    for _ in range(tries):
+        socks = [_bound_socket()]
+        try:
+            base = socks[0].getsockname()[1]
+            for i in range(1, n):
+                socks.append(_bound_socket(base + i))
+            return base
+        except OSError:
+            continue
+        finally:
+            for s in socks:
+                s.close()
+    raise RuntimeError('no block of %d consecutive free ports' % n)

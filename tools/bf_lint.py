@@ -1,29 +1,24 @@
 #!/usr/bin/env python3
-"""bf_lint: run the static pipeline verifier over a pipeline script or
-a named bench topology WITHOUT running the pipeline (docs/analysis.md).
+"""bf_lint: run the static pipeline verifier over a pipeline script
+WITHOUT running the pipeline (docs/analysis.md).
 
     python tools/bf_lint.py examples/fdmt_search.py
-    python tools/bf_lint.py --topology config8_chain
-    python tools/bf_lint.py --list-topologies
     python tools/bf_lint.py --codes
 
-**Script mode**: the script runs in a subprocess with ``BF_LINT=1``,
+The script runs in a subprocess with ``BF_LINT=1``,
 which makes every ``Pipeline.run()`` validate the constructed
 block/ring graph, report its diagnostics, and return WITHOUT launching
 block threads — the script executes end to end as a pure topology
 builder.  Post-run script logic that expects real output may fail;
 that is tolerated as long as at least one pipeline was linted (the
-diagnostics were already captured through ``BF_LINT_OUT``).
-
-**Topology mode**: ``--topology NAME`` builds one of the registered
-bench_suite pipeline topologies in-process (``bench_suite.
-build_verify_topologies``) and validates it directly — this is how
-``tools/verify_gate.py`` sweeps every pipeline-shaped bench config.
+diagnostics were already captured through ``BF_LINT_OUT``).  The
+topologies the repo ships are held clean by
+``tests/test_analysis.py::test_shipped_topologies_validate_clean``.
 
 Exit codes (matching tools/telemetry_diff.py's convention): 0 =
 advisory mode, or strict mode with no ``BF-E``; 3 = ``--strict`` and
 at least one ``BF-E`` diagnostic; 2 = the target could not be linted
-at all (script crashed before building a pipeline, unknown topology).
+at all (script crashed before building a pipeline).
 """
 
 import argparse
@@ -66,28 +61,6 @@ def lint_script(path, args, timeout):
     return records, proc
 
 
-def lint_topology(name):
-    """Build one registered bench topology in-process and validate it.
-    Returns the per-pipeline record list (a topology may build several
-    pipelines), or None when the topology reports itself unavailable
-    on this host (e.g. a mesh topology without enough devices)."""
-    import bench_suite
-    builders = bench_suite.build_verify_topologies()
-    if name not in builders:
-        raise KeyError('unknown topology %r (have: %s)'
-                       % (name, ', '.join(sorted(builders))))
-    built = builders[name]()
-    if built is None:
-        return None
-    pipelines = built if isinstance(built, (list, tuple)) else [built]
-    records = []
-    for p in pipelines:
-        diags = p.validate()
-        records.append({'pipeline': p.name, 'nblocks': len(p.blocks),
-                        'diagnostics': [d.as_dict() for d in diags]})
-    return records
-
-
 def summarize(records, label, show_info=False):
     ne = nw = ni = 0
     for rec in records:
@@ -114,10 +87,6 @@ def main():
                     help='pipeline script to lint (BF_LINT=1 mode)')
     ap.add_argument('script_args', nargs=argparse.REMAINDER,
                     help='arguments passed through to the script')
-    ap.add_argument('--topology', default=None,
-                    help='lint a named bench_suite topology in-process')
-    ap.add_argument('--list-topologies', action='store_true',
-                    help='list registered bench topologies and exit')
     ap.add_argument('--codes', action='store_true',
                     help='print the diagnostic-code catalog and exit')
     ap.add_argument('--strict', action='store_true',
@@ -134,29 +103,9 @@ def main():
         for code in sorted(CODES):
             print('%s  %s' % (code, CODES[code]))
         return 0
-    if args.list_topologies:
-        import bench_suite
-        for name in sorted(bench_suite.build_verify_topologies()):
-            print(name)
-        return 0
-
-    if args.topology:
-        try:
-            records = lint_topology(args.topology)
-        except KeyError as exc:
-            print('bf_lint: %s' % exc, file=sys.stderr)
-            return 2
-        if records is None:
-            print('bf_lint: topology %r unavailable on this host '
-                  '(skipped)' % args.topology)
-            return 0
-        nerr = summarize(records, 'topology %s' % args.topology,
-                         args.show_info)
-        return 3 if (args.strict and nerr) else 0
-
     if not args.script:
-        print('bf_lint: a script path or --topology is required '
-              '(see --help)', file=sys.stderr)
+        print('bf_lint: a script path is required (see --help)',
+              file=sys.stderr)
         return 2
     try:
         records, proc = lint_script(args.script, args.script_args,

@@ -2,8 +2,8 @@
 """Repo-invariant lint: every ``BF_*`` environment variable read by
 ``bifrost_tpu/`` must be documented in ``docs/envvars.md``, and every
 documented variable must actually be read somewhere in the repo
-(package, tools, bench drivers, or shell scripts) — no phantom knobs,
-no undocumented behavior.
+(package, tools or tests) — no phantom knobs, no undocumented
+behavior.
 
     python tools/lint_envvars.py            # report; exit 0/3
     pytest tests/test_tools.py -k envvars   # the tier-1 wiring
@@ -12,9 +12,8 @@ Detection: a QUOTED string literal matching ``BF_[A-Z0-9_]+`` in
 Python source is an env read (the package's accessors —
 ``os.environ``, ``_env_int``/``_env_float``, ``EnvVars.get``,
 ``_force_env`` — all take the name as a string literal; counter/fault
-names never start with BF_); in shell scripts any ``$BF_X`` /
-``${BF_X...}`` expansion or ``BF_X=`` assignment counts.  Docs side:
-any backticked ``BF_*`` token in docs/envvars.md.
+names never start with BF_).  Docs side: any backticked ``BF_*`` token
+in docs/envvars.md.
 
 Exit codes follow tools/telemetry_diff.py: 0 = clean, 3 = violations.
 """
@@ -30,9 +29,6 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 #: quoted BF_ literal in Python source (an env read by construction in
 #: this codebase; docstring prose mentions are unquoted)
 _PY_READ = re.compile(r"""['"](BF_[A-Z0-9_]+)['"]""")
-#: shell expansion / assignment
-_SH_READ = re.compile(r"\$\{?(BF_[A-Z0-9_]+)|^(BF_[A-Z0-9_]+)=",
-                      re.MULTILINE)
 #: documented token in docs/envvars.md (backticked, possibly with a
 #: `=value` suffix or `BF_*` glob-style family references)
 _DOC = re.compile(r"`(BF_[A-Z0-9_]+)")
@@ -66,20 +62,12 @@ def package_reads():
 
 
 def repo_reads():
-    """BF_* vars read anywhere scannable: the package, tools/, the
-    bench drivers, and shell scripts (for the documented->read
-    direction; gate knobs live in tools and watch_and_bench.sh)."""
+    """BF_* vars read anywhere scannable: the package, tools/ and
+    tests/ (for the documented->read direction)."""
     vars_ = dict(package_reads())
-    for path in _py_files('tools', 'tests') + \
-            glob.glob(os.path.join(ROOT, 'bench*.py')):
+    for path in _py_files('tools', 'tests'):
         with open(path, 'r') as f:
             for name in _PY_READ.findall(f.read()):
-                vars_.setdefault(name, set()).add(
-                    os.path.relpath(path, ROOT))
-    for path in glob.glob(os.path.join(ROOT, 'tools', '*.sh')):
-        with open(path, 'r') as f:
-            for m in _SH_READ.finditer(f.read()):
-                name = m.group(1) or m.group(2)
                 vars_.setdefault(name, set()).add(
                     os.path.relpath(path, ROOT))
     return vars_

@@ -45,9 +45,7 @@ Unmatched numeric keys are compared informationally (reported at
 >50%% drift, never flagged).  Exit code 0 = no regressions (advisory
 mode, the default, ALWAYS exits 0 unless the inputs are unreadable);
 ``--strict`` exits 3 when any watched metric regressed beyond its
-threshold — ``tools/watch_and_bench.sh`` runs the advisory mode
-against the previous round's artifact after each capture.  ``--out``
-writes the full report as JSON.
+threshold.  ``--out`` writes the full report as JSON.
 """
 
 import argparse
@@ -62,9 +60,9 @@ WATCHLIST = [
     ('*gulps_per_s*', 'lower', 'pct', 10.0),
     ('*GBps*', 'lower', 'pct', 10.0),
     ('*Msamples*', 'lower', 'pct', 10.0),
-    # bench 'value' keys are direction-tagged by flatten() from the
-    # sibling 'unit' string: most configs report a speedup/throughput
-    # (higher better), but e.g. BENCH_E2E's value is a latency p99
+    # 'value' keys are direction-tagged by flatten() from the sibling
+    # 'unit' string: most report a throughput (higher better), some a
+    # latency p99
     ('*value_throughput', 'lower', 'pct', 10.0),
     ('*value_latency', 'higher', 'pct', 25.0),
     ('*overhead_pct*', 'higher', 'abs', 2.0),
@@ -76,33 +74,28 @@ WATCHLIST = [
     ('*violations*', 'higher', 'any', 0.0),
     ('*dropped*', 'higher', 'any', 0.0),
     # compiled pipeline segments (docs/perf.md): fewer elided rings or
-    # less dispatch traffic through segments between same-config
-    # rounds means fusion silently disengaged — a perf regression even
-    # when wall-clock noise hides it
+    # less dispatch traffic through segments between two runs of one
+    # topology means fusion silently disengaged
     ('*segment.elided_rings*', 'lower', 'any', 0.0),
     ('*segment.dispatches*', 'lower', 'any', 0.0),
-    # FX correlator flagship (BENCH_FXCORR, config 19): the raced
-    # X-engine's winner rate — a drop means the quantized candidate
+    # FX correlator: the raced X-engine's winner rate — a drop means the quantized candidate
     # stopped winning or the race landed somewhere slower
     ('*xengine.gops_per_s*', 'lower', 'pct', 10.0),
-    # FDMT FRB-search flagship (BENCH_FDMT, config 22): the headline
-    # candidates/s at fixed false-alarm rate, and the halo-carry
-    # engagement counter — overlap_carried dropping between
-    # same-config rounds means the in-program halo carry silently
+    # FDMT FRB search: candidates/s at fixed false-alarm rate, and the
+    # halo-carry engagement counter — overlap_carried dropping between
+    # two runs of one topology means the in-program halo carry silently
     # disengaged and the chain fell back to per-gulp overlapped reads
     ('*fdmt.candidates_per_s*', 'lower', 'pct', 10.0),
     ('*segment.overlap_carried*', 'lower', 'any', 0.0),
-    # elastic control plane (SCHED_CHAOS, config 20): the chaos drill
-    # SIGKILLs a host mid-stream — fewer migrations or re-placement
-    # events between same-config rounds means the death watch or the
-    # re-placement path silently disengaged and the drill stopped
-    # exercising what it gates
+    # elastic control plane: fewer migrations or re-placement events
+    # between two runs of one host-death drill means the death watch
+    # or the re-placement path silently disengaged
     # (no trailing glob: 'replacements_refused' DROPPING is fine)
     ('*scheduler.migrations', 'lower', 'any', 0.0),
     ('*scheduler.replacements', 'lower', 'any', 0.0),
-    # wire-rate capture flagship (BENCH_CAPTURE, config 23): sustained
-    # ingest rate of the sharded zero-copy engine — a pps/gbps drop
-    # between same-config rounds usually means the zero-copy batched
+    # wire-rate capture: sustained ingest rate of the sharded
+    # zero-copy engine — a pps/gbps drop between two like runs
+    # usually means the zero-copy batched
     # path silently disengaged (every packet still arrives, each just
     # pays the staging copy again); loss_frac is gated absolutely
     ('*capture.pps*', 'lower', 'pct', 10.0),
@@ -111,7 +104,7 @@ WATCHLIST = [
     ('*crc_errors*', 'higher', 'any', 0.0),
     ('*reconnects*', 'higher', 'any', 0.0),
     ('*fallback*', 'higher', 'any', 0.0),
-    # fleet observability plane (FLEET_OBS, config 21): decode or
+    # fleet observability plane: decode or
     # tick errors on the collector, publish-side send errors, or
     # alert-sink write failures mean telemetry is silently dropping
     # on the floor between rounds; rollup files nest these per host
@@ -141,9 +134,8 @@ _LATENCY_UNITS = ('ms', 'latency', 'age', 'seconds')
 def flatten(obj, prefix=''):
     """{dot.path: float} over every numeric leaf (bools excluded).
 
-    A dict's 'value' key is direction-AMBIGUOUS across bench configs
-    (most report a speedup — higher better — but e.g. BENCH_E2E's is a
-    latency p99), so when a sibling 'unit' string is present the key
+    A dict's 'value' key is direction-AMBIGUOUS (most report a
+    throughput — higher better — some a latency p99), so when a sibling 'unit' string is present the key
     is rewritten to ``value_latency`` / ``value_throughput`` for the
     watchlist to match; a unit-less 'value' stays unmatched
     (informational only)."""
