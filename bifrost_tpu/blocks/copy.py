@@ -2,8 +2,8 @@
 python/bifrost/blocks/copy.py:45-71).
 
 Conversion between host storage and the device representation is defined
-in :mod:`bifrost_tpu.devrep` (bit-exact round trips; complex never
-crosses the host boundary — see xfer.py).
+in :mod:`bifrost_tpu.devrep` (bit-exact round trips; how complex data
+crosses the host boundary: xfer.py).
 
 Both directions ride the async transfer engine (bifrost_tpu.xfer):
 
@@ -129,8 +129,12 @@ class CopyBlock(TransformBlock):
                 # non-blocking: commit the span now, let the engine's
                 # bounded queue + the reader materialize the bytes
                 from .. import xfer
-                fill = xfer.engine().host_fill(ispan.data, ospan.dtype,
-                                               out)
+                # a complex product its writer left as two real
+                # planes is cut from them (devrep.ComplexPlanes)
+                src = ispan.planes
+                if src is None:
+                    src = ispan.data
+                fill = xfer.engine().host_fill(src, ospan.dtype, out)
                 ospan.set_fill(fill)
         elif ispace == 'tpu' and ospace == 'tpu':
             ospan.set(ispan.data)

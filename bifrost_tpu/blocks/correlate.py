@@ -15,15 +15,17 @@ Two block forms:
 - :class:`CorrelateBlock` — stateful: integrates ``nframe_per_integration``
   frames ACROSS gulps, one output frame per integration.  On one
   device it integrates IN PLACE: the accumulator is a pair of float32
-  (re, im) planes the block keeps for the whole sequence and donates
-  to the program of every gulp, which takes the channels through the
-  engine a chunk at a time (``_VIS_CHUNK_BYTES``), so that a gulp's
-  visibilities never exist whole beside the accumulator; the first
-  gulp of an integration overwrites the planes, the last is followed
-  by the one program that writes the complex64 product.  The block
-  holds two products' worth of HBM, the one integrating and the one
-  it hands on (the TPU computes complex64 on separate planes: a
-  complex accumulator is copied by every add, donated or not).  Under a mesh
+  (re, im) planes, shaped as the output span's frame, which the first
+  gulp of an integration makes and every later one is given, donated,
+  by a program that takes the channels through the engine a chunk at
+  a time (``_VIS_CHUNK_BYTES``), so that a gulp's visibilities never
+  exist whole beside the accumulator.  The finished planes ARE the
+  product: the block hands them to its ring as they are
+  (``devrep.ComplexPlanes``) and keeps nothing, so no complex64
+  program runs between the integration and the host (the TPU computes
+  complex64 on separate planes: a complex accumulator is copied by
+  every add, donated or not, and a program with a complex argument
+  splits all of it first).  Under a mesh
   it runs one of two measured plans: time-parallel partial visibilities
   met in a ``psum``, or the CORNER TURN — redistribute the voltages
   from time-sharded to channel-sharded with an on-chip collective
@@ -431,10 +433,13 @@ class CorrelateBlock(TransformBlock):
         return plain_fn
 
     def _build_in_place(self, shape, reim, first):
-        """The one-device program of a gulp: ``fn(x, ar, ai) -> (ar,
-        ai)`` with the float32 accumulator planes, shaped as the
-        product's frame (F, S, P, S, P), donated and updated where
-        they lie; ``first`` overwrites them instead of adding."""
+        """The one-device program of a gulp, with the float32
+        accumulator planes shaped as the output span's frame
+        (1, F, S, P, S, P): ``fn(x, ar, ai) -> (ar, ai)`` takes them
+        donated and adds where they lie; the ``first`` of an
+        integration, ``fn(x) -> (ar, ai)``, makes planes of its own
+        (the last integration's belong to the ring by then)."""
+        import jax
         import jax.numpy as jnp
         from jax import lax
         from ..ops.common import donating_jit
@@ -449,11 +454,12 @@ class CorrelateBlock(TransformBlock):
                                                      axis=1))
             out = []
             for plane, a in zip((jnp.real(vis), jnp.imag(vis)), acc):
+                plane = plane[None]
                 if not first:
                     plane = plane + lax.dynamic_slice_in_dim(
-                        a, k * fc, fc, axis=0)
+                        a, k * fc, fc, axis=1)
                 out.append(lax.dynamic_update_slice_in_dim(
-                    a, plane, k * fc, axis=0))
+                    a, plane, k * fc, axis=1))
             return tuple(out)
 
         def fn(x, ar, ai):
@@ -462,39 +468,25 @@ class CorrelateBlock(TransformBlock):
             return lax.fori_loop(0, f // fc,
                                  lambda k, acc: chunk(k, acc, x),
                                  (ar, ai))
-        return donating_jit(fn, donate_argnums=(1, 2))
+        if not first:
+            return donating_jit(fn, donate_argnums=(1, 2))
+        # every chunk is written, so what the planes start as is
+        # never read
+        return jax.jit(lambda x: fn(
+            x, *(jnp.empty((1, f, s, p, s, p), jnp.float32),) * 2))
 
     def _integrate_in_place(self, x, reim):
-        """Add one gulp into the block's own planes (made once a
-        sequence, on the gulp's device, and kept from one integration
-        to the next: the first gulp overwrites them)."""
-        import jax.numpy as jnp
+        """One gulp into the integration's planes: the first makes
+        them, on the gulp's device, the others add into them."""
         from ..telemetry import counters
-        first = self.nframe_integrated == 0
+        first = self._acc is None
         key = (tuple(x.shape), str(x.dtype), first)
         fn = self._fn.get(key)
         if fn is None:
             fn = self._fn[key] = self._build_in_place(x.shape, reim,
-                                                      first)
-        if self._acc is None:
-            _, f, s, p = x.shape[:4]
-            dev = next(iter(x.devices()))
-            self._acc = tuple(jnp.zeros((f, s, p, s, p), jnp.float32,
-                                        device=dev) for _ in range(2))
-        self._acc = fn(x, *self._acc)
+                                                       first)
+        self._acc = fn(x) if first else fn(x, *self._acc)
         counters.inc('correlate.acc_in_place')
-
-    def _product(self):
-        """The finished integration as the output span's frame: the
-        only complex64 program of the block, and it reads the planes
-        where they lie (no temporary: libtpu's memory analysis)."""
-        fn = self._fn.get('product')
-        if fn is None:
-            import jax
-            from jax import lax
-            fn = self._fn['product'] = jax.jit(
-                lambda ar, ai: lax.complex(ar, ai)[None])
-        return fn(*self._acc)
 
     def on_data(self, ispan, ospan):
         import jax.numpy as jnp
@@ -509,7 +501,10 @@ class CorrelateBlock(TransformBlock):
             if self.nframe_integrated < self.nframe_per_integration:
                 return 0
             self.nframe_integrated = 0
-            ospan.set(self._product())
+            # the planes are the ring's from here on
+            from ..planes import ComplexPlanes
+            ospan.set(ComplexPlanes(*self._acc))
+            self._acc = None
             counters.inc('correlate.integrations')
             return 1
         acc_is_none = self._acc is None

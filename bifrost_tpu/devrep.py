@@ -13,6 +13,15 @@ float planes, comes back whole as the complex64 it is, and comes back
 in pieces (a large product bound for a ring span) as rows of 32-bit
 words with re and im interleaved, which the host sees as complex with
 a view; ``from_device_rep`` is handed complex either way.
+
+A complex array that a block computed on two real planes may stay
+them in a device ring: :class:`ComplexPlanes` (defined in the leaf
+module :mod:`bifrost_tpu.planes`, which the ring and the transfer
+engine import, and named here too) is one chunk of a span,
+complex64 to whoever asks for the span's array (joined then, for that
+reader) and two float32 arrays to a reader that can use planes, which
+is how a correlator's product reaches the D2H cut with no complex64
+program on the way (docs/transfer.md, "Planes").
 """
 
 from __future__ import annotations
@@ -20,10 +29,11 @@ from __future__ import annotations
 import numpy as np
 
 from .dtype import DataType
+from .planes import ComplexPlanes, device_arrays
 from .xfer import to_device, to_host
 
 __all__ = ['to_device_rep', 'from_device_rep', 'device_rep_zeros',
-           'device_rep_dtype']
+           'device_rep_dtype', 'ComplexPlanes', 'device_arrays']
 
 
 def device_rep_dtype(dtype):
@@ -70,7 +80,7 @@ def from_device_rep(arr, dtype, out_buf):
     """device-representation array -> numpy storage (bit-exact inverse)."""
     import jax
     dtype = DataType(dtype)
-    if isinstance(arr, jax.Array):
+    if isinstance(arr, (jax.Array, ComplexPlanes)):
         arr = to_host(arr)
     else:
         arr = np.asarray(arr)

@@ -1158,8 +1158,10 @@ class Block(BlockScope):
             pend = self._pending_outputs = deque()
         counters.inc('pipeline.gulps')
         self.heartbeat()
-        arrays = [s._device_array for s in ospans
-                  if getattr(s, '_device_array', None) is not None]
+        from .planes import device_arrays
+        arrays = [a for s in ospans
+                  if getattr(s, '_device_array', None) is not None
+                  for a in device_arrays(s._device_array)]
         if arrays:
             # device-output gulps: the denominator for the hard-sync
             # rate (waits per device gulp <= 1/sync_depth steady-state)

@@ -52,6 +52,7 @@ from .dtype import DataType
 from .header_standard import trace_context
 from .space import canonical
 from .ndarray import ndarray
+from .planes import ComplexPlanes
 from .testing import faults
 # dynamic ring-protocol checker (BF_RINGCHECK=1; docs/analysis.md) —
 # every seam call below is one module-bool test when disarmed
@@ -267,6 +268,11 @@ def _build_stitcher(plan, taxis):
     return jax.jit(fn)
 
 
+def _whole(chunk):
+    """A chunk as the array a reader of ``.data`` sees."""
+    return chunk.joined() if isinstance(chunk, ComplexPlanes) else chunk
+
+
 class _DeviceStorage(object):
     """Chunk-map storage for 'tpu' rings: committed gulps are jax arrays
     keyed by absolute byte offset.  Logical shape of each chunk is
@@ -325,7 +331,8 @@ class _DeviceStorage(object):
         the request exactly.  Later reads of the range see a gap (zero
         fill) — callers must guarantee single-consumption."""
         hit = self.chunks.get(offset)
-        if hit is None or hit[0] != nbyte or not hit[3]:
+        if hit is None or hit[0] != nbyte or not hit[3] or \
+                isinstance(hit[1], ComplexPlanes):
             return None
         del self.chunks[offset]
         try:
@@ -349,7 +356,8 @@ class _DeviceStorage(object):
             if o != covered:
                 return None          # gap or misaligned chunk
             cn, arr, _taxis, owned = self.chunks[o]
-            if not owned or o + cn > end:
+            if not owned or o + cn > end or \
+                    isinstance(arr, ComplexPlanes):
                 return None          # foreign chunk / ragged tail
             run.append((o, arr))
             covered = o + cn
@@ -367,7 +375,7 @@ class _DeviceStorage(object):
         import bisect
         hit = self.chunks.get(offset)
         if hit is not None and hit[0] == nbyte:
-            return hit[1]
+            return _whole(hit[1])
         end = offset + nbyte
         # piece plan over the sorted chunk index
         i = bisect.bisect_right(self._offsets, offset) - 1
@@ -388,7 +396,7 @@ class _DeviceStorage(object):
             f0 = (covered - o) // frame_nbyte
             f1 = min(cn, end - o) // frame_nbyte
             plan.append(('a', f0, f1, len(arrs)))
-            arrs.append(arr)
+            arrs.append(_whole(arr))
             taxis = ctaxis
             covered = o + f1 * frame_nbyte
         if covered < end:
@@ -406,6 +414,15 @@ class _DeviceStorage(object):
         if fn is None:
             fn = self._stitchers.put(key, _build_stitcher(plan, taxis))
         return fn(*arrs)
+
+    def planes(self, offset, nbyte):
+        """The chunk covering exactly [offset, offset+nbyte) where it
+        is a complex array held as planes, else None."""
+        hit = self.chunks.get(offset)
+        if hit is not None and hit[0] == nbyte and \
+                isinstance(hit[1], ComplexPlanes):
+            return hit[1]
+        return None
 
     def discard_before(self, offset):
         dead = [o for o, c in self.chunks.items() if o + c[0] <= offset]
@@ -1881,7 +1898,9 @@ class WriteSpan(_SpanAPI):
         """Publish a computed gulp into this span.  ``owned=True``
         (device rings) marks the array as created exclusively for this
         ring — the committed chunk is then eligible for buffer donation
-        downstream (ring._take_exclusive)."""
+        downstream (ring._take_exclusive).  A complex gulp computed on
+        two real planes may be set as them (devrep.ComplexPlanes):
+        readers of ``.data`` see the complex array all the same."""
         if self._ring.space == 'tpu':
             if isinstance(array, ndarray):
                 array = array.as_jax()
@@ -2066,6 +2085,18 @@ class ReadSpan(_SpanAPI):
         else:
             self._data = self._host_view(writeable=False)
         return self._data
+
+    @property
+    def planes(self):
+        """Device rings: this span's chunk as the
+        :class:`~bifrost_tpu.devrep.ComplexPlanes` its writer set, for
+        a reader that can use the two real planes of a complex array
+        and spare the device the array itself; None where the span is
+        not exactly one such chunk (``.data`` is the complex array
+        either way)."""
+        if self._ring.space != 'tpu':
+            return None
+        return self._ring._storage.planes(self._begin, self._nbyte)
 
     def take_data(self, allow_parts=False):
         """Device rings: claim this span's committed chunk exclusively

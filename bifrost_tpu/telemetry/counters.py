@@ -101,10 +101,21 @@ Observability counters (docs/observability.md; complemented by
                                            as real (re, im) pairs: the
                                            complex products' (0, and
                                            there, where none is)
+- ``xfer.d2h_plane_bytes``                 bytes of those whose pieces
+                                           were cut from the two real
+                                           planes the product was
+                                           computed in
+                                           (devrep.ComplexPlanes), not
+                                           from a complex64 array
+- ``xfer.d2h_cutup_bytes``                 bytes of products in pieces
+                                           whose cuts were all issued
+                                           before the first piece was
+                                           taken (LARGE, real-worded:
+                                           docs/transfer.md)
 - ``correlate.integrations``               integrations a CorrelateBlock
                                            emitted
-- ``correlate.acc_in_place``               gulps it added into its
-                                           donated accumulator planes
+- ``correlate.acc_in_place``               gulps it integrated into
+                                           float32 planes in place
 - ``ring.<name>.gulps``                    LOGICAL gulps committed
                                            through ring ``<name>``
                                            (both cores; a macro-gulp

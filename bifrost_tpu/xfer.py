@@ -46,7 +46,12 @@ analogue of bifrost's per-block CUDA streams + async memcpy
   pieces small enough for the allocator to keep between products
   (``_D2H_PIECE_BYTES``), whatever its dtype and whichever axis has to
   be cut, each copied into its place in the span as it arrives
-  (:class:`_PieceFuture`; docs/transfer.md).
+  (:class:`_PieceFuture`; docs/transfer.md).  A LARGE product whose
+  cut has no complex argument is cut up, all of it, when its landing
+  starts: by ``host_fill`` where nothing is landing, else by the
+  completion thread before it announces the landing ahead of it, so
+  that the programs a producer dispatches the moment it is let go
+  queue behind the cuts and not the cuts behind them.
 
 Device to host, a product that crosses whole crosses as it is: the
 local v5e runtime transfers complex64 bit-exactly both ways
@@ -57,8 +62,17 @@ complex64 array on the host, one transfer at a time, at 2.5 GB/s, so
 the cut program interleaves on the device and each piece leaves as
 rows of 32-bit words, re and im element by element, which the host
 sees as complex again with a view (:func:`_pairs`; PERF.md section 6,
-PR 29).  Host to device, complex data still goes as (re, im) float
-planes recombined under jit (ROADMAP D6).
+PR 29).  A product that reaches the engine as the two float32 planes
+it was computed in (:class:`~bifrost_tpu.devrep.ComplexPlanes`: a
+correlator's, through ``ReadSpan.planes``) is cut from the planes, so
+no program between the integration and the host has a complex64
+argument or result: a program with one splits the whole of it into
+planes first, 13 ms for 2.1 GB, in each of sixteen cuts a product
+(PERF.md section 6, PR 31), which is also what makes cutting such a
+product up at once affordable (PR 32).  A pair that crosses whole is
+joined first and crosses as the complex64 it stands for.  Host to
+device, complex data still goes as (re, im) float planes recombined
+under jit (ROADMAP D6).
 
 Tunables (environment):
 
@@ -115,28 +129,34 @@ _D2H_WORKERS = 1
 _D2H_PIECE_BYTES = 16 << 20
 
 #: pieces of a LARGE product (``memory.LARGE_SPAN_BYTES``) are cut from
-#: it this many at a time, ``_D2H_AHEAD`` groups on their way while the
-#: one before them is copied into the span, and never a second
-#: product.  A smaller product is cut in one program, all of it on its
-#: way at once (PR 27's 268 MB products).
+#: it this many to a program and land this many at a time, and never a
+#: second product beside it.  A smaller product is cut in one program,
+#: all of it on its way at once (PR 27's 268 MB products).
 _D2H_GROUP = 8
 
-#: groups of a large product that are cut, and on their way, ahead of
-#: the group being taken.  A cut queues on the one device stream
-#: behind the programs of the block that makes the products (the
-#: correlator's gulps, 51 ms each), so with one group ahead the
-#: completion thread waits for the device 43 % of its time
-#: (``d2h.ready``) once the transfer itself is cheap.  A group in
-#: flight is 128 MiB of pieces on the device, as much again of the
-#: runtime's own, and its landing buffers on the host.  The served
-#: xcorr cell (PERF.md section 6, PR 29) reads 1806 Msamples/s with
-#: one ahead, 2035-2112 with two (peak HBM 11.95 GB of 13, peak RSS
-#: 26.8 GB as at the parent commit), 5 % more with three or four (12.2
-#: and 12.5 GB; 27.3 and 27.6 GB), no more with five; groups of
-#: sixteen halve what the cuts cost the device (every cut program
-#: splits the whole product into planes first, 13 ms) and read
-#: 2275-2384 two ahead, at 12.63 and 28.1 GB.  Two of eight is what
-#: fits both budgets.
+#: groups of a large product whose readback is started ahead of the
+#: group being taken: 128 MiB of landing buffers on the host each, and
+#: as much of the runtime's own on the device.  Of a LARGE complex64
+#: ARRAY they are also the groups that are CUT ahead: each of its cut
+#: programs splits the whole array into planes first (13 ms and 1.07 GB
+#: of temporaries for 2.1 GB), so its cuts are issued a group at a
+#: time, and queue on the one device stream behind whatever the block
+#: that makes the products has dispatched meanwhile.  The served xcorr
+#: cell, while its products were such arrays (PERF.md section 6,
+#: PR 29), read 1806 Msamples/s with one group ahead, 2035-2112 with
+#: two (peak HBM 11.95 GB of 13, peak RSS 26.8 GB as at the parent
+#: commit), 5 % more with three or four (12.2 and 12.5 GB; 27.3 and
+#: 27.6 GB), no more with five: a look-ahead buys off, with memory, a
+#: wait that is a matter of order.  What a cut queued behind there was
+#: a whole integration's gulp programs, dispatched within milliseconds
+#: of one another the moment a landing freed the correlator's span
+#: (PR 31's stamps), so the thread waited a third of its time for cuts
+#: that cost the device a millisecond each.  A LARGE product of real
+#: words (float planes, or any real dtype) is therefore cut up at
+#: once, ahead of that moment (:class:`_PieceFuture`,
+#: :func:`_complete_fills`; PR 32), and for it this constant is the
+#: readback's look-ahead alone.  Two of eight is what fits both
+#: budgets either way.
 _D2H_AHEAD = 2
 
 _combine_fn = None
@@ -151,7 +171,8 @@ def _combine(re, im):
 
 
 def _piece_plan(arr):
-    """``(axis, step)``: ``arr`` crosses in pieces of ``step`` indices
+    """``(axis, step)``: ``arr`` (a jax array, or the planes of a
+    complex one) crosses in pieces of ``step`` indices
     (the most that fit ``_D2H_PIECE_BYTES``, one at least; the last
     piece may be shorter) along ``axis``, its first axis longer than
     one, so that every piece is one stretch of the product's bytes.
@@ -183,30 +204,37 @@ def _cut(arr, start, axis, step, count, rows):
     ``start`` on, as ``(step, the rest)`` with ``rows``: one program
     on the device, whose start is an argument, so that one compilation
     serves every group of every product of a shape.  A piece of a
-    complex product leaves as real rows (:func:`_pairs`)."""
+    complex product leaves as real rows (:func:`_pairs`): of a
+    complex64 array its real and imaginary parts, of planes
+    (``devrep.ComplexPlanes``) their two slices, and then the program
+    has no complex type in it."""
     global _cut_fn
     if _cut_fn is None:
         import jax
         from jax import lax
 
-        def cut(x, start, axis, step, count, rows):
-            pieces = (lax.dynamic_slice_in_dim(x, start + j * step,
-                                               step, axis)
-                      for j in range(count))
-            if x.dtype.kind == 'c':
-                return tuple(_pairs(p) for p in pieces)
+        def cut(planes, start, axis, step, count, rows):
+            pieces = ([lax.dynamic_slice_in_dim(x, start + j * step,
+                                                step, axis)
+                       for x in planes] for j in range(count))
+            if len(planes) == 2:
+                return tuple(_pairs(re, im) for re, im in pieces)
+            if planes[0].dtype.kind == 'c':
+                return tuple(_pairs(p.real, p.imag) for p, in pieces)
             return tuple(p.reshape(step, -1) if rows else p
-                         for p in pieces)
+                         for p, in pieces)
         _cut_fn = jax.jit(cut, static_argnums=(2, 3, 4, 5))
-    return _cut_fn(arr, start, axis, step, count, rows)
+    from .planes import device_arrays
+    return _cut_fn(device_arrays(arr), start, axis, step, count, rows)
 
 
-def _pairs(piece):
-    """A complex piece, under jit, as real rows with re and im
-    interleaved element by element: byte for byte what the host calls
-    complex, so that the runtime moves plain 32-bit words and the host
-    takes the piece with a view (``_PieceFuture._take_group``).  Moves
-    alone, never arithmetic, so NaN payloads, -0.0 and infinities
+def _pairs(re, im):
+    """The two planes of a complex piece, under jit, as real rows with
+    re and im interleaved element by element: byte for byte what the
+    host calls complex, so that the runtime moves plain 32-bit words
+    and the host takes the piece with a view
+    (``_PieceFuture._take_group``).  Moves alone, never arithmetic,
+    so NaN payloads, -0.0 and infinities
     arrive as they left: float32 planes are stacked as the words they
     are, because libtpu's compiler joins two float arrays with a
     ``maximum`` over ``-inf`` pads, which no unsigned word minds
@@ -215,19 +243,18 @@ def _pairs(piece):
     fill a lane, each plane folded to ``(rows, those axes)`` before
     the two are stacked: XLA then interleaves inside the tiles the
     planes already have (tools/d2h_probe.py on the chip, the sixteen
-    cuts of a (1, 1024, 256, 2, 256, 2) product: 0.215 s, 0.208 of
-    them the split into planes that every cut program starts with;
-    as ``(step, everything else)`` 0.517, as the complex64 rows of
-    before 0.507; stacking the unfolded planes copies the whole
-    product first)."""
+    cuts of a (1, 1024, 256, 2, 256, 2) product: 0.215 s from
+    complex64, 0.208 of them the split into planes that a program
+    with a complex argument starts with; as ``(step, everything
+    else)`` 0.517, as the complex64 rows of before 0.507; stacking
+    the unfolded planes copies the whole product first)."""
     import jax.numpy as jnp
     from jax import lax
     tail = 1
-    while tail < piece.ndim and \
-            int(np.prod(piece.shape[-tail:])) < _LANE:
+    while tail < re.ndim and int(np.prod(re.shape[-tail:])) < _LANE:
         tail += 1
-    tail = piece.shape[-tail:]
-    planes = [p.reshape((-1,) + tail) for p in (piece.real, piece.imag)]
+    tail = re.shape[-tail:]
+    planes = [p.reshape((-1,) + tail) for p in (re, im)]
     if planes[0].dtype == jnp.float32:
         planes = [lax.bitcast_convert_type(p, jnp.uint32) for p in planes]
     return jnp.stack(planes, -1).reshape(-1, 2 * int(np.prod(tail)))
@@ -577,37 +604,65 @@ class _PieceFuture(TransferFuture):
     for :class:`HostFill`: :meth:`land` hands each group of host
     pieces, with the place of each in the product, to the caller's
     ``put`` as it arrives, so the product is never whole on the host
-    outside its destination.  The first groups (``_D2H_AHEAD`` of
-    them) are cut and on their way when the future is made, on the
-    caller's thread; whoever lands the future cuts a further group as
-    it starts on each.  The product itself is let go with its last
-    cut.  A complex product's pieces cross as real (re, im) pairs
-    (:func:`_cut`) and are seen as complex again here, with no pass
-    over them.  ``result()`` lands it into an array of its own."""
+    outside its destination.  A complex product's pieces cross as
+    real (re, im) pairs (:func:`_cut`) and are seen as complex again
+    here, with no pass over them, whether the product came as a
+    complex64 array or as its two planes (``devrep.ComplexPlanes``).
+    ``result()`` lands it into an array of its own.
+
+    When the groups are cut follows from ``whole``.  Without it the
+    first groups (``_D2H_AHEAD`` of them) are cut and on their way
+    when the future is made, on the caller's thread, and whoever lands
+    the future cuts a further group as it starts on each: at most
+    ``_D2H_AHEAD`` groups beside the product, which is what a product
+    whose every cut program costs a pass over all of it (a LARGE
+    complex64 array) can afford, and all of a smaller product, which
+    is one group.  With it nothing is cut until :meth:`cut_up` (or
+    the landing, if nobody called it) cuts every group at once, so
+    that no program dispatched after that moment runs ahead of any of
+    the cuts; only the readback of the pieces is started
+    ``_D2H_AHEAD`` groups ahead of the group being taken, so the
+    host holds the landing buffers it held.  Either way the product is
+    let go with its last cut, and a group's pieces with its landing."""
 
     __slots__ = ('_axis', '_step', '_group', '_shape', '_dtype',
-                 '_row', '_ahead')
+                 '_row', '_ahead', '_whole', '_hinted')
 
-    def __init__(self, arr, axis, step, group):
+    def __init__(self, arr, axis, step, group, whole=False):
         super(_PieceFuture, self).__init__([arr], None)
         self._axis, self._step, self._group = axis, step, group
         self._shape, self._dtype = arr.shape, np.dtype(arr.dtype)
         self._row = 0
+        #: groups that are cut: each ``[(device piece, its index in
+        #: the product)]``, the first ``_hinted`` with their readback
+        #: started
         self._ahead = deque()
-        self._cut_ahead()
+        self._hinted = 0
+        self._whole = whole
+        if not whole:
+            self._cut_ahead()
+
+    def cut_up(self):
+        """Cut every group now, where the product is cut up ``whole``
+        (nothing otherwise, nor a second time).  For whoever owns the
+        future and knows that its landing is next: the engine before
+        it queues the fill, a completion thread that has claimed it
+        (:meth:`TransferEngine.host_fill`, :func:`_complete_fills`)."""
+        if self._whole and not self._done:
+            self._cut_ahead()
 
     def _cut_ahead(self):
-        """Cut groups, and start their readback, until ``_D2H_AHEAD``
-        are on their way or the product is cut up: each
-        ``[(device piece, its index in the product)]``."""
-        while self._arrays and len(self._ahead) < _D2H_AHEAD:
+        """Cut groups until ``_D2H_AHEAD`` are cut (``whole``: every
+        group) or the product is cut up, and see that the first
+        ``_D2H_AHEAD`` of them are on their way."""
+        while self._arrays and (self._whole or
+                                len(self._ahead) < _D2H_AHEAD):
             arr, rows = self._arrays[0], self._shape[self._axis]
             full = (rows - self._row) // self._step
             step, count = (self._step, min(full, self._group)) if full \
                 else (rows - self._row, 1)
             pieces = _cut(arr, self._row, self._axis, step, count,
                           self._shape[-1] < _LANE)
-            TransferEngine._start_readback(pieces)
             lead = (slice(None),) * self._axis
             self._ahead.append(
                 [(p, lead + (slice(self._row + j * step,
@@ -616,10 +671,22 @@ class _PieceFuture(TransferFuture):
             self._row += step * count
             if self._row >= rows:
                 self._arrays = []
+            self._start_ahead()
+        self._start_ahead()
+
+    def _start_ahead(self):
+        """Start the readback of the cut groups that are among the
+        next ``_D2H_AHEAD`` to be taken."""
+        while self._hinted < min(len(self._ahead), _D2H_AHEAD):
+            TransferEngine._start_readback(
+                p for p, _where in self._ahead[self._hinted])
+            self._hinted += 1
 
     def ready(self):
         if self._done:
             return True
+        if self._arrays and not self._ahead:
+            return False           # not cut yet
         try:
             return all(p.is_ready() for group in self._ahead
                        for p, _where in group)
@@ -644,22 +711,27 @@ class _PieceFuture(TransferFuture):
     def _take_into(self, put):
         # under self._lock
         try:
+            self._cut_ahead()
             while self._ahead:
-                self._take_group(self._ahead.popleft(), put)
+                self._take_group(put)
         finally:
             self._arrays = []
             self._ahead.clear()
 
-    def _take_group(self, group, put):
-        """One group to ``put``, the next cut first.  Real pairs are
-        complex again by a view: no pass over them."""
+    def _take_group(self, put):
+        """The oldest group to ``put``, what is to be ahead of it cut
+        and on its way first; its pieces leave the device as soon as
+        the host has them.  Real pairs are complex again by a view: no
+        pass over them."""
+        pieces, places = map(list, zip(*self._ahead.popleft()))
+        self._hinted -= 1
         self._cut_ahead()
         shape = list(self._shape)
         shape[self._axis] = -1          # rows are pieces again
-        host = _cross([p for p, _where in group],
-                      sum(int(p.nbytes) for p, _where in group), list)
+        host = _cross(pieces, sum(int(p.nbytes) for p in pieces), list)
+        del pieces
         put([(h.view(self._dtype).reshape(shape), where)
-             for h, (_p, where) in zip(host, group)], not self._ahead)
+             for h, where in zip(host, places)], not self._ahead)
 
     def _fetch(self):
         out = np.empty(self._shape, self._dtype)
@@ -769,18 +841,34 @@ class HostFill(object):
         if self.error is not None:
             raise self.error
 
-    def complete(self, who):
+    def cut_up(self):
+        """For whoever owns the fill (its claimant, or the engine
+        before anybody can see it) and knows its landing is next: a
+        product that is cut up whole is cut up now
+        (:meth:`_PieceFuture.cut_up`), ahead of every program that is
+        dispatched once this returns.  A cut that fails is met again,
+        and reported, by the landing."""
+        if isinstance(self.future, _PieceFuture):
+            try:
+                self.future.cut_up()
+            except Exception:
+                pass
+
+    def complete(self, who, then=None):
         """The claimant's work: block on the transfer, convert into
         the span's host view (piece by piece where it crosses so),
         then redo the ghost mirror for wrapped spans (the commit-time
         mirror ran before the bytes landed).  A failure is recorded,
         not raised (an interrupt is both).  ``who`` is the counter
-        that says which side did it."""
+        that says which side did it; ``then()`` runs once the bytes
+        have landed and before anybody who waits for them is told."""
         try:
             if isinstance(self.future, _PieceFuture):
                 self.future.land(self._put)
             else:
                 self._put([(self.future.result(), Ellipsis)], True)
+            if then is not None:
+                then()
         except BaseException as exc:
             with self._lock:
                 self.error = exc
@@ -821,20 +909,42 @@ class HostFill(object):
 
 def _complete_fills(work, fills, stop):
     """Body of a completion thread: claim the oldest unclaimed fill of
-    the engine's queue and complete it, until told to stop.  It holds
-    the engine's condition, queue and stop flag, not the engine, so an
-    engine nobody refers to any more can be collected (and stops its
-    threads from ``__del__``)."""
-    while True:
+    the engine's queue and complete it, until told to stop.  Once a
+    fill's bytes have landed, and before it says so, it claims the
+    fill it will take next and cuts that one up
+    (:meth:`HostFill.cut_up`): a landing is what the next product's
+    producer waits for (the byte bound of :meth:`TransferEngine
+    .host_fill`, the ring span behind it), and the programs it
+    dispatches the moment it is let go queue behind the cuts, not the
+    cuts behind them.  It holds the engine's condition, queue and stop
+    flag, not the engine, so an engine nobody refers to any more can
+    be collected (and stops its threads from ``__del__``)."""
+    ahead = []             # the fill claimed before the last one's news
+
+    def claim():
+        # under ``work``
+        return None if stop.is_set() else \
+            next((f for f in fills if f._claim()), None)
+
+    def claim_next():
         with work:
-            while True:
-                if stop.is_set():
-                    return
-                fill = next((f for f in fills if f._claim()), None)
-                if fill is not None:
-                    break
-                work.wait()
-        fill.complete('xfer.fills_by_worker')
+            fill = claim()
+        if fill is not None:
+            ahead.append(fill)
+            fill.cut_up()
+
+    while True:
+        if ahead:
+            fill = ahead.pop()
+        else:
+            with work:
+                fill = claim()
+                while fill is None:
+                    if stop.is_set():
+                        return
+                    work.wait()
+                    fill = claim()
+        fill.complete('xfer.fills_by_worker', claim_next)
         del fill           # hold no product while idle
 
 
@@ -1185,9 +1295,12 @@ class TransferEngine(object):
         """TransferFuture for a jax array, every dtype as it is.  With
         ``out_view``, the host view it is bound for, a large array
         crosses in pieces (:class:`_PieceFuture`) where the view has
-        the array's shape down to the axis that is cut."""
+        the array's shape down to the axis that is cut.  The planes
+        of a complex array (``devrep.ComplexPlanes``) are cut as they
+        are, and joined first where they cross whole."""
         faults.fire('xfer.d2h')
         import jax
+        from .planes import ComplexPlanes
         if hasattr(arr, 'as_numpy'):       # bifrost_tpu.ndarray
             return TransferFuture([], lambda _h: None,
                                   result=arr.as_numpy(), done=True)
@@ -1199,8 +1312,9 @@ class TransferEngine(object):
         c.inc('xfer.d2h_issued')
         c.inc('xfer.d2h_bytes', nbytes)
         _obs()[0].observe('xfer.d2h_nbytes', nbytes)
+        planes = isinstance(arr, ComplexPlanes)
         plan = _piece_plan(arr) if out_view is not None and \
-            isinstance(arr, jax.Array) else None
+            (planes or isinstance(arr, jax.Array)) else None
         if plan is not None:
             axis, step = plan
             if tuple(getattr(out_view, 'shape', ())[:axis + 1]) == \
@@ -1211,10 +1325,21 @@ class TransferEngine(object):
                 # (0 too, so that a reader finds the counter)
                 c.inc('xfer.d2h_pair_bytes',
                       nbytes if arr.dtype.kind == 'c' else 0)
-                count = -(-arr.shape[axis] // step)
+                # and of those, the ones cut from planes
+                c.inc('xfer.d2h_plane_bytes', nbytes if planes else 0)
+                # a LARGE product is cut in groups, and all of them
+                # when its landing starts where a cut costs its pieces
+                # alone: not from a complex64 array, which every cut
+                # program splits whole before it slices
+                large = nbytes >= LARGE_SPAN_BYTES
+                whole = large and (planes or arr.dtype.kind != 'c')
+                c.inc('xfer.d2h_cutup_bytes', nbytes if whole else 0)
                 return _PieceFuture(
                     arr, axis, step,
-                    count if nbytes < LARGE_SPAN_BYTES else _D2H_GROUP)
+                    _D2H_GROUP if large else -(-arr.shape[axis] // step),
+                    whole)
+        if planes:
+            arr = arr.joined()
         self._start_readback((arr,))
         return TransferFuture([arr], _first)
 
@@ -1287,6 +1412,12 @@ class TransferEngine(object):
             return fill
         _counters().inc('xfer.d2h_async')
         with self._work:
+            if all(f.done for f in self._fills):
+                # no landing to wait for: its own starts now, and its
+                # cuts go to the device before anything the caller
+                # lets go by returning (else the completion thread
+                # cuts it up, before it announces the one ahead of it)
+                fill.cut_up()
             self._fills.append(fill)
             over = self._past_bound(self._fills)
             self._start_workers()

@@ -1,8 +1,9 @@
 """Large products through the pipeline (docs/transfer.md, "Depth by
 bytes"; PERF.md section 6, PR 28): ring depth follows from a span's
-bytes, the correlator integrates in place, the dispatch-ahead queue
-and the fills in flight are bounded by bytes.  The sizes here are
-small; the rule's one constant is patched down to them."""
+bytes, the correlator integrates in place and hands its product on as
+the two float32 planes it integrated in (PR 31), the dispatch-ahead
+queue and the fills in flight are bounded by bytes.  The sizes here
+are small; the rule's one constant is patched down to them."""
 
 import numpy as np
 import pytest
@@ -84,10 +85,11 @@ def _oracle(raw):
 
 @pytest.mark.parametrize('chunked', [False, True])
 def test_correlate_block_integrates_in_place(chunked, monkeypatch):
-    """Three integrations of four gulps, exact against int64; every
-    gulp goes into the block's donated planes, the first of an
-    integration by a program that overwrites them (no zero product),
-    with the channels in one chunk or in several."""
+    """Three integrations of four gulps, exact against int64; the
+    first gulp of an integration makes the planes (no zero product),
+    every other goes into them, donated, with the channels in one
+    chunk or in several; the finished planes go to the ring as they
+    are, and no program joins them."""
     import importlib
     C = importlib.import_module('bifrost_tpu.blocks.correlate')
     G, F, S, P, NINT, GPI = 8, 6, 3, 2, 3, 4
@@ -105,7 +107,7 @@ def test_correlate_block_integrates_in_place(chunked, monkeypatch):
     def spy(self, x, reim):
         before = self._acc
         integrate(self, x, reim)
-        seen.append((self.nframe_integrated == 0, before, self._acc))
+        seen.append((before, self._acc))
     monkeypatch.setattr(C.CorrelateBlock, '_integrate_in_place', spy)
     with bf.Pipeline() as p:
         src = NumpySourceBlock(
@@ -124,17 +126,159 @@ def test_correlate_block_integrates_in_place(chunked, monkeypatch):
             out[k], _oracle(raw[k * G * GPI:(k + 1) * G * GPI]))
     assert counters.get('correlate.acc_in_place') == GPI * NINT
     assert counters.get('correlate.integrations') == NINT
-    # two programs a gulp shape (first / add) and the product's, and
-    # the accumulator each gulp was given is gone: it was donated
-    assert sorted(k[2] for k in corr._fn if k != 'product') == \
-        [False, True]
-    assert [first for first, _b, _a in seen] == \
+    # two programs a gulp shape (first / add) and none that joins the
+    # planes; the first gulp of an integration is given nothing (the
+    # last integration's planes are the ring's), and what every other
+    # gulp was given is gone: it was donated
+    assert sorted(corr._fn) == sorted(
+        ((G, F, S, P, 2), 'int8', first) for first in (False, True))
+    assert [before is None for before, _a in seen] == \
         ([True] + [False] * (GPI - 1)) * NINT
-    for _first, before, after in seen[1:]:
-        assert all(a.is_deleted() for a in before)
+    for before, after in seen:
+        assert before is None or all(a.is_deleted() for a in before)
         assert all(a.dtype == np.float32 and
-                   a.shape == (F, S, P, S, P) for a in after)
+                   a.shape == (1, F, S, P, S, P) for a in after)
+    assert corr._acc is None
     assert corr.impl_info['nchan_chunk'] == (2 if chunked else F)
+
+
+def _correlated(raw, G, GPI, tail):
+    """host source -> copy('tpu') -> correlate -> ``tail(corr)``, run;
+    what ``tail`` returned."""
+    _, F, S, P = raw.shape
+    hdr = simple_header([-1, F, S, P], 'ci8',
+                        labels=['time', 'freq', 'station', 'pol'],
+                        gulp_nframe=G)
+    with bf.Pipeline() as p:
+        src = NumpySourceBlock(
+            [raw[k:k + G] for k in range(0, len(raw), G)], hdr,
+            gulp_nframe=G)
+        corr = bf.blocks.correlate(bf.blocks.copy(src, space='tpu'),
+                                   nframe_per_integration=G * GPI)
+        out = tail(corr)
+        p.run()
+    return out
+
+
+class _Tap(bf.pipeline.SinkBlock):
+    """A device reader that keeps what ``take(ispan)`` gives it."""
+
+    def __init__(self, iring, take, **kwargs):
+        super(_Tap, self).__init__(iring, **kwargs)
+        self.take, self.got = take, []
+
+    def define_valid_input_spaces(self):
+        return ('tpu',)
+
+    def on_sequence(self, iseq):
+        pass
+
+    def on_data(self, ispan):
+        self.got.append(self.take(ispan))
+
+
+@pytest.mark.parametrize('reader', ['device_block', 'stitched',
+                                    'copy_tpu_tpu', 'strict_scope',
+                                    'async_off', 'to_host',
+                                    'to_host_async'])
+def test_readers_of_a_correlator_ring_see_complex64(reader, monkeypatch):
+    """The ring holds the product as planes; whoever asks a span for
+    its array gets the complex64 it stands for: a device block, a read
+    over two products (the stitcher), copy('tpu' -> 'tpu'), strict
+    D2H, ``to_host`` and ``to_host_async`` of the pair itself."""
+    import jax
+    from bifrost_tpu import xfer
+    from bifrost_tpu.devrep import ComplexPlanes
+    G, F, S, P, NINT, GPI = 4, 6, 3, 2, 4, 2
+    raw = _voltages(G * GPI * NINT, F, S, P, seed=11)
+    want = np.stack([_oracle(raw[k * G * GPI:(k + 1) * G * GPI])
+                     for k in range(NINT)])
+    if reader == 'async_off':
+        monkeypatch.setenv('BF_XFER_ASYNC', '0')
+        xfer.reset_engine()
+
+    def seen(ispan):
+        data = ispan.data
+        assert isinstance(data, jax.Array) and \
+            data.dtype == np.complex64 and \
+            isinstance(ispan.planes, ComplexPlanes)
+        return np.asarray(data)
+
+    tail = {
+        'device_block': lambda corr: _Tap(corr, seen),
+        'stitched': lambda corr: GatherSink(corr, gulp_nframe=2),
+        'copy_tpu_tpu': lambda corr: GatherSink(
+            bf.blocks.copy(corr, space='tpu')),
+        'strict_scope': lambda corr: GatherSink(
+            bf.blocks.copy(corr, space='system', sync_strict=True)),
+        'async_off': lambda corr: GatherSink(
+            bf.blocks.copy(corr, space='system')),
+        'to_host': lambda corr: _Tap(
+            corr, lambda ispan: xfer.to_host(ispan.planes)),
+        'to_host_async': lambda corr: _Tap(
+            corr, lambda ispan: xfer.to_host_async(ispan.planes).result()),
+    }[reader]
+    try:
+        sink = _correlated(raw, G, GPI, tail)
+    finally:
+        xfer.reset_engine()
+    got = np.concatenate(sink.got if isinstance(sink, _Tap)
+                         else sink.gulps)
+    assert got.dtype == np.complex64
+    np.testing.assert_array_equal(got, want)
+    if reader == 'stitched':        # a span over two chunks is no pair
+        assert [g.shape[0] for g in sink.gulps] == [2, 2]
+
+
+def test_planes_handed_on_are_not_written_again():
+    """The planes a product went to the ring in belong to the ring:
+    the next integration makes its own, so a reader that still holds
+    two products finds them whole while the third is integrated and
+    after."""
+    G, F, S, P, NINT, GPI = 4, 6, 3, 2, 3, 2
+    raw = _voltages(G * GPI * NINT, F, S, P, seed=5)
+    tap = _correlated(raw, G, GPI,
+                      lambda corr: _Tap(corr, lambda ispan: ispan.planes))
+    assert len(tap.got) == NINT
+    arrays = [a for pair in tap.got for a in (pair.re, pair.im)]
+    assert len(set(map(id, arrays))) == 2 * NINT
+    for k, pair in enumerate(tap.got):
+        assert not pair.is_deleted() and pair.is_ready()
+        assert pair.shape == (1, F, S, P, S, P) and \
+            pair.dtype == np.complex64 and \
+            pair.nbytes == 8 * F * (S * P) ** 2
+        np.testing.assert_array_equal(
+            (np.asarray(pair.re) + 1j * np.asarray(pair.im))[0],
+            _oracle(raw[k * G * GPI:(k + 1) * G * GPI]))
+
+
+def test_partial_commit_and_sub_span_of_planes():
+    """A short commit slices both planes; a read of part of a chunk
+    is the complex array's slice, and no pair."""
+    import jax
+    from bifrost_tpu.devrep import ComplexPlanes
+    rng = np.random.RandomState(2)
+    re, im = rng.randn(2, 4, 8).astype(np.float32)
+    ring = Ring(space='tpu')
+    hdr = simple_header([-1, 8], 'cf32', gulp_nframe=4)
+    with ring.begin_writing() as w:
+        with w.begin_sequence(hdr, 4, 8) as seq:
+            with seq.reserve(4) as sp:
+                sp.set(ComplexPlanes(jax.device_put(re),
+                                     jax.device_put(im)))
+                sp.commit(3)
+            with ring.open_earliest_sequence(guarantee=False) as rs:
+                with rs.acquire(0, 3) as span:
+                    pair = span.planes
+                    assert pair.shape == (3, 8)
+                    np.testing.assert_array_equal(
+                        np.asarray(span.data), (re + 1j * im)[:3])
+                with rs.acquire(1, 2) as span:
+                    assert span.planes is None
+                    np.testing.assert_array_equal(
+                        np.asarray(span.data), (re + 1j * im)[1:3])
+    with pytest.raises(ValueError):
+        ComplexPlanes(jax.device_put(re), jax.device_put(im[:2]))
 
 
 def test_correlate_block_float_voltages_in_place():
@@ -174,10 +318,12 @@ def test_served_chain_with_large_products(ahead, monkeypatch):
     """host source -> copy('tpu') -> correlate -> copy('system') ->
     sink with the rule's constant below a product: the product rings
     are two deep on both sides, the input rings three; the products
-    cross in pieces as real (re, im) pairs, group by group, ``ahead``
-    groups cut before the one being taken, one product in flight at a
-    time; the block's dispatch-ahead queue holds one; every
-    visibility is exact."""
+    cross in pieces as real (re, im) pairs cut from the planes the
+    correlator handed on, each cut up when its landing starts and
+    taken group by group, ``ahead`` groups on their way beside the
+    one being taken, one product in flight at a time; the block's
+    dispatch-ahead queue holds one (its two planes); every visibility
+    is exact."""
     from bifrost_tpu import xfer
     from bifrost_tpu.telemetry import spans
     G, F, S, P, NINT, GPI = 4, 16, 4, 2, 5, 2
@@ -229,16 +375,21 @@ def test_served_chain_with_large_products(ahead, monkeypatch):
         assert cap(d2h) == 3 * product      # two and the ghost region
         assert g['ring.held_bytes.system'] >= 4 * gulp + 3 * product
         assert g['ring.held_bytes.tpu'] >= 3 * gulp + 2 * product
-        # every product in pieces, four groups of two each, as pairs
+        # every product in pieces, four groups of two each, as pairs,
+        # cut from planes: no program made it complex on the way
         assert counters.get('xfer.d2h_piece_bytes') == \
             counters.get('xfer.d2h_pair_bytes') == \
+            counters.get('xfer.d2h_plane_bytes') == \
+            counters.get('xfer.d2h_cutup_bytes') == \
             counters.get('xfer.d2h_bytes') == NINT * product
+        assert 'product' not in corr._fn
         names = [ev[0] for _t, ev in spans.events()]
         assert names.count('d2h.fill') == 4 * NINT
         assert 'd2h.convert' not in names
         # no product was queued behind an unfinished one
         assert len(most) == NINT and max(most) == 0
-        assert len(corr._pending_outputs) == 1
+        assert [[a.dtype for a in gulp]
+                for gulp in corr._pending_outputs] == [[np.float32] * 2]
     finally:
         xfer.reset_engine()
 

@@ -488,7 +488,8 @@ def test_reader_that_arrives_first_claims_the_fill(gated):
                                                 sp.data.as_numpy()))
                     sp.commit(8)
             for fut in gated.futures:            # landed, by the workers
-                fut.gate.set()
+                assert fut.entered.wait(SOON)    # (each has one: a drain
+                fut.gate.set()                   # that came first would)
             within(gated.drain, True)
             busy = [gated.host_fill(data[:8], 'f32', np.zeros((8, 16),
                                                               np.float32))
@@ -717,7 +718,7 @@ def _product(dtype, nframe, nchan):
     """(host data of bifrost dtype ``dtype``, how it reaches the
     device) for a product of ``(nframe, nchan)``."""
     import jax
-    from bifrost_tpu.devrep import to_device_rep
+    from bifrost_tpu.devrep import to_device_rep, ComplexPlanes
     from bifrost_tpu.dtype import DataType
     rng = np.random.RandomState(11)
     if dtype == 'f32':
@@ -725,10 +726,16 @@ def _product(dtype, nframe, nchan):
     elif dtype == 'cf32':
         data = (rng.randn(nframe, nchan) +
                 1j * rng.randn(nframe, nchan)).astype(np.complex64)
-    elif dtype == 'cf32_special':
-        # H2D recombines complex with arithmetic: these go as they are
+    elif dtype in ('cf32_special', 'cf32_planes'):
+        # H2D recombines complex with arithmetic: these go as they
+        # are, whole or as the two planes a block computed them in
         words = rng.choice(_SPECIAL_WORDS, (nframe, nchan, 2))
-        return words.view(np.complex64)[..., 0], jax.device_put
+        data = words.view(np.complex64)[..., 0]
+        if dtype == 'cf32_special':
+            return data, jax.device_put
+        return data, lambda gulp: ComplexPlanes(
+            jax.device_put(np.ascontiguousarray(gulp.real)),
+            jax.device_put(np.ascontiguousarray(gulp.imag)))
     elif dtype == 'cf64':
         return (rng.randn(nframe, nchan) +
                 1j * rng.randn(nframe, nchan)), jax.device_put
@@ -745,13 +752,15 @@ def _words(a):
 
 
 @pytest.mark.parametrize('dtype', ['f32', 'ci8', 'cf32', 'cf32_special',
-                                   'cf64'])
+                                   'cf32_planes', 'cf64'])
 def test_large_product_crosses_in_pieces(dtype, monkeypatch):
     """A product over twice the piece size is cut on the device and
     lands piece by piece, bit for bit, in a span that wraps (ghost
     mirror included), whatever its dtype: a complex one as real
     (re, im) pairs that the host sees as complex again, with no
-    conversion of its own (complex128 on the CPU backend alone)."""
+    conversion of its own (complex128 on the CPU backend alone),
+    whether it reaches the engine as a complex array or as its two
+    planes."""
     import jax
     from bifrost_tpu.telemetry import spans
     monkeypatch.setattr(xfer, '_D2H_PIECE_BYTES', 128)
@@ -796,7 +805,90 @@ def test_large_product_crosses_in_pieces(dtype, monkeypatch):
     # reader whatever it counts
     assert counters.snapshot()['xfer.d2h_pair_bytes'] == \
         (3 * fills[0].nbytes if dtype.startswith('cf') else 0)
+    assert counters.snapshot()['xfer.d2h_plane_bytes'] == \
+        (3 * fills[0].nbytes if dtype == 'cf32_planes' else 0)
     assert 'd2h.convert' not in _span_names()
+
+
+def _special_planes(shape, seed):
+    """(re, im) host planes of float32 words arithmetic would not
+    bring through, and the complex64 they stand for."""
+    rng = np.random.RandomState(seed)
+    re, im = (rng.choice(_SPECIAL_WORDS, shape).view(np.float32)
+              for _ in range(2))
+    both = np.empty(shape + (2,), np.float32)
+    both[..., 0], both[..., 1] = re, im
+    return re, im, both.view(np.complex64)[..., 0]
+
+
+@pytest.mark.parametrize('rows', [False, True])
+@pytest.mark.parametrize('shape,axis,step', [((10, 16), 0, 4),
+                                             ((1, 7, 4, 2, 4, 2), 1, 2)])
+def test_cut_from_planes_is_the_cut_of_the_complex_array(shape, axis,
+                                                         step, rows):
+    """Every piece cut from planes is, word for word, the piece cut
+    from the complex64 array they stand for (NaN payloads, -0.0,
+    infinities, denormals), the last short one too, with ``rows``
+    either way; and it is the product's own bytes in their order."""
+    import jax
+    from bifrost_tpu.devrep import ComplexPlanes
+    re, im, data = _special_planes(shape, seed=31)
+    pair = ComplexPlanes(jax.device_put(re), jax.device_put(im))
+    whole = jax.device_put(data)
+    assert np.array_equal(_words(np.asarray(pair.joined())), _words(data))
+    full, rest = divmod(shape[axis], step)
+    cuts = [(0, step, full)] + ([(full * step, rest, 1)] if rest else [])
+    lead = (slice(None),) * axis
+    for start, n, count in cuts:
+        mine = xfer._cut(pair, start, axis, n, count, rows)
+        theirs = xfer._cut(whole, start, axis, n, count, rows)
+        assert len(mine) == len(theirs) == count
+        for j, (a, b) in enumerate(zip(mine, theirs)):
+            assert a.dtype == b.dtype == np.uint32 and a.shape == b.shape
+            assert np.array_equal(np.asarray(a), np.asarray(b))
+            want = data[lead + (slice(start + j * n,
+                                      start + (j + 1) * n),)]
+            assert np.array_equal(_words(np.asarray(a)).ravel(),
+                                  _words(want).ravel())
+
+
+def test_cut_of_planes_names_no_complex_type():
+    """The program that cuts planes has no complex type in it, which
+    is what spares the chip the split of the whole product (the cut of
+    the complex64 array names one: the control)."""
+    import jax
+    from bifrost_tpu.devrep import device_arrays
+    re, im, data = _special_planes((1, 16, 4, 2, 4, 2), seed=3)
+    planes = (jax.device_put(re), jax.device_put(im))
+    xfer._cut(jax.device_put(data), 0, 1, 2, 2, True)    # builds _cut_fn
+    text = xfer._cut_fn.lower(planes, 0, 1, 2, 2, True).as_text()
+    assert 'complex' not in text and 'ui32' in text
+    control = xfer._cut_fn.lower(
+        device_arrays(jax.device_put(data)), 0, 1, 2, 2, True).as_text()
+    assert 'complex' in control
+
+
+def test_small_planes_cross_whole_as_complex64(monkeypatch):
+    """Planes under the piece threshold, or asked for outside a ring
+    fill, are joined and cross as the complex64 they stand for."""
+    import jax
+    from bifrost_tpu.devrep import ComplexPlanes, from_device_rep
+    monkeypatch.setattr(xfer, '_D2H_PIECE_BYTES', 1 << 20)
+    re, im, data = _special_planes((8, 16), seed=9)
+    pair = ComplexPlanes(jax.device_put(re), jax.device_put(im))
+    eng = xfer.engine()
+    counters.reset()
+    out = np.zeros_like(data)
+    fill = eng.host_fill(pair, 'cf32', out)
+    fill.wait()
+    assert not isinstance(fill.future, xfer._PieceFuture)
+    assert np.array_equal(_words(out), _words(data))
+    assert counters.get('xfer.d2h_plane_bytes') == 0
+    assert counters.get('xfer.d2h_bytes') == data.nbytes
+    for got in (eng.to_host(pair), eng.to_host_async(pair).result(),
+                from_device_rep(pair, 'cf32', np.zeros_like(data))):
+        assert got.dtype == np.complex64
+        assert np.array_equal(_words(got), _words(data))
 
 
 @pytest.mark.parametrize('ctype', [np.float32, np.complex64])
@@ -857,67 +949,292 @@ def test_small_products_cross_whole_and_uneven_ones_in_pieces(
                                    else np.float32)}
 
 
-@pytest.mark.parametrize('ahead', [1, 2, 3])
-def test_single_frame_complex_product_streams_in_groups(ahead,
-                                                        monkeypatch):
-    """A product whose leading axis is one frame (an integration of a
-    correlator) is cut along the first axis that can be cut; a LARGE
-    one is cut a group at a time, ``_D2H_AHEAD`` groups on their way
-    beside the one being taken and never more."""
-    import weakref
-    import jax
+#: a single-frame product of twelve channels of 512 bytes (complex64)
+#: or 256 (float32): LARGE, and six groups of two pieces, once the
+#: constants are what ``_small_constants`` makes them
+_FRAME = (1, 12, 4, 2, 4, 2)
+
+
+def _small_constants(monkeypatch, ahead=2, inflight=None):
     from bifrost_tpu import memory
-    from bifrost_tpu.telemetry import spans
-    monkeypatch.setattr(xfer, '_D2H_PIECE_BYTES', 512)
     monkeypatch.setattr(xfer, '_D2H_GROUP', 2)
     monkeypatch.setattr(xfer, '_D2H_AHEAD', ahead)
-    monkeypatch.setattr(memory, 'LARGE_SPAN_BYTES', 4096)
-    counters.reset()
-    spans.reset()
-    rng = np.random.RandomState(5)
-    shape = (1, 12, 4, 2, 4, 2)              # 12 channels of 512 bytes
-    data = (rng.randn(*shape) + 1j * rng.randn(*shape)) \
+    monkeypatch.setattr(memory, 'LARGE_SPAN_BYTES', 2048)
+    if inflight is not None:
+        monkeypatch.setattr(memory, 'INFLIGHT_BYTES', inflight)
+
+
+def _frame_product(form, seed, monkeypatch):
+    """``(host data, what reaches the engine, its bifrost dtype)`` of
+    one ``_FRAME`` product: a complex64 array (``cf32``), the two
+    planes of one (``planes``) or a float32 array (``f32``), with
+    pieces of one channel."""
+    import jax
+    from bifrost_tpu.devrep import ComplexPlanes
+    rng = np.random.RandomState(seed)
+    monkeypatch.setattr(xfer, '_D2H_PIECE_BYTES',
+                        256 if form == 'f32' else 512)
+    if form == 'f32':
+        data = rng.randn(*_FRAME).astype(np.float32)
+        return data, jax.device_put(data), 'f32'
+    data = (rng.randn(*_FRAME) + 1j * rng.randn(*_FRAME)) \
         .astype(np.complex64)
-    held, cut_at, alive = [], [], []
-    cut = xfer._cut
+    if form == 'cf32':
+        return data, jax.device_put(data), 'cf32'
+    return data, ComplexPlanes(
+        jax.device_put(np.ascontiguousarray(data.real)),
+        jax.device_put(np.ascontiguousarray(data.imag))), 'cf32'
 
-    def live():
+
+class _Schedule(object):
+    """What the engine asks of the device, in order, through patched
+    ``_cut``, ``_start_readback`` and ``_cross``: ``cuts`` (thread,
+    first index), ``hints`` (groups whose readback has been started)
+    and ``takes`` (groups whose crossing has begun); per take the cuts
+    and hints issued by then, and the device pieces still alive."""
+
+    def __init__(self, monkeypatch):
+        import weakref
+        self.cuts, self.hints, self.takes = [], 0, 0
+        self.at_take, self.alive = [], []
+        cut, cross = xfer._cut, xfer._cross
+        start = xfer.TransferEngine._start_readback
+
+        def counting_cut(arr, start_, *rest):
+            pieces = cut(arr, start_, *rest)
+            self.cuts.append((threading.current_thread().name,
+                              int(start_)))
+            self.alive.extend(weakref.ref(p) for p in pieces)
+            return pieces
+
+        def counting_start(arrays):
+            self.hints += 1
+            self.most_ahead = max(self.most_ahead,
+                                  self.hints - self.takes)
+            return start(arrays)
+
+        def counting_cross(arrays, *rest):
+            self.at_take.append((len(self.cuts), self.hints, self.live()))
+            self.takes += 1
+            return cross(arrays, *rest)
+        self.most_ahead = 0
+        monkeypatch.setattr(xfer, '_cut', counting_cut)
+        monkeypatch.setattr(xfer, '_cross', counting_cross)
+        monkeypatch.setattr(xfer.TransferEngine, '_start_readback',
+                            staticmethod(counting_start))
+
+    def live(self):
         gc.collect()
-        return sum(1 for ref in alive if ref() is not None)
+        return sum(1 for ref in self.alive if ref() is not None)
 
-    def counting_cut(arr, start, axis, step, count, rows):
-        cut_at.append(live())
-        pieces = cut(arr, start, axis, step, count, rows)
-        # complex: handed over as rows of 32-bit words, re and im
-        # interleaved
-        assert all(p.shape == (1, 128) and p.dtype == np.uint32
-                   for p in pieces)
-        held.append(sum(int(p.nbytes) for p in pieces))
-        alive.extend(weakref.ref(p) for p in pieces)
-        return pieces
-    monkeypatch.setattr(xfer, '_cut', counting_cut)
+
+@pytest.mark.parametrize('form', ['cf32', 'planes', 'f32'])
+@pytest.mark.parametrize('ahead', [1, 2, 3])
+def test_single_frame_large_product_streams_in_groups(ahead, form,
+                                                      monkeypatch):
+    """A product whose leading axis is one frame (an integration of a
+    correlator) is cut along the first axis that can be cut, and a
+    LARGE one lands a group at a time with the readback of
+    ``_D2H_AHEAD`` groups started beside the one being taken and
+    never more, however it comes.  When it is CUT follows from what a
+    cut costs: a complex64 array group by group, ``_D2H_AHEAD`` ahead
+    (each cut program splits the whole of it first); its two planes,
+    or an array of real words, all at once when the landing starts,
+    and nothing before."""
+    _small_constants(monkeypatch, ahead)
+    data, product, dtype = _frame_product(form, 5, monkeypatch)
+    whole = form != 'cf32'
+    seen = _Schedule(monkeypatch)
     eng = xfer.engine()
     out = np.zeros_like(data)
-    fut = eng._future_for(jax.device_put(data), out)
+    fut = eng._future_for(product, out)
+    del product
     assert (fut._axis, fut._step, fut._group) == (1, 1, 2)
-    assert len(held) == len(fut._ahead) == ahead     # cut by the caller
+    assert fut._whole == whole
+    # by the caller: the look-ahead's groups, or nothing yet
+    assert len(seen.cuts) == len(fut._ahead) == seen.hints == \
+        (0 if whole else ahead)
+    assert not (whole and fut.ready())
     landed = []
 
     def put(group, last):
-        landed.append(live())
+        landed.append((len(group), last))
         for host, where in group:
-            assert host.dtype == np.complex64
+            assert host.dtype == data.dtype
             out[where] = host
     fut.land(put)
     assert np.array_equal(out, data)
-    assert held == [1024] * 6            # six groups of two channels
-    # before a cut: the group being taken and one short of the
-    # look-ahead; while a group lands: it and the look-ahead
-    assert max(cut_at) <= 2 * ahead and max(landed) <= 2 * (1 + ahead)
-    assert landed[0] == 2 * (1 + ahead) and len(landed) == 6
+    assert landed == [(2, False)] * 5 + [(2, True)]
+    assert [start for _who, start in seen.cuts] == [0, 2, 4, 6, 8, 10]
+    for k, (cuts, hints, _live) in enumerate(seen.at_take):
+        # every cut before the first take, or the look-ahead's alone
+        assert cuts == (6 if whole else min(k + 1 + ahead, 6))
+        # the group being taken and the look-ahead are on their way
+        assert hints == min(k + 1 + ahead, 6)
+    assert seen.most_ahead == min(ahead + 1, 6)
+    # a group's pieces go with it (on this backend once the host's
+    # views of them have, with the ``put`` before): those still to
+    # come remain, all of them or the look-ahead's
+    assert [live for _c, _h, live in seen.at_take] == \
+        [2 * ((6 - k) if whole else min(1 + ahead, 6 - k))
+         for k in range(6)]
+    del landed, put
+    assert seen.live() == 0
+    nbytes = data.nbytes
     assert counters.get('xfer.d2h_piece_bytes') == \
-        counters.get('xfer.d2h_pair_bytes') == data.nbytes
+        counters.get('xfer.d2h_bytes') == nbytes
+    assert counters.get('xfer.d2h_pair_bytes') == \
+        (0 if form == 'f32' else nbytes)
+    assert counters.snapshot()['xfer.d2h_plane_bytes'] == \
+        (nbytes if form == 'planes' else 0)
+    assert counters.snapshot()['xfer.d2h_cutup_bytes'] == \
+        (nbytes if whole else 0)
     assert fut.done and fut._arrays == [] and not fut._ahead
+
+
+def test_a_product_under_the_large_size_is_never_cut_up(monkeypatch):
+    """What is counted as cut up is LARGE: a smaller product in pieces
+    is one group, cut when the future is made, as before."""
+    _small_constants(monkeypatch)
+    from bifrost_tpu import memory
+    monkeypatch.setattr(memory, 'LARGE_SPAN_BYTES', 1 << 20)
+    data, product, dtype = _frame_product('planes', 6, monkeypatch)
+    seen = _Schedule(monkeypatch)
+    out = np.zeros_like(data)
+    fill = xfer.engine().host_fill(product, dtype, out)
+    assert not fill.future._whole and fill.future._group == 12
+    assert [start for _who, start in seen.cuts] == [0]
+    fill.wait()
+    assert np.array_equal(out, data)
+    assert counters.snapshot()['xfer.d2h_cutup_bytes'] == 0
+    assert counters.get('xfer.d2h_plane_bytes') == data.nbytes
+
+
+@pytest.mark.parametrize('form', ['planes', 'f32'])
+def test_the_next_product_is_cut_up_before_the_last_one_is_announced(
+        form, monkeypatch):
+    """The order that keeps a producer's programs behind the cuts.  A
+    product that finds nothing landing is cut up by ``host_fill``
+    itself, before it returns; one that finds a landing under way
+    (and waits for it: the byte bound) is cut up by the completion
+    thread once the bytes of the one before have landed and BEFORE
+    anybody who waits for them is told, so whatever the caller lets
+    go by returning is dispatched behind all of the cuts."""
+    _small_constants(monkeypatch, inflight=2048)
+    seen = _Schedule(monkeypatch)
+    eng = xfer.engine()
+    gate, entered = threading.Event(), threading.Event()
+    cross = xfer._cross
+
+    def gated_cross(arrays, *rest):
+        entered.set()
+        assert gate.wait(SOON), 'the gate never opened'
+        return cross(arrays, *rest)
+    monkeypatch.setattr(xfer, '_cross', gated_cross)
+    a, pa, dtype = _frame_product(form, 7, monkeypatch)
+    b, pb, dtype = _frame_product(form, 8, monkeypatch)
+    outs = [np.zeros_like(a), np.zeros_like(b)]
+    fills = [eng.host_fill(pa, dtype, outs[0])]
+    del pa
+    # nothing was landing: cut up here, all of it, nothing taken yet
+    assert seen.cuts == [('MainThread', s) for s in range(0, 12, 2)]
+    assert entered.wait(SOON)            # the worker is at its first take
+    order = []
+
+    def second():
+        fills.append(eng.host_fill(pb, dtype, outs[1]))
+        order.append(('returned', len(seen.cuts)))
+    t = threading.Thread(target=second)
+    t.start()
+    t.join(0.2)
+    assert t.is_alive() and len(seen.cuts) == 6      # held up, uncut
+    del pb
+    gate.set()
+    t.join(SOON)
+    assert not t.is_alive()
+    # every cut of the second product was issued by the completion
+    # thread before the caller was let go
+    assert order == [('returned', 12)]
+    assert seen.cuts[6:] == [('xfer-d2h-0', s) for s in range(0, 12, 2)]
+    for fill in fills:
+        within(fill.wait)
+    assert np.array_equal(outs[0], a) and np.array_equal(outs[1], b)
+    assert counters.get('xfer.fills_by_worker') == 2
+    assert counters.get('xfer.d2h_cutup_bytes') == a.nbytes + b.nbytes
+    assert seen.live() == 0
+
+
+def test_fault_in_the_middle_of_a_cut_up_product_poisons_the_ring(
+        monkeypatch):
+    """A transfer that fails at its third group, every group cut by
+    then: the fill records it and poisons the ring, the groups before
+    it landed once and none after, and no piece stays on the device."""
+    from bifrost_tpu.ring import Ring
+    from bifrost_tpu.testing import faults
+    _small_constants(monkeypatch)
+    data, product, dtype = _frame_product('planes', 9, monkeypatch)
+    seen = _Schedule(monkeypatch)
+    eng = xfer.engine()
+    ring = Ring(space='system')
+    hdr = simple_header([-1] + list(_FRAME[1:]), 'cf32', gulp_nframe=1)
+    puts = []
+    put = xfer.HostFill._put
+
+    def counting_put(self, group, last):
+        puts.extend(where for _host, where in group)
+        return put(self, group, last)
+    monkeypatch.setattr(xfer.HostFill, '_put', counting_put)
+    with faults.injected('xfer.result', after=2, count=1):
+        with ring.begin_writing() as w:
+            with w.begin_sequence(hdr, 1, 3) as seq:
+                with seq.reserve(1) as sp:
+                    view = sp.data.as_numpy()
+                    view[...] = 0
+                    fill = eng.host_fill(product, dtype, view)
+                    del product
+                    sp.set_fill(fill)
+                    sp.commit(1)
+                assert fill._landed.wait(SOON)
+                assert isinstance(fill.error, faults.FaultInjected)
+                assert ring.poisoned
+                landed = np.array(view)
+    assert len(seen.cuts) == 6 and seen.takes == 3    # the third failed
+    # groups 0 and 1 (channels 0-3) once each, nothing else
+    assert [w[1] for w in puts] == [slice(k, k + 1) for k in range(4)]
+    assert np.array_equal(landed[:, :4], data[:, :4])
+    assert not landed[:, 4:].any()
+    assert counters.get('xfer.fill_errors') == 1
+    assert fill.future.done and not fill.future._ahead
+    with pytest.raises(faults.FaultInjected):
+        within(fill.wait)
+    with pytest.raises(faults.FaultInjected):
+        within(eng.drain)
+    # the three groups behind the failed one are let go; its own two
+    # pieces live as long as the error's traceback does
+    assert seen.live() <= 2
+
+
+def test_a_cut_that_fails_ahead_of_the_landing_is_reported_by_it(
+        monkeypatch):
+    """``HostFill.cut_up`` swallows a failing cut (it runs for a fill
+    that is not the caller's business yet); the landing meets it
+    again and fails the fill with it."""
+    _small_constants(monkeypatch)
+    data, product, dtype = _frame_product('f32', 10, monkeypatch)
+    boom = RuntimeError('the cut failed')
+    calls = []
+
+    def failing_cut(*args):
+        calls.append(threading.current_thread().name)
+        raise boom
+    monkeypatch.setattr(xfer, '_cut', failing_cut)
+    out = np.zeros_like(data)
+    fill = xfer.engine().host_fill(product, dtype, out)
+    assert calls[0] == 'MainThread'          # tried, and kept quiet
+    with pytest.raises(RuntimeError, match='the cut failed'):
+        within(fill.wait)
+    assert len(calls) == 2 and fill.error is boom
+    assert not out.any()
 
 
 def test_fills_in_flight_are_bounded_by_bytes(gated, monkeypatch):

@@ -59,6 +59,19 @@ the form in which a piece leaves the device:
     rows ``(step, rest)``, ``c_planes_lane_rows`` as lane-filling rows,
     ``c_planes_as_is`` in the product's own shape, with no program on
     the device but the slices.
+(d) ``d_from_planes_words`` (PR 31): (b)'s ``b_pairs_words`` cut from
+    a product that is held as two float32 planes and never was
+    complex64 on the device, as a correlator's reaches xfer.py since
+    PR 31: what is left of (b)'s cut once no program has a complex
+    argument to split.
+
+(b) ``b_pairs_words`` and (d) cross twice more with the product CUT
+UP AT ONCE (``products_cut_up_at_once``, PR 32): all sixteen cut
+programs dispatched before the first group is taken, the readback
+hinted one group ahead as before; what xfer.py does with a LARGE
+product of real words, and why it does not with a complex64 one
+(there the sixteen whole-product splits then stand in front of the
+first piece).  Form names after ``complex`` run those forms alone.
 
 For each: ``cut_s_a_product`` (the sixteen cut programs of a product
 with nothing else on the device and no transfer), and for each of
@@ -243,7 +256,8 @@ def _lane_rows(shape):
 
 
 def _pairs(p, tail, words=False):
-    planes = [q.reshape((-1,) + tail) for q in (p.real, p.imag)]
+    re, im = p if isinstance(p, tuple) else (p.real, p.imag)
+    planes = [q.reshape((-1,) + tail) for q in (re, im)]
     if words:
         planes = [jax.lax.bitcast_convert_type(q, jnp.uint32)
                   for q in planes]
@@ -283,32 +297,43 @@ COMPLEX_FORMS = {
         lambda p: [q.reshape(-1, int(np.prod(_lane_rows(p.shape))))
                    for q in (p.real, p.imag)], _interleave),
     'c_planes_as_is': (lambda p: [p.real, p.imag], _interleave),
+    # its piece is the pair of plane slices (``_FROM_PLANES``)
+    'd_from_planes_words': (
+        lambda p: [_pairs(p, _lane_rows(p[0].shape), words=True)],
+        _as_complex),
 }
+_FROM_PLANES = {'d_from_planes_words'}
+_planes_of = jax.jit(lambda z: (z.real, z.imag))
 
 
 def _complex_cut(form):
+    """``x`` is the complex64 product, or the pair of its planes."""
     def cut(x, start):
         out = []
         for j in range(CGROUP):
-            out += form(jax.lax.dynamic_slice_in_dim(
-                x, start + j * CSTEP, CSTEP, 1))
+            out += form(jax.tree.map(
+                lambda a: jax.lax.dynamic_slice_in_dim(
+                    a, start + j * CSTEP, CSTEP, 1), x))
         return tuple(out)
     return jax.jit(cut)
 
 
-def _cross_complex(x, cut, land, out):
-    """One product into ``out`` as the engine does it, one group ahead;
-    seconds by part."""
+def _cross_complex(x, cut, land, out, at_once=False):
+    """One product into ``out`` as the engine does it, one group ahead
+    (``at_once``: every group cut before the first is taken, the
+    readback one group ahead); seconds by part."""
     took = {'ready_s': 0.0, 'asarray_s': 0.0, 'fill_s': 0.0}
+    starts = list(range(0, CSHAPE[1], CSTEP * CGROUP))
+    t0 = time.perf_counter()
+    cut_up = {s: cut(x, s) for s in starts} if at_once else {}
+    took['dispatch_s'] = time.perf_counter() - t0
 
     def hinted(start):
-        group = cut(x, start)
+        group = cut_up.pop(start) if at_once else cut(x, start)
         for piece in group:
             piece.copy_to_host_async()
         return group
 
-    starts = list(range(0, CSHAPE[1], CSTEP * CGROUP))
-    t0 = time.perf_counter()
     ahead = hinted(starts[0])
     for k, start in enumerate(starts):
         group = ahead
@@ -332,9 +357,13 @@ def _cross_complex(x, cut, land, out):
     return took
 
 
-def complex_case():
-    """(a), (b), (c) of the module docstring; the line so far goes to
-    standard error after every form."""
+_AT_ONCE = {'b_pairs_words', 'd_from_planes_words'}
+
+
+def complex_case(only=()):
+    """(a) to (d) of the module docstring (``only``: those forms
+    alone); the line so far goes to standard error after every
+    form."""
     out = {'shape': list(CSHAPE),
            'nbytes': 8 * int(np.prod(CSHAPE)), 'forms': {}}
     bits = jax.jit(lambda k: jax.random.bits(k, CSHAPE, jnp.uint32))
@@ -355,25 +384,39 @@ def complex_case():
     host = np.zeros(CSHAPE, np.complex64)                # touched
     starts = range(0, CSHAPE[1], CSTEP * CGROUP)
     for name, (form, land) in COMPLEX_FORMS.items():
+        if only and name not in only:
+            continue
         cut = _complex_cut(form)
-        jax.block_until_ready(cut(prods[0], 0))          # compile
+        # from planes: a third product's worth of HBM, one at a time
+        given = _planes_of if name in _FROM_PLANES else (lambda z: z)
+        x = jax.block_until_ready(given(prods[0]))
+        jax.block_until_ready(cut(x, 0))                 # compile
         cuts = []
         for _ in range(2):
             t0 = time.perf_counter()
-            jax.block_until_ready([cut(prods[0], s) for s in starts])
+            jax.block_until_ready([cut(x, s) for s in starts])
             cuts.append(time.perf_counter() - t0)
         got = {'cut_s_a_product': cuts, 'products': []}
-        for x, want in zip(prods, wants):
+        for prod, want in zip(prods, wants):
             host[...] = 0
+            del x
+            x = jax.block_until_ready(given(prod))
             took = _cross_complex(x, cut, land, host)
             have = host.view(np.uint32).reshape(want.shape)
             took['exact'] = bool(np.array_equal(have, want))
             if not took['exact']:
                 took['words_wrong'] = int((have != want).sum())
             got['products'].append(took)
+            if name in _AT_ONCE:
+                host[...] = 0
+                took = _cross_complex(x, cut, land, host, at_once=True)
+                took['exact'] = bool(np.array_equal(
+                    host.view(np.uint32).reshape(want.shape), want))
+                got.setdefault('products_cut_up_at_once', []).append(took)
         got['peak_hbm_gb_so_far'] = (
             jax.devices()[0].memory_stats() or {}).get(
             'peak_bytes_in_use', 0) / 1e9
+        del x
         out['forms'][name] = got
         print(json.dumps({'complex': out}), file=sys.stderr, flush=True)
     return out
@@ -385,7 +428,8 @@ def main():
     out = {'device': {'platform': dev.platform,
                       'kind': dev.device_kind}}
     if 'complex' in cases:
-        out['complex'] = complex_case()
+        out['complex'] = complex_case(
+            [c for c in cases if c in COMPLEX_FORMS])
     if 'float32' in cases:
         float32_case(out)
     print(json.dumps(out))
