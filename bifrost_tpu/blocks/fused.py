@@ -592,6 +592,21 @@ class FusedBlock(TransformBlock):
                      for p in parts]
         return self._dispatch_device(fn, parts)
 
+    def _count_transformed(self, ngulps):
+        """``spectrometer.gulps``: gulps that went through a chain
+        with a transform in it (the whole-chain kernel, or an FftStage
+        whose path the executed plan recorded);
+        ``spectrometer.long_gulps``: those whose transform took the
+        three-level path (ops.fft.long_fft)."""
+        info = self.impl_info or {}
+        fft = info.get('fft')
+        if fft is None and info.get('impl') != 'pallas-spectrometer':
+            return
+        from ..telemetry import counters
+        counters.inc('spectrometer.gulps', ngulps)
+        if fft is not None and fft.get('path') == 'long':
+            counters.inc('spectrometer.long_gulps', ngulps)
+
     def on_data(self, ispan, ospan):
         if self._gulp_batch_active > 1 and self._macro_gulp_in:
             x = self._take_donatable(ispan, allow_parts=True)
@@ -604,12 +619,15 @@ class FusedBlock(TransformBlock):
             ospan.set(self._execute_macro(parts, donate,
                                           self._macro_gulp_in),
                       owned=True)
+            self._count_transformed(
+                max(1, -(-ispan.nframe // self._macro_gulp_in)))
             return
         x = self._take_donatable(ispan)
         if x is not None:
             ospan.set(self._execute_plan(x, donate=True), owned=True)
         else:
             ospan.set(self._execute_plan(ispan.data), owned=True)
+        self._count_transformed(1)
 
 
 def fused(iring, stages, *args, **kwargs):

@@ -10,6 +10,8 @@ once and XLA fuses the lot — callbacks for free.
 
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 
 from ..dtype import DataType
@@ -221,12 +223,41 @@ def fft_impl_choice():
     return os.environ.get('BF_FFT_IMPL', '').strip().lower()
 
 
-def fftn_dispatch(x, axes, inverse=False):
-    """jnp.fft.fftn/ifftn (unnormalized inverse), or the DFT-matmul
-    path when BF_FFT_IMPL=dftmm (per axis; MXU-bound)."""
+def fft_path(shape, axes, inverse=False, dtype='complex64'):
+    """What :func:`fftn_dispatch` runs for a transform of ``axes`` of
+    an array of ``shape``, as a record a block can publish: from the
+    shape and the documented variables alone.  ``{'path': 'long',
+    'factors': ..., 'precision': ...}`` for a forward complex64
+    transform of one last axis whose length takes three levels
+    (:func:`long_factors`); ``'dftmm'`` where BF_FFT_IMPL forces the
+    two-level matrix form; else ``'xla'`` (jnp.fft)."""
     import os
-    import jax.numpy as jnp
     if fft_impl_choice() == 'dftmm':
+        return {'path': 'dftmm'}
+    ndim = len(shape)
+    axes = [a % ndim for a in axes]
+    factors = long_factors(shape[axes[0]]) if axes == [ndim - 1] else None
+    if factors is None or inverse or str(dtype) != 'complex64':
+        return {'path': 'xla'}
+    # BF_FFT_DFT_DTYPE=bf16 is the matrix forms' lower-precision
+    # switch: one bf16 pass a product where float32 accuracy takes three
+    bf16 = os.environ.get('BF_FFT_DFT_DTYPE', '').strip().lower() \
+        in ('bf16', 'bfloat16')
+    return {'path': 'long', 'factors': list(factors),
+            'precision': 'default' if bf16 else 'high'}
+
+
+def fftn_dispatch(x, axes, inverse=False):
+    """The transform of ``axes`` by the one implementation its shape
+    selects (:func:`fft_path`): jnp.fft.fftn/ifftn (unnormalized
+    inverse); three levels of DFT matrices (:func:`long_fft`) for a
+    length past LONG_NFFT; the DFT-matmul path when BF_FFT_IMPL=dftmm
+    (per axis; MXU-bound)."""
+    import os
+    import jax
+    import jax.numpy as jnp
+    plan = fft_path(x.shape, axes, inverse, x.dtype)
+    if plan['path'] == 'dftmm':
         cdt = os.environ.get('BF_FFT_DFT_DTYPE', '').strip().lower() \
             or None
         y = x
@@ -234,8 +265,159 @@ def fftn_dispatch(x, axes, inverse=False):
             y = dft_matmul_fft(y, ax, inverse=inverse,
                                compute_dtype=cdt)
         return y
+    if plan['path'] == 'long':
+        # real() and imag() of a complex64 made of planes are those
+        # planes again once XLA has simplified the program, and the
+        # TPU computes complex64 on separate planes anyway
+        return jax.lax.complex(*long_fft(
+            jnp.real(x), jnp.imag(x), tuple(plan['factors']),
+            precision=plan['precision']))
     if inverse:
         y = jnp.fft.ifftn(x, axes=axes)
         import numpy as np_
         return y * np_.prod([x.shape[a] for a in axes])
     return jnp.fft.fftn(x, axes=axes)
+
+
+# ---------------------------------------------------------------------------
+# Long transforms: three levels of DFT matrices
+# ---------------------------------------------------------------------------
+
+#: the largest DFT matrix a level multiplies by: the MXU's width, and
+#: the lane count a level's minor dimension keeps
+MAX_FACTOR = 128
+#: two levels of such matrices reach this length (the fused
+#: spectrometer's kernel, ops/spectrometer.py, and jnp.fft below it);
+#: a power of two past it, up to MAX_FACTOR ** 3, takes three
+LONG_NFFT = MAX_FACTOR ** 2
+
+
+def long_factors(n):
+    """(n1, n2, n3), n = n1 * n2 * n3, for a power of two ``n`` past
+    two levels, or None where three levels of at most MAX_FACTOR do
+    not reach (or ``n`` is no such length): the minor factor n3 is
+    the lane count, the other two share what is left, the larger
+    first (2^20: 128, 64, 128)."""
+    if n <= LONG_NFFT or n > MAX_FACTOR ** 3 or n & (n - 1):
+        return None
+    rest = (n // MAX_FACTOR).bit_length() - 1
+    n1 = 1 << ((rest + 1) // 2)
+    return n1, (n // MAX_FACTOR) // n1, MAX_FACTOR
+
+
+@functools.lru_cache(maxsize=4)
+def _long_consts(factors):
+    """Host-built float32 (re, im) planes of the three DFT matrices
+    and the two twiddle tables of :func:`long_fft`, from float64."""
+    n1, n2, n3 = factors
+    m = n2 * n3
+
+    def planes(z):
+        return (np.ascontiguousarray(z.real, np.float32),
+                np.ascontiguousarray(z.imag, np.float32))
+
+    def dft(k):
+        i = np.arange(k)
+        return planes(np.exp(-2j * np.pi * ((i[:, None] * i[None, :]) % k)
+                             / k))
+    # level 1's twiddle W_N^(k1 * (n3*n2' + n3')), level 2's W_M^(k2 n3')
+    t1 = np.exp(-2j * np.pi * (np.arange(n1)[:, None] * np.arange(m)[None, :])
+                / (n1 * m)).reshape(n1, n2, n3)
+    t2 = np.exp(-2j * np.pi * (np.arange(n2)[:, None]
+                               * np.arange(n3)[None, :]) / m)
+    return dft(n1), planes(t1), dft(n2), planes(t2), dft(n3)
+
+
+#: the spectra of one chunk of a long transform, float32 (re, im)
+#: planes, hold at most this many bytes: a level's intermediates then
+#: stay in the chip's fast memory between the products.  On the v5e a
+#: 268 MB gulp of 128 transforms of 2^20 points, detected, takes 48.0 ms
+#: whole, 41.7 in chunks of 128 MiB, 22.4 of 64 and of 32, 20.1 of 16
+#: (one coarse channel's two polarisations): PERF.md section 6, PR 33
+_CHUNK_BYTES = 1 << 24
+
+
+def _chunk_rows(nrow, n, keep):
+    """Transforms a chunk: the largest divisor of ``nrow`` that is a
+    multiple of ``keep`` (rows that must stay together: a pair of
+    polarisations) and whose spectra fit ``_CHUNK_BYTES``; ``keep`` at
+    least."""
+    fit = max(_CHUNK_BYTES // (8 * n), keep)
+    return next(r for r in range(min(fit, nrow), 0, -1)
+                if nrow % r == 0 and r % keep == 0)
+
+
+def long_fft(xr, xi, factors, precision='high', natural=True,
+             then=None, keep=1):
+    """Forward c2c transform over the last axis of the real planes
+    ``xr``, ``xi`` (..., n) in three levels of DFT matrix products,
+    n = n1 * n2 * n3 = ``factors``: with n = (n2 n3) a + n3 b + c and
+    k = k1 + n1 k2 + n1 n2 k3,
+
+        A[k1, b, c]  = sum_a F1[k1, a] x[a, b, c]  * W_n^(k1 (n3 b + c))
+        B[k1, k2, c] = sum_b F2[k2, b] A[k1, b, c] * W_(n2 n3)^(k2 c)
+        X[k1, k2, k3] = sum_c F3[k3, c] B[k1, k2, c]
+
+    The planes (any real dtype) are cast to float32; every product is
+    real planes against real planes, four a level, summed in float32
+    (stacked as one product against [[Fr, -Fi], [Fi, Fr]] a level read
+    28.8 ms where this reads 21.2: PERF.md section 6, PR 33).
+    ``precision``: 'high' (three bf16 passes of the MXU a
+    product: float32 accuracy) or 'default' (one: the lower-precision
+    control; six, ``HIGHEST``, read 3e-8 for 1.6e-6 at 27.8 ms for
+    22.4 and have no caller).  The leading axes go through a chunk
+    at a time (:func:`_chunk_rows`, ``keep`` rows never apart), and
+    ``then(yr, yi)``, given the float32 planes (rows, n) of a chunk's
+    spectra, is applied to each chunk before the next is begun (a
+    spectrometer's detection: the spectra never reach HBM whole); its
+    result's leading axis must be the chunk's.  Returns the planes
+    (..., n), or ``then``'s results joined along their first axis: in
+    frequency order where ``natural``, else as (n1, n2, n3) flattened,
+    k1 major, which saves the reordering for a caller that sums
+    spectra before it needs their order."""
+    import jax
+    import jax.numpy as jnp
+    n1, n2, n3 = factors
+    n = n1 * n2 * n3
+    lead = xr.shape[:-1]
+    if xr.shape[-1] != n:
+        raise ValueError('long_fft: %d points do not factor as %r'
+                         % (xr.shape[-1], (factors,)))
+    prec = {'high': jax.lax.Precision.HIGH,
+            'default': jax.lax.Precision.DEFAULT}[precision]
+    f1, t1, f2, t2, f3 = _long_consts(tuple(factors))
+
+    def level(spec, f, ar, ai):
+        fr, fi = (jnp.asarray(p) for p in f)
+        mm = functools.partial(jnp.einsum, spec, precision=prec,
+                               preferred_element_type=jnp.float32)
+        return mm(fr, ar) - mm(fi, ai), mm(fr, ai) + mm(fi, ar)
+
+    def twiddle(ar, ai, t):
+        tr, ti = (jnp.asarray(p) for p in t)
+        return ar * tr - ai * ti, ar * ti + ai * tr
+
+    def one(xr, xi):
+        """(rows, n) planes -> the chunk's spectra, or then() of them."""
+        ar = xr.astype(jnp.float32).reshape(-1, n1, n2, n3)
+        ai = xi.astype(jnp.float32).reshape(-1, n1, n2, n3)
+        ar, ai = twiddle(*level('ka,rabc->rkbc', f1, ar, ai), t1)
+        ar, ai = twiddle(*level('kb,rabc->rakc', f2, ar, ai), t2)
+        ar, ai = level('kc,rabc->rkba' if natural else 'kc,rabc->rabk',
+                       f3, ar, ai)
+        ar, ai = ar.reshape(-1, n), ai.reshape(-1, n)
+        return (ar, ai) if then is None else then(ar, ai)
+
+    nrow = int(np.prod(lead, dtype=np.int64))
+    rows = _chunk_rows(nrow, n, keep)
+    xr, xi = xr.reshape(nrow // rows, rows, n), xi.reshape(nrow // rows,
+                                                           rows, n)
+    if rows == nrow:
+        out = one(xr[0], xi[0])
+    else:
+        out = jax.tree_util.tree_map(
+            lambda y: y.reshape((-1,) + y.shape[2:]),
+            jax.lax.map(lambda planes: one(*planes), (xr, xi)))
+    if then is not None:
+        return out
+    return out[0].reshape(lead + (n,)), out[1].reshape(lead + (n,))

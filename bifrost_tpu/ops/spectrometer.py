@@ -61,7 +61,8 @@ import functools
 
 import numpy as np
 
-__all__ = ['fused_spectrometer', 'spectrometer_oracle',
+__all__ = ['fused_spectrometer', 'long_spectrometer',
+           'spectrometer_oracle',
            'spectrometer_accuracy', 'choose_precision',
            'spectrometer_mode', 'resolve_transpose']
 
@@ -368,6 +369,36 @@ def fused_spectrometer(volt, nfft=None, rfactor=4, time_tile=32,
         return out.reshape(T, 4, nout)
     # epilogue: (T, 4, j, s) -> (T, 4, s, j) -> natural order
     return jnp.swapaxes(out, 2, 3).reshape(T, 4, nout)
+
+
+def long_spectrometer(volt, factors, precision='high'):
+    """ci8 dual-pol voltages -> Stokes spectra for a transform past the
+    kernel's two levels: (..., 2, nfft, 2) int8, (pol, fine_time,
+    re/im) last, -> (..., 4, nfft) float32 ordered [I, Q, U, V], the
+    semantics of FftStage -> DetectStage('stokes').  The transform is
+    ops.fft.long_fft (three levels of DFT matrices, ``factors``); the
+    detection runs inside its loop over chunks of the leading axes,
+    both polarisations of a spectrum in one chunk, so that the
+    complex spectra (8 B a sample) never reach HBM: the program reads
+    the voltages (2 B a sample) and writes the Stokes planes (8)."""
+    import jax.numpy as jnp
+    from .fft import long_fft
+    lead, nfft = volt.shape[:-3], volt.shape[-2]
+    if volt.shape[-3] != 2 or volt.shape[-1] != 2:
+        raise ValueError("expected (..., 2 pol, nfft, re/im) ci8 input")
+
+    def stokes(zr, zi):
+        zr, zi = zr.reshape(-1, 2, nfft), zi.reshape(-1, 2, nfft)
+        xr_, yr_, xi_, yi_ = zr[:, 0], zr[:, 1], zi[:, 0], zi[:, 1]
+        xx = xr_ * xr_ + xi_ * xi_
+        yy = yr_ * yr_ + yi_ * yi_
+        xyr = xr_ * yr_ + xi_ * yi_       # x * conj(y)
+        xyi = xi_ * yr_ - xr_ * yi_
+        return jnp.stack([xx + yy, xx - yy, 2.0 * xyr, -2.0 * xyi],
+                         axis=1)
+    out = long_fft(volt[..., 0], volt[..., 1], factors,
+                   precision=precision, then=stokes, keep=2)
+    return out.reshape(lead + (4, nfft))
 
 
 def spectrometer_oracle(volt, rfactor=4):

@@ -188,6 +188,18 @@ class FftStage(Stage):
                     if shift:
                         y = jnp.fft.fftshift(y, axes=axes)
             return y.astype(odt)
+        if mode == 'c2c':
+            # which implementation the transform takes at this shape,
+            # for the block that runs it to publish (compose_stages)
+            from .ops.fft import fft_path
+            shape = list(in_meta['shape'])
+            if in_meta.get('reim', False):
+                shape = shape[:-1]
+            fn.impl_info = dict(
+                fft_path(shape, axes, inverse,
+                         'complex128' if itype.nbits > 32
+                         else 'complex64'),
+                nfft=[int(shape[a]) for a in axes])
         return fn
 
 
@@ -1067,10 +1079,13 @@ def compose_stages(stages, headers, shape, dtype, substitute=True):
         # discarded
         plan = match_spectrometer(stages, headers, shape, dtype)
         if plan is None:
+            plan = match_long_spectrometer(stages, headers, shape, dtype)
+        if plan is None:
             plan = match_beamformer(stages, headers, shape, dtype)
         if plan is not None:
             return plan, plan.info
     fns = []
+    info = {'impl': 'xla-fused'}
     cur = jax.ShapeDtypeStruct(tuple(shape), dtype)
     for stage, ihdr in zip(stages, headers[:-1]):
         idt = DataType(ihdr['_tensor']['dtype'])
@@ -1078,9 +1093,11 @@ def compose_stages(stages, headers, shape, dtype, substitute=True):
                 'reim': idt.kind == 'ci'}
         fn = stage.build(meta)
         fns.append(fn)
+        if getattr(fn, 'impl_info', None):
+            info['fft'] = fn.impl_info
         cur = jax.eval_shape(fn, cur)
     composed = lambda x: _reduce(lambda v, f: f(v), fns, x)
-    return composed, {'impl': 'xla-fused'}
+    return composed, info
 
 
 class SpectrometerPlan(object):
@@ -1174,4 +1191,45 @@ def match_spectrometer(stages, headers, shape, dtype):
         'transpose': trans,
         'nfft': nfft,
         'rfactor': factor,
+    })
+
+
+def match_long_spectrometer(stages, headers, shape, dtype):
+    """Recognize the Guppi spectrometer's production form —
+    FftStage(c2c forward, no shift, last axis) -> DetectStage('stokes')
+    on ci8 dual-pol input (..., pol, fine_time) whose transform is
+    past two levels (ops.fft.fft_path says 'long': chosen from the
+    length alone, on every backend) — and return
+    ops.spectrometer.long_spectrometer as a :class:`SpectrometerPlan`:
+    the three-level transform with the detection inside its loop over
+    chunks, so the complex spectra never reach HBM.  Any other chain
+    with such a transform runs it through FftStage (the same
+    long_fft, its spectra written whole)."""
+    if len(stages) != 2:
+        return None
+    f, d = stages
+    if not (isinstance(f, FftStage) and isinstance(d, DetectStage)):
+        return None
+    if headers[0]['_tensor']['dtype'] != 'ci8' or str(dtype) != 'int8':
+        return None
+    nd = len(shape) - 1                 # logical rank: (re, im) is last
+    if nd < 3 or shape[-1] != 2 or shape[-3] != 2:
+        return None
+    if f.mode != 'c2c' or f.inverse or f.apply_fftshift \
+            or f.axes != [nd - 1]:
+        return None
+    if d.mode != 'stokes' or d.axis_index != nd - 2 or d.npol != 2:
+        return None
+    from .ops.fft import fft_path
+    from .ops import spectrometer as spec
+    path = fft_path(shape[:-1], f.axes)
+    if path['path'] != 'long':
+        return None
+    factors, prec = tuple(path['factors']), path['precision']
+
+    def fn(x):
+        return spec.long_spectrometer(x, factors, precision=prec)
+    return SpectrometerPlan(fn, {
+        'impl': 'long-spectrometer',
+        'fft': dict(path, nfft=[int(shape[-2])]),
     })

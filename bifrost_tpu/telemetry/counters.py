@@ -116,6 +116,22 @@ Observability counters (docs/observability.md; complemented by
                                            emitted
 - ``correlate.acc_in_place``               gulps it integrated into
                                            float32 planes in place
+- ``accumulate.gulps`` /
+  ``accumulate.acc_in_place`` /
+  ``accumulate.integrations``              gulps an AccumulateBlock
+                                           integrated / those it added
+                                           into the donated accumulator
+                                           where it lay (all but the
+                                           first of an integration, on
+                                           the device) / integrations
+                                           it handed to its ring
+- ``spectrometer.gulps`` /
+  ``spectrometer.long_gulps``              gulps a FusedBlock took
+                                           through a chain with a
+                                           transform in it / those
+                                           whose transform ran as
+                                           three levels of DFT matrices
+                                           (ops.fft.long_fft)
 - ``ring.<name>.gulps``                    LOGICAL gulps committed
                                            through ring ``<name>``
                                            (both cores; a macro-gulp

@@ -394,6 +394,58 @@ def test_served_chain_with_large_products(ahead, monkeypatch):
         xfer.reset_engine()
 
 
+def test_accumulate_product_rings_follow_span_bytes(monkeypatch):
+    """An accumulate block's product with the rule's constant at its
+    size (the gpuspec-hsr product is exactly LARGE_SPAN_BYTES: `>=`):
+    the rings that carry it are two deep on both sides of the D2H
+    copy, the spectra ring in front of the block too (a gulp's spectra
+    are a product's size), the input rings three; the product crosses
+    in pieces, cut up when its landing starts; the sums are exact."""
+    from bifrost_tpu import xfer
+    G, C, N, NINT, NPROD = 1, 8, 64, 3, 4
+    product = C * N * 4                             # 2048 bytes
+    assert memory.span_depth(1 << 30, 3) == 2       # the real product
+    monkeypatch.setattr(memory, 'LARGE_SPAN_BYTES', product)
+    monkeypatch.setattr(memory, 'INFLIGHT_BYTES', 2 * product)
+    monkeypatch.setattr(xfer, '_D2H_PIECE_BYTES', 256)
+    monkeypatch.setattr(xfer, '_D2H_GROUP', 2)
+    xfer.reset_engine()
+    counters.reset()
+    rng = np.random.RandomState(4)
+    data = rng.randint(-64, 64, size=(NINT * NPROD, C, N)) \
+        .astype(np.float32)
+    hdr = simple_header([-1, C, N], 'f32', gulp_nframe=G)
+    try:
+        with bf.Pipeline() as p:
+            src = NumpySourceBlock(list(data[:, None]), hdr, gulp_nframe=G)
+            h2d = bf.blocks.copy(src, space='tpu')
+            acc = bf.blocks.accumulate(h2d, NINT)
+            d2h = bf.blocks.copy(acc, space='system')
+            sink = GatherSink(d2h)
+            p.run()
+        np.testing.assert_array_equal(
+            sink.result(), data.reshape(NPROD, NINT, C, N).sum(1))
+        g = counters.gauges()
+
+        def cap(block):
+            return g['ring.%s.capacity_bytes' % block.orings[0].name]
+        # a gulp here is a product's size, so every ring holds two
+        # (and the host rings their ghost region)
+        assert cap(src) == 3 * product
+        assert cap(h2d) == 2 * product
+        assert cap(acc) == 2 * product
+        assert cap(d2h) == 3 * product
+        assert counters.get('accumulate.integrations') == NPROD
+        assert counters.get('accumulate.acc_in_place') == \
+            (NINT - 1) * NPROD
+        assert counters.get('xfer.d2h_piece_bytes') == \
+            counters.get('xfer.d2h_cutup_bytes') == \
+            counters.get('xfer.d2h_bytes') == NPROD * product
+        assert counters.get('xfer.d2h_plane_bytes') == 0
+    finally:
+        xfer.reset_engine()
+
+
 def test_dispatch_ahead_queue_is_bounded_by_bytes(monkeypatch):
     """A device block's queue of outputs it has not waited for drains
     to the newest once it holds more than INFLIGHT_BYTES, whatever
