@@ -117,23 +117,28 @@ class CopyBlock(TransformBlock):
         if ospace == 'tpu' and ispace != 'tpu':
             buf = ispan.data.as_numpy()
             # engine-created device array: the committed chunk is
-            # exclusively this ring's (donation-eligible downstream)
+            # exclusively this ring's (donation-eligible downstream);
+            # a ci8 gulp bound for one device crosses as its words
             ospan.set(to_device_rep(buf, ispan.dtype,
                                     sharding=self._h2d_sharding(ispan)),
                       owned=True)
         elif ispace == 'tpu' and ospace != 'tpu':
             out = ospan.data.as_numpy()
+            # a complex product its writer left as two real planes
+            # is cut from them (devrep.ComplexPlanes); the words of a
+            # ci8 gulp are the host's bytes, and cross as they are
+            # (devrep.ComplexWords)
+            src = ispan.planes
+            if src is None:
+                src = ispan.words
+            if src is None:
+                src = ispan.data
             if self._d2h_strict():
-                from_device_rep(ispan.data, ospan.dtype, out)
+                from_device_rep(src, ospan.dtype, out)
             else:
                 # non-blocking: commit the span now, let the engine's
                 # bounded queue + the reader materialize the bytes
                 from .. import xfer
-                # a complex product its writer left as two real
-                # planes is cut from them (devrep.ComplexPlanes)
-                src = ispan.planes
-                if src is None:
-                    src = ispan.data
                 fill = xfer.engine().host_fill(src, ospan.dtype, out)
                 ospan.set_fill(fill)
         elif ispace == 'tpu' and ospace == 'tpu':

@@ -48,6 +48,7 @@ from copy import deepcopy
 
 from ..pipeline import TransformBlock
 from ..stages import CorrelateStage
+from ..words import ComplexWords
 from .fft import _StageBlock
 
 __all__ = ['CorrelateBlock', 'CorrelateStageBlock', 'correlate']
@@ -432,13 +433,19 @@ class CorrelateBlock(TransformBlock):
             return jfn(x, acc)
         return plain_fn
 
-    def _build_in_place(self, shape, reim, first):
+    def _build_in_place(self, shape, reim, first, words=False):
         """The one-device program of a gulp, with the float32
         accumulator planes shaped as the output span's frame
         (1, F, S, P, S, P): ``fn(x, ar, ai) -> (ar, ai)`` takes them
         donated and adds where they lie; the ``first`` of an
         integration, ``fn(x) -> (ar, ai)``, makes planes of its own
-        (the last integration's belong to the ring by then)."""
+        (the last integration's belong to the ring by then).  With
+        ``words`` ``x`` is the ci8 gulp's int16 words as the ring
+        holds them (devrep.ComplexWords: one axis), folded here to
+        (time, freq, station x pol), which is the engine's own order
+        of axes, so a chunk's int8 planes are two sign-extending
+        shifts of its slice and the pairs of ``shape`` are never
+        made."""
         import jax
         import jax.numpy as jnp
         from jax import lax
@@ -447,11 +454,21 @@ class CorrelateBlock(TransformBlock):
         _, f, s, p = shape[:4]
         fc = _chunk_nchan(f, s * p)
 
+        engine = self.engine
+
+        def chunk_vis(x, k):
+            x = lax.dynamic_slice_in_dim(x, k * fc, fc, axis=1)
+            if not words:
+                return local_vis(x)
+            # low byte re, high byte im (little-endian)
+            re = ((x << 8) >> 8).astype(jnp.int8)
+            im = (x >> 8).astype(jnp.int8)
+            return engine(re, im).reshape(fc, s, p, s, p)
+
         def chunk(k, acc, x):
             # real() and imag() of the engine's complex64 are its own
             # two planes again once XLA has simplified the program
-            vis = local_vis(lax.dynamic_slice_in_dim(x, k * fc, fc,
-                                                     axis=1))
+            vis = chunk_vis(x, k)
             out = []
             for plane, a in zip((jnp.real(vis), jnp.imag(vis)), acc):
                 plane = plane[None]
@@ -463,6 +480,8 @@ class CorrelateBlock(TransformBlock):
             return tuple(out)
 
         def fn(x, ar, ai):
+            if words:
+                x = x.reshape(shape[0], f, s * p)   # one pass
             if fc == f:
                 return chunk(0, (ar, ai), x)
             return lax.fori_loop(0, f // fc,
@@ -477,21 +496,29 @@ class CorrelateBlock(TransformBlock):
 
     def _integrate_in_place(self, x, reim):
         """One gulp into the integration's planes: the first makes
-        them, on the gulp's device, the others add into them."""
+        them, on the gulp's device, the others add into them.  A gulp
+        that comes as words (devrep.ComplexWords) goes to the program
+        as them."""
         from ..telemetry import counters
         first = self._acc is None
-        key = (tuple(x.shape), str(x.dtype), first)
+        words = isinstance(x, ComplexWords)
+        key = (tuple(x.shape), 'words' if words else str(x.dtype), first)
         fn = self._fn.get(key)
         if fn is None:
             fn = self._fn[key] = self._build_in_place(x.shape, reim,
-                                                       first)
+                                                       first, words)
+        if words:
+            x = x.words
         self._acc = fn(x) if first else fn(x, *self._acc)
         counters.inc('correlate.acc_in_place')
 
     def on_data(self, ispan, ospan):
         import jax.numpy as jnp
         from ..telemetry import counters
-        x = ispan.data
+        # a ci8 gulp on one device: the program starts from its words
+        x = ispan.words if self.mesh is None else None
+        if x is None:
+            x = ispan.data
         reim = ispan.tensor['dtype'].kind == 'ci' and \
             not jnp.issubdtype(x.dtype, jnp.complexfloating)
         if self.mesh is None:

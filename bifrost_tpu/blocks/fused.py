@@ -12,6 +12,7 @@ FFT→detect→reduce fused).
 from __future__ import annotations
 
 from ..pipeline import TransformBlock
+from ..words import ComplexWords
 
 __all__ = ['FusedBlock', 'fused', 'device_stages']
 
@@ -209,15 +210,20 @@ class FusedBlock(TransformBlock):
         ov = chain_overlap_nframe(self.stages) or 0
         try:
             import jax
-            from ..devrep import device_rep_zeros
+            from ..devrep import device_rep_zeros, whole
             # overlapped chains read gulp + lookahead frames per span
             shape = tuple(int(s) if s != -1 else int(gulp) + ov
                           for s in t['shape'])
-            jax.block_until_ready(
-                self._execute_plan(device_rep_zeros(shape, t['dtype'])))
+
+            def zeros(shape):
+                # the form a gulp will come in: a ci8 gulp on one
+                # device as words (on_data), pairs under a mesh
+                z = device_rep_zeros(shape, t['dtype'])
+                return z if self.mesh is None else whole(z)
+            jax.block_until_ready(self._execute_plan(zeros(shape)))
             if self._donation_on():
                 jax.block_until_ready(self._execute_plan(
-                    device_rep_zeros(shape, t['dtype']), donate=True))
+                    zeros(shape), donate=True))
         except Exception:
             self._plans = {}
             return
@@ -232,17 +238,17 @@ class FusedBlock(TransformBlock):
             if k > 1 and self._macro_static_reason() is None and \
                     (not ov or self.macro_overlap_safe()):
                 import jax
-                from ..devrep import device_rep_zeros
                 taxis = t['shape'].index(-1)
                 mshape = list(shape)
                 # halo carry: K logical gulps + ONE overlap history
                 mshape[taxis] = int(gulp) * k + ov
+                # a macro span is read whole (``.data``): pairs
                 jax.block_until_ready(self._execute_macro(
-                    [device_rep_zeros(tuple(mshape), t['dtype'])],
+                    [whole(zeros(tuple(mshape)))],
                     donate=False, gulp_nframe=int(gulp)))
                 if self._donation_on():
                     jax.block_until_ready(self._execute_macro(
-                        [device_rep_zeros(tuple(mshape), t['dtype'])],
+                        [whole(zeros(tuple(mshape)))],
                         donate=True, gulp_nframe=int(gulp)))
         except Exception:
             # keep the per-gulp plans warmed above; the macro plan
@@ -256,9 +262,15 @@ class FusedBlock(TransformBlock):
             n = stage.output_nframe(n)
         return n
 
-    def _build_plan(self, shape, dtype, donate=False):
+    def _build_plan(self, shape, dtype, donate=False, words=False):
+        """The plan of a gulp of ``shape`` and ``dtype``; with
+        ``words`` (one device only) the same plan as a function of the
+        gulp's int16 words (stages.from_words): the whole-chain
+        kernel folds them to its rows, one pass where the pairs take
+        four, any other chain makes the pairs first, inside the one
+        program."""
         import jax
-        from ..stages import compose_stages
+        from ..stages import compose_stages, from_words
         from ..ops.common import donating_jit
         from ..telemetry import counters as _counters
         # every plan build (trace + compile) is counted: the service
@@ -271,6 +283,9 @@ class FusedBlock(TransformBlock):
             # the stage pattern + accuracy gate admit
             composed, info = compose_stages(
                 self.stages, self._headers, shape, dtype)
+            if words:
+                composed = from_words(composed, shape)
+                info = dict(info, input='words')
             if donate:
                 # the donated gulp's HBM buffer is reusable in place
                 # for any matching intermediate of the chain
@@ -452,13 +467,18 @@ class FusedBlock(TransformBlock):
         call) — mesh plans donate too: the sharded input's per-device
         buffers alias same-layout intermediates/outputs shard by
         shard (donation-under-sharding, docs/parallel.md)."""
-        key = (tuple(x.shape), str(x.dtype), bool(donate))
+        words = isinstance(x, ComplexWords)
+        if words and self.mesh is not None:
+            x, words = x.pairs(), False
+        key = (tuple(x.shape), 'words' if words else str(x.dtype),
+               bool(donate))
         plan = self._plans.get(key)
         if plan is None:
             plan = self._depot_fetch(key)
         if plan is None:
             self._last_built_impl = None
-            plan = self._build_plan(x.shape, x.dtype, donate=donate)
+            plan = self._build_plan(x.shape, x.dtype, donate=donate,
+                                    words=words)
             self._plans[key] = plan
             self._record_impl(key, self._last_built_impl)
             self._depot_store(key)
@@ -469,7 +489,7 @@ class FusedBlock(TransformBlock):
         if taxis is not None:
             from ..parallel.scope import shard_gulp
             x = shard_gulp(x, self.mesh, taxis)
-        return self._dispatch_device(fn, (x,))
+        return self._dispatch_device(fn, (x.words if words else x,))
 
     def _execute_macro(self, parts, donate, gulp_nframe):
         """Macro-gulp execution: run ONE compiled program over a
@@ -597,13 +617,18 @@ class FusedBlock(TransformBlock):
         with a transform in it (the whole-chain kernel, or an FftStage
         whose path the executed plan recorded);
         ``spectrometer.long_gulps``: those whose transform took the
-        three-level path (ops.fft.long_fft)."""
+        three-level path (ops.fft.long_fft);
+        ``spectrometer.word_gulps``: those whose program started from
+        the gulp's int16 words (devrep.ComplexWords), counted at 0
+        too so that a reader finds the counter."""
         info = self.impl_info or {}
         fft = info.get('fft')
         if fft is None and info.get('impl') != 'pallas-spectrometer':
             return
         from ..telemetry import counters
         counters.inc('spectrometer.gulps', ngulps)
+        counters.inc('spectrometer.word_gulps',
+                     ngulps if info.get('input') == 'words' else 0)
         if fft is not None and fft.get('path') == 'long':
             counters.inc('spectrometer.long_gulps', ngulps)
 
@@ -622,11 +647,15 @@ class FusedBlock(TransformBlock):
             self._count_transformed(
                 max(1, -(-ispan.nframe // self._macro_gulp_in)))
             return
-        x = self._take_donatable(ispan)
+        # a ci8 gulp on one device: the plan starts from its words
+        words = self.mesh is None
+        x = self._take_donatable(ispan, words=words)
         if x is not None:
             ospan.set(self._execute_plan(x, donate=True), owned=True)
         else:
-            ospan.set(self._execute_plan(ispan.data), owned=True)
+            x = ispan.words if words else None
+            ospan.set(self._execute_plan(ispan.data if x is None else x),
+                      owned=True)
         self._count_transformed(1)
 
 

@@ -350,7 +350,8 @@ def _chunk_rows(nrow, n, keep):
 def long_fft(xr, xi, factors, precision='high', natural=True,
              then=None, keep=1):
     """Forward c2c transform over the last axis of the real planes
-    ``xr``, ``xi`` (..., n) in three levels of DFT matrix products,
+    ``xr``, ``xi`` (..., n), or of their rows of n where they come on
+    one axis, in three levels of DFT matrix products,
     n = n1 * n2 * n3 = ``factors``: with n = (n2 n3) a + n3 b + c and
     k = k1 + n1 k2 + n1 n2 k3,
 
@@ -379,8 +380,11 @@ def long_fft(xr, xi, factors, precision='high', natural=True,
     import jax.numpy as jnp
     n1, n2, n3 = factors
     n = n1 * n2 * n3
-    lead = xr.shape[:-1]
-    if xr.shape[-1] != n:
+    # planes on one axis longer than a transform are rows of n end to
+    # end (made from a gulp's words, devrep.ComplexWords)
+    flat = xr.ndim == 1 and xr.shape[0] > n and xr.shape[0] % n == 0
+    lead = (xr.shape[0] // n,) if flat else xr.shape[:-1]
+    if not flat and xr.shape[-1] != n:
         raise ValueError('long_fft: %d points do not factor as %r'
                          % (xr.shape[-1], (factors,)))
     prec = {'high': jax.lax.Precision.HIGH,
@@ -410,14 +414,25 @@ def long_fft(xr, xi, factors, precision='high', natural=True,
 
     nrow = int(np.prod(lead, dtype=np.int64))
     rows = _chunk_rows(nrow, n, keep)
-    xr, xi = xr.reshape(nrow // rows, rows, n), xi.reshape(nrow // rows,
-                                                           rows, n)
     if rows == nrow:
-        out = one(xr[0], xi[0])
+        out = one(xr.reshape(nrow, n), xi.reshape(nrow, n))
+    elif flat:
+        # a chunk is a stretch of the one axis, sliced where it lies:
+        # folding a whole plane to (chunks, rows, n) first is a
+        # relayout of the gulp, and one that libtpu takes 19 s to
+        # compile from 128 rows of 2^20 (PERF.md section 6, PR 34)
+        def chunk(k):
+            return one(*(jax.lax.dynamic_slice_in_dim(
+                p, k * rows * n, rows * n).reshape(rows, n)
+                for p in (xr, xi)))
+        out = jax.lax.map(chunk, jnp.arange(nrow // rows))
     else:
+        out = jax.lax.map(lambda planes: one(*planes),
+                          (xr.reshape(nrow // rows, rows, n),
+                           xi.reshape(nrow // rows, rows, n)))
+    if rows != nrow:
         out = jax.tree_util.tree_map(
-            lambda y: y.reshape((-1,) + y.shape[2:]),
-            jax.lax.map(lambda planes: one(*planes), (xr, xi)))
+            lambda y: y.reshape((-1,) + y.shape[2:]), out)
     if then is not None:
         return out
     return out[0].reshape(lead + (n,)), out[1].reshape(lead + (n,))

@@ -31,8 +31,23 @@ operand (which is how both FFT steps avoid materializing a transpose),
 (c) 2-D transposes, and (d) int16 loads with shift arithmetic.  The
 kernel is built strictly from that set:
 
-- ci8 re/im pairs enter as one int16 per complex sample (an XLA
-  bitcast, free) and are split with sign-extending shifts in-kernel;
+- ci8 samples enter as one int16 per complex sample, rows of
+  ``nfft`` words, (time, pol) collapsed, and are split with
+  sign-extending shifts in-kernel.  That is the host's own order, and
+  since PR 34 a gulp on one device is held as those words on one axis
+  (``devrep.ComplexWords``): :func:`fused_spectrometer` given the
+  words folds them to rows (one pass of the device, a relayout of
+  1024-word tiles to ``T(8,128)`` ones; none where they come as the
+  rows already) and feeds ``pallas_call``.  Given the int8 array with
+  its trailing (re, im) axis it makes the words by a bitcast, which
+  this docstring used to call "an XLA bitcast, free".  On the chip it
+  is not.  The runtime keeps ``s8[16384,2,4096,2]`` as
+  ``{2,0,3,1:T(8,128)(4,1)}`` ((re, im) far from minor-most), and the
+  compiled program opened with ``shift-left_reduce_fusion`` (re |
+  im << 8: the device interleaves again), ``bitcast_convert_type``,
+  ``copy`` (back to the host's order of axes) and ``reshape``: 3.5 ms
+  of a 15.9 ms gulp (PERF.md section 6, PR 34;
+  tests/test_tpu_compile.py holds the forms);
 - both FFT matmuls are ``dot_general`` with contracting dim 1, so the
   data never transposes between steps;
 - the frequency reduce groups the fast output index r (a SUBLANE
@@ -296,8 +311,15 @@ def fused_spectrometer(volt, nfft=None, rfactor=4, time_tile=32,
                        transpose='auto'):
     """ci8 dual-pol voltages -> reduced Stokes spectra, one kernel.
 
-    volt: (T, 2, nfft, 2) int8 — (time, pol, fine_time, re/im), the
-    device representation of dtype 'ci8' gulps.
+    volt: the gulp's int16 words, one a complex sample (low byte re),
+    in (time, pol, fine_time) order: on one axis with ``nfft`` given,
+    as a ci8 gulp on one device is held
+    (``devrep.ComplexWords.words``: folded to rows here, one pass of
+    the device), or as the rows (2 T, nfft) the kernel reads, which it
+    then reads as they are.  Or (T, 2, nfft, 2) int8 — (time, pol,
+    fine_time, re/im), the pairs form of dtype 'ci8' gulps — from
+    which the words are made first (four passes over the gulp on the
+    chip: module docstring).
     Returns (T, 4, nfft // rfactor) float32 ordered [I, Q, U, V],
     identical semantics to the fused stage chain
     FftStage -> DetectStage('stokes') -> ReduceStage('freq', rfactor).
@@ -316,13 +338,23 @@ def fused_spectrometer(volt, nfft=None, rfactor=4, time_tile=32,
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    T, npol, n, two = volt.shape
-    if npol != 2 or two != 2:
-        raise ValueError("expected (time, 2 pol, nfft, re/im) ci8 input")
-    if nfft is None:
-        nfft = n
-    if n != nfft:
-        raise ValueError("nfft mismatch")
+    words = volt.dtype == jnp.int16
+    if words:
+        if nfft is None and volt.ndim == 2:
+            nfft = volt.shape[1]
+        T = volt.size // (2 * nfft) if nfft else 0
+        if not nfft or T * 2 * nfft != volt.size:
+            raise ValueError("expected the int16 words of (time, 2 pol, "
+                             "nfft) ci8 input, and nfft with them")
+    else:
+        T, npol, n, two = volt.shape
+        if npol != 2 or two != 2:
+            raise ValueError("expected (time, 2 pol, nfft, re/im) ci8 "
+                             "input, or its int16 words")
+        if nfft is None:
+            nfft = n
+        if n != nfft:
+            raise ValueError("nfft mismatch")
     if nfft % rfactor:
         raise ValueError("rfactor must divide nfft")
     n1, n2 = _choose_split(nfft, rfactor)
@@ -344,10 +376,13 @@ def fused_spectrometer(volt, nfft=None, rfactor=4, time_tile=32,
                              transpose == 'kernel', tuple(names),
                              use_bd)
     rows_tile = 2 * tt
-    # one int16 per complex sample (free XLA bitcast of the (re, im)
-    # int8 pair; little-endian: low byte = re)
-    v16 = jax.lax.bitcast_convert_type(volt, jnp.int16)   # (T, 2, n)
-    flat = v16.reshape(T * 2, n)
+    # one int16 per complex sample (little-endian: low byte = re):
+    # the words folded to rows (one pass; none where they are rows),
+    # else a bitcast of the (re, im) int8 pairs, which costs the
+    # device four passes over the gulp (module docstring)
+    if not words:
+        volt = jax.lax.bitcast_convert_type(volt, jnp.int16)  # (T, 2, n)
+    flat = volt.reshape(T * 2, nfft)
     grid = (T // tt,)
     if transpose == 'kernel':
         out_spec = pl.BlockSpec((tt, 4, n2, j), lambda i: (i, 0, 0, 0))
@@ -374,7 +409,13 @@ def fused_spectrometer(volt, nfft=None, rfactor=4, time_tile=32,
 def long_spectrometer(volt, factors, precision='high'):
     """ci8 dual-pol voltages -> Stokes spectra for a transform past the
     kernel's two levels: (..., 2, nfft, 2) int8, (pol, fine_time,
-    re/im) last, -> (..., 4, nfft) float32 ordered [I, Q, U, V], the
+    re/im) last, or int16, the same samples as words (low byte re),
+    with those axes or all on one (a gulp's words as a device ring
+    holds them; nfft is ``factors``' product; split here with
+    sign-extending shifts, one pass, into planes on one axis, of which
+    long_fft slices its chunks where they lie),
+    -> (..., 4, nfft) float32 ordered [I, Q, U, V] ((rows / 2, 4, nfft)
+    from one axis), the
     semantics of FftStage -> DetectStage('stokes').  The transform is
     ops.fft.long_fft (three levels of DFT matrices, ``factors``); the
     detection runs inside its loop over chunks of the leading axes,
@@ -383,8 +424,26 @@ def long_spectrometer(volt, factors, precision='high'):
     the voltages (2 B a sample) and writes the Stokes planes (8)."""
     import jax.numpy as jnp
     from .fft import long_fft
-    lead, nfft = volt.shape[:-3], volt.shape[-2]
-    if volt.shape[-3] != 2 or volt.shape[-1] != 2:
+    if volt.dtype == jnp.int16:
+        nfft = int(np.prod(factors))
+        if volt.ndim == 1:
+            lead, npol = (volt.size // (2 * nfft),), 2
+        else:
+            lead, npol = volt.shape[:-2], volt.shape[-2]
+        if volt.size != int(np.prod(lead)) * 2 * nfft or \
+                (volt.ndim > 1 and volt.shape[-1] != nfft):
+            raise ValueError("expected the int16 words of (..., 2 pol, "
+                             "%d) ci8 input" % nfft)
+        flat = volt.reshape(-1)
+        re = ((flat << 8) >> 8).astype(jnp.int8)
+        im = (flat >> 8).astype(jnp.int8)
+    else:
+        if volt.shape[-1] != 2:
+            raise ValueError("expected (..., 2 pol, nfft, re/im) ci8 "
+                             "input, or its int16 words")
+        re, im = volt[..., 0], volt[..., 1]
+        lead, npol, nfft = re.shape[:-2], re.shape[-2], re.shape[-1]
+    if npol != 2:
         raise ValueError("expected (..., 2 pol, nfft, re/im) ci8 input")
 
     def stokes(zr, zi):
@@ -396,7 +455,7 @@ def long_spectrometer(volt, factors, precision='high'):
         xyi = xi_ * yr_ - xr_ * yi_
         return jnp.stack([xx + yy, xx - yy, 2.0 * xyr, -2.0 * xyi],
                          axis=1)
-    out = long_fft(volt[..., 0], volt[..., 1], factors,
+    out = long_fft(re, im, factors,
                    precision=precision, then=stokes, keep=2)
     return out.reshape(lead + (4, nfft))
 

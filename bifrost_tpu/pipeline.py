@@ -1798,7 +1798,7 @@ class TransformBlock(MultiTransformBlock):
                 return profiling.profiled_dispatch(thunk)
         return profiling.profiled_dispatch(thunk)
 
-    def _take_donatable(self, ispan, allow_parts=False):
+    def _take_donatable(self, ispan, allow_parts=False, words=False):
         """The input span's device chunk claimed exclusively for
         donation, or None (donation off / exclusivity unprovable —
         callers fall back to ``ispan.data``).  With ``allow_parts``
@@ -1806,7 +1806,10 @@ class TransformBlock(MultiTransformBlock):
         exclusively-owned chunks exactly tiling the span — the macro
         plan concatenates them inside the donating jit, so upstream
         K=1 producers still feed a donating macro consumer.  Counts
-        donation hits/misses."""
+        donation hits/misses.  A ci8 gulp held as its words
+        (devrep.ComplexWords) comes as them to a caller whose program
+        starts from ``words``, else as the int8 pairs made from them:
+        a fresh array, the caller's alone."""
         if not self._donation_on():
             return None
         from .telemetry import counters
@@ -1819,6 +1822,9 @@ class TransformBlock(MultiTransformBlock):
         x = ispan.take_data(allow_parts=allow_parts)
         counters.inc('donation.hits' if x is not None
                      else 'donation.misses')
+        if not words:
+            from .planes import whole
+            x = [whole(p) for p in x] if isinstance(x, list) else whole(x)
         return x
 
     def _define_valid_input_spaces(self):

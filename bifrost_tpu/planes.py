@@ -1,15 +1,19 @@
 """The chunk type of a device ring that is not one jax array: a complex
 array as the two real planes it was computed in.  A leaf module (it
-imports nothing of the package), so that the ring, the dispatch-ahead
-queue, the transfer engine and :mod:`bifrost_tpu.devrep` can all name
-the type without depending on one another.
+imports nothing of the package but its fellow leaf
+:mod:`bifrost_tpu.words`, the other such chunk type), so that the
+ring, the dispatch-ahead queue, the transfer engine and
+:mod:`bifrost_tpu.devrep` can all name the type without depending on
+one another.
 """
 
 from __future__ import annotations
 
 import numpy as np
 
-__all__ = ['ComplexPlanes', 'device_arrays']
+from .words import ComplexWords
+
+__all__ = ['ComplexPlanes', 'device_arrays', 'whole']
 
 _join_fn = None
 
@@ -81,4 +85,17 @@ def device_arrays(chunk):
     """The jax arrays a device ring chunk is made of."""
     if isinstance(chunk, ComplexPlanes):
         return (chunk.re, chunk.im)
+    if isinstance(chunk, ComplexWords):
+        return (chunk.words,)
     return (chunk,)
+
+
+def whole(chunk):
+    """A device ring chunk as the one array a reader of ``.data``
+    sees: planes joined, words as (re, im) pairs, made now for that
+    reader; an array as it is."""
+    if isinstance(chunk, ComplexPlanes):
+        return chunk.joined()
+    if isinstance(chunk, ComplexWords):
+        return chunk.pairs()
+    return chunk

@@ -52,7 +52,8 @@ from .dtype import DataType
 from .header_standard import trace_context
 from .space import canonical
 from .ndarray import ndarray
-from .planes import ComplexPlanes
+from .planes import ComplexPlanes, whole as _whole
+from .words import ComplexWords
 from .testing import faults
 # dynamic ring-protocol checker (BF_RINGCHECK=1; docs/analysis.md) —
 # every seam call below is one module-bool test when disarmed
@@ -268,11 +269,6 @@ def _build_stitcher(plan, taxis):
     return jax.jit(fn)
 
 
-def _whole(chunk):
-    """A chunk as the array a reader of ``.data`` sees."""
-    return chunk.joined() if isinstance(chunk, ComplexPlanes) else chunk
-
-
 class _DeviceStorage(object):
     """Chunk-map storage for 'tpu' rings: committed gulps are jax arrays
     keyed by absolute byte offset.  Logical shape of each chunk is
@@ -415,12 +411,13 @@ class _DeviceStorage(object):
             fn = self._stitchers.put(key, _build_stitcher(plan, taxis))
         return fn(*arrs)
 
-    def planes(self, offset, nbyte):
+    def chunk_as(self, form, offset, nbyte):
         """The chunk covering exactly [offset, offset+nbyte) where it
-        is a complex array held as planes, else None."""
+        is held as a ``form`` (ComplexPlanes, ComplexWords), else
+        None."""
         hit = self.chunks.get(offset)
         if hit is not None and hit[0] == nbyte and \
-                isinstance(hit[1], ComplexPlanes):
+                isinstance(hit[1], form):
             return hit[1]
         return None
 
@@ -1899,8 +1896,10 @@ class WriteSpan(_SpanAPI):
         (device rings) marks the array as created exclusively for this
         ring — the committed chunk is then eligible for buffer donation
         downstream (ring._take_exclusive).  A complex gulp computed on
-        two real planes may be set as them (devrep.ComplexPlanes):
-        readers of ``.data`` see the complex array all the same."""
+        two real planes may be set as them (devrep.ComplexPlanes), a
+        ci8 gulp as its int16 words (devrep.ComplexWords): readers of
+        ``.data`` see the complex array, or the int8 (re, im) pairs,
+        all the same."""
         if self._ring.space == 'tpu':
             if isinstance(array, ndarray):
                 array = array.as_jax()
@@ -2078,7 +2077,7 @@ class ReadSpan(_SpanAPI):
             def zeros_fn(nframe):
                 from .devrep import device_rep_zeros
                 shape = (t['ringlet_shape'] + [nframe] + t['frame_shape'])
-                return device_rep_zeros(shape, t['dtype'])
+                return _whole(device_rep_zeros(shape, t['dtype']))
 
             self._data = self._ring._storage.get(
                 self._begin, self._nbyte, t['frame_nbyte'], zeros_fn)
@@ -2096,7 +2095,22 @@ class ReadSpan(_SpanAPI):
         either way)."""
         if self._ring.space != 'tpu':
             return None
-        return self._ring._storage.planes(self._begin, self._nbyte)
+        return self._ring._storage.chunk_as(ComplexPlanes, self._begin,
+                                            self._nbyte)
+
+    @property
+    def words(self):
+        """Device rings: this span's chunk as the
+        :class:`~bifrost_tpu.devrep.ComplexWords` an H2D copy of a ci8
+        gulp set (one int16 a complex sample, the host's bytes in the
+        host's order), for a reader whose program starts from the
+        words and spares the device the int8 array with its (re, im)
+        axis; None where the span is not exactly one such chunk
+        (``.data`` is the int8 array either way)."""
+        if self._ring.space != 'tpu':
+            return None
+        return self._ring._storage.chunk_as(ComplexWords, self._begin,
+                                            self._nbyte)
 
     def take_data(self, allow_parts=False):
         """Device rings: claim this span's committed chunk exclusively
@@ -2105,7 +2119,10 @@ class ReadSpan(_SpanAPI):
         or None when exclusivity cannot be proven — partial span,
         multi-chunk stitch, multi-reader ring, or a chunk the framework
         does not own (WriteSpan.set(..., owned=True)).  Callers fall
-        back to ``.data`` on None.
+        back to ``.data`` on None.  The chunk of a ci8 gulp that an
+        H2D copy set comes as it is held, its words
+        (:class:`~bifrost_tpu.devrep.ComplexWords`): ``chunk.pairs()``
+        is the int8 array, made for the caller alone.
 
         ``allow_parts=True`` (macro-gulp spans) additionally claims a
         run of owned chunks exactly tiling the span, returned as a

@@ -1106,12 +1106,30 @@ class SpectrometerPlan(object):
     publish what actually ran (ProcLog ``<block>/impl``) instead of
     benchmarks re-deriving the decision (VERDICT r3 item 4)."""
 
-    def __init__(self, fn, info):
+    def __init__(self, fn, info, words=None):
         self.fn = fn
         self.info = dict(info)
+        #: the same plan as a function of the gulp's int16 words
+        #: (devrep.ComplexWords.words), where the kernel can start
+        #: from them (:func:`from_words`)
+        self.words = words
 
     def __call__(self, x):
         return self.fn(x)
+
+
+def from_words(composed, shape):
+    """The one-gulp function ``composed`` (:func:`compose_stages`, for
+    a ci8 gulp of ``shape``, (re, im) last) as a function of the
+    gulp's int16 words, one a complex sample, as a device ring holds
+    them (devrep.ComplexWords): the plan's own where its kernel starts
+    from words, else the pairs are made first, inside the program."""
+    fn = getattr(composed, 'words', None)
+    if fn is not None:
+        return fn
+    from .words import pairs_of
+    shape = tuple(shape)
+    return lambda w: composed(pairs_of(w, shape))
 
 
 def match_spectrometer(stages, headers, shape, dtype):
@@ -1181,7 +1199,8 @@ def match_spectrometer(stages, headers, shape, dtype):
     factor = r.factor
 
     def fn(x):
-        return spec.fused_spectrometer(x, rfactor=factor,
+        # the pairs, or the gulp's words (fused_spectrometer)
+        return spec.fused_spectrometer(x, nfft=nfft, rfactor=factor,
                                        time_tile=tile, precision=prec,
                                        transpose=trans)
     return SpectrometerPlan(fn, {
@@ -1191,7 +1210,7 @@ def match_spectrometer(stages, headers, shape, dtype):
         'transpose': trans,
         'nfft': nfft,
         'rfactor': factor,
-    })
+    }, words=fn)
 
 
 def match_long_spectrometer(stages, headers, shape, dtype):
@@ -1229,7 +1248,11 @@ def match_long_spectrometer(stages, headers, shape, dtype):
 
     def fn(x):
         return spec.long_spectrometer(x, factors, precision=prec)
+
+    def words(w):
+        # the words on one axis: (rows / 2, 4, nfft) comes back
+        return fn(w).reshape(tuple(shape[:-3]) + (4, shape[-2]))
     return SpectrometerPlan(fn, {
         'impl': 'long-spectrometer',
         'fft': dict(path, nfft=[int(shape[-2])]),
-    })
+    }, words=words)

@@ -11,6 +11,10 @@ gulp dropped from 1 to <= 1/sync_depth").
 Counter names used by the framework:
 
 - ``xfer.h2d_issued`` / ``xfer.h2d_bytes``  host->device transfers
+- ``xfer.h2d_word_bytes``                  bytes of those that crossed
+                                           as int16 words, one a ci8
+                                           sample, in the host's order
+                                           (devrep.ComplexWords)
 - ``xfer.h2d_staged``                      H2D via a reused staging slot
 - ``xfer.h2d_unstaged``                    H2D that fell back to a fresh
                                            defensive copy
@@ -126,12 +130,17 @@ Observability counters (docs/observability.md; complemented by
                                            the device) / integrations
                                            it handed to its ring
 - ``spectrometer.gulps`` /
-  ``spectrometer.long_gulps``              gulps a FusedBlock took
+  ``spectrometer.long_gulps`` /
+  ``spectrometer.word_gulps``              gulps a FusedBlock took
                                            through a chain with a
                                            transform in it / those
                                            whose transform ran as
                                            three levels of DFT matrices
-                                           (ops.fft.long_fft)
+                                           (ops.fft.long_fft) / those
+                                           whose program started from
+                                           the gulp's int16 words
+                                           (0, and there, where none
+                                           did)
 - ``ring.<name>.gulps``                    LOGICAL gulps committed
                                            through ring ``<name>``
                                            (both cores; a macro-gulp

@@ -72,7 +72,12 @@ planes first, 13 ms for 2.1 GB, in each of sixteen cuts a product
 product up at once affordable (PR 32).  A pair that crosses whole is
 joined first and crosses as the complex64 it stands for.  Host to
 device, complex data still goes as (re, im) float planes recombined
-under jit (ROADMAP D6).
+under jit (ROADMAP D6).  A ci8 gulp is no complex array to this
+engine: ``devrep.to_device_rep`` hands it over as the int16 words the
+host holds (one device) or as int8 (re, im) pairs (a mesh), and the
+words of a device ring come back as the int16 array they are, into
+the span seen as int16 words (:meth:`TransferEngine.host_fill`;
+docs/transfer.md, "Words").
 
 Tunables (environment):
 
@@ -195,7 +200,6 @@ def _piece_plan(arr):
 #: rows, ``(step, everything else)``: the same bytes in the same
 #: order, relaid on the device.
 _LANE = 128
-
 _cut_fn = None
 
 
@@ -1291,13 +1295,23 @@ class TransferEngine(object):
             except Exception:
                 pass               # optional fast-path hint only
 
-    def _future_for(self, arr, out_view=None):
+    def _future_for(self, arr, out_view=None, convert=_first):
         """TransferFuture for a jax array, every dtype as it is.  With
         ``out_view``, the host view it is bound for, a large array
         crosses in pieces (:class:`_PieceFuture`) where the view has
         the array's shape down to the axis that is cut.  The planes
         of a complex array (``devrep.ComplexPlanes``) are cut as they
-        are, and joined first where they cross whole."""
+        are, and joined first where they cross whole.  ``convert`` is
+        what a readback that crosses whole does with its host array."""
+        from .words import ComplexWords
+        if isinstance(arr, ComplexWords):
+            # bound for no ring span (:meth:`host_fill` hands those
+            # over as the words): the words cross, and are the int8
+            # (re, im) pairs on the host by a view
+            shape = arr.shape
+            return self._future_for(
+                arr.words,
+                convert=lambda host: host[0].view(np.int8).reshape(shape))
         faults.fire('xfer.d2h')
         import jax
         from .planes import ComplexPlanes
@@ -1341,7 +1355,7 @@ class TransferEngine(object):
         if planes:
             arr = arr.joined()
         self._start_readback((arr,))
-        return TransferFuture([arr], _first)
+        return TransferFuture([arr], convert)
 
     def to_host(self, arr):
         """array -> numpy; blocks until the value is ready (the D2H
@@ -1404,7 +1418,18 @@ class TransferEngine(object):
         representation of bifrost dtype ``dtype``) into ``out_view``,
         queued for the engine's completion threads.  Bounded like
         to_host_async; completed on the caller, before returning, when
-        the engine is disabled."""
+        the engine is disabled.  The words of a ci8 gulp
+        (``devrep.ComplexWords``) are the span's own bytes: they cross
+        as the int16 array they are, in pieces where it is large, into
+        the span seen as int16 words; a span that cannot be seen so (a
+        ring with ringlets) gets the pairs."""
+        from .words import ComplexWords, host_view
+        if isinstance(dev_arr, ComplexWords):
+            view = host_view(out_view)
+            if view is not None:
+                dev_arr, dtype, out_view = dev_arr.words, 'i16', view
+            else:
+                dev_arr = dev_arr.pairs()
         fill = HostFill(self._future_for(dev_arr, out_view), dtype,
                         out_view)
         if not async_enabled():

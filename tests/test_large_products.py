@@ -130,8 +130,9 @@ def test_correlate_block_integrates_in_place(chunked, monkeypatch):
     # planes; the first gulp of an integration is given nothing (the
     # last integration's planes are the ring's), and what every other
     # gulp was given is gone: it was donated
+    # (a ci8 gulp on one device reaches the program as its words)
     assert sorted(corr._fn) == sorted(
-        ((G, F, S, P, 2), 'int8', first) for first in (False, True))
+        ((G, F, S, P, 2), 'words', first) for first in (False, True))
     assert [before is None for before, _a in seen] == \
         ([True] + [False] * (GPI - 1)) * NINT
     for before, after in seen:

@@ -277,6 +277,7 @@ def test_chain_against_the_reference_over_sequences():
     assert acc._acc is None
     assert acc.impl_info == {
         'impl': 'long-spectrometer', 'accumulate': nint,
+        'input': 'words',       # the chain's program starts from them
         'fft': dict(F.fft_path((1, nchan, 2, nfine), [3]), nfft=[nfine])}
 
 

@@ -23,9 +23,9 @@ def test_fused_plan_builds_outside_on_data(monkeypatch):
     orig_build = FusedBlock._build_plan
     orig_on_data = FusedBlock.on_data
 
-    def spy_build(self, shape, dtype, donate=False):
+    def spy_build(self, shape, dtype, **kw):
         builds.append(state['in_on_data'])
-        return orig_build(self, shape, dtype, donate=donate)
+        return orig_build(self, shape, dtype, **kw)
 
     def spy_on_data(self, ispan, ospan):
         state['in_on_data'] = True

@@ -81,17 +81,21 @@ _HSR_GULP = (1, 64, 2, 1 << 20, 2)
 _HSR_PRODUCT = (1, 64, 4, 1 << 20)
 
 
-def test_long_transform_chain_of_a_gpuspec_hsr_gulp(one_chip):
+@pytest.mark.parametrize('form', ['pairs', 'words'])
+def test_long_transform_chain_of_a_gpuspec_hsr_gulp(one_chip, form):
     """FftStage -> DetectStage('stokes') as FusedBlock composes them,
     at the deployment's shape: the long spectrometer, three levels of
     matrix products in a loop over 64 chunks of one coarse channel,
     no FFT call, no complex type, no VMEM or layout refusal; beside
     the 1 GiB of Stokes it writes, the voltages' int8 planes (copied
     and relaid: 0.13 GB each) and a chunk's temporaries, not the 1 GiB
-    of a gulp's spectra."""
+    of a gulp's spectra.  From the gulp's int16 words on one axis,
+    as a gulp on one device is held (PR 34), the same, the planes
+    made by one pass of shifts and a chunk sliced where it lies:
+    less in temporaries than from the pairs."""
     import jax
     from bifrost_tpu.stages import (FftStage, DetectStage, walk_headers,
-                                    compose_stages)
+                                    compose_stages, from_words)
     hdr = {'_tensor': {'shape': [-1, 64, 2, 1 << 20], 'dtype': 'ci8',
                        'labels': ['time', 'freq', 'pol', 'fine_time'],
                        'scales': [[0, 1]] * 4, 'units': [None] * 4}}
@@ -101,14 +105,24 @@ def test_long_transform_chain_of_a_gpuspec_hsr_gulp(one_chip):
     assert info == {'impl': 'long-spectrometer',
                     'fft': {'path': 'long', 'factors': [128, 64, 128],
                             'precision': 'high', 'nfft': [1 << 20]}}
-    comp = jax.jit(fn).trace(jax.ShapeDtypeStruct(
-        _HSR_GULP, np.int8, sharding=one_chip)).lower().compile()
+    if form == 'words':
+        fn, arg = from_words(fn, _HSR_GULP), jax.ShapeDtypeStruct(
+            (128 << 20,), np.int16, sharding=one_chip)
+    else:
+        arg = jax.ShapeDtypeStruct(_HSR_GULP, np.int8, sharding=one_chip)
+    comp = jax.jit(fn).trace(arg).lower().compile()
     text, mem = comp.as_text(), comp.memory_analysis()
+    if form == 'words':
+        # the words land as the host holds them
+        assert 's16[134217728]{0:T(1024)(128)(2,1)} parameter(0)' in text
     assert ' fft(' not in text and 'c64' not in text
     assert text.count('convolution(') >= 12     # four products a level
     assert re.search(r'while\(', text)          # the loop over chunks
     assert mem.output_size_in_bytes == int(np.prod(_HSR_PRODUCT)) * 4
-    assert mem.temp_size_in_bytes <= 3 << 28     # 0.67 GB as compiled
+    # 0.67 GB as compiled from the pairs; from the words 0.40: the
+    # planes are made on one axis and a chunk is sliced where it lies
+    assert mem.temp_size_in_bytes <= (7 << 26 if form == 'words'
+                                      else 3 << 28)
 
 
 def test_in_place_sum_of_a_gpuspec_hsr_product(one_chip):
@@ -123,3 +137,123 @@ def test_in_place_sum_of_a_gpuspec_hsr_product(one_chip):
     mem = comp.memory_analysis()
     assert mem.alias_size_in_bytes == mem.output_size_in_bytes == 1 << 30
     assert mem.temp_size_in_bytes == 0
+
+
+# ---------------------------------------------------------------------------
+# a ci8 gulp as int16 words: what stands between the entry parameter and
+# the spectrometer's kernel, and what the correlator's program holds
+# ---------------------------------------------------------------------------
+
+#: the gpuspec cells' gulp, (time, pol, fine_time), and the xcorr cell's,
+#: (time, freq, station, pol): complex ci8 samples
+_GPUSPEC_GULP = (16384, 2, 4096)
+_XCORR_GULP = (512, 1024, 256, 2)
+
+
+def _entry(text):
+    """``{name: (opcode, operand names)}`` of the entry computation."""
+    ops = {}
+    for line in text[text.index('ENTRY'):].splitlines():
+        m = re.match(r'\s*(?:ROOT )?%([\w.\-]+) = .*?\s([\w\-]+)\((.*)',
+                     line)
+        if m:
+            ops[m.group(1)] = (m.group(2),
+                               re.findall(r'%([\w.\-]+)', m.group(3)))
+    return ops
+
+
+def _in_front_of_the_kernel(text):
+    """Opcodes from the Mosaic kernel's first operand back to the
+    entry parameter (bitcasts move nothing and are left out)."""
+    ops = _entry(text)
+    kernel = [name for name, (op, args) in ops.items()
+              if op == 'custom-call' and args and
+              'tpu_custom_call' in text[text.index('%' + name + ' = '):]
+              .split('\n', 1)[0]]
+    assert len(kernel) == 1, kernel
+    chain, at = [], ops[kernel[0]][1][0]
+    while ops[at][0] != 'parameter':
+        if ops[at][0] != 'bitcast':
+            chain.append(ops[at][0])
+        at = ops[at][1][0]
+    return chain
+
+
+@pytest.mark.parametrize('form', ['words', 'rows', 'pairs'])
+def test_what_stands_in_front_of_the_spectrometer_kernel(one_chip, form):
+    """The gpuspec chain's kernel at the cell's full gulp and its own
+    configuration there (tile 16, three bf16 passes, the transpose in
+    the epilogue).  From the gulp's words on one axis, as a ring holds
+    them, ONE pass folds them to the kernel's rows.  From the rows
+    themselves the ``tpu_custom_call``'s first operand IS the entry
+    parameter.  From int8 (re, im) pairs, the control and what a
+    mesh-scoped gulp still pays, the device interleaves them again
+    and restores the host's order of axes: four passes over the
+    gulp."""
+    import jax
+    from bifrost_tpu.ops import spectrometer as spec
+    ntime, _npol, nfft = _GPUSPEC_GULP
+    arg = jax.ShapeDtypeStruct(
+        *{'words': ((2 * ntime * nfft,), np.int16),
+          'rows': ((2 * ntime, nfft), np.int16),
+          'pairs': (_GPUSPEC_GULP + (2,), np.int8)}[form],
+        sharding=one_chip)
+    comp = jax.jit(lambda v: spec.fused_spectrometer(
+        v, nfft=nfft, rfactor=4, time_tile=16, precision='high',
+        transpose='epilogue')).trace(arg).lower().compile()
+    text = comp.as_text()
+    front = _in_front_of_the_kernel(text)
+    if form == 'words':
+        assert front == ['reshape']
+        # one axis lands as the host holds it: no tiles of rows to make
+        assert 's16[134217728]{0:T(1024)(128)(2,1)} parameter(0)' in text
+        assert 'bitcast-convert' not in text
+    elif form == 'rows':
+        assert front == []
+        assert 's16[32768,4096]{1,0:T(8,128)(2,1)} parameter(0)' in text
+    else:
+        assert front == ['reshape', 'copy', 'bitcast-convert', 'fusion']
+        # (re, im) is far from minor-most in what the runtime lands
+        assert 's8[16384,2,4096,2]{2,0,3,1:T(8,128)(4,1)} parameter(0)' \
+            in text
+    assert comp.memory_analysis().output_size_in_bytes == ntime * 4096 * 4
+
+
+def test_correlator_gulp_program_from_words(one_chip):
+    """The correlator's in-place program of a gulp (every gulp but the
+    first of an integration) at the xcorr cell's shape, from the
+    gulp's words on one axis: one pass folds them to (time, freq,
+    station x pol), the engine's own order, and the planes of a chunk
+    of channels are two shifts of its slice: temporaries larger than
+    from the pairs by that one folded gulp, the donated accumulator
+    planes still the output."""
+    import jax
+    from bifrost_tpu.blocks.correlate import CorrelateBlock
+    from bifrost_tpu.ops.linalg import XEngine
+    blk = CorrelateBlock.__new__(CorrelateBlock)     # no pipeline here
+    blk.engine = XEngine(accuracy='f32', impl=None)
+    nchan = _XCORR_GULP[1]
+    acc = jax.ShapeDtypeStruct((1, nchan, 256, 2, 256, 2), np.float32,
+                               sharding=one_chip)
+    mems = {}
+    for form, arg in (
+            ('pairs', (_XCORR_GULP + (2,), np.int8)),
+            ('words', ((int(np.prod(_XCORR_GULP)),), np.int16))):
+        fn = blk._build_in_place(_XCORR_GULP + (2,), True, False,
+                                 form == 'words')
+        comp = fn.trace(jax.ShapeDtypeStruct(*arg, sharding=one_chip),
+                        acc, acc).lower().compile()
+        text, mems[form] = comp.as_text(), comp.memory_analysis()
+        if form == 'words':
+            ops = _entry(text)
+            loop = [args for op, args in ops.values() if op == 'while']
+            assert len(loop) == 1
+            assert {ops[a][0] for a in ops[loop[0][0]][1]} <= \
+                {'parameter', 'reshape', 'constant', 'copy'}
+            assert 's16[268435456]{0:T(1024)(128)(2,1)} parameter(0)' \
+                in text
+    gulp = int(np.prod(_XCORR_GULP)) * 2
+    assert mems['words'].temp_size_in_bytes <= \
+        mems['pairs'].temp_size_in_bytes + gulp
+    for mem in mems.values():
+        assert mem.alias_size_in_bytes == 2 * int(np.prod(acc.shape)) * 4

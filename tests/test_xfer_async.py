@@ -796,9 +796,10 @@ def test_large_product_crosses_in_pieces(dtype, monkeypatch):
     assert np.array_equal(_words(first), _words(data[:8]))
     assert np.array_equal(_words(got), _words(data[18:22]))
     # 8 frames of 128 (f32), 64 (ci8), 256 (cf32) or 512 (cf64) bytes in
-    # pieces of at most 128: one frame a piece, or two
+    # pieces of at most 128: one frame a piece, or two (the ci8 gulp
+    # crosses as its int16 words, one row of 256: 64 words a piece)
     assert all(isinstance(f.future, xfer._PieceFuture) for f in fills)
-    assert fills[0].future._step == (2 if dtype == 'ci8' else 1)
+    assert fills[0].future._step == (64 if dtype == 'ci8' else 1)
     assert counters.get('xfer.d2h_piece_bytes') == \
         counters.get('xfer.d2h_bytes') == 3 * fills[0].nbytes
     # the complex ones as real pairs; the counter is there for a
@@ -1302,19 +1303,26 @@ def test_fused_chain_donation_bitexact_and_reported():
     assert np.array_equal(out_plain, out_donate)
 
 
-def test_donation_roundtrip_ci8_planes():
-    """ci8 device-rep gulps (int8 re/im planes) survive a donating
-    identity-ish computation bit-exactly."""
-    import jax.numpy as jnp
-    from bifrost_tpu.devrep import to_device_rep, from_device_rep
+@pytest.mark.parametrize('form', ['pairs', 'words'])
+def test_donation_roundtrip_ci8_planes(form):
+    """ci8 device-rep gulps (int8 re/im pairs, made from the words a
+    gulp on one device is held as, or the int16 words themselves)
+    survive a donating identity-ish computation bit-exactly."""
+    from bifrost_tpu.devrep import (to_device_rep, from_device_rep,
+                                    ComplexWords)
     from bifrost_tpu.ops.common import donating_jit
     raw = _make_raw(nt=16, nf=32, seed=9)
-    dev = to_device_rep(raw, 'ci8')
-    ref = np.asarray(dev).copy()
-    fn = donating_jit(lambda x: (x + jnp.int8(1)) - jnp.int8(1),
-                      donate_argnums=(0,))
+    chunk = to_device_rep(raw, 'ci8')
+    assert isinstance(chunk, ComplexWords)
+    ref = np.asarray(chunk).copy()
+    dev = chunk.pairs() if form == 'pairs' else chunk.words
+    one = dev.dtype.type(1)
+    fn = donating_jit(lambda x: (x + one) - one, donate_argnums=(0,))
     out = fn(dev)
     assert dev.is_deleted()             # donated input is consumed
+    assert chunk.is_deleted() == (form == 'words')
+    if form == 'words':
+        out = ComplexWords(out, raw.shape)
     assert np.array_equal(np.asarray(out), ref)
     back = np.zeros_like(raw)
     from_device_rep(out, 'ci8', back)
