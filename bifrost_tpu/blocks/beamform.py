@@ -21,7 +21,11 @@ __all__ = ['BeamformBlock', 'beamform']
 
 class BeamformBlock(_StageBlock):
     """Beamform a ['time', 'freq', 'station'[, 'pol']] voltage stream
-    against a fixed weight set.  ``accuracy`` declares the class lossy
+    against a fixed weight set: one for every channel, or with
+    ``(F, P, B, S)`` weights a set per channel (stages.BeamformStage).
+    The output is the complex beams, so at a deployment's shape, where
+    they would not fit the chip, the fused chain is the form to use
+    (stages.match_beamformer).  ``accuracy`` declares the class lossy
     candidates must stay inside to race ('f32' | 'bf16' | 'int8' —
     ops.beamform docstring); ``impl`` / ``BF_BEAM_IMPL`` force one."""
 

@@ -166,6 +166,9 @@ class FusedBlock(TransformBlock):
         self._published_impl = None
         self._published_key = None
         self._donate_on = None
+        from ..stages import BeamformStage
+        self._beam_stage = next((st for st in self.stages
+                                 if isinstance(st, BeamformStage)), None)
         # ring-resident sharding advertisement: under a mesh this block
         # commits output spans sharded over the OUTPUT frame axis; a
         # stale input descriptor must never survive a layout change
@@ -283,16 +286,27 @@ class FusedBlock(TransformBlock):
             # the stage pattern + accuracy gate admit
             composed, info = compose_stages(
                 self.stages, self._headers, shape, dtype)
+            bound = getattr(composed, 'bound', None)
             if words:
-                composed = from_words(composed, shape)
                 info = dict(info, input='words')
+            if bound is not None:
+                # operands that live on the device (a beamformer's
+                # weights) are arguments of the program: closed over,
+                # they would be folded into it as constants
+                composed, operands = bound()
+            elif words:
+                composed = from_words(composed, shape)
             if donate:
                 # the donated gulp's HBM buffer is reusable in place
                 # for any matching intermediate of the chain
-                self._set_impl(dict(info, donate_argnums=[0]))
-                return donating_jit(composed, donate_argnums=(0,)), None
+                info = dict(info, donate_argnums=[0])
+                plan = donating_jit(composed, donate_argnums=(0,))
+            else:
+                plan = jax.jit(composed)
             self._set_impl(info)
-            return jax.jit(composed), None
+            if bound is not None:
+                return (lambda x, _plan=plan: _plan(x, *operands)), None
+            return plan, None
         composed, _ = compose_stages(self.stages, self._headers,
                                      shape, dtype, substitute=False)
         # Scale the whole fused chain over the scope's mesh: shard the
@@ -632,6 +646,31 @@ class FusedBlock(TransformBlock):
         if fft is not None and fft.get('path') == 'long':
             counters.inc('spectrometer.long_gulps', ngulps)
 
+    def _count_beamformed(self, ngulps, nframe):
+        """``beamform.gulps``: gulps that went through a chain with a
+        beamformer in it; ``beamform.fused_gulps``: of them, through
+        the one kernel (stages.match_beamformer: no beam voltage in
+        HBM); ``beamform.word_gulps``: of them, those whose program
+        started from the gulp's int16 words; ``beamform.int8_ops``:
+        8 x beams x samples of their ``nframe`` frames, from the
+        shapes whatever implements them.  All counted at 0 too, so
+        that a reader finds the counters."""
+        stage = self._beam_stage
+        if stage is None:
+            return
+        info = self.impl_info or {}
+        from ..telemetry import counters
+        shape = self._headers[0]['_tensor']['shape']
+        counters.inc('beamform.gulps', ngulps)
+        counters.inc('beamform.fused_gulps', ngulps if info.get('impl')
+                     == 'pallas-beamform-detect' else 0)
+        counters.inc('beamform.word_gulps',
+                     ngulps if info.get('input') == 'words' else 0)
+        counters.inc('beamform.int8_ops', int(nframe) *
+                     stage.engine.ops_per_frame(
+                         shape[1], stage.npol if stage.mode == 'perpol'
+                         else 1))
+
     def on_data(self, ispan, ospan):
         if self._gulp_batch_active > 1 and self._macro_gulp_in:
             x = self._take_donatable(ispan, allow_parts=True)
@@ -644,8 +683,9 @@ class FusedBlock(TransformBlock):
             ospan.set(self._execute_macro(parts, donate,
                                           self._macro_gulp_in),
                       owned=True)
-            self._count_transformed(
-                max(1, -(-ispan.nframe // self._macro_gulp_in)))
+            ngulps = max(1, -(-ispan.nframe // self._macro_gulp_in))
+            self._count_transformed(ngulps)
+            self._count_beamformed(ngulps, ispan.nframe)
             return
         # a ci8 gulp on one device: the plan starts from its words
         words = self.mesh is None
@@ -657,6 +697,7 @@ class FusedBlock(TransformBlock):
             ospan.set(self._execute_plan(ispan.data if x is None else x),
                       owned=True)
         self._count_transformed(1)
+        self._count_beamformed(1, ispan.nframe)
 
 
 def fused(iring, stages, *args, **kwargs):

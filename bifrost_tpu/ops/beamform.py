@@ -4,7 +4,11 @@ papers: "The Tensor-Core Beamformer" arXiv:2505.03269 for the quantized
 fused kernel shape, "GPU-Powered Coherent Beamforming" arXiv:1412.4907
 for the workload geometry).
 
-The hot product is y[t, f, p, b] = sum_s w[p, b, s] * x[t, f, p, s]:
+The hot product is y[t, f, p, b] = sum_s w[p, b, s] * x[t, f, p, s],
+or, for a tied-array beamformer whose beams are delays and so a phase
+per channel, sum_s w[f, p, b, s] * x[t, f, p, s] (weights
+``(F, P, B, S)``: upstream hands bfLinAlgMatMul a (chan, beam, input)
+batch):
 a batched GEMM whose voltage operand is, in a capture pipeline, ci8
 ring data — int8 (re, im) planes that the MXU multiplies at ~7x the
 f32 rate on the bench host (docs/perf.md ceilings table) and more on
@@ -33,6 +37,19 @@ XLA complex64 baseline at the actual shape before any timing:
                      planar_bf16 math with the pallas kernel's VMEM
                      locality, accepting int8 OR float voltage planes;
                      TPU-only in races, LOSSY like planar_bf16
+
+The four einsum candidates take the frequency axis of per-channel
+weights as a batch axis of the same contraction.  The two Pallas
+complex-beam kernels hold one weight set in VMEM for every channel
+and all T frames of a channel: they are not raced for per-channel
+weights and say so (``mprobe.refused``); what runs such weights on
+the chip is the fused chain below, not a complex-beam candidate
+(ROADMAP D4).
+
+Gates and probes run on a TILE OF TIME (:func:`probe_nframe`): the
+candidates' cost is linear in the frames, and at a deployment's shape
+(16384 frames x 64 channels x 2 pol x 864 beams) the baseline's
+complex64 output would be 14.5 GB, which no chip holds.
 
 The ci8 ring's device representation (int8 planes with a trailing
 (re, im) axis) feeds the int8 candidates DIRECTLY — unpack is fused
@@ -69,7 +86,12 @@ from .linalg import (_force_env, _probe_wanted, _mm_hilo, _mm_bf16,
 
 __all__ = ['Beamformer', 'BEAM_CLASSES', 'beam_class_rtol',
            'quantize_weights', 'fused_mode', 'fused_usable',
-           'fused_detect']
+           'fused_detect', 'fused_operands', 'probe_nframe',
+           'GATE_BYTES']
+
+#: the most a gate or a race lets one candidate's output take: the
+#: candidates are probed on as many frames as stay under it
+GATE_BYTES = 256 << 20
 
 #: accuracy class -> gate rtol vs the XLA complex64 baseline.  'f32'
 #: is the LinAlg production bound; 'bf16' admits one-pass bf16 input
@@ -88,6 +110,12 @@ _INT_IMPLS = frozenset(['int8_wide', 'pallas'])
 
 _IMPL_NAMES = ('xla', 'planar', 'planar_bf16', 'pallas_bf16',
                'int8_wide', 'pallas')
+
+_PER_CHANNEL = ('the kernel holds one weight set for every channel '
+                'and all frames of a channel in VMEM; per-channel '
+                'weights run through xla, planar, planar_bf16, '
+                'int8_wide, or the fused chain '
+                '(stages.match_beamformer)')
 
 
 def beam_class_rtol(accuracy):
@@ -113,24 +141,33 @@ def quantize_weights(wr, wi):
     return q(wr), q(wi), scale
 
 
+def probe_nframe(ntime, nfreq, npol, nbeam):
+    """Frames a gate or a race of the candidates runs on, of a gulp's
+    ``ntime``: as many as keep one candidate's complex64 output under
+    GATE_BYTES."""
+    return max(1, min(int(ntime),
+                      GATE_BYTES // (8 * nfreq * npol * nbeam)))
+
+
 def _wide_weight_block(wr8, wi8):
-    """(P, 2S, 2B) int8 block W2 with z @ W2 = [yr | yi] for
+    """([F,] P, 2S, 2B) int8 block W2 with z @ W2 = [yr | yi] for
     z = [re | im]: one widened int8 contraction carries the full
     complex product (the single-big-kernel trick of the widened gram,
     ops.linalg._aah_i8_gram, adapted to a@b)."""
-    # wr8/wi8: (P, B, S)
-    wrT = np.swapaxes(wr8, -1, -2)            # (P, S, B)
+    # wr8/wi8: ([F,] P, B, S)
+    wrT = np.swapaxes(wr8, -1, -2)            # ([F,] P, S, B)
     wiT = np.swapaxes(wi8, -1, -2)
     top = np.concatenate([wrT, wiT], axis=-1)             # re rows
     bot = np.concatenate([-wiT, wrT], axis=-1)            # im rows
-    return np.concatenate([top, bot], axis=-2)            # (P, 2S, 2B)
+    return np.concatenate([top, bot], axis=-2)     # ([F,] P, 2S, 2B)
 
 
 def _esum(a, b, acc):
-    """The canonical contraction: (T, F, P, S) x (P, B, S)
+    """The canonical contraction: (T, F, P, S) x ([F,] P, B, S)
     -> (T, F, P, B)."""
     import jax.numpy as jnp
-    return jnp.einsum('tfps,pbs->tfpb', a, b,
+    return jnp.einsum('tfps,fpbs->tfpb' if np.ndim(b) == 4
+                      else 'tfps,pbs->tfpb', a, b,
                       preferred_element_type=acc)
 
 
@@ -144,7 +181,10 @@ class Beamformer(object):
       a single 'beam' axis;
     - ``(B, S)`` with a distinct pol axis — the same weights applied
       per polarization; output keeps the pol axis;
-    - ``(P, B, S)`` — per-polarization weight sets.
+    - ``(P, B, S)`` — per-polarization weight sets;
+    - ``(F, P, B, S)`` — a set per frequency channel (a tied-array
+      beam is a delay: a phase that differs from channel to channel),
+      P one or the stream's.  One quantisation scale for all of it.
 
     ``accuracy``: 'f32' (default) | 'bf16' | 'int8' — the accuracy
     class candidates must stay inside to race (see module docstring).
@@ -164,9 +204,12 @@ class Beamformer(object):
         w = np.asarray(weights)
         if w.ndim == 2:
             w = w[None]                       # (1, B, S)
-        if w.ndim != 3:
-            raise ValueError('weights must be (B, N) or (P, B, S)')
-        self.npol_w, self.nbeam, self.nstand = w.shape
+        if w.ndim not in (3, 4):
+            raise ValueError('weights must be (B, N), (P, B, S) or '
+                             '(F, P, B, S), got %s' % (w.shape,))
+        #: channels the weights are for, None where one set serves all
+        self.nfreq_w = w.shape[0] if w.ndim == 4 else None
+        self.npol_w, self.nbeam, self.nstand = w.shape[-3:]
         self.wr = np.ascontiguousarray(w.real, np.float32)
         self.wi = np.ascontiguousarray(w.imag, np.float32)
         self.wr8, self.wi8, self.wscale = quantize_weights(self.wr,
@@ -177,6 +220,8 @@ class Beamformer(object):
         self.probe_ms = {}
         self._jits = {}
         self._consts = {}
+        self._operands = {}
+        self._fused_runs = {}
 
     # -- candidate implementations --------------------------------------
 
@@ -195,7 +240,7 @@ class Beamformer(object):
         if self.npol_w == npol:
             return self.wr, self.wi, self.wr8, self.wi8
         if self.npol_w == 1:
-            rep = lambda m: np.repeat(m, npol, axis=0)
+            rep = lambda m: np.repeat(m, npol, axis=-3)
             return (rep(self.wr), rep(self.wi), rep(self.wr8),
                     rep(self.wi8))
         raise ValueError('weights have %d pol sets but voltages %d'
@@ -267,6 +312,7 @@ class Beamformer(object):
     def _impl_pallas(self, npol):
         import jax.numpy as jnp
         from . import pallas_kernels as pk
+        self._one_set('pallas')
         _, _, wr8, wi8 = self._pol_weights(npol)
         wr8j = self._const('wr8%d' % npol, lambda: wr8)
         wi8j = self._const('wi8%d' % npol, lambda: wi8)
@@ -288,6 +334,7 @@ class Beamformer(object):
         f32 weight planes, voltages cast to bf16 in VMEM."""
         import jax.numpy as jnp
         from . import pallas_kernels as pk
+        self._one_set('pallas_bf16')
         wr, wi, _, _ = self._pol_weights(npol)
         wrj = self._const('wr%d' % npol, lambda: wr)
         wij = self._const('wi%d' % npol, lambda: wi)
@@ -301,17 +348,27 @@ class Beamformer(object):
             return jnp.stack(outs, axis=2).astype(jnp.complex64)
         return fn
 
+    def _one_set(self, name):
+        """The Pallas complex-beam kernels hold ONE weight set in VMEM
+        for every channel: refuse per-channel weights by name."""
+        if self.nfreq_w is not None:
+            raise ValueError(
+                'beamform candidate %r takes one weight set for every '
+                'channel, not %d channels\' own (%s)'
+                % (name, self.nfreq_w, _PER_CHANNEL))
+
     @staticmethod
     def int8_planes(re, im, w2, nbeam):
         """EXACT integer core of the widened-int8 candidate: int8
-        voltage planes (T, F, P, S) against the (P, 2S, 2B) widened
+        voltage planes (T, F, P, S) against the ([F,] P, 2S, 2B) widened
         weight block -> (yr, yi) int32 planes (T, F, P, B).  Pure
         int32 accumulation — bit-identical to the numpy int64 oracle
         (tests/test_beamform.py asserts this); the caller applies the
         dequantization scale."""
         import jax.numpy as jnp
         z = jnp.concatenate([re, im], axis=-1)        # (T, F, P, 2S)
-        y = jnp.einsum('tfpz,pzc->tfpc', z, w2,
+        y = jnp.einsum('tfpz,fpzc->tfpc' if np.ndim(w2) == 4
+                       else 'tfpz,pzc->tfpc', z, w2,
                        preferred_element_type=jnp.int32)
         return y[..., :nbeam], y[..., nbeam:]
 
@@ -347,14 +404,23 @@ class Beamformer(object):
         the race outright (it could only mislead the gate run)."""
         rtol = beam_class_rtol(self.accuracy)
         names = ['xla', 'planar']
+        pallas = []
         if rtol >= BEAM_CLASSES['bf16']:
             names.append('planar_bf16')
-            if self._pallas_raceable():
-                names.append('pallas_bf16')
+            pallas.append('pallas_bf16')
         if int_input and rtol >= BEAM_CLASSES['int8']:
             names.append('int8_wide')
-            if self._pallas_raceable():
-                names.append('pallas')
+            pallas.append('pallas')
+        if pallas and self._pallas_raceable():
+            if self.nfreq_w is None:
+                names.extend(pallas)
+            else:
+                # not given the frequency axis (module docstring):
+                # they would have raced here, so it is said
+                from . import mprobe
+                for name in pallas:
+                    mprobe.refused('beamform', name,
+                                   NotImplementedError(_PER_CHANNEL))
         return names
 
     @staticmethod
@@ -384,9 +450,10 @@ class Beamformer(object):
 
     def _key(self, shape, dtype, int_input):
         rtol = beam_class_rtol(self.accuracy)
-        key = ('acc=%s w=(%d,%d,%d) v=%s %s'
-               % (self.accuracy, self.npol_w, self.nbeam, self.nstand,
-                  tuple(shape), dtype))
+        key = ('acc=%s w=(%s%d,%d,%d) v=%s %s'
+               % (self.accuracy, '' if self.nfreq_w is None
+                  else '%d,' % self.nfreq_w, self.npol_w, self.nbeam,
+                  self.nstand, tuple(shape), dtype))
         if rtol != BEAM_CLASSES[self.accuracy]:
             # an explicit BF_BEAM_GATE_RTOL is part of the
             # measurement's identity (LinAlg gate-key policy)
@@ -400,11 +467,14 @@ class Beamformer(object):
         from . import mprobe
         return mprobe.accuracy_gate(
             'beamform', {n: self._jit(n, npol) for n in names},
-            make_args, beam_class_rtol(self.accuracy), lossy=_LOSSY)
+            make_args, beam_class_rtol(self.accuracy), lossy=_LOSSY,
+            max_bytes=GATE_BYTES)
 
     def _select(self, shape, dtype, int_input, make_args):
         """Measured winner for voltage planes of this shape/dtype —
-        gate first, race the survivors, cache per the mprobe policy."""
+        gate first, race the survivors, cache per the mprobe policy.
+        ``make_args`` gives the planes the candidates are run on: a
+        tile of the shape's frames (:func:`probe_nframe`)."""
         npol = shape[2]
         key = self._key(shape, dtype, int_input)
         if self._force:
@@ -444,19 +514,20 @@ class Beamformer(object):
         import jax.numpy as jnp
         npol = npol or self.npol_w
         shape = (t, f, npol, self.nstand)
-        rng = np.random.RandomState(seed)
-        if int_input:
-            re = rng.randint(-64, 64, shape).astype(np.int8)
-            im = rng.randint(-64, 64, shape).astype(np.int8)
-            dtype = 'int8'
-        else:
-            re = rng.randn(*shape).astype(np.float32)
-            im = rng.randn(*shape).astype(np.float32)
-            dtype = 'float32'
-        if not _probe_wanted() and not self._force:
-            name = self._default(int_input)
+        dtype = 'int8' if int_input else 'float32'
+        if self._force or not _probe_wanted():
+            name = self._force or self._default(int_input)
             self.chosen[self._key(shape, dtype, int_input)] = name
             return name
+        # the winner of the gulp's shape, measured on a tile of it
+        tile = (probe_nframe(t, f, npol, self.nbeam),) + shape[1:]
+        rng = np.random.RandomState(seed)
+        if int_input:
+            re = rng.randint(-64, 64, tile).astype(np.int8)
+            im = rng.randint(-64, 64, tile).astype(np.int8)
+        else:
+            re = rng.randn(*tile).astype(np.float32)
+            im = rng.randn(*tile).astype(np.float32)
         rej = jnp.asarray(re)
         imj = jnp.asarray(im)
         return self._select(shape, dtype, int_input,
@@ -482,11 +553,13 @@ class Beamformer(object):
                     self.chosen[key] = name = cached[0]
                 else:
                     name = self._default(int_input)
+            elif _probe_wanted():
+                n = probe_nframe(shape[0], shape[1], shape[2],
+                                 self.nbeam)
+                name = self._select(shape, str(re.dtype), int_input,
+                                    lambda: (re[:n], im[:n]))
             else:
-                name = self._select(
-                    shape, str(re.dtype), int_input,
-                    lambda: (re, im)) if _probe_wanted() \
-                    else self._default(int_input)
+                name = self._default(int_input)
         if isinstance(re, jax.core.Tracer):
             return self._build(name, shape[2])(re, im)
         return self._jit(name, shape[2])(re, im)
@@ -499,62 +572,122 @@ class Beamformer(object):
 
 
 # ---------------------------------------------------------------------------
-# fused beamform -> Stokes detect -> integrate (the whole-chain kernel
-# substitution, stages.match_beamformer)
+# fused beamform -> detect -> integrate [-> requantise] (the whole-chain
+# kernel substitution, stages.match_beamformer)
 # ---------------------------------------------------------------------------
 
 def fused_mode():
     """BF_BEAM_FUSED: 'auto' (default — substitute the fused Pallas
-    kernel when the chain matches, the engine's accuracy class admits
-    int8, and the kernel compiles natively on this backend), 'force'
-    (substitute wherever it compiles, including interpret mode — test
-    hook), or 'off' (never substitute)."""
+    kernel when the chain matches and the engine's accuracy class
+    admits int8: compiled by Mosaic on a TPU, where a refusal is
+    reported and the stages run; interpreted elsewhere, which is what
+    tests and rehearsals run), 'force' (substitute whatever the
+    accuracy class, and raise on a refusal — test hook), or 'off'
+    (never substitute)."""
     v = os.environ.get('BF_BEAM_FUSED', 'auto').strip().lower()
     return v if v in ('auto', 'force', 'off') else 'auto'
 
 
-def fused_detect(engine, x, rfactor):
-    """The fused chain on a ci8 device-rep gulp ``x`` of shape
-    (T, F, S, 2, 2): beamform both pols with ``engine``'s quantized
-    weights, Stokes-detect, integrate ``rfactor`` frames — one Pallas
-    program, beam voltages never leaving VMEM.  Returns
-    (T // rfactor, F, 4, B) float32 ordered [I, Q, U, V]."""
+def fused_operands(engine, bf16=False):
+    """The fused kernel's weight operand ON THE DEVICE, made once an
+    engine and kept: ``(F or 1, 4 S, 4 Bp)``
+    (pallas_kernels.beam_wide_weights), int8 from the engine's
+    quantised planes, or bfloat16 from its float planes for the forced
+    one-pass candidate.  An ARGUMENT of the gulp's program, never a
+    constant folded into it: at a deployment's 58.7 MB a folded
+    constant is set-up time and program size (blocks/fused.py passes
+    it; a caller that closes over it gets the constant)."""
+    key = 'fz_bf16' if bf16 else 'fz_int8'
+    w = engine._operands.get(key)
+    if w is None:
+        import jax
+        import jax.numpy as jnp
+        from . import pallas_kernels as pk
+        wr, wi, wr8, wi8 = engine._pol_weights(2)
+        planes = (wr, wi) if bf16 else (wr8, wi8)
+        host = pk.beam_wide_weights(
+            *(p if p.ndim == 4 else p[None] for p in planes),
+            dtype=jnp.bfloat16 if bf16 else None)
+        with jax.ensure_compile_time_eval():
+            w = engine._operands[key] = jax.device_put(host)
+    return (w,)
+
+
+def fused_detect(engine, x, rfactor, stokes='stokes', scale=1.0,
+                 quantize=None, time_tile=None, bf16=False,
+                 operands=None, nfreq=None):
+    """The fused chain on a dual-pol ci8 gulp: beamform both pols with
+    ``engine``'s quantised weights (its own set for every channel, or
+    one for all), detect (``stokes``: 'stokes' or 'stokes_i'),
+    integrate ``rfactor`` frames, multiply by ``scale`` and, with
+    ``quantize`` = (lo, hi, dtype), round and clip — one Pallas
+    program, time in tiles, beam voltages never leaving VMEM
+    (pallas_kernels.beamform_detect).
+
+    ``x``: the gulp's device representation (T, F, S, 2, 2) int8, or
+    its int16 words on one axis with ``nfreq`` beside them
+    (devrep.ComplexWords.words: (freq, station, pol) are a frame's
+    words in the host's own order, so the kernel reads them as they
+    landed, folded to rows of frames).  ``operands``:
+    :func:`fused_operands`, where the caller passes them through its
+    own jit as arguments.  Returns (T // rfactor, F, 4 or 1, B)."""
+    import jax
     import jax.numpy as jnp
     from . import pallas_kernels as pk
-    _, _, wr8, wi8 = engine._pol_weights(2)
-    wxr = engine._const('fz_wxr', lambda: wr8[0])
-    wxi = engine._const('fz_wxi', lambda: wi8[0])
-    wyr = engine._const('fz_wyr', lambda: wr8[1])
-    wyi = engine._const('fz_wyi', lambda: wi8[1])
-    rex, imx = x[:, :, :, 0, 0], x[:, :, :, 0, 1]
-    rey, imy = x[:, :, :, 1, 0], x[:, :, :, 1, 1]
-    i, q, u, v = pk.beamform_detect_int8(
-        wxr, wxi, wyr, wyi, rex, imx, rey, imy,
-        engine.wscale, rfactor)
-    return jnp.stack([i, q, u, v], axis=2)
+    nstand = engine.nstand
+    if x.dtype == jnp.int16:
+        if not nfreq or x.size % (nfreq * nstand * 2):
+            raise ValueError('the words of a (T, %r, %d, 2) ci8 gulp '
+                             'wanted, got %s' % (nfreq, nstand, x.shape))
+        rows = x.reshape(-1, nfreq * nstand * 2)
+    else:
+        nfreq = x.shape[1]
+        rows = jax.lax.bitcast_convert_type(x, jnp.int16) \
+            .reshape(x.shape[0], nfreq * nstand * 2)
+    (w,) = fused_operands(engine, bf16) if operands is None else operands
+    wscale = 1.0 if bf16 else float(engine.wscale)
+    return pk.beamform_detect(
+        rows, w, nfreq, engine.nbeam, rfactor, stokes=stokes,
+        scale=wscale * wscale * float(scale), quantize=quantize,
+        time_tile=time_tile)
 
 
-#: (nbeam, nstand, t, f, rfactor) -> bool; the compile probe runs at
-#: the EXACT substitution shape (the spectrometer lesson: VMEM limits
-#: bind at the real tile, not a toy probe), memoized either way so a
-#: backend that persistently rejects the config is not re-probed per
-#: plan rebuild
+def how_key(how):
+    """fused_detect's keywords as a dictionary key."""
+    return tuple(sorted((k, str(v)) for k, v in how.items()))
+
+
+#: probe key -> bool; the compile probe runs at the EXACT substitution
+#: shape (the spectrometer lesson: VMEM limits bind at the real tile,
+#: not a toy probe), memoized either way so a backend that persistently
+#: rejects the config is not re-probed per plan rebuild
 _fused_probe = {}
 
 
-def fused_usable(engine, t, f, rfactor):
-    """True when the fused kernel compiles AND runs on this backend at
-    the exact shape match_beamformer would substitute.  A refusal is
-    reported through ``mprobe.refused`` under BF_BEAM_FUSED=auto and
-    RAISES under ``force``."""
-    key = (engine.nbeam, engine.nstand, t, f, rfactor)
+def fused_usable(engine, t, f, rfactor, **how):
+    """True when the fused kernel (``how``: fused_detect's keywords)
+    compiles on this backend at the exact shape match_beamformer would
+    substitute, from the gulp's words.  On a TPU the program is
+    compiled ahead of time by Mosaic and XLA and not run: nothing of
+    a gulp's size is made for the probe.  Off the TPU the kernel is
+    interpreted and there is nothing to refuse.  A refusal is reported
+    through ``mprobe.refused`` under BF_BEAM_FUSED=auto and RAISES
+    under ``force``."""
+    key = (engine.nfreq_w, engine.nbeam, engine.nstand, t, f, rfactor,
+           how_key(how))
     hit = _fused_probe.get(key)
     if hit is not None:
         return hit
     try:
+        import jax
         import jax.numpy as jnp
-        x = jnp.zeros((t, f, engine.nstand, 2, 2), jnp.int8)
-        np.asarray(fused_detect(engine, x, rfactor))
+        if jax.default_backend() == 'tpu':
+            ops = fused_operands(engine, how.get('bf16', False))
+            jax.jit(lambda w, *o: fused_detect(
+                engine, w, rfactor, nfreq=f, operands=o, **how)) \
+                .lower(jax.ShapeDtypeStruct(
+                    (t * f * engine.nstand * 2,), jnp.int16), *ops) \
+                .compile()
         _fused_probe[key] = True
     except Exception as e:
         if fused_mode() == 'force':

@@ -120,7 +120,8 @@ def _flip_spent(name, full_key, ms, noise):
     return False
 
 
-def accuracy_gate(family, fns, make_args, rtol, lossy=(), base='xla'):
+def accuracy_gate(family, fns, make_args, rtol, lossy=(), base='xla',
+                  max_bytes=None):
     """(keep, had_errors): the candidates of ``fns`` ({name: fn}) whose
     output at the actual shape stays within ``rtol`` (relative to the
     baseline's peak) of the ``base`` candidate's.  Runs once per
@@ -136,9 +137,22 @@ def accuracy_gate(family, fns, make_args, rtol, lossy=(), base='xla'):
     complex64 visibilities are 2 GB per candidate at the BASELINE
     shape, and holding all five at once exhausted the v5e's 16 GB —
     the last candidate was refused for HBM, not for anything it did
-    (measured on the chip, PR 21)."""
+    (measured on the chip, PR 21).  With ``max_bytes`` the baseline's
+    output is counted from its shape before anything runs, and a gate
+    that would make more is an error of the caller, which is to hand
+    the gate a tile of its work (ops.beamform.probe_nframe): the
+    beamformer's baseline at a deployment's gulp is 14.5 GB."""
+    import jax
     import jax.numpy as jnp
     args = make_args()
+    if max_bytes is not None and base in fns:
+        out = jax.eval_shape(fns[base], *args)
+        nbytes = int(out.size) * out.dtype.itemsize
+        if nbytes > max_bytes:
+            raise ValueError(
+                '%s: the gate\'s %r baseline would make %d bytes at '
+                'shape %s, over the %d a gate may; gate a tile of it'
+                % (family, base, nbytes, tuple(out.shape), max_bytes))
     errored = []
 
     def run(name):

@@ -17,7 +17,8 @@ from __future__ import annotations
 import os
 
 __all__ = ['available', 'stokes_detect', 'xcorr_herm', 'xcorr_cross',
-           'beamform_int8', 'beamform_bf16', 'beamform_detect_int8',
+           'beamform_int8', 'beamform_bf16', 'beamform_detect',
+           'beam_wide_weights', 'beam_time_tile',
            'ring_permute']
 
 _checked = None
@@ -351,83 +352,152 @@ def beamform_bf16(wr, wi, re, im, interpret=None):
     return _chan_major(yr), _chan_major(yi)
 
 
-def beamform_detect_int8(wxr, wxi, wyr, wyi, rex, imx, rey, imy,
-                         scale, rfactor, interpret=None):
-    """Fused int8 beamform -> Stokes detect -> time integrate, one
-    frequency channel per program.
+#: frames of one program of :func:`beamform_detect` on the chip: the
+#: int32 beam sums of a tile (four sections of 7 x 128 columns at 864
+#: beams) and their float32 squares stay inside the 16 MiB of scoped
+#: VMEM, and 512 / 16 = 32 output rows fill one tile of 8-bit words
+BEAM_TIME_TILE = 512
 
-    Per channel: both polarizations' beam voltages (8 int8 MXU dots,
-    int32 accumulation) are dequantized to f32 IN VMEM, the Stokes
-    products (I, Q, U, V) form on the VPU, and the R-frame time
-    integration reduces before anything returns to HBM — beam voltages
-    never round-trip HBM, which is the point of the fused variant
-    (the Tensor-Core Beamformer's beamform+detect pipeline,
-    arXiv:2505.03269).
 
-    wxr..wyi: (B, S) int8 weight planes for the X / Y polarizations;
-    rex..imy: (T, F, S) int8 per-pol voltage planes; ``scale`` the
-    weight dequantization factor (1/w_scale); ``rfactor`` R must
-    divide T.  Returns (I, Q, U, V): four (T//R, F, B) float32 arrays
-    (stacked into the pol axis by the caller).
+def beam_time_tile(ntime, rfactor, out_itemsize, target=None):
+    """Frames a program of :func:`beamform_detect` takes: the largest
+    count of whole output tiles (32 rows of 8-bit words, 8 of wider
+    ones, ``rfactor`` frames each) that divides ``ntime`` and stays
+    at or under ``target`` (BEAM_TIME_TILE), else all of them."""
+    target = BEAM_TIME_TILE if target is None else int(target)
+    unit = rfactor * (32 if out_itemsize == 1 else 8)
+    if ntime % unit:
+        return ntime
+    tile = max(unit, target - target % unit)
+    while ntime % tile:
+        tile -= unit
+    return tile
+
+
+def beam_wide_weights(wr, wi, dtype=None):
+    """The weight operand of :func:`beamform_detect` (numpy):
+    (F, 2, B, S) planes of dual-polarisation weights, F one (one set
+    for every channel) or the channels' own, as (F, 4 S, 4 Bp) with
+    Bp the beams padded to whole lanes of 128.  Rows follow the
+    kernel's operand z = [re | im], each half in the gulp's own
+    (station, pol) order; columns are the four sections xr, xi, yr,
+    yi.  A row of the other polarisation holds zeros: the gulp's
+    samples are multiplied as they lie, and the MXU does twice the
+    products that count, where taking the polarisations apart would
+    cost a pass of the lanes.  Integer planes keep their type (int8:
+    -wi must fit, so quantize_weights clips at 127); float planes
+    come as ``dtype``."""
+    import numpy as np
+    nf, npol, nbeam, nstand = wr.shape
+    if npol != 2:
+        raise ValueError('dual-polarisation weights wanted, got %d'
+                         % npol)
+    bp = -(-nbeam // 128) * 128
+    w = np.zeros((nf, 2, nstand, 2, 4, bp), dtype or wr.dtype)
+    for p in range(2):
+        r = np.swapaxes(wr[:, p], 1, 2)           # (F, S, B)
+        i = np.swapaxes(wi[:, p], 1, 2)
+        w[:, 0, :, p, 2 * p, :nbeam] = r
+        w[:, 1, :, p, 2 * p, :nbeam] = -i
+        w[:, 0, :, p, 2 * p + 1, :nbeam] = i
+        w[:, 1, :, p, 2 * p + 1, :nbeam] = r
+    return w.reshape(nf, 4 * nstand, 4 * bp)
+
+
+def beamform_detect(xw, w, nchan, nbeam, rfactor, stokes='stokes_i',
+                    scale=1.0, quantize=None, time_tile=None,
+                    interpret=None):
+    """Beamform, detect, integrate and (optionally) requantise a
+    dual-polarisation ci8 gulp in one kernel, a channel and a tile of
+    time a program: the beam voltages exist in VMEM alone.
+
+    xw: the gulp's int16 words (low byte re, high byte im) as rows of
+    frames, ``(T, nchan * S * 2)``: (freq, station, pol) along a row,
+    the host's own order.  w: :func:`beam_wide_weights`,
+    ``(nchan or 1, 4 S, 4 Bp)``, int8 (exact int32 sums on the MXU) or
+    bfloat16 (one pass, float32 sums: lossy by the weights'
+    rounding).  Per program: the words are split by sign-extending
+    shifts, z = [re | im] meets the channel's weights in four dots
+    (one a section), the sums are squared in float32, ``rfactor``
+    frames are added, the result is multiplied by ``scale`` and, with
+    ``quantize`` = (lo, hi, dtype), rounded and clipped.
+
+    ``stokes``: 'stokes_i' (one plane) or 'stokes' (I, Q, U, V).
+    Returns (T // rfactor, nchan, nst, nbeam), float32 or
+    ``quantize``'s dtype.
     """
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    T, F, S = rex.shape
-    B = wxr.shape[0]
+    T, row = xw.shape
+    if row % nchan or w.shape[1] != 2 * (row // nchan) or \
+            w.shape[0] not in (1, nchan):
+        raise ValueError('words %s and weights %s do not agree on %d '
+                         'channels' % (xw.shape, w.shape, nchan))
+    if stokes not in ('stokes_i', 'stokes'):
+        raise ValueError('stokes_i or stokes, got %r' % (stokes,))
     if T % rfactor:
         raise ValueError('rfactor %d does not divide T=%d'
                          % (rfactor, T))
-    Tout = T // rfactor
+    sp = row // nchan                           # words a (frame, channel)
+    bp = w.shape[2] // 4
+    nst = 1 if stokes == 'stokes_i' else 4
+    odt = jnp.float32 if quantize is None else jnp.dtype(quantize[2])
+    tt = beam_time_tile(T, rfactor, jnp.dtype(odt).itemsize, time_tile)
+    if T % tt or tt % rfactor:
+        raise ValueError('time tile %d does not fit T=%d, rfactor=%d'
+                         % (tt, T, rfactor))
+    rows = tt // rfactor
     interpret = _xcorr_interpret(interpret)
     scale = float(scale)
+    exact = jnp.issubdtype(w.dtype, jnp.integer)
+    acc = jnp.int32 if exact else jnp.float32
+    own = w.shape[0] == nchan
 
-    def kernel(wxr_ref, wxi_ref, wyr_ref, wyi_ref,
-               rex_ref, imx_ref, rey_ref, imy_ref,
-               oi_ref, oq_ref, ou_ref, ov_ref):
-        def beam(r_ref, i_ref, wr_ref, wi_ref):
-            r = r_ref[0]
-            i = i_ref[0]
-            wr_ = wr_ref[...]
-            wi_ = wi_ref[...]
-            br = (_dot_beam(r, wr_, jnp.int32) -
-                  _dot_beam(i, wi_, jnp.int32)).astype(jnp.float32)
-            bi = (_dot_beam(r, wi_, jnp.int32) +
-                  _dot_beam(i, wr_, jnp.int32)).astype(jnp.float32)
-            return br * scale, bi * scale
+    def kernel(x_ref, w_ref, o_ref):
+        v = x_ref[...].astype(jnp.int32)                  # (tt, sp)
+        z = jnp.concatenate([(v << 24) >> 24, v >> 8], axis=1)
+        z = z.astype(jnp.int8) if exact else \
+            z.astype(jnp.float32).astype(jnp.bfloat16)
 
-        bxr, bxi = beam(rex_ref, imx_ref, wxr_ref, wxi_ref)
-        byr, byi = beam(rey_ref, imy_ref, wyr_ref, wyi_ref)
-        xx = bxr * bxr + bxi * bxi
-        yy = byr * byr + byi * byi
-        # x * conj(y)
-        xy_r = bxr * byr + bxi * byi
-        xy_i = bxi * byr - bxr * byi
+        def section(j):
+            y = jax.lax.dot_general(
+                z, w_ref[0, :, j * bp:(j + 1) * bp],
+                (((1,), (0,)), ((), ())), preferred_element_type=acc)
+            return y.astype(jnp.float32)                  # (tt, bp)
 
-        def integ(v):
-            # (T, B) -> (T//R, R, B) sum over R: minor dim stays B, so
-            # the reshape is Mosaic-legal (leading-dim split only)
-            return v.reshape(Tout, rfactor, B).sum(axis=1)
+        if nst == 1:
+            planes = [sum(section(j) ** 2 for j in range(4))]
+        else:
+            xr, xi, yr, yi = (section(j) for j in range(4))
+            xx, yy = xr * xr + xi * xi, yr * yr + yi * yi
+            planes = [xx + yy, xx - yy,
+                      2.0 * (xr * yr + xi * yi),          # x conj(y)
+                      -2.0 * (xi * yr - xr * yi)]
+        for k, plane in enumerate(planes):
+            # (tt, bp) -> (rows, rfactor, bp): the lanes stay, so the
+            # reshape splits the leading dimension alone
+            out = plane.reshape(rows, rfactor, bp).sum(axis=1) * scale
+            if quantize is not None:
+                out = jnp.clip(jnp.round(out), quantize[0], quantize[1]) \
+                    .astype(jnp.int32)
+            o_ref[:, k * bp:(k + 1) * bp] = out.astype(odt)
 
-        oi_ref[0] = integ(xx + yy)
-        oq_ref[0] = integ(xx - yy)
-        ou_ref[0] = integ(2.0 * xy_r)
-        ov_ref[0] = integ(-2.0 * xy_i)
-
-    spec_w = pl.BlockSpec((B, S), lambda f: (0, 0))
-    spec_x = pl.BlockSpec((1, T, S), lambda f: (f, 0, 0))
-    spec_o = pl.BlockSpec((1, Tout, B), lambda f: (f, 0, 0))
-    stokes = pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
-        grid=(F,),
-        in_specs=[spec_w] * 4 + [spec_x] * 4,
-        out_specs=[spec_o] * 4,
-        out_shape=[jax.ShapeDtypeStruct((F, Tout, B), jnp.float32)] * 4,
+        grid=(nchan, T // tt),
+        in_specs=[pl.BlockSpec((tt, sp), lambda f, i: (i, f)),
+                  pl.BlockSpec((1,) + tuple(w.shape[1:]),
+                               (lambda f, i: (f, 0, 0)) if own else
+                               (lambda f, i: (0, 0, 0)))],
+        out_specs=pl.BlockSpec((rows, nst * bp), lambda f, i: (i, f)),
+        out_shape=jax.ShapeDtypeStruct(
+            (T // rfactor, nchan * nst * bp), odt),
         interpret=interpret,
-    )(wxr, wxi, wyr, wyi,
-      *(_chan_major(x) for x in (rex, imx, rey, imy)))
-    return tuple(_chan_major(v) for v in stokes)
+        name='beamform_detect',
+    )(xw, w)
+    return out.reshape(T // rfactor, nchan, nst, bp)[..., :nbeam]
 
 
 def fdmt_step(d1, d2, passthrough, rows_hi_max, sgn, T, interpret=False):

@@ -507,7 +507,11 @@ class BeamformStage(Stage):
     - ``(B, S)`` / ``(P, B, S)`` with a pol axis -> per-pol beams,
       output ``['time', 'freq', 'pol', 'beam']`` (the dual-pol form
       the fused beamform->Stokes-detect->integrate substitution
-      recognizes, :func:`match_beamformer`).
+      recognizes, :func:`match_beamformer`);
+    - ``(F, P, B, S)``: a weight set per channel (a tied-array beam is
+      a delay, so a phase per channel), P one or the stream's, F the
+      stream's channels: per-pol beams as above.  The three forms
+      above keep their meaning: one set for every channel.
 
     Time-concat equivariant (``batch_safe``): macro-gulp block mode
     and the mesh frame-local shard_map plan both apply unchanged.
@@ -533,9 +537,14 @@ class BeamformStage(Stage):
                             '%s' % itensor['dtype'])
         shape = itensor['shape']
         eng = self.engine
+        if eng.nfreq_w is not None and eng.nfreq_w != shape[1]:
+            raise ValueError(
+                'weights are for %d frequency channels but the stream '
+                'has %d' % (eng.nfreq_w, shape[1]))
         if labels[2:] == ['station', 'pol']:
             s, p = shape[2], shape[3]
-            if eng.npol_w == 1 and eng.nstand == s * p:
+            if eng.nfreq_w is None and eng.npol_w == 1 and \
+                    eng.nstand == s * p:
                 self.mode = 'fold'
             elif eng.nstand == s and eng.npol_w in (1, p):
                 self.mode = 'perpol'
@@ -986,25 +995,38 @@ class ThresholdStage(Stage):
 
 def match_beamformer(stages, headers, shape, dtype):
     """Recognize the quantized beamform-and-detect pattern —
-    BeamformStage (per-pol, dual pol) -> DetectStage('stokes', pol) ->
-    ReduceStage over the frame axis, on ci8 input — and return the
-    fused Pallas kernel (ops.pallas_kernels.beamform_detect_int8) as a
-    callable plan when the engine's accuracy class and the backend
-    admit it, else None.
+    BeamformStage (per-pol, dual pol; one weight set or a set per
+    channel) -> DetectStage('stokes' | 'stokes_i', pol) -> ReduceStage
+    over the frame axis [-> QuantizeStage to an 8-bit real type], on
+    ci8 input — and return the fused Pallas kernel
+    (ops.pallas_kernels.beamform_detect) as a callable plan when the
+    engine's accuracy class and the backend admit it, else None.
 
-    The fused kernel beamforms both polarizations (8 int8 MXU dots,
-    int32 accumulation), dequantizes, forms Stokes products and
-    integrates R frames all in VMEM — beam voltages never round-trip
-    HBM (the Tensor-Core Beamformer's fused pipeline, arXiv:2505.03269).
-    Substitution requires the 'int8' accuracy class (the kernel's
-    weights are quantized by construction) — see
-    ops.beamform.fused_mode for the BF_BEAM_FUSED override.
+    The fused kernel takes a channel and a tile of time a program:
+    the gulp's words are split in VMEM, both polarizations are
+    beamformed (int8 MXU dots against the channel's own weights, int32
+    accumulation), the sums are squared in float32, R frames are
+    integrated and, with the QuantizeStage, scaled, rounded and
+    clipped — beam voltages never round-trip HBM (the Tensor-Core
+    Beamformer's fused pipeline, arXiv:2505.03269), which at a
+    deployment's shape is the difference between 57 MB a gulp and
+    14.5 GB.  The plan starts from the gulp's int16 words
+    (``plan.words``) and takes the weights as an argument of the
+    program (``plan.bound``).  Substitution requires the 'int8'
+    accuracy class (the kernel's weights are quantized by
+    construction) — see ops.beamform.fused_mode for the BF_BEAM_FUSED
+    override; a forced ``pallas_bf16`` candidate (BF_BEAM_IMPL) runs
+    the same kernel with one bfloat16 pass of the float weights
+    (lossy: the control of an int8 deployment), any other forced
+    candidate runs unfused.
     """
-    if len(stages) != 3:
+    if len(stages) not in (3, 4):
         return None
-    b, d, r = stages
+    b, d, r = stages[:3]
+    q = stages[3] if len(stages) == 4 else None
     if not (isinstance(b, BeamformStage) and isinstance(d, DetectStage)
-            and isinstance(r, ReduceStage)):
+            and isinstance(r, ReduceStage)
+            and (q is None or isinstance(q, QuantizeStage))):
         return None
     if headers[0]['_tensor']['dtype'] != 'ci8':
         return None
@@ -1015,37 +1037,64 @@ def match_beamformer(stages, headers, shape, dtype):
         return None
     if getattr(b, 'mode', None) != 'perpol':
         return None
-    if d.mode != 'stokes' or d.axis_index != 2 or d.npol != 2:
+    if d.mode not in ('stokes', 'stokes_i') or d.axis_index != 2 \
+            or d.npol != 2:
         return None
     if r.op != 'sum' or r.axis != r.frame_axis or not r.factor:
         return None
     if ntime % r.factor:
         return None
+    quantize = None
+    if q is not None:
+        if q.dtype.kind not in ('i', 'u') or q.dtype.nbits != 8:
+            return None
+        from .ops.quantize import _clip_limits
+        quantize = _clip_limits(q.dtype) + (q.dtype.as_jax_dtype(),)
     from .ops import beamform as _beam
+    from .ops import pallas_kernels as _pk
     mode = _beam.fused_mode()
     if mode == 'off':
         return None
     eng = b.engine
-    if mode != 'force':
-        if _beam.beam_class_rtol(eng.accuracy) < \
-                _beam.BEAM_CLASSES['int8'] and \
-                eng._force != 'pallas':
-            return None
-        if not _beam.Beamformer._pallas_raceable():
-            return None
-    if not _beam.fused_usable(eng, ntime, nfreq, r.factor):
+    if eng._force not in (None, 'pallas', 'pallas_bf16'):
+        return None
+    bf16 = eng._force == 'pallas_bf16'
+    if mode != 'force' and eng._force is None and \
+            _beam.beam_class_rtol(eng.accuracy) < \
+            _beam.BEAM_CLASSES['int8']:
+        return None
+    how = dict(stokes=d.mode, quantize=quantize, bf16=bf16,
+               scale=1.0 if q is None else float(q.scale))
+    if not _beam.fused_usable(eng, ntime, nfreq, r.factor, **how):
         return None
     factor = r.factor
+    # one function an engine and form, so that a later sequence of
+    # the same shapes finds its program compiled
+    key = (nfreq, factor, _beam.how_key(how))
+    run = eng._fused_runs.get(key)
+    if run is None:
+        def run(x, *operands):
+            return _beam.fused_detect(eng, x, factor, nfreq=nfreq,
+                                      operands=operands or None, **how)
+        eng._fused_runs[key] = run
 
-    def fn(x):
-        return _beam.fused_detect(eng, x, factor)
-    return SpectrometerPlan(fn, {
+    def bound():
+        # (fn(x, *operands), operands): the weights as arguments of
+        # the caller's program; x the pairs or the gulp's int16 words
+        return run, _beam.fused_operands(eng, bf16)
+    return SpectrometerPlan(run, {
         'impl': 'pallas-beamform-detect',
+        'stokes': d.mode,
         'rfactor': factor,
+        'time_tile': _pk.beam_time_tile(
+            ntime, factor, 4 if quantize is None else 1),
         'nbeam': eng.nbeam,
+        'weights': 'one set' if eng.nfreq_w is None else 'per channel',
+        'dot': 'bf16' if bf16 else 'int8',
+        'quantize': None if q is None else str(q.dtype),
         'accuracy': eng.accuracy,
         'wscale': float(eng.wscale),
-    })
+    }, words=run, bound=bound)
 
 
 def walk_headers(stages, hdr):
@@ -1106,13 +1155,18 @@ class SpectrometerPlan(object):
     publish what actually ran (ProcLog ``<block>/impl``) instead of
     benchmarks re-deriving the decision (VERDICT r3 item 4)."""
 
-    def __init__(self, fn, info, words=None):
+    def __init__(self, fn, info, words=None, bound=None):
         self.fn = fn
         self.info = dict(info)
         #: the same plan as a function of the gulp's int16 words
         #: (devrep.ComplexWords.words), where the kernel can start
         #: from them (:func:`from_words`)
         self.words = words
+        #: ``bound() -> (fn(x, *operands), operands)`` where the
+        #: plan has operands that live on the device (a beamformer's
+        #: weights) and belong among the program's arguments, not
+        #: its constants (blocks/fused.py)
+        self.bound = bound
 
     def __call__(self, x):
         return self.fn(x)

@@ -141,6 +141,21 @@ Observability counters (docs/observability.md; complemented by
                                            the gulp's int16 words
                                            (0, and there, where none
                                            did)
+- ``beamform.gulps`` /
+  ``beamform.fused_gulps`` /
+  ``beamform.word_gulps`` /
+  ``beamform.int8_ops``                    gulps a FusedBlock took
+                                           through a chain with a
+                                           beamformer in it / of them,
+                                           through the one kernel, no
+                                           beam voltage in HBM
+                                           (stages.match_beamformer) /
+                                           of them, started from the
+                                           gulp's int16 words / 8 x
+                                           beams x samples of their
+                                           frames, from the shapes
+                                           (all at 0, and there, where
+                                           nothing of the kind ran)
 - ``ring.<name>.gulps``                    LOGICAL gulps committed
                                            through ring ``<name>``
                                            (both cores; a macro-gulp

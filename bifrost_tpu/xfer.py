@@ -177,18 +177,24 @@ def _combine(re, im):
 
 def _piece_plan(arr):
     """``(axis, step)``: ``arr`` (a jax array, or the planes of a
-    complex one) crosses in pieces of ``step`` indices
-    (the most that fit ``_D2H_PIECE_BYTES``, one at least; the last
-    piece may be shorter) along ``axis``, its first axis longer than
-    one, so that every piece is one stretch of the product's bytes.
-    None where it crosses whole: no larger than two pieces, or on
-    more than one device."""
+    complex one) crosses in pieces of ``step`` indices along ``axis``,
+    its first axis longer than one, so that every piece is one
+    stretch of the product's bytes: as few pieces as fit
+    ``_D2H_PIECE_BYTES`` each (one index at least), and those of one
+    length where the axis divides, so that one program cuts them all
+    (a 56.6 MB product of 1024 rows in pieces of the 296 that fit
+    left a fourth piece of 136 rows to a program of its own, a gulp:
+    four of 256 do not; PERF.md section 6, PR 35).  None where it
+    crosses whole: no larger than two pieces, or on more than one
+    device."""
     nbytes = int(arr.nbytes)
     if nbytes <= 2 * _D2H_PIECE_BYTES or \
             len(arr.sharding.device_set) != 1:
         return None
     axis = next(i for i, n in enumerate(arr.shape) if n > 1)
-    return axis, max(_D2H_PIECE_BYTES * arr.shape[axis] // nbytes, 1)
+    length = arr.shape[axis]
+    most = max(_D2H_PIECE_BYTES * length // nbytes, 1)
+    return axis, -(-length // -(-length // most))
 
 
 #: the runtime hands a device array to the host in the device's own
@@ -198,8 +204,18 @@ def _piece_plan(arr):
 #: a span is a gather at 2.5 GB/s where a row-major piece copies at
 #: 10 (my chip runs, PERF.md section 6, PR 28).  Such pieces are cut as
 #: rows, ``(step, everything else)``: the same bytes in the same
-#: order, relaid on the device.
+#: order, relaid on the device.  An axis of one before the last says
+#: nothing about order either: of a (1024, 64, 1, 864) u8 product the
+#: compiler lays pieces of 256 rows out with the FIRST axis along the
+#: lanes (``{0,3,2,1}``; pieces of 296 rows it happened to leave in the
+#: host's order), and the completion thread then spent 105 ms a product
+#: taking them apart where rows cost it 10 (PR 35).
 _LANE = 128
+
+
+def _as_rows(shape):
+    """Whether pieces of a product of ``shape`` are cut as rows."""
+    return shape[-1] < _LANE or (len(shape) > 2 and shape[-2] == 1)
 _cut_fn = None
 
 
@@ -666,7 +682,7 @@ class _PieceFuture(TransferFuture):
             step, count = (self._step, min(full, self._group)) if full \
                 else (rows - self._row, 1)
             pieces = _cut(arr, self._row, self._axis, step, count,
-                          self._shape[-1] < _LANE)
+                          _as_rows(self._shape))
             lead = (slice(None),) * self._axis
             self._ahead.append(
                 [(p, lead + (slice(self._row + j * step,

@@ -595,11 +595,11 @@ def b_beamform(seed):
         * eng.wscale
     p = (np.abs(bq) ** 2).reshape(T // rf, rf, F, B).sum(1)
     want_s = np.stack([2 * p, 0 * p, 2 * p, 0 * p])
-    w8 = [jnp.asarray(eng.wr8[0]), jnp.asarray(eng.wi8[0])] * 2
-    x8 = [rej[:, :, 0], imj[:, :, 0]] * 2
-    out['beamform_detect_int8 kernel'] = verdict(
-        lambda: jnp.stack(pk.beamform_detect_int8(
-            *(w8 + x8), eng.wscale, rf, interpret=False)),
+    from bifrost_tpu.ops.beamform import fused_detect
+    x5 = jnp.stack([jnp.stack([rej[:, :, 0], imj[:, :, 0]], -1)] * 2,
+                   axis=3)                       # (T, F, S, pol, re/im)
+    out['beamform_detect kernel'] = verdict(
+        lambda: jnp.moveaxis(fused_detect(eng, x5, rf), 2, 0),
         want_s, 1e-5, hard=True)
     winner = Beamformer(w, accuracy='int8').prewarm(T, F, npol=1,
                                                     seed=seed)

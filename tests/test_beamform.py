@@ -4,8 +4,10 @@ beamform->detect->integrate substitution in stages.py).
 
 Kernel parity runs in Pallas interpret mode on the CPU test backend.
 On the chip, chip_smoke.py phase B compiles every candidate at the
-BASELINE.json shape and holds it to the float64 oracle; no cell of the
-benchmark times the beamformer yet (ROADMAP R7).
+BASELINE.json shape and holds it to the float64 oracle, and the
+benchmark's cell ``beamform-tab-replay`` runs the fused chain with a
+weight set per channel at a deployment's shape (PR 35; its rehearsal
+size is the pipeline test's below).
 """
 
 import os
@@ -148,7 +150,7 @@ def test_pallas_beamform_bf16_within_class():
 
 
 def test_pallas_fused_detect_matches_quantized_oracle():
-    """beamform_detect_int8: dual-pol beamform -> Stokes -> R-frame
+    """beamform_detect: dual-pol beamform -> Stokes -> R-frame
     integrate in one program, vs the float64 oracle built from the
     QUANTIZED weights (the kernel's weights are int8 by construction)."""
     from bifrost_tpu.ops.beamform import fused_detect
@@ -461,3 +463,308 @@ def test_gemm_ops_accounting():
     eng = Beamformer(w, accuracy='int8')
     assert eng.ops_per_frame(nfreq=16) == 8 * 16 * 2 * 4 * 8
     assert eng.ops_per_frame(nfreq=16, npol=1) == 8 * 16 * 1 * 4 * 8
+
+
+# ---------------------------------------------------------------------------
+# a weight set per channel (a tied-array beam is a delay: PR 35)
+# ---------------------------------------------------------------------------
+
+def _phase_weights(F, P, B, S, seed=0):
+    """(w8r, w8i, w): int8 weights of random phase and modulus 127,
+    and the complex64 form a stage is handed, w8 / 127."""
+    rng = np.random.default_rng(seed)
+    phase = rng.random((F, P, B, S)) * 2 * np.pi
+    w8r = np.rint(127 * np.cos(phase)).astype(np.int8)
+    w8i = np.rint(127 * np.sin(phase)).astype(np.int8)
+    w = ((w8r + 1j * w8i) * np.float32(1 / 127.)).astype(np.complex64)
+    return w8r, w8i, w
+
+
+def _int_beams(x, w8r, w8i):
+    """int64 beam sums (re, im): x (T, F, S, P, 2) int8 against
+    ([F,] P, B, S) int8 weights -> (T, F, P, B)."""
+    xr, xi = x[..., 0].astype(np.int64), x[..., 1].astype(np.int64)
+    wr, wi = w8r.astype(np.int64), w8i.astype(np.int64)
+    sub = 'tfsp,fpbs->tfpb' if wr.ndim == 4 else 'tfsp,pbs->tfpb'
+    return (np.einsum(sub, xr, wr) - np.einsum(sub, xi, wi),
+            np.einsum(sub, xr, wi) + np.einsum(sub, xi, wr))
+
+
+def _stokes_i(x, w8r, w8i, R, scale):
+    """float64 Stokes I of the int64 beams, R frames summed, times
+    ``scale`` over 127^2: unrounded."""
+    br, bi = _int_beams(x, w8r, w8i)
+    p = (br.astype(np.float64) ** 2 + bi.astype(np.float64) ** 2) \
+        .sum(axis=2)
+    p = p.reshape(p.shape[0] // R, R, *p.shape[1:]).sum(axis=1)
+    return p * (scale / 127. ** 2)
+
+
+@pytest.mark.parametrize('name', ['xla', 'planar', 'planar_bf16',
+                                  'int8_wide'])
+def test_per_channel_weights_every_candidate_kept(name):
+    """(F, P, B, S) weights: the four einsum candidates take the
+    frequency axis as a batch axis and stay inside their class of the
+    int64 oracle; the widened int8 one is exact."""
+    T, F, P, S, B = 16, 3, 2, 8, 5
+    w8r, w8i, w = _phase_weights(F, P, B, S)
+    eng = Beamformer(w, accuracy='int8')
+    assert (eng.nfreq_w, eng.npol_w, eng.nbeam, eng.nstand) == (F, P, B, S)
+    x = np.random.default_rng(2).integers(
+        -64, 64, (T, F, S, P, 2), dtype=np.int8)
+    br, bi = _int_beams(x, w8r, w8i)
+    ref = (br + 1j * bi) / 127.
+    re = np.ascontiguousarray(x[..., 0].transpose(0, 1, 3, 2))
+    im = np.ascontiguousarray(x[..., 1].transpose(0, 1, 3, 2))
+    y = np.asarray(eng._jit(name, P)(re, im))
+    rel = np.max(np.abs(y - ref)) / np.max(np.abs(ref))
+    bound = {'xla': 1e-5, 'planar': 1e-3, 'planar_bf16': 8e-3,
+             'int8_wide': 1e-6}[name]
+    assert y.shape == (T, F, P, B) and rel <= bound, (name, rel)
+    # the frequency axis is part of a measurement's identity
+    assert eng._key(re.shape, 'int8', True) != \
+        Beamformer(w[0], accuracy='int8')._key(re.shape, 'int8', True)
+
+
+@pytest.mark.parametrize('name', ['pallas', 'pallas_bf16'])
+def test_per_channel_weights_refuse_the_pallas_candidates(name,
+                                                          monkeypatch):
+    """The complex-beam kernels hold one weight set for every channel:
+    forced, they refuse per-channel weights by name; where they would
+    have raced (a TPU), they are left out and the selection says so."""
+    from bifrost_tpu.ops import mprobe
+    _, _, w = _phase_weights(3, 2, 5, 8)
+    eng = Beamformer(w, accuracy='int8', impl=name)
+    re, im = _volt_planes(16, 3, 2, 8)
+    with pytest.raises(ValueError, match='one weight set'):
+        eng(re, im)
+    monkeypatch.setattr(Beamformer, '_pallas_raceable',
+                        staticmethod(lambda: True))
+    import warnings
+    with warnings.catch_warnings():
+        warnings.simplefilter('ignore', RuntimeWarning)   # said once
+        names = Beamformer(w, accuracy='int8')._candidates(True)
+    assert names == ['xla', 'planar', 'planar_bf16', 'int8_wide']
+    assert 'one weight set' in mprobe.refusals()['beamform/%s' % name]
+    # one set for every channel still races them
+    assert name in Beamformer(w[0], accuracy='int8')._candidates(True)
+
+
+@pytest.mark.parametrize('per_channel', [True, False],
+                         ids=['per_channel', 'one_set'])
+@pytest.mark.parametrize('tile', [None, 1024],
+                         ids=['default_two_tiles', 'untiled'])
+def test_fused_chain_against_the_oracle(tile, per_channel):
+    """The one kernel (stokes_i, sum 16, u8) against the int64/float64
+    oracle: within half a step and float32's rounding of the
+    unrounded reference, tiled and untiled alike, and from the gulp's
+    words bit-identical to the pairs."""
+    from bifrost_tpu.ops.beamform import fused_detect
+    from bifrost_tpu.ops.pallas_kernels import beam_time_tile
+    T, F, P, S, B, R = 1024, 3, 2, 8, 5, 16
+    w8r, w8i, w = _phase_weights(F, P, B, S, seed=4)
+    if not per_channel:
+        w8r, w8i, w = w8r[0], w8i[0], w[0]
+    eng = Beamformer(w, accuracy='int8')
+    x = np.random.default_rng(5).integers(
+        -64, 64, (T, F, S, P, 2), dtype=np.int8)
+    scale = 64. / (R * P * S * 2731.)
+    want = np.clip(_stokes_i(x, w8r, w8i, R, scale), 0, 255)
+    how = dict(stokes='stokes_i', scale=scale,
+               quantize=(0, 255, 'uint8'), time_tile=tile)
+    pairs = np.asarray(fused_detect(eng, x, R, **how))
+    words = np.asarray(fused_detect(
+        eng, x.view(np.int16).reshape(-1), R, nfreq=F, **how))
+    assert beam_time_tile(T, R, 1, tile) == (512 if tile is None
+                                             else tile)
+    assert pairs.dtype == np.uint8 and pairs.shape == (T // R, F, 1, B)
+    assert np.array_equal(words, pairs)
+    err = np.max(np.abs(pairs[:, :, 0].astype(np.float64) - want))
+    assert err <= 0.5 + 1e-3, err
+    assert 40 < want.mean() < 90          # the scale: a quarter of u8
+
+
+def test_weights_round_trip_through_the_engines_quantisation():
+    """int8 weights handed over as w8 / 127 come back from the
+    engine's quantisation (one scale: the largest modulus over 127)
+    bit for bit, and the scale is 1 / 127."""
+    w8r, w8i, w = _phase_weights(4, 2, 16, 8, seed=9)
+    eng = Beamformer(w, accuracy='int8')
+    assert np.array_equal(eng.wr8, w8r) and np.array_equal(eng.wi8, w8i)
+    assert eng.wscale == pytest.approx(1 / 127., rel=1e-6)
+    assert eng.ops_per_frame(nfreq=4) == 8 * 4 * 2 * 16 * 8
+
+
+def test_frequency_axis_that_does_not_match_is_refused():
+    """Weights for 4 channels on a stream of 6: refused in
+    transform_header, with both numbers."""
+    from bifrost_tpu.stages import BeamformStage
+    _, _, w = _phase_weights(4, 2, 5, 8)
+    st = BeamformStage(w, accuracy='int8')
+    with pytest.raises(ValueError, match=r'4 frequency channels.*has 6'):
+        st.transform_header(simple_header(
+            [-1, 6, 8, 2], 'ci8',
+            labels=['time', 'freq', 'station', 'pol']))
+    ohdr = st.transform_header(simple_header(
+        [-1, 4, 8, 2], 'ci8', labels=['time', 'freq', 'station', 'pol']))
+    assert ohdr['_tensor']['labels'] == ['time', 'freq', 'pol', 'beam']
+    assert ohdr['_tensor']['shape'] == [-1, 4, 2, 5]
+    with pytest.raises(ValueError):
+        Beamformer(np.zeros((2, 4, 2, 5, 8), np.complex64))
+
+
+def test_standalone_block_takes_per_channel_weights():
+    """bf.blocks.beamform with (F, P, B, S) weights gives the complex
+    beams, at a shape whose output fits."""
+    T, F, S, P, B = 16, 4, 8, 2, 4
+    w8r, w8i, w = _phase_weights(F, P, B, S, seed=3)
+    hdr = simple_header([-1, F, S, P], 'ci8',
+                        labels=['time', 'freq', 'station', 'pol'])
+    gulps = _ci8_gulps(T, F, S, P)
+    out, _ = _run_block_chain(gulps, hdr, w, T, name='BeamPerChan')
+    x = np.stack([gulps[0]['re'], gulps[0]['im']], axis=-1)
+    br, bi = _int_beams(x, w8r, w8i)
+    assert out.shape == (T, F, P, B) and out.dtype == np.complex64
+    np.testing.assert_allclose(out, (br + 1j * bi) / 127., rtol=1e-5)
+
+
+def _tab_config():
+    """The benchmark's configuration at its rehearsal size, and its
+    module (the plain reference: imports nothing of the program)."""
+    import importlib.util
+    import json
+    root = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), 'perfbench', 'configs')
+    with open(os.path.join(root, 'beamform_tab.json')) as f:
+        cfg = json.load(f)
+    cfg.update({k: v for k, v in cfg['rehearse'].items() if k != 'input'})
+    cfg['input'] = dict(cfg['input'], **cfg['rehearse']['input'])
+    spec = importlib.util.spec_from_file_location(
+        'beamform_tab_for_tests', os.path.join(root, 'beamform_tab.py'))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return cfg, mod
+
+
+def test_pipeline_at_rehearsal_size_over_two_sequences():
+    """host ring -> copy('tpu') -> fused [beamform, stokes_i, sum 16,
+    u8] -> copy('system') -> sink through the public API, at the
+    benchmark's rehearsal size (4 channels x 8 antennas x 16 beams,
+    1024 frames: two time tiles), two sequences of two gulps: every
+    product against the configuration's reference, the chain
+    substituted whole and started from words, the four counters, and
+    nothing compiled after the first gulp."""
+    from bifrost_tpu.telemetry import counters
+    cfg, mod = _tab_config()
+    T, (F, S, P), B = cfg['gulp_nframe'], cfg['input']['frame_shape'], \
+        cfg['nbeam']
+    seed = 2 ** 31 + 11
+    hdr = mod.header(cfg)
+    gulps = [g for s in (1, 2) for g in _ci8_gulps(T, F, S, P, n=2,
+                                                   seed=s, lim=64)]
+    compiles = []
+
+    class TwoSequences(NumpySourceBlock):
+        def __init__(self):
+            super(NumpySourceBlock, self).__init__(['first', 'second'],
+                                                   T, space='system')
+            self._header = hdr
+
+        def create_reader(self, name):
+            self._gulps = [g.copy() for g in
+                           (gulps[:2] if name == 'first' else gulps[2:])]
+            return super(TwoSequences, self).create_reader(name)
+
+    class Sink(GatherSink):
+        def on_data(self, ispan):
+            compiles.append(counters.snapshot().get('jit.compiles', 0))
+            return super(Sink, self).on_data(ispan)
+
+    counters.reset()
+    with bf.Pipeline() as p:
+        src = TwoSequences()
+        blk = mod.chain(bf, bf.blocks.copy(src, space='tpu'), cfg,
+                        seed=seed)
+        sink = Sink(bf.blocks.copy(blk, space='system'))
+        p.run()
+    out = sink.result()
+    nout = T // cfg['tscrunch']
+    assert out.shape == (4 * nout, F, 1, B) and out.dtype == np.uint8
+    idx = (seed, np.arange(nout), np.arange(B))
+    for k, gulp in enumerate(gulps):
+        want = mod.reference([gulp], idx, cfg)
+        name, err = mod.compare(mod.take(out[k * nout:(k + 1) * nout],
+                                         idx), want)
+        assert name == 'max_lsb_err' and err <= 0.5 + 1e-3, (k, err)
+    info = blk.impl_info
+    assert info['impl'] == 'pallas-beamform-detect'
+    assert info['input'] == 'words' and info['weights'] == 'per channel'
+    assert info['stokes'] == 'stokes_i' and info['quantize'] == 'u8'
+    assert info['dot'] == 'int8' and info['time_tile'] == 512
+    snap = counters.snapshot()
+    assert snap['beamform.gulps'] == snap['beamform.fused_gulps'] == \
+        snap['beamform.word_gulps'] == 4
+    assert snap['beamform.int8_ops'] == 4 * 8 * B * T * F * S * P
+    assert len(compiles) == 4 and len(set(compiles)) == 1, compiles
+
+
+def test_a_band_beamformed_in_shares_equals_the_whole():
+    """The share tied to the whole: an 8-channel band beamformed in
+    two shares of 4 channels by separate chains, each with its own
+    channels' weights, concatenated, equals the whole band's product
+    byte for byte (no exchange between workers exists, so none is
+    stood in for)."""
+    from bifrost_tpu.stages import (BeamformStage, DetectStage,
+                                    ReduceStage, QuantizeStage)
+    T, F, S, P, B, R = 512, 8, 8, 2, 6, 16
+    _, _, w = _phase_weights(F, P, B, S, seed=6)
+    scale = 64. / (R * P * S * 2731.)
+    gulps = _ci8_gulps(T, F, S, P, n=2, seed=8, lim=64)
+
+    def run(chans, name):
+        hdr = simple_header([-1, len(chans), S, P], 'ci8',
+                            labels=['time', 'freq', 'station', 'pol'])
+        part = [np.ascontiguousarray(g[:, chans]) for g in gulps]
+        chain = [BeamformStage(w[chans], accuracy='int8'),
+                 DetectStage('stokes_i'), ReduceStage('time', R),
+                 QuantizeStage('u8', scale)]
+        out, _ = _run_block_chain(part, hdr, None, T, fused_chain=chain,
+                                  name=name)
+        return out
+    whole = run(np.arange(F), 'BandWhole')
+    shares = [run(np.arange(4), 'BandLow'),
+              run(np.arange(4, 8), 'BandHigh')]
+    assert whole.shape == (2 * T // R, F, 1, B)
+    assert whole.dtype == np.uint8 and whole.std() > 1
+    assert np.array_equal(np.concatenate(shares, axis=1), whole)
+
+
+def test_gates_and_probes_run_in_tiles_of_time(monkeypatch):
+    """Neither prewarm nor the gate makes a full gulp's beam voltages:
+    with the most a gate may make set to 4 KiB, the untiled gate at
+    (64, 4, 2, 8) counts the baseline's 16 KiB and refuses, and
+    prewarm gates and races a tile of 16 frames and caches the winner
+    under the gulp's own shape."""
+    import jax.numpy as jnp
+    from bifrost_tpu.ops import beamform as beam
+    monkeypatch.setattr(beam, 'GATE_BYTES', 4096)
+    monkeypatch.setenv('BF_CACHE_DIR', os.path.join(
+        os.environ.get('TMPDIR', '/tmp'), 'bf_gate_tiles_%d' % os.getpid()))
+    T, F, P, S, B = 64, 4, 2, 8, 4
+    assert beam.probe_nframe(T, F, P, B) == 16
+    assert beam.probe_nframe(16384, 64, 2, 864) * 64 * 2 * 864 * 8 \
+        <= 256 << 20                      # the default, at the deployment
+    _, _, w = _phase_weights(F, P, B, S)
+    eng = Beamformer(w, accuracy='int8')
+    re, im = (jnp.asarray(a) for a in _volt_planes(T, F, P, S))
+    with pytest.raises(ValueError, match=r'16384 bytes.*4096'):
+        eng._gate(['xla', 'int8_wide'], P, lambda: (re, im))
+    seen = []
+    gate = eng._gate
+    monkeypatch.setattr(eng, '_gate', lambda names, npol, make_args: (
+        seen.append(make_args()[0].shape), gate(names, npol,
+                                                make_args))[1])
+    monkeypatch.setenv('BF_LINALG_PROBE', '1')
+    winner = eng.prewarm(T, F, npol=P)
+    assert seen == [(16, F, P, S)]
+    assert eng.chosen[eng._key((T, F, P, S), 'int8', True)] == winner

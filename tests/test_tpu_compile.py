@@ -257,3 +257,90 @@ def test_correlator_gulp_program_from_words(one_chip):
         mems['pairs'].temp_size_in_bytes + gulp
     for mem in mems.values():
         assert mem.alias_size_in_bytes == 2 * int(np.prod(acc.shape)) * 4
+
+
+# ---------------------------------------------------------------------------
+# beamform-tab: 864 Stokes-I beams of 64 channels x 64 dishes x 2 pol
+# ---------------------------------------------------------------------------
+
+#: the beamform-tab cell's gulp (time, freq, station, pol) and weights
+_TAB_GULP = (16384, 64, 64, 2)
+_TAB_WEIGHTS = (64, 2, 864, 64)
+
+
+def test_beamformer_gulp_program_of_a_beamform_tab_gulp(one_chip,
+                                                        monkeypatch):
+    """[BeamformStage(int8, a weight set per channel), DetectStage(
+    'stokes_i'), ReduceStage('time', 16), QuantizeStage('u8')] as
+    FusedBlock composes it, at the deployment's shape, from the
+    gulp's int16 words: ONE Mosaic kernel (a channel and 512 frames a
+    program) with int8 dots and int32 sums inside, one pass in front
+    of it (the fold of the words to rows of frames), no complex type;
+    the weights are the program's second ARGUMENT (58.7 MB, widened),
+    not a constant folded into it; beside the 56.6 MB product the
+    temporaries are that one folded gulp (268 MB) and the product's
+    own relayout: under 0.4 GB, where the beam voltages of a gulp
+    would be 14.5 GB."""
+    import jax
+    from bifrost_tpu.ops import pallas_kernels as pk
+    from bifrost_tpu.stages import (BeamformStage, DetectStage,
+                                    ReduceStage, QuantizeStage,
+                                    walk_headers, compose_stages)
+    # off the chip the kernel would be interpreted: compile it
+    monkeypatch.setattr(pk, '_xcorr_interpret', lambda interpret: False)
+    hdr = {'_tensor': {'shape': [-1] + list(_TAB_GULP[1:]), 'dtype': 'ci8',
+                       'labels': ['time', 'freq', 'station', 'pol'],
+                       'scales': [[0, 1]] * 4, 'units': [None] * 4}}
+    rng = np.random.default_rng(0)
+    w = np.exp(2j * np.pi * rng.random(_TAB_WEIGHTS, dtype=np.float32)) \
+        .astype(np.complex64)
+    stages = [BeamformStage(w, accuracy='int8'), DetectStage('stokes_i'),
+              ReduceStage('time', 16), QuantizeStage('u8', 1.1e-5)]
+    plan, info = compose_stages(stages, walk_headers(stages, hdr),
+                                _TAB_GULP + (2,), np.dtype('int8'))
+    assert info['impl'] == 'pallas-beamform-detect'
+    assert (info['weights'], info['dot'], info['time_tile']) == \
+        ('per channel', 'int8', 512)
+    fn, operands = plan.bound()
+    (wide,) = operands
+    assert wide.shape == (64, 256, 4 * 896) and wide.dtype == np.int8
+    args = (jax.ShapeDtypeStruct((int(np.prod(_TAB_GULP)),), np.int16,
+                                 sharding=one_chip),
+            jax.ShapeDtypeStruct(wide.shape, wide.dtype,
+                                 sharding=one_chip))
+    traced = jax.jit(fn).trace(*args)
+    kernel = str(traced.jaxpr)
+    assert kernel.count('pallas_call') == 1
+    assert kernel.count('dot_general') == 4 == \
+        kernel.count('preferred_element_type=int32')   # one a section
+    assert 'i8[512,256]' in kernel          # z = [re | im] of a tile
+    comp = traced.lower().compile()
+    text, mem = comp.as_text(), comp.memory_analysis()
+    assert _in_front_of_the_kernel(text) == ['reshape']
+    assert 's16[134217728]{0:T(1024)(128)(2,1)} parameter(0)' in text
+    assert re.search(r's8\[64,256,3584\]\S* parameter\(1\)', text)
+    assert 'c64' not in text and 'bitcast-convert' not in text
+    assert mem.argument_size_in_bytes >= (256 << 20) + wide.size
+    assert mem.output_size_in_bytes == 1024 * 64 * 864
+    assert mem.generated_code_size_in_bytes < (4 << 20)
+    assert mem.temp_size_in_bytes < 400e6
+
+
+def test_cut_of_a_beamform_tab_product_is_rows_in_one_program(one_chip):
+    """The 56.6 MB u8 product (1024, 64, 1, 864) crosses in four
+    pieces of 256 rows, all cut by one program (xfer._piece_plan), as
+    rows in the host's order (xfer._as_rows: an axis of one before the
+    last lets the compiler lay a piece out time-minor, which the host
+    would have to take apart), with no temporary."""
+    class Product(object):
+        shape, nbytes, sharding = (1024, 64, 1, 864), 1024 * 64 * 864, \
+            type('S', (), {'device_set': {0}})
+    axis, step = xfer._piece_plan(Product)
+    assert (axis, step) == (0, 256) and xfer._as_rows(Product.shape)
+    assert not xfer._as_rows(_GPUSPEC) and xfer._as_rows(_XCORR)
+    comp = _compiled_cut(one_chip, [Product.shape], np.uint8,
+                         0, step, 4, True)
+    text = comp.as_text()
+    root = [line for line in text.splitlines() if 'ROOT' in line][-1]
+    assert root.count('u8[256,55296]{1,0:T(8,128)(4,1)}') >= 4
+    assert comp.memory_analysis().temp_size_in_bytes == 0

@@ -950,6 +950,36 @@ def test_small_products_cross_whole_and_uneven_ones_in_pieces(
                                    else np.float32)}
 
 
+@pytest.mark.parametrize('shape,itemsize,want', [
+    ((1024, 64, 1, 864), 1, (0, 256)),        # beamform-tab: 4 x 256
+    ((16384, 4, 1024), 4, (0, 1024)),         # gpuspec: 16 x 1024
+    ((1, 1024, 256, 2, 256, 2), 8, (1, 8)),   # xcorr: 128 x 8 channels
+    ((1, 64, 4, 1 << 20), 4, (1, 1)),         # gpuspec-hsr: 64 x 1
+    ((1000, 64, 1, 864), 1, (0, 250)),
+    ((7, 6 << 20), 1, (0, 2)),                # 2, 2, 2, 1: no divisor
+    ((512, 1024), 4, None),                   # 2 MiB crosses whole
+], ids=['beamform_tab', 'gpuspec', 'xcorr', 'gpuspec_hsr', 'thousand',
+        'seven_rows', 'small'])
+def test_piece_plan_cuts_pieces_of_one_length(shape, itemsize, want):
+    """As few pieces as fit 16 MiB each, evened out: where the axis
+    divides, one program cuts them all (the rule of PR 27 left a
+    1024-row u8 product a fourth piece of 136 rows, and a second cut
+    program a gulp: PR 35); the served cells' plans are what they
+    were."""
+    class Product(object):
+        class sharding(object):
+            device_set = {0}
+    arr = Product()
+    arr.shape, arr.nbytes = shape, int(np.prod(shape)) * itemsize
+    plan = xfer._piece_plan(arr)
+    assert plan == want
+    if plan is not None:
+        axis, step = plan
+        assert step * arr.nbytes // shape[axis] <= xfer._D2H_PIECE_BYTES
+        old = max(xfer._D2H_PIECE_BYTES * shape[axis] // arr.nbytes, 1)
+        assert -(-shape[axis] // step) == -(-shape[axis] // old)
+
+
 #: a single-frame product of twelve channels of 512 bytes (complex64)
 #: or 256 (float32): LARGE, and six groups of two pieces, once the
 #: constants are what ``_small_constants`` makes them
