@@ -7,8 +7,13 @@ crosses the host boundary: xfer.py).
 
 Both directions ride the async transfer engine (bifrost_tpu.xfer):
 
-- host→device gulps are staged through the engine's reusable buffer
-  ring and shipped with a non-blocking device_put (devrep → xfer);
+- host→device gulps are shipped with a non-blocking device_put
+  (devrep → xfer): to one device on a copying backend from the input
+  ring's own span, which the engine keeps open (a second open span of
+  this block's reader) until the transfer has consumed it, and which
+  this block has it release before its reader leaves the sequence;
+  staged through the engine's reusable buffer ring otherwise
+  (docs/transfer.md, "From the ring span");
 - device→host gulps are committed as *deferred fills*
   (xfer.HostFill): the span publishes immediately, the D2H readback
   runs in flight, and readers of the output ring materialize the bytes
@@ -103,6 +108,17 @@ class CopyBlock(TransformBlock):
             ndim += 1        # device rep grows a trailing (re,im) axis
         return time_sharding(self.mesh, ndim, self._h2d_taxis)
 
+    def _process_sequence(self, orings, iseqs):
+        """The spans of the input ring that H2D transfers still read
+        (``xfer._Hold``) are this reader's: they are waited for and
+        released before it moves on to another sequence or closes,
+        however the sequence ends (its end, a shutdown, a failure)."""
+        try:
+            return super(CopyBlock, self)._process_sequence(orings, iseqs)
+        finally:
+            from .. import xfer
+            xfer.engine().release_held(iseqs[0])
+
     def _d2h_strict(self):
         """Synchronous D2H required?  Scope sync_strict wins; else the
         engine's global async switch (BF_SYNC_STRICT / BF_XFER_ASYNC)."""
@@ -118,9 +134,12 @@ class CopyBlock(TransformBlock):
             buf = ispan.data.as_numpy()
             # engine-created device array: the committed chunk is
             # exclusively this ring's (donation-eligible downstream);
-            # a ci8 gulp bound for one device crosses as its words
+            # a ci8 gulp bound for one device crosses as its words,
+            # and from the span's own memory where the engine can hold
+            # the span open meanwhile
             ospan.set(to_device_rep(buf, ispan.dtype,
-                                    sharding=self._h2d_sharding(ispan)),
+                                    sharding=self._h2d_sharding(ispan),
+                                    span=ispan),
                       owned=True)
         elif ispace == 'tpu' and ospace != 'tpu':
             out = ospan.data.as_numpy()

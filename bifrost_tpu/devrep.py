@@ -78,17 +78,23 @@ def _as_words(dtype):
     return dtype.kind == 'ci' and dtype.nbits == 8 and dtype.veclen == 1
 
 
-def to_device_rep(buf, dtype, sharding=None):
+def to_device_rep(buf, dtype, sharding=None, span=None):
     """numpy storage -> device-representation jax array.  ``sharding``
     (a jax Sharding over the DEVICE-REP shape — note ci* types grow a
     trailing (re, im) axis) places the gulp mesh-resident via the
     sharded H2D path (xfer.to_device).  A ci8 gulp with no sharding
     asked for crosses as its int16 words, the bytes as the host holds
-    them, and is a :class:`ComplexWords`."""
+    them, and is a :class:`ComplexWords`.  ``span`` is the open read
+    span of a host ring that ``buf`` is the memory of: where the
+    device representation is those bytes as they lie (ci8 words, ci16,
+    every plain real type), the engine may ship them from the span and
+    hold it open instead of copying (``xfer.TransferEngine.to_device``);
+    a representation that is computed on the host is a fresh array and
+    is staged."""
     dtype = DataType(dtype)
     if _as_words(dtype) and sharding is None:
         from .telemetry import counters
-        words = to_device(host_words(buf))
+        words = to_device(host_words(buf), span=span)
         counters.inc('xfer.h2d_word_bytes', int(words.nbytes))
         return ComplexWords(words, buf.shape)
     if dtype.kind == 'ci':
@@ -99,7 +105,8 @@ def to_device_rep(buf, dtype, sharding=None):
             return to_device(np.stack([re, im], axis=-1),
                              sharding=sharding)
         return to_device(np.ascontiguousarray(buf).view(
-            buf.dtype[0]).reshape(buf.shape + (2,)), sharding=sharding)
+            buf.dtype[0]).reshape(buf.shape + (2,)), sharding=sharding,
+            span=span)
     if dtype.kind == 'cf' and dtype.nbits == 16:
         re = buf['re'].astype(np.float32)
         im = buf['im'].astype(np.float32)
@@ -107,7 +114,7 @@ def to_device_rep(buf, dtype, sharding=None):
     if dtype.is_packed:
         from .ops.map import _to_logical
         return to_device(_to_logical(buf, dtype), sharding=sharding)
-    return to_device(buf, sharding=sharding)
+    return to_device(buf, sharding=sharding, span=span)
 
 
 def from_device_rep(arr, dtype, out_buf):

@@ -13,17 +13,27 @@ This engine replaces both with a pipelined staging layer, the TPU
 analogue of bifrost's per-block CUDA streams + async memcpy
 (reference: src/cuda.cpp streams; Cranmer et al. 2017):
 
-- **H2D staging ring** — host gulps are copied once into small,
-  128-byte-aligned staging buffers and shipped with ``device_put``
-  (zero-copy on the CPU backend, async DMA on TPU).  On copying
-  backends the buffers form a reusable ring, recycled once the DMA is
-  observed complete.  On zero-copy backends each transfer gets a fresh
-  aligned buffer: the device array aliases the buffer for its whole
-  lifetime, and reuse is provably unsafe even after the array dies (an
-  in-flight computation still reads it) — but alignment alone already
-  halves the copy count versus the old defensive ``np.array`` (which
-  landed unaligned and forced the runtime into a second copy).  Both
-  modes preserve the aliasing-safety the old defensive copy bought.
+- **H2D staging ring** — a host gulp the caller may recycle at once is
+  copied once into a small, 128-byte-aligned staging buffer and
+  shipped with ``device_put`` (zero-copy on the CPU backend, async DMA
+  on TPU).  On copying backends the buffers form a reusable ring,
+  recycled once the DMA is observed complete.  On zero-copy backends
+  each transfer gets a fresh aligned buffer: the device array aliases
+  the buffer for its whole lifetime, and reuse is provably unsafe even
+  after the array dies (an in-flight computation still reads it) — but
+  alignment alone already halves the copy count versus the old
+  defensive ``np.array`` (which landed unaligned and forced the
+  runtime into a second copy).  Both modes preserve the
+  aliasing-safety the old defensive copy bought.
+
+- **H2D from the ring span** — a gulp that lies in a read span of a
+  host ring (a ``CopyBlock``'s input) is not copied at all on a
+  copying backend: ``device_put`` is given the span's own memory and
+  the span stays open, a second open span of the same reader over the
+  same bytes, until the runtime has let go of the memory
+  (:class:`_Hold`; docs/transfer.md, "From the ring span").  Who can
+  be served so is decided from what the engine can observe
+  (:meth:`TransferEngine._lendable`); everybody else is staged.
 
 - **non-blocking D2H** — ``to_host_async`` starts the readback with
   ``copy_to_host_async()`` and returns a :class:`TransferFuture`; a
@@ -88,7 +98,8 @@ Tunables (environment):
                            ``memory.INFLIGHT_BYTES`` of them besides
                            the newest
 - ``BF_XFER_STAGING``      staging slots per (shape, dtype) (default 4)
-- ``BF_XFER_STAGE_MIN``    min bytes to use a staging slot (default 16384)
+- ``BF_XFER_STAGE_MIN``    min bytes to use a staging slot, or to ship
+                           from a ring span (default 16384)
 """
 
 from __future__ import annotations
@@ -524,6 +535,99 @@ class _StagingPool(object):
                                self._on_array_death(s))
         with self._lock:
             self._busy.append(slot)
+
+
+#: seconds between two looks of a thread that waits for a lent span.
+#: The runtime lets go of host memory on a thread of its own, and
+#: jaxlib drops the reference it kept at the next call into it from
+#: Python (``collect_garbage`` is one), so there is no event to sleep
+#: on: the waiter asks, a thousand times a second at most.
+_HOLD_POLL_S = 1e-3
+
+_collect_fn = None
+
+
+def _collect_runtime_garbage():
+    """Have jaxlib drop, now, the Python references its runtime has
+    finished with: it parks them until some thread next calls into it
+    (one that holds the GIL), and a pipeline that has come to rest
+    makes no such call."""
+    global _collect_fn
+    if _collect_fn is None:
+        try:
+            from jax._src.lib import xla_client
+            _collect_fn = xla_client._xla.collect_garbage
+        except Exception:
+            _collect_fn = lambda: None         # noqa: E731
+    _collect_fn()
+
+
+class _Lease(object):
+    """Host memory lent to the runtime for one transfer.  numpy sees
+    it through the array interface, so the array made from it
+    (``np.asarray(lease)``, what ``device_put`` is handed) and every
+    view or re-typing of that array that anybody makes and keeps has
+    this object at the end of its chain of bases.  The runtime keeps
+    the array it was handed until it is done with the host bytes (the
+    keepalive :class:`_StagingPool` leans on for a slot whose array
+    died), so this object's death says that nobody reads the memory
+    any more, whoever deleted or donated the DEVICE array meanwhile:
+    a ``weakref.finalize`` on it is a handle to the transfer's
+    completion that no reader of the device ring can delete.  It
+    cannot fire early; a runtime that copied the bytes before it
+    returned and kept nothing lets it fire as soon as the caller drops
+    the array, which is right too."""
+
+    __slots__ = ('__array_interface__', '__weakref__')
+
+    def __init__(self, view):
+        self.__array_interface__ = dict(view.__array_interface__)
+
+
+class _Hold(object):
+    """One read span of a host ring kept open while the runtime reads
+    a gulp from its memory: a second open span of the block's own
+    reader over the bytes of the span the block was given, which both
+    ring cores count (the guarantee stays at the oldest open span, so
+    no writer gets at the bytes when the pipeline releases its own).
+    ``lend()`` is the array to ship; once the runtime has let go of it
+    the hold is ``consumed()`` and whoever looks next releases the span
+    (:meth:`TransferEngine._reap`)."""
+
+    __slots__ = ('span', 'view', 'nbytes', '_gone', '_timer')
+
+    def __init__(self, span, view):
+        #: its ``sequence`` is the reader it belongs to, whose holds
+        #: are released in the order they were made, and before it
+        #: moves on or closes
+        self.span = span
+        self.view = view           # keeps the ring's buffer too
+        self.nbytes = int(view.nbytes)
+        self._gone = threading.Event()
+        self._timer = None
+
+    def lend(self):
+        lease = _Lease(self.view)
+        weakref.finalize(lease, self._gone.set).atexit = False
+        return np.asarray(lease)
+
+    def shipped(self):
+        """``device_put`` has returned: the hold's span starts."""
+        self._timer = _timed('h2d.hold', 'wait', 'xfer.h2d_hold_s',
+                             bytes=self.nbytes)
+        self._timer.__enter__()
+
+    def consumed(self):
+        return self._gone.is_set()
+
+    def wait(self):
+        while not self._gone.wait(_HOLD_POLL_S):
+            _collect_runtime_garbage()
+
+    def release(self):
+        if self._timer is not None:
+            self._timer.__exit__(None, None, None)
+        self.span.release()
 
 
 class TransferFuture(object):
@@ -988,6 +1092,10 @@ class TransferEngine(object):
         self._work = threading.Condition(self._lock)
         self._stop = threading.Event()
         self._workers = []
+        #: spans of host rings lent to transfers in flight
+        #: (:class:`_Hold`), in the order they were shipped
+        self._holds = []
+        self._hold_lock = threading.Lock()
         _obs()[1].watch_jax()
 
     def _is_zero_copy(self):
@@ -1046,6 +1154,93 @@ class TransferEngine(object):
         c.inc('xfer.h2d_issued')
         c.inc('xfer.h2d_bytes', int(nbytes))
         return out
+
+    # -- H2D from a ring span (docs/transfer.md, "From the ring span") ----
+    def _lendable(self, arr, span):
+        """Whether ``arr`` can cross from where it lies: it is the
+        memory of ``span``, an open read span of a host ring, all of
+        it in one stretch that does not run into the ring's ghost
+        region; the span's reader is guaranteed (only its open spans
+        keep a writer off the bytes) and its ring holds two such spans
+        at least (one may stay lent while the block waits for the
+        next); the backend copies (one that aliases host memory would
+        read the ring for the array's whole life), strict mode is off
+        and the gulp is worth it (``stage_min``).  Everything else is
+        staged."""
+        if span is None or self._is_zero_copy() or strict_mode() \
+                or int(arr.nbytes) < self.stage_min:
+            return False
+        ring = span.ring
+        if ring.space == 'tpu' or ring.nringlet != 1 \
+                or not getattr(span.sequence, 'guarantee', False):
+            return False
+        begin, nbyte, size = span._begin, span._nbyte, ring.total_span
+        return (0 < nbyte == int(arr.nbytes) and arr.flags.c_contiguous
+                and begin % size + nbyte <= size and size >= 2 * nbyte
+                and arr.ctypes.data == span.data.as_numpy().ctypes.data)
+
+    def _ship_lent(self, arr, span, device):
+        """Ship ``arr`` from the ring span it lies in, with no host
+        copy: open the span a second time, hand ``device_put`` its
+        memory, and leave the second span open until the runtime has
+        let go of it (:class:`_Hold`).  A failed ``device_put``
+        releases it at once: no transfer reads it."""
+        hold = _Hold(span.sequence.acquire(span.frame_offset,
+                                           span.nframe), arr)
+        try:
+            faults.fire('xfer.h2d')
+            out = self._put(hold.lend(), device)
+        except BaseException:
+            hold.release()
+            raise
+        hold.shipped()
+        c = _counters()
+        with self._hold_lock:
+            self._holds.append(hold)
+            c.set_gauge('xfer.h2d_spans_held', len(self._holds))
+        c.inc('xfer.h2d_direct')
+        c.inc('xfer.h2d_direct_bytes', hold.nbytes)
+        c.inc('xfer.h2d_issued')
+        c.inc('xfer.h2d_bytes', hold.nbytes)
+        return out
+
+    def _reap(self):
+        """Release every lent span the runtime has let go of, each
+        reader's in the order they were shipped; returns the holds
+        that are left."""
+        with self._hold_lock:
+            if not self._holds:
+                return []
+            _collect_runtime_garbage()
+            stuck, left = set(), []
+            for hold in self._holds:
+                reader = id(hold.span.sequence)
+                if reader not in stuck and hold.consumed():
+                    hold.release()
+                else:
+                    stuck.add(reader)
+                    left.append(hold)
+            self._holds = left
+            _counters().set_gauge('xfer.h2d_spans_held', len(left))
+            return left
+
+    def release_held(self, sequence=None, keep=0):
+        """Wait until reader ``sequence`` (None: every reader) has at
+        most ``keep`` spans lent to transfers, releasing them as they
+        come back.  The engine calls it with one after every ship from
+        a span: the transfer just issued stays in flight behind the
+        caller's next ``acquire``, which a ring two spans deep can
+        serve, and the one before it is waited for here, with the link
+        busy meanwhile.  The block calls it with none before its
+        reader moves to another sequence or closes (``CopyBlock``): a
+        span outlives neither."""
+        while True:
+            mine = [h for h in self._reap()
+                    if sequence is None or h.span.sequence is sequence]
+            if len(mine) <= keep:
+                return
+            with _timed('h2d.hold_wait', 'wait', 'xfer.h2d_hold_wait_s'):
+                mine[0].wait()
 
     # -- sharded H2D (mesh-resident pipelines; docs/parallel.md) ----------
     def _shard_plan(self, shape, sharding):
@@ -1142,10 +1337,12 @@ class TransferEngine(object):
         return out
 
     def _stage_real(self, arr, device):
-        """Ship a real-valued numpy array: always exactly ONE host copy
-        into an engine-owned aligned buffer, then an async device_put —
-        the caller may mutate/recycle ``arr`` the moment this returns,
-        on every backend.
+        """Ship a real-valued numpy array the caller keeps for itself:
+        exactly ONE host copy into an engine-owned aligned buffer,
+        then an async device_put — the caller may mutate/recycle
+        ``arr`` the moment this returns, on every backend.  (A gulp
+        that lies in a ring span the engine may hold open is not
+        copied at all: :meth:`_ship_lent`.)
 
         Zero-copy backends (CPU): the buffer is FRESH per transfer —
         aligned so device_put stays zero-copy (the old defensive
@@ -1165,11 +1362,19 @@ class TransferEngine(object):
             arr.shape, arr.dtype, int(arr.nbytes),
             lambda buf: np.copyto(buf, arr, casting='no'), device)
 
-    def to_device(self, arr, device=None, sharding=None):
+    def to_device(self, arr, device=None, sharding=None, span=None):
         """numpy -> jax.Array; complex is shipped as two float planes
         and recombined on device.  Safe against the caller mutating or
         recycling ``arr`` after the call returns (the staging-pool
         contract).
+
+        ``span`` is the open :class:`~bifrost_tpu.ring.ReadSpan` that
+        ``arr`` is the memory of, from a caller that reads a host ring
+        (``CopyBlock``): where :meth:`_lendable` allows, the gulp
+        crosses from the span's memory with no host copy, and the
+        engine keeps the span open (a second open span of its reader)
+        until the transfer has consumed it.  The contract towards the
+        caller is the same: it releases its own span when it likes.
 
         ``sharding`` (a jax Sharding spanning several devices) routes
         the transfer through the sharded H2D path: host bytes are
@@ -1188,14 +1393,28 @@ class TransferEngine(object):
         # host-side transfer time (staging copy + async device_put
         # issue) and transfer-size distribution
         _obs()[0].observe('xfer.h2d_nbytes', int(arr.nbytes))
+        lent = False
         with _timed('h2d', 'xfer', 'xfer.h2d_s', bytes=int(arr.nbytes)):
-            if not np.iscomplexobj(arr):
-                return self._stage_real(arr, device)
-            re, im = self._planes(arr)
-            c = _counters()
-            c.inc('xfer.h2d_issued')
-            c.inc('xfer.h2d_bytes', int(arr.nbytes))
-            return _combine(self._put(re, device), self._put(im, device))
+            if np.iscomplexobj(arr):
+                re, im = self._planes(arr)
+                c = _counters()
+                c.inc('xfer.h2d_issued')
+                c.inc('xfer.h2d_bytes', int(arr.nbytes))
+                out = _combine(self._put(re, device),
+                               self._put(im, device))
+            elif self._lendable(arr, span):
+                out, lent = self._ship_lent(arr, span, device), True
+            else:
+                out = self._stage_real(arr, device)
+                if span is not None:
+                    # a ring's gulp that was staged counts, at 0, so
+                    # that a reader of the share finds it
+                    _counters().inc('xfer.h2d_direct_bytes', 0)
+        if lent:
+            # outside the call's span: waiting for the transfer before
+            # this one is no work of this one's
+            self.release_held(span.sequence, keep=1)
+        return out
 
     @staticmethod
     def _planes(arr):
@@ -1508,9 +1727,10 @@ class TransferEngine(object):
         waits for nobody: a future that has finished on its own is
         harvested if no peer is at it, a fill is left to its claimant.
         With ``block=True`` (shutdown) every outstanding transfer is
-        completed or waited for.  It also lets the staging pool take
-        back the slots of H2D transfers that have landed
-        (:meth:`_StagingPool.reclaim`).
+        completed or waited for, every ring span lent to an H2D
+        transfer (:class:`_Hold`) among them.  It also lets the
+        staging pool take back the slots of H2D transfers that have
+        landed (:meth:`_StagingPool.reclaim`).
 
         A failed transfer raises out of the draining thread (the
         failure is recorded on the future/fill, so the queues still
@@ -1519,6 +1739,8 @@ class TransferEngine(object):
         n = 0
         error = None
         self._pool.reclaim()
+        if block:
+            self.release_held()
         with self._lock:
             pending = list(self._pending)
             fills = list(self._fills)
@@ -1591,12 +1813,15 @@ def _close_at_exit():
         _engine.close(timeout=5.0)
 
 
-def to_device(arr, device=None, sharding=None):
+def to_device(arr, device=None, sharding=None, span=None):
     """numpy -> jax.Array via the transfer engine (module docstring).
     Alias-safe: the caller may mutate/recycle ``arr`` immediately.
     ``sharding`` routes through the sharded H2D path (per-shard staged
-    placement over a mesh — docs/parallel.md)."""
-    return engine().to_device(arr, device, sharding=sharding)
+    placement over a mesh — docs/parallel.md); ``span`` is the open
+    read span of a host ring that ``arr`` is the memory of, which the
+    engine may ship from and hold open instead of copying
+    (:meth:`TransferEngine.to_device`)."""
+    return engine().to_device(arr, device, sharding=sharding, span=span)
 
 
 def to_host(arr):

@@ -38,7 +38,28 @@ one transfer in flight behind the one being staged (the H2D block),
 for ``BESIDE_S`` seconds: milliseconds a copy of the source alone and
 beside each form, and milliseconds a gulp of the shipper.
 
+``ring`` on the command line runs another probe in the forms' place
+(PERF.md section 6, PR 36): the ``gpuspec`` gulp of words shipped by
+the package's own engine (``bifrost_tpu.xfer.TransferEngine``) from a
+read span of a host ``Ring`` in ``system`` space, four gulps deep, as
+``CopyBlock`` ships it, (a) ``staged``: copied into a staging slot
+first, the engine's path for memory the caller recycles, (b)
+``direct``: from the span's own memory, the span held open until the
+runtime has let go of it.  For each, one transfer at a time: seconds
+from the call to ``block_until_ready`` and the process's CPU seconds
+over them; for ``direct`` also when the runtime let go of the host
+memory, counted from the call (``lease_s``: looked for every 0.2 ms
+with ``collect_garbage``, the device array kept, and again with the
+device array deleted at once as a donating reader deletes it), and
+whether that is seen without such a call.  With ``beside``, both forms
+next to a thread that copies a gulp as fast as it can (the benchmark's
+source), the shipper one transfer behind the one it issues, for
+``BESIDE_S`` seconds: milliseconds a gulp of the shipper, milliseconds
+a copy of the source (alone, and beside each form), CPU seconds a
+shipped gulp, and what the holds read.
+
     chiprun -- python3 tools/h2d_probe.py
+    chiprun -- python3 tools/h2d_probe.py ring beside
 """
 
 import json
@@ -172,8 +193,219 @@ def source_alone(gulp):
     return 1e3 * statistics.median(took)
 
 
+def ring_probe(with_beside):
+    """The ``gpuspec`` gulp from a host ring's span, staged and
+    direct, through the package's engine."""
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from bifrost_tpu import xfer
+    from bifrost_tpu.ring import Ring
+    from bifrost_tpu.telemetry import counters, histograms
+    from bifrost_tpu.words import host_view
+
+    shape = GULPS['gpuspec']
+    nframe = shape[0]
+    rng = np.random.default_rng(36)
+    gulp = aligned(shape, np.int16)
+    gulp.view(np.int8)[...] = rng.integers(
+        -64, 64, gulp.view(np.int8).shape, dtype=np.int8)
+    flat = gulp.reshape(-1)
+    hdr = {'name': 'probe', 'time_tag': 0, 'gulp_nframe': nframe,
+           '_tensor': {'shape': [-1] + list(shape[1:]), 'dtype': 'ci8',
+                       'labels': ['time', 'pol', 'fine_time'],
+                       'scales': [[0, 1]] * 3, 'units': [None] * 3}}
+    depth = 4
+    ring = Ring(space='system')
+    writer = ring.begin_writing()
+    writer.__enter__()
+    wseq = writer.begin_sequence(hdr, nframe, depth * nframe)
+    wseq.__enter__()
+    for _ in range(depth):
+        with wseq.reserve(nframe) as sp:
+            host_view(sp.data.as_numpy())[...] = flat
+            sp.commit(nframe)
+    rseq = ring.open_earliest_sequence(guarantee=True)
+    eng = xfer.TransferEngine()
+    out = {'ring': type(ring).__name__, 'depth': depth,
+           'total_span': int(ring.total_span), 'nbytes': int(gulp.nbytes),
+           'zero_copy_backend': bool(eng._is_zero_copy())}
+
+    def words_of(span):
+        return host_view(span.data.as_numpy())
+
+    def ship(k, direct):
+        """(device array, seconds until to_device returned)."""
+        with rseq.acquire((k % depth) * nframe, nframe) as span:
+            w = words_of(span)
+            if k < depth:
+                out.setdefault('span_address_mod_4096', []).append(
+                    int(w.ctypes.data % 4096))
+            t0 = time.perf_counter()
+            arr = eng.to_device(w, span=span if direct else None)
+            return arr, time.perf_counter() - t0
+
+    def lease(k, delete):
+        """Seconds from the call until the runtime let go of the host
+        memory, and until the array was ready."""
+        with rseq.acquire((k % depth) * nframe, nframe) as span:
+            hold = xfer._Hold(rseq.acquire((k % depth) * nframe, nframe),
+                              words_of(span))
+            t0 = time.perf_counter()
+            arr = jax.device_put(hold.lend())
+            t_put = time.perf_counter() - t0
+            if delete:
+                arr.delete()
+            t_gone = None
+            while t_gone is None and time.perf_counter() - t0 < 5.0:
+                xfer._collect_runtime_garbage()
+                if hold.consumed():
+                    t_gone = time.perf_counter() - t0
+                else:
+                    time.sleep(2e-4)
+            t_ready = None
+            if not delete:
+                arr.block_until_ready()
+                t_ready = time.perf_counter() - t0
+            hold.release()
+            return {'put_returns_s': t_put, 'lease_s': t_gone,
+                    'ready_s': t_ready}
+
+    for name, delete in (('lease_array_kept', False),
+                         ('lease_array_deleted', True)):
+        got = [lease(k, delete) for k in range(REPS)]
+        out[name] = {
+            key: (statistics.median(g[key] for g in got)
+                  if all(g[key] is not None for g in got) else None)
+            for key in ('put_returns_s', 'lease_s', 'ready_s')}
+        out[name]['lease_s_all'] = [g['lease_s'] for g in got]
+    # is the runtime's letting go seen with no call into jaxlib?
+    with rseq.acquire(0, nframe) as span:
+        hold = xfer._Hold(rseq.acquire(0, nframe), words_of(span))
+        arr = jax.device_put(hold.lend())
+        arr.block_until_ready()
+        time.sleep(0.2)
+        seen_alone = hold.consumed()
+        xfer._collect_runtime_garbage()
+        out['lease_seen_without_a_call'] = bool(seen_alone)
+        out['lease_seen_after_collect_garbage'] = bool(hold.consumed())
+        hold.release()
+        del arr
+    print(json.dumps(out), file=sys.stderr, flush=True)
+
+    # a runtime that keeps the host array for as long as the device
+    # array lives never gives a held span back: the engine's direct
+    # path would wait for ever, so it is not entered
+    forms = [('staged', False)]
+    if out['lease_array_kept']['lease_s'] is not None:
+        forms.append(('direct', True))
+    else:
+        out['direct'] = 'not run: the lease outlives the transfer'
+
+    for form, direct in forms:
+        counters.reset()
+        takes = []
+        for k in range(REPS + 1):
+            cpu0 = _cpu_s()
+            t0 = time.perf_counter()
+            arr, t_call = ship(k, direct)
+            arr.block_until_ready()
+            wall = time.perf_counter() - t0
+            cpu = _cpu_s() - cpu0
+            eng.release_held(rseq)
+            if k:                                   # the first warms
+                takes.append((wall, cpu, t_call))
+        exact = bool(np.array_equal(np.asarray(arr), flat))
+        del arr
+        out[form] = {
+            'wall_s_best': min(t[0] for t in takes),
+            'wall_s_median': statistics.median(t[0] for t in takes),
+            'cpu_s_median': statistics.median(t[1] for t in takes),
+            'to_device_returns_s_median': statistics.median(
+                t[2] for t in takes),
+            'gbps_best': gulp.nbytes / min(t[0] for t in takes) / 1e9,
+            'exact': exact,
+            'counters': {k: v for k, v in counters.snapshot().items()
+                         if k.startswith('xfer.h2d')}}
+        print(json.dumps(out), file=sys.stderr, flush=True)
+
+    if with_beside:
+        out['source_alone_ms_a_copy'] = source_alone(gulp)
+        for form, direct in forms:
+            counters.reset()
+            hist = histograms.get_or_create('xfer.h2d_hold_s', unit='s')
+            wait = histograms.get_or_create('xfer.h2d_hold_wait_s',
+                                            unit='s')
+            h0, w0 = (hist.count, hist.total), (wait.count, wait.total)
+            target = np.zeros_like(gulp)
+            stop = threading.Event()
+            copies = []
+
+            def source():
+                while not stop.is_set():
+                    t0 = time.perf_counter()
+                    np.copyto(target, gulp)
+                    copies.append(time.perf_counter() - t0)
+
+            th = threading.Thread(target=source, name='probe-source')
+            cpu0 = _cpu_s()
+            th.start()
+            ships, behind, k = [], None, 0
+            end = time.perf_counter() + BESIDE_S
+            while time.perf_counter() < end:
+                t0 = time.perf_counter()
+                arr, _t = ship(k, direct)
+                if behind is not None:
+                    behind.block_until_ready()
+                    # as every block does once a gulp: the pool takes
+                    # its slot back while the array still lives (one
+                    # that died unseen costs the slot, and 0.3 s of
+                    # first touches for its replacement)
+                    eng.drain()
+                behind = arr
+                ships.append(time.perf_counter() - t0)
+                k += 1
+            behind.block_until_ready()
+            eng.release_held(rseq)
+            stop.set()
+            th.join()
+            cpu = _cpu_s() - cpu0
+            del behind, arr
+            out[form]['beside'] = {
+                'source_ms_a_copy_median':
+                    1e3 * statistics.median(copies),
+                'source_ms_a_copy_mean': 1e3 * statistics.fmean(copies),
+                'ship_ms_a_gulp_median': 1e3 * statistics.median(ships),
+                'ship_ms_a_gulp_mean': 1e3 * statistics.fmean(ships),
+                'cpu_s_a_shipped_gulp': cpu / len(ships),
+                'copies': len(copies), 'ships': len(ships),
+                'holds': hist.count - h0[0],
+                'hold_ms_mean': 1e3 * (hist.total - h0[1]) /
+                    max(hist.count - h0[0], 1),
+                'hold_wait_ms_a_gulp': 1e3 * (wait.total - w0[1]) /
+                    len(ships),
+                'counters': {k: v for k, v in counters.snapshot().items()
+                             if k.startswith('xfer.h2d')}}
+            print(json.dumps(out), file=sys.stderr, flush=True)
+    rseq.close()
+    wseq.__exit__(None, None, None)
+    writer.__exit__(None, None, None)
+    return out
+
+
 def main():
     dev = jax.devices()[0]
+    if 'ring' in sys.argv[1:]:
+        import faulthandler
+        faulthandler.dump_traceback_later(700, exit=True)
+        out = {'device': {'platform': dev.platform,
+                          'kind': dev.device_kind}, 'reps': REPS,
+               'ring_probe': ring_probe('beside' in sys.argv[1:])}
+        os.makedirs('chiprun_out', exist_ok=True)
+        with open(os.path.join('chiprun_out', 'h2d_probe_ring.json'),
+                  'w') as f:
+            json.dump(out, f)
+        print(json.dumps(out))
+        return 0
     only = [a for a in sys.argv[1:] if a in GULPS]
     with_beside = 'beside' in sys.argv[1:]
     out = {'device': {'platform': dev.platform, 'kind': dev.device_kind},
