@@ -22,6 +22,10 @@ occupancy) into a plain dict, and two exporters publish it:
   ``_count``), ring occupancy becomes a gauge.
 
 ``BF_METRICS_INTERVAL`` sets the publish period (seconds, default 5).
+The publisher's thread is also who samples the process's CPU by
+thread (:mod:`~bifrost_tpu.telemetry.threadcpu`), once every
+``CPU_SAMPLE_S``: the snapshot's ``threads`` section and the
+``bf_thread_*`` series are the newest of those readings by family.
 Everything here is read-only over the live metric state; a publisher
 failure never propagates into the pipeline.
 """
@@ -30,13 +34,17 @@ from __future__ import annotations
 
 import os
 import threading
+import time
 
-from . import counters, histograms, spans
+from . import counters, histograms, spans, threadcpu
 
 __all__ = ['snapshot', 'write_prometheus', 'prometheus_text',
            'MetricsPublisher', 'RateTracker']
 
 DEFAULT_INTERVAL = 5.0
+#: seconds between two readings of the process's threads
+#: (``threadcpu.sample``) by a running publisher: 0.2 ms of CPU each
+CPU_SAMPLE_S = 1.0
 
 
 class RateTracker(object):
@@ -66,7 +74,6 @@ class RateTracker(object):
         ``counts`` is a counters.snapshot() dict; ``hists`` an optional
         histograms.snapshot() dict (count/sum deltas — e.g. the
         send-stall seconds accrued per wall second)."""
-        import time
         now = time.monotonic()
         out = {'dt': None, 'counters': {}, 'histograms': {}}
         hstate = {name: (h.get('count', 0), h.get('sum', 0.0))
@@ -202,6 +209,21 @@ def _mesh_summary(counts):
     return out
 
 
+def _threads_section():
+    """The newest reading of the process's CPU by thread family
+    (``threadcpu``: a running publisher's last sample, else one taken
+    now), cumulative seconds; {} without ``/proc``."""
+    reading = threadcpu.newest(max_age_s=2 * CPU_SAMPLE_S) or \
+        threadcpu.read()
+    if reading is None:
+        return {}
+    return {'clock': reading['clock'],
+            'process_cpu_s': reading['process_cpu_s'],
+            'steal_s': reading['steal_s'],
+            'throttled_s': reading['throttled_s'],
+            'families': threadcpu.by_family(reading)}
+
+
 def snapshot(pipeline=None, rates=False):
     """The unified metrics snapshot::
 
@@ -213,6 +235,9 @@ def snapshot(pipeline=None, rates=False):
          'tenants':    {tenant_id: {state,health,gulps,bytes,
                         quota_shed_*,ring_shed_*,slo,...}},
          'scheduler':  {placements,migrations,replacements,...},
+         'threads':    {clock, process_cpu_s, steal_s, throttled_s,
+                        families: {family: {cpu_s, runq_s, threads,
+                                            named}}},
          'rates':      {dt, counters: {name: per_s},
                         histograms: {name: {count_per_s, sum_per_s}}}}
 
@@ -256,6 +281,7 @@ def snapshot(pipeline=None, rates=False):
         'mesh': _mesh_summary(counts),
         'tenants': _tenant_section(),
         'scheduler': _scheduler_section(),
+        'threads': _threads_section(),
         'identity': identity,
     }
     if rates:
@@ -366,6 +392,25 @@ def prometheus_text(snap=None):
         lines.append('bifrost_tpu_tenant_health{tenant="%s",'
                      'state="%s"} 1' % (label,
                                         _esc(d.get('health', '?'))))
+    # CPU by thread family (telemetry.threadcpu): cumulative seconds
+    # on a CPU and runnable but waiting for one, from the kernel's
+    # scheduler clock; the machine's steal and the cgroup's throttle
+    threads = snap.get('threads') or {}
+    fams = threads.get('families', {})
+    if fams:
+        lines.append('# TYPE bf_thread_cpu_seconds_total counter')
+        lines.append('# TYPE bf_thread_runq_seconds_total counter')
+    for series, key in (('bf_thread_cpu_seconds_total', 'cpu_s'),
+                        ('bf_thread_runq_seconds_total', 'runq_s')):
+        for name in sorted(fams):
+            if fams[name].get(key) is not None:
+                lines.append('%s{family="%s"} %.6f'
+                             % (series, _esc(name), fams[name][key]))
+    for series, key in (('bf_cpu_steal_seconds_total', 'steal_s'),
+                        ('bf_cpu_throttled_seconds_total', 'throttled_s')):
+        if threads.get(key) is not None:
+            lines.append('# TYPE %s counter' % series)
+            lines.append('%s %.6f' % (series, threads[key]))
     return '\n'.join(lines) + '\n'
 
 
@@ -432,8 +477,18 @@ class MetricsPublisher(threading.Thread):
             self._fleet = None
 
     def run(self):
-        while not self._stop_event.wait(self.interval):
-            self.publish()
+        # the pipeline's one housekeeping thread also keeps the series
+        # of CPU readings by thread: one as it starts, one every
+        # CPU_SAMPLE_S, one as it stops
+        threadcpu.sample()
+        due = time.monotonic() + self.interval
+        while not self._stop_event.wait(
+                min(CPU_SAMPLE_S, max(due - time.monotonic(), 0.0))):
+            if time.monotonic() >= due:
+                self.publish()
+                due = time.monotonic() + self.interval
+            else:
+                threadcpu.sample()
         self.publish()               # final snapshot at shutdown
 
     # -- publishing --------------------------------------------------------
@@ -446,6 +501,7 @@ class MetricsPublisher(threading.Thread):
 
     def publish(self):
         try:
+            threadcpu.sample()
             snap = snapshot(self.pipeline, rates=self._rates)
             self._note_watermarks(snap)
             self._publish_proclog(snap)

@@ -9,10 +9,12 @@ dispatch-ahead wait (``pipeline.py``), ring reserve/acquire blocked
 time (``ring.py``, both cores), the parts of H2D and D2H
 (``xfer.py``), compilations and full garbage collections (this
 module) -- records one COMPLETE span (name, category, start, duration,
-args) into a bounded per-thread buffer.  Recording has no switch: the
-site already takes two ``perf_counter`` stamps for its always-on
-histogram, and appending one tuple to the thread's ``deque`` (no lock:
-the buffer is ``threading.local``) is the whole added cost, 1-2 us.
+args, CPU time) into a bounded per-thread buffer.  Recording has no
+switch: the site already takes two ``perf_counter`` stamps for its
+always-on histogram, and two readings of the thread's own CPU clock
+(``time.thread_time()``, 0.4 us each) and appending one tuple to the
+thread's ``deque`` (no lock: the buffer is ``threading.local``) are the
+whole added cost, 2-3 us.
 
 One call feeds both sinks::
 
@@ -21,10 +23,15 @@ One call feeds both sinks::
 
 takes the two stamps once and records the histogram and the span with
 the same duration, so a site's span durations sum to its histogram's
-sum.  Parentage is by nesting on the thread (a child lies inside its
-parent's interval; self time = duration - children); across threads a
-gulp is followed by ``seq``/``gulp`` (compute spans) and ``frame``
-(ring spans: first frame of the span in its sequence).
+sum.  An event is ``(name, cat, ts_us, dur_us, args, cpu_us)``:
+``cpu_us`` is what the thread spent ON a CPU inside the span, so a
+``wait`` span says whether its thread slept (near 0) or spun (near
+``dur_us``); None where the interval is not one thread's time (an
+event stamped after the fact through :func:`record`, a
+:class:`interval`).  Parentage is by nesting on the thread (a child
+lies inside its parent's interval; self time = duration - children);
+across threads a gulp is followed by ``seq``/``gulp`` (compute spans)
+and ``frame`` (ring spans: first frame of the span in its sequence).
 
 Consumers of the buffers:
 
@@ -62,8 +69,8 @@ from collections import deque
 
 from . import counters, histograms
 
-__all__ = ['trace_file', 'timed', 'record', 'now_us', 'origin_s',
-           'configure', 'reconfigure', 'watch_jax',
+__all__ = ['trace_file', 'timed', 'interval', 'record', 'now_us',
+           'origin_s', 'configure', 'reconfigure', 'watch_jax',
            'export', 'export_if_configured', 'flight_record',
            'flight_events', 'prune_dead_buffers', 'reset', 'events',
            'dropped_spans', 'dropped_by_thread',
@@ -77,6 +84,7 @@ DEFAULT_BUFFER = 16384
 MAX_BUFFERS = 512
 
 _perf_counter = time.perf_counter
+_thread_time = time.thread_time
 _t0 = _perf_counter()
 
 _config_lock = threading.Lock()
@@ -233,12 +241,13 @@ def _drain(buf):
     return out
 
 
-def record(name, cat, ts_us, dur_us, args=None):
+def record(name, cat, ts_us, dur_us, args=None, cpu_us=None):
     """Record one complete span from span-clock stamps
     (:func:`now_us`): for events whose interval is known only after
-    the fact (a listener's callback, a synthesized member span).
+    the fact (a listener's callback, a synthesized member span), which
+    as a rule have no CPU time to give (``cpu_us`` None).
     Instrumentation sites use :class:`timed`."""
-    _append((name, cat, ts_us, dur_us, args))
+    _append((name, cat, ts_us, dur_us, args, cpu_us))
 
 
 def prune_dead_buffers():
@@ -316,9 +325,10 @@ class timed(object):
     failures still produce a complete, correctly nested event, which
     is what makes the flight recorder trustworthy around crashes.
     ``args`` may be set or added to inside the block (a ring span
-    learns its ``frame`` only once the call returns)."""
+    learns its ``frame`` only once the call returns).  Beside each
+    stamp it reads the thread's CPU clock: the event's ``cpu_us``."""
 
-    __slots__ = ('name', 'cat', 'hist', 'args', 't0')
+    __slots__ = ('name', 'cat', 'hist', 'args', 't0', 'c0')
 
     def __init__(self, name, cat='', hist=None, **args):
         self.name = name
@@ -327,25 +337,42 @@ class timed(object):
         self.args = args or None
 
     def __enter__(self):
+        self.c0 = _thread_time()
         self.t0 = _perf_counter()
         return self
 
     def __exit__(self, *exc):
         t0 = self.t0
         dt = _perf_counter() - t0
+        c0 = self.c0
+        cpu_us = None if c0 is None else (_thread_time() - c0) * 1e6
         hist = self.hist
         if hist is not None:
             if hist.__class__ is str:
                 hist = histograms.get_or_create(hist, unit='s')
             hist.record(dt)
         _append((self.name, self.cat, (t0 - _t0) * 1e6, dt * 1e6,
-                 self.args))
+                 self.args, cpu_us))
         return False
+
+
+class interval(timed):
+    """A :class:`timed` whose two ends may lie on different threads,
+    or with the thread's other work between them (``h2d.hold``: from
+    one transfer's ``device_put`` to the release a later call makes):
+    an interval, not a thread's time, so its ``cpu_us`` is None."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        self.c0 = None
+        self.t0 = _perf_counter()
+        return self
 
 
 def events():
     """Snapshot of all recorded events as
-    ``[(thread_name, (name, cat, ts_us, dur_us, args)), ...]``."""
+    ``[(thread_name, (name, cat, ts_us, dur_us, args, cpu_us)), ...]``."""
     with _buffers_lock:
         bufs = [(t.name, b) for t, b, _d in _buffers]
     out = []
@@ -387,9 +414,12 @@ def export(path=None):
         first = False
         head = ',{"name":%s,"cat":%s,"ph":"X","pid":' + str(pid) + \
             ',"tid":' + str(tid) + ',"ts":%.3f,"dur":%.3f'
-        for name, cat, ts, dur, args in _drain(buf):
+        for name, cat, ts, dur, args, cpu in _drain(buf):
             chunks.append(head % (dumps(name), dumps(cat or 'bf'),
                                   ts, dur))
+            if cpu is not None:
+                # the trace-event format's own thread-clock duration
+                chunks.append(',"tdur":%.3f' % cpu)
             if args:
                 chunks.append(',"args":%s}' % dumps(args))
             else:
@@ -453,7 +483,7 @@ def flight_record(per_thread=32):
         lines.append('  NOTE: %d span(s) dropped to buffer overflow '
                      '(BF_SPAN_BUFFER saturation) — the oldest '
                      'history below is incomplete' % dropped)
-    for ts, tname, (name, cat, _ts, dur, args) in merged:
+    for ts, tname, (name, cat, _ts, dur, args, _cpu) in merged:
         extra = ' %r' % (args,) if args else ''
         lines.append('  t=%12.3fms +%10.3fms  [%-7s] %-24s %s%s'
                      % (ts / 1e3, dur / 1e3, (cat or 'bf')[:7],
@@ -473,7 +503,7 @@ def flight_events(per_thread=64):
         bufs = [(t.name, b) for t, b, _d in _buffers]
     out = []
     for tname, buf in bufs:
-        for name, cat, ts, dur, args in _drain(buf)[-per_thread:]:
+        for name, cat, ts, dur, args, _cpu in _drain(buf)[-per_thread:]:
             out.append([tname, name, cat or 'bf',
                         round(ts, 3), round(dur, 3), args])
     out.sort(key=lambda e: e[3])
@@ -508,7 +538,7 @@ def _on_jax_duration(event, duration_secs, **kwargs):
         histograms.get_or_create('jit.compile_s', unit='s') \
             .record(duration_secs)
         counters.inc('jit.compiles')
-    _append(('jit.compile', 'jit', now_us() - dur, dur, args))
+    _append(('jit.compile', 'jit', now_us() - dur, dur, args, None))
 
 
 def watch_jax():
@@ -545,7 +575,8 @@ def _on_gc(phase, info):
         t0 = getattr(_gc_tls, 't0', None)
         if t0 is not None:
             _gc_tls.t0 = None
-            _append(('host.gc', 'host', t0, now_us() - t0, {'gen': 2}))
+            _append(('host.gc', 'host', t0, now_us() - t0, {'gen': 2},
+                     None))
 
 
 gc.callbacks.append(_on_gc)

@@ -613,8 +613,9 @@ class _Hold(object):
 
     def shipped(self):
         """``device_put`` has returned: the hold's span starts."""
-        self._timer = _timed('h2d.hold', 'wait', 'xfer.h2d_hold_s',
-                             bytes=self.nbytes)
+        # an interval, not a thread's time: a later call releases it
+        self._timer = _obs()[1].interval(
+            'h2d.hold', 'wait', 'xfer.h2d_hold_s', bytes=self.nbytes)
         self._timer.__enter__()
 
     def consumed(self):
@@ -1055,7 +1056,8 @@ def _complete_fills(work, fills, stop):
             fill = claim()
         if fill is not None:
             ahead.append(fill)
-            fill.cut_up()
+            with _timed('d2h.cut', 'xfer', bytes=fill.nbytes):
+                fill.cut_up()
 
     while True:
         if ahead:
@@ -1063,11 +1065,15 @@ def _complete_fills(work, fills, stop):
         else:
             with work:
                 fill = claim()
-                while fill is None:
-                    if stop.is_set():
-                        return
-                    work.wait()
-                    fill = claim()
+                if fill is None:
+                    # the thread's rest between products: a span, so
+                    # that it is nobody's unexplained stretch
+                    with _timed('d2h.idle', 'wait'):
+                        while fill is None:
+                            if stop.is_set():
+                                return
+                            work.wait()
+                            fill = claim()
         fill.complete('xfer.fills_by_worker', claim_next)
         del fill           # hold no product while idle
 

@@ -188,7 +188,8 @@ def test_spans_nest_and_close_under_faults(monkeypatch, tmp_path):
                     faults.fire('xfer.h2d')
     evs = [ev for _t, ev in spans.events() if ev[1] == 'test']
     assert [ev[0] for ev in evs] == ['inner', 'outer']  # close order
-    (iname, _c, its, idur, _a), (oname, _c2, ots, odur, oargs) = evs
+    (iname, _c, its, idur, _a), (oname, _c2, ots, odur, oargs) = \
+        (ev[:5] for ev in evs)
     # inner nests inside outer despite the exception exit
     assert ots <= its
     assert its + idur <= ots + odur + 1.0   # 1us slack
@@ -235,7 +236,7 @@ def test_snapshot_merges_counters_histograms_rings():
     snap = bf.telemetry.snapshot()
     assert set(snap) == {'counters', 'gauges', 'histograms', 'rings',
                          'devices', 'mesh', 'tenants', 'scheduler',
-                         'identity'}
+                         'threads', 'identity'}
     # every ring's capacity, and their sum a space (depth by bytes)
     caps = {k: v for k, v in snap['gauges'].items()
             if k.endswith('.capacity_bytes')}

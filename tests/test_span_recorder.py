@@ -390,3 +390,117 @@ def test_block_loop_stamps_are_on_the_span_clock():
                  if ev[0] == name + '.on_data') * 1e-6
     assert gulp['count'] == 6
     assert inside <= gulp['sum']
+
+
+# -- every span's CPU, from its thread's clock ------------------------------
+
+def _burn(seconds):
+    end = time.perf_counter() + seconds
+    while time.perf_counter() < end:
+        pass
+
+
+@pytest.mark.parametrize('what,low,high', [
+    (_burn, 0.8, 1.2),            # a spin: on a CPU for all of it
+    (time.sleep, 0.0, 0.05),      # a sleep: for none of it
+], ids=['spin', 'sleep'])
+def test_cpu_us_tells_a_spin_from_a_sleep(what, low, high):
+    # a spin on a machine that is short of cores is kept off them for
+    # a part of its span: three tries at one that was not
+    for attempt in range(3):
+        spans.reset()
+        c0 = time.thread_time()
+        with spans.timed('t.cpu', 'test'):
+            what(0.1)
+        spent_us = (time.thread_time() - c0) * 1e6
+        (ev,) = [ev for _t, ev in spans.events() if ev[0] == 't.cpu']
+        assert len(ev) == 6 and ev[3] >= 100e3
+        # the thread's own clock, read by the span as by anybody
+        assert ev[5] <= spent_us + 1.0 and ev[5] >= spent_us - 2000.0
+        if low * ev[3] <= ev[5] <= high * ev[3]:
+            return
+    raise AssertionError('cpu_us %.0f of dur_us %.0f' % (ev[5], ev[3]))
+
+
+def test_fields_0_to_4_are_where_they_were():
+    h = histograms.get_or_create('t.fields_s', unit='s')
+    t0 = spans.now_us()
+    with spans.timed('t.fields', 'test', hist=h, k=1):
+        pass
+    (ev,) = [ev for _t, ev in spans.events() if ev[0] == 't.fields']
+    name, cat, ts_us, dur_us, args = ev[:5]
+    assert (name, cat, args) == ('t.fields', 'test', {'k': 1})
+    assert t0 <= ts_us <= spans.now_us() and dur_us >= 0
+    assert dur_us * 1e-6 == pytest.approx(h.total, rel=1e-9)
+    assert ev[5] >= 0
+
+
+def test_a_recorded_event_has_no_cpu_time_unless_given():
+    spans.record('t.after', 'test', 1.0, 2.0)
+    spans.record('t.after_args', 'test', 3.0, 4.0, {'k': 2})
+    spans.record('t.after_cpu', 'test', 5.0, 6.0, cpu_us=1.5)
+    got = {ev[0]: ev for _t, ev in spans.events() if ev[1] == 'test'}
+    assert got['t.after'] == ('t.after', 'test', 1.0, 2.0, None, None)
+    assert got['t.after_args'][4:] == ({'k': 2}, None)
+    assert got['t.after_cpu'][5] == 1.5
+    import gc
+    gc.collect()
+    (ev,) = [ev for _t, ev in spans.events() if ev[0] == 'host.gc']
+    assert ev[5] is None
+
+
+def test_an_interval_is_not_a_thread_s_time():
+    """``h2d.hold`` starts in one call and ends in a later one, maybe
+    another thread's: wall time and a histogram, no CPU time."""
+    h = histograms.get_or_create('t.hold_s', unit='s')
+    timer = spans.interval('t.hold', 'wait', h, bytes=8)
+    timer.__enter__()
+    _burn(0.01)
+    t = threading.Thread(target=timer.__exit__, args=(None, None, None),
+                         name='the-releaser')
+    t.start()
+    t.join(10)
+    (thread, ev), = [(t, ev) for t, ev in spans.events()
+                     if ev[0] == 't.hold']
+    assert thread == 'the-releaser'
+    assert ev[3] >= 10e3 and ev[4] == {'bytes': 8} and ev[5] is None
+    assert h.count == 1
+
+
+def test_the_chrome_export_has_tdur_where_a_span_has_cpu_time(tmp_path):
+    with spans.timed('t.export', 'test'):
+        _burn(0.005)
+    spans.record('t.export_after', 'test', 1.0, 2.0, {'k': 1})
+    data = json.loads(open(spans.export(str(tmp_path / 't.json'))).read())
+    evs = {e['name']: e for e in data['traceEvents'] if e.get('ph') == 'X'}
+    assert evs['t.export']['tdur'] == pytest.approx(
+        evs['t.export']['dur'], rel=0.3)
+    assert 'tdur' not in evs['t.export_after']
+    assert evs['t.export_after']['args'] == {'k': 1}
+    # the wire form of the fleet plane stays as it was
+    assert all(len(e) == 6 and not isinstance(e[5], float)
+               for e in spans.flight_events())
+    assert 't.export' in spans.flight_record()
+
+
+def test_the_completion_thread_s_rest_is_a_span_and_costs_nothing():
+    """``d2h.idle``: the completion thread's wait for a fill to claim,
+    asleep on the engine's condition (``d2h.cut``, its cutting up of
+    the product it lands next: tests/test_xfer_async.py)."""
+    eng = xfer.TransferEngine()
+    data = [np.full((8, 16), float(k), np.float32) for k in range(3)]
+    outs = [np.zeros_like(d) for d in data]
+    for d, out in zip(data, outs):
+        eng.host_fill(eng.to_device(d), 'f32', out).wait()
+        time.sleep(0.05)           # the completion threads rest
+    eng.drain(True)
+    eng.close()
+    for d, out in zip(data, outs):
+        np.testing.assert_array_equal(out, d)
+    idle = [(t, ev) for t, ev in spans.events() if ev[0] == 'd2h.idle']
+    assert idle and all(t.startswith('xfer-d2h-') and ev[1] == 'wait'
+                        for t, ev in idle)
+    assert sum(ev[3] for _t, ev in idle) >= 50e3
+    assert sum(ev[5] for _t, ev in idle) <= \
+        0.2 * sum(ev[3] for _t, ev in idle)
+    assert 'xfer.d2h_idle_s' not in histograms.snapshot()

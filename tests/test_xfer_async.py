@@ -1193,6 +1193,14 @@ def test_the_next_product_is_cut_up_before_the_last_one_is_announced(
     assert counters.get('xfer.fills_by_worker') == 2
     assert counters.get('xfer.d2h_cutup_bytes') == a.nbytes + b.nbytes
     assert seen.live() == 0
+    # the completion thread's cutting of the product it lands next is
+    # a span of its own, and its rest before the first product another
+    mine = {ev[0]: (t, ev) for t, ev in spans.events()
+            if ev[0] in ('d2h.cut', 'd2h.idle')}
+    thread, cut = mine['d2h.cut']
+    assert thread == 'xfer-d2h-0' and cut[1] == 'xfer'
+    assert cut[4] == {'bytes': outs[1].nbytes}
+    assert mine['d2h.idle'][1][1] == 'wait'
 
 
 def test_fault_in_the_middle_of_a_cut_up_product_poisons_the_ring(

@@ -24,11 +24,13 @@ one transfer at a time, as
 For each: seconds from the call to ``block_until_ready`` (the best
 and the median of ``REPS``), the process's CPU seconds over the same
 stretch (``getrusage``: microseconds), those seconds by thread family
-(``tools/thread_cpu.py``'s reading of ``/proc/self/task``, in ticks of
-10 ms: the mean over all ``REPS``), the layout the runtime gave the
-device array, and whether the bytes read back are the gulp's.  One
-JSON line on standard output, the line so far on standard error after
-every form.  Shape names on the command line run those alone.
+(``bifrost_tpu.telemetry.threadcpu``'s reading of ``/proc/self/task``:
+the scheduler's clock, nanoseconds, or ticks of 10 ms where the kernel
+keeps no ``schedstat``; the mean over all ``REPS``), the layout the
+runtime gave the device array, and whether the bytes read back are the
+gulp's.  One JSON line on standard output, the line so far on standard
+error after every form.  Shape names on the command line run those
+alone.
 
 ``beside`` on the command line adds, for each form, what the served
 cells are bound by: a thread that copies the gulp into a second host
@@ -73,8 +75,9 @@ import time
 import jax
 import numpy as np
 
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-from thread_cpu import family, threads        # noqa: E402
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+from bifrost_tpu.telemetry.threadcpu import family, read   # noqa: E402
 
 GULPS = {'gpuspec': (16384, 2, 4096),
          'xcorr': (512, 1024, 256, 2),
@@ -126,7 +129,7 @@ def _layout(arr):
 
 def put_once(host):
     """One transfer: wall and CPU seconds to ready, CPU by thread."""
-    before, cpu0 = threads(), _cpu_s()
+    before, cpu0 = read()['threads'], _cpu_s()
     t0 = time.perf_counter()
     arr = jax.device_put(host)
     t_put = time.perf_counter() - t0
@@ -134,10 +137,11 @@ def put_once(host):
     wall = time.perf_counter() - t0
     cpu = _cpu_s() - cpu0
     by = {}
-    for tid, (name, s1, _f) in threads().items():
-        s0 = before.get(tid, (name, 0.0, 0))[1]
+    for tid, (name, named, s1, _q) in read()['threads'].items():
+        s0 = before.get(tid, (name, named, 0.0, None))[2]
         if s1 - s0 > 0:
-            by[family(name)] = by.get(family(name), 0.0) + s1 - s0
+            fam = family(name, named)
+            by[fam] = by.get(fam, 0.0) + s1 - s0
     return arr, {'wall_s': wall, 'put_returns_s': t_put, 'cpu_s': cpu,
                  'cpu_s_by_thread': by}
 
@@ -196,8 +200,6 @@ def source_alone(gulp):
 def ring_probe(with_beside):
     """The ``gpuspec`` gulp from a host ring's span, staged and
     direct, through the package's engine."""
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
     from bifrost_tpu import xfer
     from bifrost_tpu.ring import Ring
     from bifrost_tpu.telemetry import counters, histograms
