@@ -116,6 +116,11 @@ Observability counters (docs/observability.md; complemented by
                                            before the first piece was
                                            taken (LARGE, real-worded:
                                            docs/transfer.md)
+- ``xfer.d2h_flat_bytes``                  bytes of pieces that left
+                                           the device as one axis of
+                                           uint32 words in the host's
+                                           byte order (docs/transfer.md,
+                                           "One axis home")
 - ``correlate.integrations``               integrations a CorrelateBlock
                                            emitted
 - ``correlate.acc_in_place``               gulps it integrated into

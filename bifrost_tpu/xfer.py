@@ -67,12 +67,20 @@ Device to host, a product that crosses whole crosses as it is: the
 local v5e runtime transfers complex64 bit-exactly both ways
 (chip_smoke.py fact ii, PR 21), and complex128 exists on the CPU
 backend alone, where ``np.asarray`` hands it over as it is.  The
-pieces of a complex product do not: the runtime interleaves a
-complex64 array on the host, one transfer at a time, at 2.5 GB/s, so
-the cut program interleaves on the device and each piece leaves as
-rows of 32-bit words, re and im element by element, which the host
-sees as complex again with a view (:func:`_pairs`; PERF.md section 6,
-PR 29).  A product that reaches the engine as the two float32 planes
+pieces of a product do not: each leaves the device as ONE axis of
+uint32 words in the host's byte order (:func:`_words`), which the
+device lays out as the host holds it, so that the runtime's threads
+move it with no pass of their own; from tiles they lay a piece out in
+the host's order byte by byte, about 0.4 CPU-s a GB (docs/transfer.md,
+"One axis home"; PERF.md section 6, PR 38).  The host sees the
+product's dtype and shape again with a view.  A piece of words wider
+than four bytes (the CPU backend's float64) or of bytes that are no
+whole number of words leaves as rows (:func:`_as_rows`).  The runtime
+also interleaves a complex64 array on the host, one transfer at a
+time, at 2.5 GB/s, so the cut program interleaves a complex piece on
+the device into its (re, im) pairs of 32-bit words, element by
+element, before they go to one axis (:func:`_pairs`; PERF.md section
+6, PR 29).  A product that reaches the engine as the two float32 planes
 it was computed in (:class:`~bifrost_tpu.devrep.ComplexPlanes`: a
 correlator's, through ``ReadSpan.planes``) is cut from the planes, so
 no program between the integration and the host has a complex64
@@ -213,30 +221,34 @@ def _piece_plan(arr):
 #: lane is not the host's: a (1, 8, 256, 2, 256, 2) complex64 piece
 #: arrives with strides (.., 8192, 4096, 8, 2048), and copying it into
 #: a span is a gather at 2.5 GB/s where a row-major piece copies at
-#: 10 (my chip runs, PERF.md section 6, PR 28).  Such pieces are cut as
-#: rows, ``(step, everything else)``: the same bytes in the same
-#: order, relaid on the device.  An axis of one before the last says
-#: nothing about order either: of a (1024, 64, 1, 864) u8 product the
-#: compiler lays pieces of 256 rows out with the FIRST axis along the
-#: lanes (``{0,3,2,1}``; pieces of 296 rows it happened to leave in the
-#: host's order), and the completion thread then spent 105 ms a product
-#: taking them apart where rows cost it 10 (PR 35).
+#: 10 (my chip runs, PERF.md section 6, PR 28).  A piece that cannot
+#: leave as words (:func:`_words`) is cut as rows, ``(step, everything
+#: else)``: the same bytes in the same order, relaid on the device.  An
+#: axis of one before the last says nothing about order either: of a
+#: (1024, 64, 1, 864) u8 product the compiler lays pieces of 256 rows
+#: out with the FIRST axis along the lanes (``{0,3,2,1}``; pieces of
+#: 296 rows it happened to leave in the host's order), and the
+#: completion thread then spent 105 ms a product taking them apart
+#: where rows cost it 10 (PR 35).
 _LANE = 128
 
 
 def _as_rows(shape):
-    """Whether pieces of a product of ``shape`` are cut as rows."""
+    """Whether pieces of a product of ``shape`` that cannot leave as
+    words are cut as rows."""
     return shape[-1] < _LANE or (len(shape) > 2 and shape[-2] == 1)
 _cut_fn = None
 
 
 def _cut(arr, start, axis, step, count, rows):
     """``count`` pieces of ``step`` indices along ``axis`` from index
-    ``start`` on, as ``(step, the rest)`` with ``rows``: one program
-    on the device, whose start is an argument, so that one compilation
-    serves every group of every product of a shape.  A piece of a
-    complex product leaves as real rows (:func:`_pairs`): of a
-    complex64 array its real and imaginary parts, of planes
+    ``start`` on: one program on the device, whose start is an
+    argument, so that one compilation serves every group of every
+    product of a shape.  Each piece leaves as one axis of uint32 words
+    in the host's byte order where it can (:func:`_words`), else as
+    ``(step, the rest)`` with ``rows``.  A piece of a complex product
+    is its real (re, im) pairs first (:func:`_pairs`): of a complex64
+    array its real and imaginary parts, of planes
     (``devrep.ComplexPlanes``) their two slices, and then the program
     has no complex type in it."""
     global _cut_fn
@@ -244,33 +256,71 @@ def _cut(arr, start, axis, step, count, rows):
         import jax
         from jax import lax
 
+        def leave(p, rows=0):
+            """``rows`` rows first (else as it is), then one axis of
+            words where it can be."""
+            p = p.reshape(rows, -1) if rows else p
+            words = _words(p)
+            return p if words is None else words
+
         def cut(planes, start, axis, step, count, rows):
             pieces = ([lax.dynamic_slice_in_dim(x, start + j * step,
                                                 step, axis)
                        for x in planes] for j in range(count))
             if len(planes) == 2:
-                return tuple(_pairs(re, im) for re, im in pieces)
+                return tuple(leave(_pairs(re, im)) for re, im in pieces)
             if planes[0].dtype.kind == 'c':
-                return tuple(_pairs(p.real, p.imag) for p, in pieces)
-            return tuple(p.reshape(step, -1) if rows else p
-                         for p, in pieces)
+                return tuple(leave(_pairs(p.real, p.imag))
+                             for p, in pieces)
+            return tuple(leave(p, step if rows else 0) for p, in pieces)
         _cut_fn = jax.jit(cut, static_argnums=(2, 3, 4, 5))
     from .planes import device_arrays
     return _cut_fn(device_arrays(arr), start, axis, step, count, rows)
 
 
+def _words(x):
+    """A piece under jit as ONE axis of uint32 words in the host's byte
+    order, or None where it cannot be: words wider than four bytes
+    (float64, complex pairs of it: the CPU backend's alone), a bool, or
+    bytes that are no whole number of words.  A narrower dtype is
+    folded to ``(-1, 4 // itemsize)`` first, so a word holds the bytes
+    the host holds there.  The device lays one axis out as the host
+    holds it, and the runtime then moves the piece with no pass of its
+    own (docs/transfer.md, "One axis home"): from tiles it lays a piece
+    out in the host's order byte by byte on its own threads, about
+    0.4 CPU-s a GB, the host's largest cost where products are as large
+    as gulps (PERF.md section 6, PR 38).  Moves alone, so every word
+    leaves as it was."""
+    import jax.numpy as jnp
+    from jax import lax
+    size = x.dtype.itemsize
+    if size > 4 or x.dtype == jnp.bool_ or x.size * size % 4:
+        return None
+    if size < 4:
+        # the fold of the last axis, where it divides, leaves the
+        # compiler the tiles it has (the beamformer's u8 rows: the
+        # piece widened to u32 for a moment, four times its bytes);
+        # one of the whole piece lays the bytes of a word along the
+        # lanes (32 times them: tests/test_tpu_compile.py)
+        fold = 4 // size
+        x = x.reshape(x.shape[:-1] + (-1, fold) if x.shape[-1] % fold == 0
+                      else (-1, fold))
+    return lax.bitcast_convert_type(x, jnp.uint32).reshape(-1)
+
+
 def _pairs(re, im):
     """The two planes of a complex piece, under jit, as real rows with
     re and im interleaved element by element: byte for byte what the
-    host calls complex, so that the runtime moves plain 32-bit words
-    and the host takes the piece with a view
-    (``_PieceFuture._take_group``).  Moves alone, never arithmetic,
+    host calls complex, so that the host takes the piece with a view
+    (``_PieceFuture._take_group``), and then one axis of those words
+    (:func:`_cut`).  Moves alone, never arithmetic,
     so NaN payloads, -0.0 and infinities
     arrive as they left: float32 planes are stacked as the words they
     are, because libtpu's compiler joins two float arrays with a
     ``maximum`` over ``-inf`` pads, which no unsigned word minds
     (float64 planes, which only the CPU backend has, as they are:
-    there a stack is a copy).  A row is the fewest trailing axes that
+    there a stack is a copy, and the rows leave as they are).  A row is
+    the fewest trailing axes that
     fill a lane, each plane folded to ``(rows, those axes)`` before
     the two are stacked: XLA then interleaves inside the tiles the
     planes already have (tools/d2h_probe.py on the chip, the sixteen
@@ -288,7 +338,11 @@ def _pairs(re, im):
     planes = [p.reshape((-1,) + tail) for p in (re, im)]
     if planes[0].dtype == jnp.float32:
         planes = [lax.bitcast_convert_type(p, jnp.uint32) for p in planes]
-    return jnp.stack(planes, -1).reshape(-1, 2 * int(np.prod(tail)))
+    # made as rows whatever follows: asked for one axis at once, libtpu's
+    # compiler interleaves with the (re, im) axis minor in tiles of 128
+    # lanes, 64 times the piece in temporaries (tests/test_tpu_compile.py)
+    return lax.optimization_barrier(
+        jnp.stack(planes, -1).reshape(-1, 2 * int(np.prod(tail))))
 
 
 def _counters():
@@ -729,8 +783,9 @@ class _PieceFuture(TransferFuture):
     for :class:`HostFill`: :meth:`land` hands each group of host
     pieces, with the place of each in the product, to the caller's
     ``put`` as it arrives, so the product is never whole on the host
-    outside its destination.  A complex product's pieces cross as
-    real (re, im) pairs (:func:`_cut`) and are seen as complex again
+    outside its destination.  Its pieces cross as one axis of uint32
+    words where they can, a complex product's as its real (re, im)
+    pairs (:func:`_cut`), and are seen as the product's dtype again
     here, with no pass over them, whether the product came as a
     complex64 array or as its two planes (``devrep.ComplexPlanes``).
     ``result()`` lands it into an array of its own.
@@ -788,6 +843,9 @@ class _PieceFuture(TransferFuture):
                 else (rows - self._row, 1)
             pieces = _cut(arr, self._row, self._axis, step, count,
                           _as_rows(self._shape))
+            _counters().inc('xfer.d2h_flat_bytes', sum(
+                int(p.nbytes) for p in pieces
+                if p.ndim == 1 and p.dtype == np.uint32))
             lead = (slice(None),) * self._axis
             self._ahead.append(
                 [(p, lead + (slice(self._row + j * step,
@@ -846,7 +904,8 @@ class _PieceFuture(TransferFuture):
     def _take_group(self, put):
         """The oldest group to ``put``, what is to be ahead of it cut
         and on its way first; its pieces leave the device as soon as
-        the host has them.  Real pairs are complex again by a view: no
+        the host has them.  A piece of words on one axis, and real
+        pairs, are the product's dtype and shape again by a view: no
         pass over them."""
         pieces, places = map(list, zip(*self._ahead.popleft()))
         self._hinted -= 1
@@ -1582,6 +1641,9 @@ class TransferEngine(object):
                       nbytes if arr.dtype.kind == 'c' else 0)
                 # and of those, the ones cut from planes
                 c.inc('xfer.d2h_plane_bytes', nbytes if planes else 0)
+                # the pieces that leave as one axis of words are counted
+                # as they are cut (``_PieceFuture._cut_ahead``)
+                c.inc('xfer.d2h_flat_bytes', 0)
                 # a LARGE product is cut in groups, and all of them
                 # when its landing starts where a cut costs its pieces
                 # alone: not from a complex64 array, which every cut

@@ -41,11 +41,24 @@ def _compiled_cut(one_chip, shapes, dtype, *static):
     return xfer._cut_fn.lower(args, 0, *static).compile()
 
 
+def _one_axis_of_words(text, count, nbytes):
+    """The program's ``count`` outputs are ONE axis of uint32 words of
+    ``nbytes`` each, laid out as the host holds them (``T(1024)``), and
+    no output packs sub-word elements (a tile with a second level,
+    ``(4,1)`` or ``(2,1)``)."""
+    root = [line for line in text.splitlines() if 'ROOT' in line][-1]
+    assert root.count('u32[%d]{0:T(1024)}' % (nbytes // 4)) == count
+    assert not re.search(r'T\(\d+(,\d+)?\)\(\d,1\)', root)
+
+
 @pytest.mark.parametrize('form', ['planes', 'complex64'])
 def test_cut_of_an_xcorr_product(one_chip, form):
-    """From planes: slices and one interleave, no temporary, no
-    complex type.  From complex64 (the control, and what a mesh-scoped
-    product still pays): the split of all 2.1 GB first."""
+    """From planes: slices and one interleave, each piece ONE axis of
+    uint32 words, no complex type, temporaries under one piece (the
+    interleave is made as rows first: asked for one axis at once, the
+    compiler put the (re, im) axis minor in tiles of 128 lanes, 64
+    times the piece).  From complex64 (the control, and what a
+    mesh-scoped product still pays): the split of all 2.1 GB first."""
     if form == 'planes':
         comp = _compiled_cut(one_chip, [_XCORR] * 2, np.float32,
                              1, 8, 8, True)
@@ -55,9 +68,10 @@ def test_cut_of_an_xcorr_product(one_chip, form):
     text, mem = comp.as_text(), comp.memory_analysis()
     split = len(re.findall(r'custom_call_target="X64(Split|Combine)',
                            text))
+    _one_axis_of_words(text, 8, 16 << 20)
     if form == 'planes':
         assert split == 0 and 'c64' not in text
-        assert mem.temp_size_in_bytes == 0
+        assert mem.temp_size_in_bytes < 16 << 20
     else:
         assert split >= 2
         assert mem.temp_size_in_bytes >= int(np.prod(_XCORR)) * 4
@@ -65,11 +79,32 @@ def test_cut_of_an_xcorr_product(one_chip, form):
 
 
 def test_cut_of_a_gpuspec_product_is_slices_alone(one_chip):
+    """Sixteen pieces of 1024 frames, each ONE axis of uint32 words: the
+    slices and the relayout of each into the host's order, a copy on
+    the device, and nothing else; no custom call, no temporary."""
     comp = _compiled_cut(one_chip, [_GPUSPEC], np.float32,
                          0, 1024, 16, False)
     text, mem = comp.as_text(), comp.memory_analysis()
     assert 'custom-call' not in text and mem.temp_size_in_bytes == 0
-    assert text.count('dynamic-slice') >= 16
+    assert text.count('dynamic-slice') >= 16 and 'c64' not in text
+    _one_axis_of_words(text, 16, 16 << 20)
+
+
+def test_cut_of_a_gpuspec_hsr_product(one_chip):
+    """The 1 GiB (1, 64, 4, 2^20) product in pieces of one coarse
+    channel, eight to a program: each ONE axis of uint32 words, no
+    custom call, no temporary."""
+    class Product(object):
+        shape, nbytes, sharding = _HSR_PRODUCT, 1 << 30, \
+            type('S', (), {'device_set': {0}})
+    assert xfer._piece_plan(Product) == (1, 1)
+    assert not xfer._as_rows(_HSR_PRODUCT)
+    comp = _compiled_cut(one_chip, [_HSR_PRODUCT], np.float32,
+                         1, 1, 8, False)
+    text, mem = comp.as_text(), comp.memory_analysis()
+    assert 'custom-call' not in text and mem.temp_size_in_bytes == 0
+    assert 'c64' not in text
+    _one_axis_of_words(text, 8, 16 << 20)
 
 
 # ---------------------------------------------------------------------------
@@ -326,12 +361,17 @@ def test_beamformer_gulp_program_of_a_beamform_tab_gulp(one_chip,
     assert mem.temp_size_in_bytes < 400e6
 
 
-def test_cut_of_a_beamform_tab_product_is_rows_in_one_program(one_chip):
+def test_cut_of_a_beamform_tab_product_is_words_in_one_program(one_chip):
     """The 56.6 MB u8 product (1024, 64, 1, 864) crosses in four
-    pieces of 256 rows, all cut by one program (xfer._piece_plan), as
-    rows in the host's order (xfer._as_rows: an axis of one before the
-    last lets the compiler lay a piece out time-minor, which the host
-    would have to take apart), with no temporary."""
+    pieces of 256 rows, all cut by one program (xfer._piece_plan), each
+    ONE axis of uint32 words: four bytes of a row to a word, as the
+    host holds them.  The rows come first (xfer._as_rows: an axis of
+    one before the last lets the compiler lay a piece out time-minor),
+    then the fold of the last axis.  Where the compiler does not allow
+    a temporary no larger than a piece: it packs bytes into words by
+    widening each to a u32 first and shifting, so a piece's bytes stand
+    in HBM four times over as u32 for a moment (a fold of the whole
+    piece to (-1, 4) asked for 32 times them, the lanes padded)."""
     class Product(object):
         shape, nbytes, sharding = (1024, 64, 1, 864), 1024 * 64 * 864, \
             type('S', (), {'device_set': {0}})
@@ -340,7 +380,15 @@ def test_cut_of_a_beamform_tab_product_is_rows_in_one_program(one_chip):
     assert not xfer._as_rows(_GPUSPEC) and xfer._as_rows(_XCORR)
     comp = _compiled_cut(one_chip, [Product.shape], np.uint8,
                          0, step, 4, True)
-    text = comp.as_text()
-    root = [line for line in text.splitlines() if 'ROOT' in line][-1]
-    assert root.count('u8[256,55296]{1,0:T(8,128)(4,1)}') >= 4
-    assert comp.memory_analysis().temp_size_in_bytes == 0
+    text, mem = comp.as_text(), comp.memory_analysis()
+    piece = 256 * 64 * 864
+    _one_axis_of_words(text, 4, piece)
+    assert 'c64' not in text and 'custom-call' not in text
+    # the libtpu of jax 0.9.0: 0.17 GB, twelve pieces' worth, of the
+    # first two pieces' widened bytes and relayouts (the other two
+    # stand in VMEM)
+    assert mem.temp_size_in_bytes < 13 * piece
+    entry = text[text.index('ENTRY'):]
+    sizes = [int(np.prod([int(n) for n in shape.split(',')])) * 4
+             for shape in re.findall(r'u32\[([\d,]+)\]', entry)]
+    assert max(sizes) == 4 * piece

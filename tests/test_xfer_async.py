@@ -739,6 +739,13 @@ def _product(dtype, nframe, nchan):
     elif dtype == 'cf64':
         return (rng.randn(nframe, nchan) +
                 1j * rng.randn(nframe, nchan)), jax.device_put
+    elif dtype == 'f64':
+        return rng.randn(nframe, nchan), jax.device_put
+    elif dtype in ('u8', 'u8_odd', 'i16'):
+        info = np.iinfo(dtype.split('_')[0].replace('u', 'uint')
+                        .replace('i1', 'int1'))
+        return rng.randint(info.min, info.max + 1, (nframe, nchan)) \
+            .astype(info.dtype), jax.device_put
     else:
         data = np.zeros((nframe, nchan), DataType('ci8').as_numpy_dtype())
         data['re'] = rng.randint(-100, 100, (nframe, nchan))
@@ -751,8 +758,15 @@ def _words(a):
     return np.ascontiguousarray(a).view(np.uint8)
 
 
+#: items a frame of each product, where it is not 32
+_NCHAN = {'u8': 64, 'u8_odd': 37}
+#: frames (ci8: int16 words) a piece, where it is not one
+_STEP = {'ci8': 64, 'u8': 2, 'u8_odd': 3, 'i16': 2}
+
+
 @pytest.mark.parametrize('dtype', ['f32', 'ci8', 'cf32', 'cf32_special',
-                                   'cf32_planes', 'cf64'])
+                                   'cf32_planes', 'cf64', 'u8', 'u8_odd',
+                                   'i16', 'f64'])
 def test_large_product_crosses_in_pieces(dtype, monkeypatch):
     """A product over twice the piece size is cut on the device and
     lands piece by piece, bit for bit, in a span that wraps (ghost
@@ -760,19 +774,30 @@ def test_large_product_crosses_in_pieces(dtype, monkeypatch):
     (re, im) pairs that the host sees as complex again, with no
     conversion of its own (complex128 on the CPU backend alone),
     whether it reaches the engine as a complex array or as its two
-    planes."""
+    planes.  Every piece of four-byte words or narrower whose bytes
+    are a whole number of words leaves as ONE axis of uint32 words
+    (``xfer._words``), counted by ``xfer.d2h_flat_bytes``; pieces of
+    8-byte words and of 111 or 74 bytes keep rows."""
     import jax
     from bifrost_tpu.telemetry import spans
     monkeypatch.setattr(xfer, '_D2H_PIECE_BYTES', 128)
+    crossed = []
+    cross = xfer._cross
+
+    def spy(arrays, *args):
+        crossed.extend((a.ndim, np.dtype(a.dtype), int(a.nbytes))
+                       for a in arrays)
+        return cross(arrays, *args)
+    monkeypatch.setattr(xfer, '_cross', spy)
     counters.reset()
     spans.reset()
-    nframe, nchan = 24, 32
+    nframe, nchan = 24, _NCHAN.get(dtype, 32)
     eng = xfer.engine()
     from bifrost_tpu.ring import Ring
     ring = Ring(space='system')
     hdr = simple_header([-1, nchan], dtype.split('_')[0], gulp_nframe=8)
     fills = []
-    with jax.enable_x64(dtype == 'cf64'):
+    with jax.enable_x64(dtype in ('cf64', 'f64')):
         data, put = _product(dtype, nframe, nchan)
         with ring.begin_writing() as w:
             with w.begin_sequence(hdr, 8, 20) as seq:
@@ -795,20 +820,47 @@ def test_large_product_crosses_in_pieces(dtype, monkeypatch):
     assert first.dtype == got.dtype == data.dtype
     assert np.array_equal(_words(first), _words(data[:8]))
     assert np.array_equal(_words(got), _words(data[18:22]))
-    # 8 frames of 128 (f32), 64 (ci8), 256 (cf32) or 512 (cf64) bytes in
-    # pieces of at most 128: one frame a piece, or two (the ci8 gulp
-    # crosses as its int16 words, one row of 256: 64 words a piece)
+    # 8 frames of 128 (f32), 64 (ci8), 256 (cf32, f64), 512 (cf64), 64
+    # (u8, i16) or 37 (u8_odd) bytes in pieces of at most 128: one
+    # frame a piece, or more (the ci8 gulp crosses as its int16 words,
+    # one row of 256: 64 words a piece)
     assert all(isinstance(f.future, xfer._PieceFuture) for f in fills)
-    assert fills[0].future._step == (64 if dtype == 'ci8' else 1)
+    assert fills[0].future._step == _STEP.get(dtype, 1)
+    for f in fills:                    # the one between the two read too
+        f.wait()
+    nbytes = 3 * fills[0].nbytes
     assert counters.get('xfer.d2h_piece_bytes') == \
-        counters.get('xfer.d2h_bytes') == 3 * fills[0].nbytes
+        counters.get('xfer.d2h_bytes') == nbytes
     # the complex ones as real pairs; the counter is there for a
     # reader whatever it counts
     assert counters.snapshot()['xfer.d2h_pair_bytes'] == \
-        (3 * fills[0].nbytes if dtype.startswith('cf') else 0)
+        (nbytes if dtype.startswith('cf') else 0)
     assert counters.snapshot()['xfer.d2h_plane_bytes'] == \
-        (3 * fills[0].nbytes if dtype == 'cf32_planes' else 0)
+        (nbytes if dtype == 'cf32_planes' else 0)
+    # every piece left as one axis of words, or none did
+    flat = dtype not in ('cf64', 'f64', 'u8_odd')
+    assert sum(n for _d, _t, n in crossed) == nbytes
+    assert all((ndim, dt == np.uint32) == ((1, True) if flat else (2, False))
+               for ndim, dt, _n in crossed)
+    assert counters.snapshot()['xfer.d2h_flat_bytes'] == \
+        (nbytes if flat else 0)
     assert 'd2h.convert' not in _span_names()
+
+
+def test_a_piece_that_is_no_whole_number_of_words_keeps_rows():
+    """The rule is the piece's own: of one u8 product, a piece of two
+    frames (74 bytes) keeps rows and one of four (148 bytes) leaves as
+    one axis of words."""
+    import jax
+    data = np.arange(6 * 37, dtype=np.uint8).reshape(6, 37)
+    arr = jax.device_put(data)
+    pieces = xfer._cut(arr, 0, 0, 2, 1, True) + \
+        xfer._cut(arr, 2, 0, 4, 1, True)
+    assert [(p.ndim, np.dtype(p.dtype)) for p in pieces] == \
+        [(2, np.uint8), (1, np.uint32)]
+    assert np.array_equal(np.asarray(pieces[0]), data[:2])
+    assert np.array_equal(np.asarray(pieces[1]).view(np.uint8),
+                          data[2:].reshape(-1))
 
 
 def _special_planes(shape, seed):
@@ -940,14 +992,14 @@ def test_small_products_cross_whole_and_uneven_ones_in_pieces(
     assert np.array_equal(eng.to_host(eng.to_device(big)), big)
     assert whole == [np.dtype(ctype)] * 2        # crossed as they are
     # as is the result of a piece future nobody landed, whose pieces
-    # crossed as reals (a complex one's as the words of its reals)
+    # crossed as one axis of uint32 words (a complex one's, the words
+    # of its reals)
     del whole[:]
     fut = eng._future_for(jax.device_put(big), np.zeros_like(big))
     assert isinstance(fut, xfer._PieceFuture)
     got = fut.result()
     assert got.dtype == big.dtype and np.array_equal(got, big)
-    assert set(whole) == {np.dtype(np.uint32 if item == 8
-                                   else np.float32)}
+    assert set(whole) == {np.dtype(np.uint32)}
 
 
 @pytest.mark.parametrize('shape,itemsize,want', [

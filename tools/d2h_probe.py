@@ -80,11 +80,31 @@ two products in turn the seconds inside ``block_until_ready``,
 ``asarray_gbps`` / ``wall_gbps``; ``exact`` says the host buffer holds
 the product's bits, word for word.
 
+The flat case (PR 38; ``flat`` on the command line, with ``beside``
+once more next to a thread that copies a 268 MB buffer as fast as it
+can, as the benchmark's source does; cell names after it run those
+alone): a 16 MiB piece of each served cell's product in the form
+``xfer._cut`` gave it until PR 38 (``gpuspec`` ``f32[1024,4,1024]``,
+``gpuspec-hsr`` ``f32[1,4,1048576]``, ``beamform-tab`` ``u8[256,55296]``,
+``xcorr`` the pair rows ``u32[4096,1024]``) and the same bytes as one
+axis of uint32 words, ``u32[N]``.  Sixty-four ready pieces a reading,
+taken one after the other by ``np.asarray`` on a thread that is not
+the main one, each hinted before: all sixty-four at once, one at a
+time, or sixteen ahead of the take (``FLAT_MODES``): wall ms a piece,
+process CPU-s a GB,
+and CPU-s a GB by thread family (``bifrost_tpu.telemetry.threadcpu``),
+``futex-default-S`` among them: the runtime's pool that lays a tiled
+device array out in the host's order.  Also written to
+``chiprun_out/d2h_probe_flat.json``.
+
     chiprun -- python3 tools/d2h_probe.py
+    chiprun -- python3 tools/d2h_probe.py flat beside
 """
 
 import json
+import os
 import resource
+import statistics
 import sys
 import threading
 import time
@@ -92,6 +112,9 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 SHAPE = (16384, 4, 1024)
 NBYTES = 4 * int(np.prod(SHAPE))
@@ -422,11 +445,180 @@ def complex_case(only=()):
     return out
 
 
+#: a 16 MiB piece of each served cell's product as ``xfer._cut`` left
+#: it until PR 38 (name -> shape, dtype); ``xcorr`` is the (re, im)
+#: pair rows ``xfer._pairs`` made of a (1, 8, 256, 2, 256, 2) slice
+FLAT_PIECES = {'gpuspec': ((1024, 4, 1024), np.float32),
+               'gpuspec-hsr': ((1, 4, 1048576), np.float32),
+               'beamform-tab': ((256, 55296), np.uint8),
+               'xcorr': ((4096, 1024), np.uint32)}
+FLAT_COUNT, FLAT_REPS, FLAT_BESIDE = 64, 5, (16384, 4, 4096)
+
+
+def _piece_maker(shape, dtype, flat):
+    """A program that makes one piece from a seed: the same bytes in
+    ``shape`` or, with ``flat``, as one axis of uint32 words."""
+    n = int(np.prod(shape)) * np.dtype(dtype).itemsize // 4
+
+    def make(s):
+        words = jnp.arange(n, dtype=jnp.uint32) * jnp.uint32(2654435761) + s
+        if flat:
+            return words
+        return jax.lax.bitcast_convert_type(words, dtype).reshape(shape)
+    return jax.jit(make)
+
+
+def _layout(arr):
+    try:
+        return str(arr.format.layout)
+    except Exception:
+        try:
+            return str(arr.format)
+        except Exception as e:
+            return 'unknown (%s)' % type(e).__name__
+
+
+def _family_cpu(before, after):
+    """CPU seconds by thread family between two ``threadcpu`` readings."""
+    from bifrost_tpu.telemetry.threadcpu import family
+    by = {}
+    for tid, (name, named, s1, _q) in after['threads'].items():
+        s0 = before['threads'].get(tid, (name, named, 0.0, None))[2]
+        if s1 - s0 > 0:
+            fam = family(name, named)
+            by[fam] = by.get(fam, 0.0) + s1 - s0
+    return by
+
+
+def _take_flat(make, seed0, ahead):
+    """``FLAT_COUNT`` ready pieces taken one after the other by
+    ``np.asarray`` on a thread that is not the main one, each hinted
+    ``ahead`` pieces before its take (``FLAT_COUNT``: all at once
+    first): wall seconds from the first hint to the last take, process
+    CPU seconds and CPU seconds by family over the same stretch."""
+    from bifrost_tpu.telemetry.threadcpu import read
+    xs = [make(jnp.uint32(seed0 + k)) for k in range(FLAT_COUNT)]
+    jax.block_until_ready(xs)
+    took = []
+
+    def taker():
+        for k, x in enumerate(xs):
+            if k + ahead - 1 < len(xs) and k:
+                xs[k + ahead - 1].copy_to_host_async()
+            t0 = time.perf_counter()
+            np.asarray(x)
+            took.append(time.perf_counter() - t0)
+
+    before, cpu0 = read(), _cpu_s()
+    t0 = time.perf_counter()
+    for x in xs[:ahead]:
+        x.copy_to_host_async()
+    th = threading.Thread(target=taker, name='probe-take')
+    th.start()
+    th.join()
+    wall = time.perf_counter() - t0
+    cpu, after = _cpu_s() - cpu0, read()
+    return {'wall_s': wall, 'asarray_ms_median': 1e3 * sorted(took)[
+                len(took) // 2],
+            'cpu_s': cpu, 'cpu_s_by_family': _family_cpu(before, after)}
+
+
+#: reading -> (pieces hinted ahead of the take, beside a source)
+FLAT_MODES = {'alone': (FLAT_COUNT, False), 'beside': (FLAT_COUNT, True),
+              'one_at_a_time': (1, False), 'sixteen_ahead': (16, False),
+              'sixteen_ahead_beside': (16, True)}
+
+
+def flat_case(only=(), with_beside=False):
+    """A piece of each cell's form as cut until PR 38 and the same
+    bytes as ``u32[N]``, ``FLAT_COUNT`` of each a reading: wall ms a
+    piece, process CPU-s a GB, the ``futex-default-S`` family's CPU-s
+    a GB (``threadcpu``: ticks of 10 ms on the benchmark's machine, so
+    a GB a reading); with ``beside``, once more while a thread copies a
+    268 MB buffer as fast as it can (the benchmark's source)."""
+    out = {'count': FLAT_COUNT, 'reps': FLAT_REPS, 'pieces': {}}
+    stop = threading.Event()
+    src = np.ones(FLAT_BESIDE, np.float32)
+    dst = np.zeros_like(src)
+    copies = []
+
+    def source():
+        while not stop.is_set():
+            t0 = time.perf_counter()
+            np.copyto(dst, src)
+            copies.append(time.perf_counter() - t0)
+
+    modes = [m for m, (_a, side) in FLAT_MODES.items()
+             if with_beside or not side]
+    seed = 1
+    for name, (shape, dtype) in FLAT_PIECES.items():
+        if only and name not in only:
+            continue
+        nbytes = int(np.prod(shape)) * np.dtype(dtype).itemsize
+        got = out['pieces'][name] = {'shape': list(shape),
+                                     'dtype': np.dtype(dtype).name,
+                                     'nbytes': nbytes}
+        for form, flat in (('as_cut', False), ('flat', True)):
+            make = _piece_maker(shape, dtype, flat)
+            x = jax.block_until_ready(make(jnp.uint32(0)))
+            want = np.asarray(x).reshape(-1).view(np.uint8)
+            got[form] = {'device_shape': list(x.shape),
+                         'device_dtype': str(x.dtype),
+                         'layout': _layout(x)}
+            del x
+            for mode in modes:
+                ahead, side = FLAT_MODES[mode]
+                th = None
+                if side:
+                    stop.clear()
+                    th = threading.Thread(target=source, name='probe-source')
+                    th.start()
+                    time.sleep(0.2)
+                reps = []
+                for _ in range(FLAT_REPS):
+                    reps.append(_take_flat(make, seed, ahead))
+                    seed += FLAT_COUNT
+                if th is not None:
+                    stop.set()
+                    th.join()
+                gb = FLAT_COUNT * nbytes / 1e9
+                fams = {}
+                for r in reps:
+                    for k, v in r['cpu_s_by_family'].items():
+                        fams[k] = fams.get(k, 0.0) + v / FLAT_REPS / gb
+                got[form][mode] = {
+                    'wall_ms_a_piece_median': 1e3 * statistics.median(
+                        r['wall_s'] for r in reps) / FLAT_COUNT,
+                    'asarray_ms_median': statistics.median(
+                        r['asarray_ms_median'] for r in reps),
+                    'cpu_s_a_gb_median': statistics.median(
+                        r['cpu_s'] for r in reps) / gb,
+                    'cpu_s_a_gb_by_family_mean': fams,
+                    'futex_default_s_cpu_s_a_gb': fams.get(
+                        'futex-default-S', 0.0)}
+            # exact: the first piece again, as one axis or as cut
+            y = make(jnp.uint32(0))
+            got[form]['exact'] = bool(np.array_equal(
+                np.asarray(y).reshape(-1).view(np.uint8), want))
+            del y, want
+            print(json.dumps({'flat': out}), file=sys.stderr, flush=True)
+    if copies:
+        out['source_ms_a_copy_median'] = 1e3 * statistics.median(copies)
+    return out
+
+
 def main():
     dev = jax.devices()[0]
     cases = sys.argv[1:] or ['float32', 'complex']
     out = {'device': {'platform': dev.platform,
                       'kind': dev.device_kind}}
+    if 'flat' in cases:
+        out['flat'] = flat_case([c for c in cases if c in FLAT_PIECES],
+                                'beside' in cases)
+        os.makedirs('chiprun_out', exist_ok=True)
+        with open(os.path.join('chiprun_out', 'd2h_probe_flat.json'),
+                  'w') as f:
+            json.dump(out, f)
     if 'complex' in cases:
         out['complex'] = complex_case(
             [c for c in cases if c in COMPLEX_FORMS])
