@@ -27,6 +27,10 @@ def device_stages(block):
     its single stage."""
     from .fft import _StageBlock
     if isinstance(block, FusedBlock):
+        # a chain whose program takes arguments besides the gulp runs
+        # as its own block's program (Stage.n_gulp_operands)
+        if block._takes_operands():
+            return None
         return list(block.stages)
     if isinstance(block, _StageBlock):
         return [block._stage]
@@ -37,6 +41,10 @@ class FusedBlock(TransformBlock):
     def __init__(self, iring, stages, *args, **kwargs):
         super(FusedBlock, self).__init__(iring, *args, **kwargs)
         self.stages = list(stages)
+        #: the stages whose function takes operands besides the gulp
+        #: (Stage.n_gulp_operands)
+        self._operand_stages = [s for s in self.stages
+                                if getattr(s, 'n_gulp_operands', 0)]
         #: compiled plans keyed by (shape, dtype, donate) for the
         #: per-gulp path and by ('macro', part_shapes, dtype, donate,
         #: G, mode) for macro-gulp batches — the donating and
@@ -64,6 +72,19 @@ class FusedBlock(TransformBlock):
 
     def define_valid_input_spaces(self):
         return ('tpu',)
+
+    def _takes_operands(self):
+        """True where a stage's function takes more than the gulp: the
+        program then runs one gulp at a time on one device."""
+        return bool(self._operand_stages)
+
+    def _gulp_operands(self, frame_offset, nframe):
+        """Every stage's operands for the gulp of ``nframe`` frames from
+        ``frame_offset``, in the chain's order."""
+        out = ()
+        for stage in self._operand_stages:
+            out += tuple(stage.gulp_operands(frame_offset, nframe))
+        return out
 
     # -- warm-start plan sharing (bifrost_tpu.service) --------------------
     def plan_signature(self):
@@ -129,8 +150,9 @@ class FusedBlock(TransformBlock):
         span shards over the mesh time axis exactly like a single gulp
         (K·G frames instead of G), so batched dispatch composes with
         sharded plans.  This is where dispatch amortization actually
-        pays on TPU: one program K gulps wide AND N chips wide."""
-        return True
+        pays on TPU: one program K gulps wide AND N chips wide.  Not a
+        chain whose program takes operands besides the gulp."""
+        return not self._takes_operands()
 
     def macro_overlap_safe(self):
         """In-segment halo carry (docs/perf.md): a 'block'-mode chain
@@ -166,9 +188,14 @@ class FusedBlock(TransformBlock):
         self._published_impl = None
         self._published_key = None
         self._donate_on = None
-        from ..stages import BeamformStage
+        from ..stages import (BeamformStage, CoherentDedispersionStage,
+                              FoldStage)
         self._beam_stage = next((st for st in self.stages
                                  if isinstance(st, BeamformStage)), None)
+        self._dedisp_stage = next(
+            (st for st in self.stages
+             if isinstance(st, CoherentDedispersionStage)), None)
+        self._folds = any(isinstance(st, FoldStage) for st in self.stages)
         # ring-resident sharding advertisement: under a mesh this block
         # commits output spans sharded over the OUTPUT frame axis; a
         # stale input descriptor must never survive a layout change
@@ -223,10 +250,12 @@ class FusedBlock(TransformBlock):
                 # device as words (on_data), pairs under a mesh
                 z = device_rep_zeros(shape, t['dtype'])
                 return z if self.mesh is None else whole(z)
-            jax.block_until_ready(self._execute_plan(zeros(shape)))
+            gulp_ops = self._gulp_operands(0, int(gulp))
+            jax.block_until_ready(self._execute_plan(zeros(shape),
+                                                     gulp=gulp_ops))
             if self._donation_on():
                 jax.block_until_ready(self._execute_plan(
-                    zeros(shape), donate=True))
+                    zeros(shape), donate=True, gulp=gulp_ops))
         except Exception:
             self._plans = {}
             return
@@ -307,6 +336,10 @@ class FusedBlock(TransformBlock):
             if bound is not None:
                 return (lambda x, _plan=plan: _plan(x, *operands)), None
             return plan, None
+        if self._takes_operands():
+            raise ValueError('%s: a chain whose program takes operands '
+                             'besides the gulp runs on one device'
+                             % self.name)
         composed, _ = compose_stages(self.stages, self._headers,
                                      shape, dtype, substitute=False)
         # Scale the whole fused chain over the scope's mesh: shard the
@@ -473,14 +506,16 @@ class FusedBlock(TransformBlock):
         except OSError:
             pass
 
-    def _execute_plan(self, x, donate=False):
+    def _execute_plan(self, x, donate=False, gulp=()):
         """Plan-cache dispatch + execution shared by on_data and
         _prewarm (one copy of the key/shard logic, so the pre-warmed
         key can never drift from the hot path's).  ``donate=True``
         requires an exclusively-owned ``x`` (it is deleted by the
         call) — mesh plans donate too: the sharded input's per-device
         buffers alias same-layout intermediates/outputs shard by
-        shard (donation-under-sharding, docs/parallel.md)."""
+        shard (donation-under-sharding, docs/parallel.md).
+        ``gulp``: the stages' operands (:meth:`_gulp_operands`),
+        arguments of the program after the gulp."""
         words = isinstance(x, ComplexWords)
         if words and self.mesh is not None:
             x, words = x.pairs(), False
@@ -503,7 +538,8 @@ class FusedBlock(TransformBlock):
         if taxis is not None:
             from ..parallel.scope import shard_gulp
             x = shard_gulp(x, self.mesh, taxis)
-        return self._dispatch_device(fn, (x.words if words else x,))
+        return self._dispatch_device(fn, (x.words if words else x,)
+                                     + tuple(gulp))
 
     def _execute_macro(self, parts, donate, gulp_nframe):
         """Macro-gulp execution: run ONE compiled program over a
@@ -671,6 +707,34 @@ class FusedBlock(TransformBlock):
                          shape[1], stage.npol if stage.mode == 'perpol'
                          else 1))
 
+    def _count_dedispersed(self, ngulps, nframe):
+        """``dedisp.gulps``: gulps that went through a chain with a
+        coherent dedispersion in it; ``dedisp.fused_gulps``: of them,
+        through the one program (stages.match_coherent_fold: no
+        spectrum or dedispersed voltage in HBM whole);
+        ``dedisp.long_gulps``: of them, those whose transforms, the
+        forward AND the inverse (``impl_info['fft']['inverse']``, which
+        ``spectrometer.long_gulps`` does not read), took the three-level
+        path (ops.fft.long_fft); ``fold.samples``: where the chain folds
+        them too, the samples it dedispersed, every channel and
+        polarisation, of their ``nframe`` frames (as many as the blocks
+        hold new ones: each sample of the stream once).  All counted at 0 too, so that a reader finds the
+        counters."""
+        stage = self._dedisp_stage
+        if stage is None:
+            return
+        info = self.impl_info or {}
+        fft = info.get('fft') or {}
+        from ..telemetry import counters
+        counters.inc('dedisp.gulps', ngulps)
+        counters.inc('dedisp.fused_gulps', ngulps if info.get('impl')
+                     == 'coherent-fold' else 0)
+        counters.inc('dedisp.long_gulps', ngulps if fft.get('path') ==
+                     (fft.get('inverse') or {}).get('path') == 'long'
+                     else 0)
+        if self._folds:
+            counters.inc('fold.samples', int(nframe) * stage.frame_samples)
+
     def on_data(self, ispan, ospan):
         if self._gulp_batch_active > 1 and self._macro_gulp_in:
             x = self._take_donatable(ispan, allow_parts=True)
@@ -689,15 +753,18 @@ class FusedBlock(TransformBlock):
             return
         # a ci8 gulp on one device: the plan starts from its words
         words = self.mesh is None
+        gulp = self._gulp_operands(ispan.frame_offset, ispan.nframe)
         x = self._take_donatable(ispan, words=words)
         if x is not None:
-            ospan.set(self._execute_plan(x, donate=True), owned=True)
+            ospan.set(self._execute_plan(x, donate=True, gulp=gulp),
+                      owned=True)
         else:
             x = ispan.words if words else None
-            ospan.set(self._execute_plan(ispan.data if x is None else x),
-                      owned=True)
+            ospan.set(self._execute_plan(ispan.data if x is None else x,
+                                         gulp=gulp), owned=True)
         self._count_transformed(1)
         self._count_beamformed(1, ispan.nframe)
+        self._count_dedispersed(1, ispan.nframe)
 
 
 def fused(iring, stages, *args, **kwargs):

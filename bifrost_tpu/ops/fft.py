@@ -227,17 +227,17 @@ def fft_path(shape, axes, inverse=False, dtype='complex64'):
     """What :func:`fftn_dispatch` runs for a transform of ``axes`` of
     an array of ``shape``, as a record a block can publish: from the
     shape and the documented variables alone.  ``{'path': 'long',
-    'factors': ..., 'precision': ...}`` for a forward complex64
-    transform of one last axis whose length takes three levels
-    (:func:`long_factors`); ``'dftmm'`` where BF_FFT_IMPL forces the
-    two-level matrix form; else ``'xla'`` (jnp.fft)."""
+    'factors': ..., 'precision': ...}`` for a complex64 transform,
+    forward or inverse, of one last axis whose length takes three
+    levels (:func:`long_factors`); ``'dftmm'`` where BF_FFT_IMPL
+    forces the two-level matrix form; else ``'xla'`` (jnp.fft)."""
     import os
     if fft_impl_choice() == 'dftmm':
         return {'path': 'dftmm'}
     ndim = len(shape)
     axes = [a % ndim for a in axes]
     factors = long_factors(shape[axes[0]]) if axes == [ndim - 1] else None
-    if factors is None or inverse or str(dtype) != 'complex64':
+    if factors is None or str(dtype) != 'complex64':
         return {'path': 'xla'}
     # BF_FFT_DFT_DTYPE=bf16 is the matrix forms' lower-precision
     # switch: one bf16 pass a product where float32 accuracy takes three
@@ -271,7 +271,7 @@ def fftn_dispatch(x, axes, inverse=False):
         # TPU computes complex64 on separate planes anyway
         return jax.lax.complex(*long_fft(
             jnp.real(x), jnp.imag(x), tuple(plan['factors']),
-            precision=plan['precision']))
+            precision=plan['precision'], inverse=inverse))
     if inverse:
         y = jnp.fft.ifftn(x, axes=axes)
         import numpy as np_
@@ -348,8 +348,9 @@ def _chunk_rows(nrow, n, keep):
 
 
 def long_fft(xr, xi, factors, precision='high', natural=True,
-             then=None, keep=1):
-    """Forward c2c transform over the last axis of the real planes
+             then=None, keep=1, inverse=False):
+    """Forward (or, with ``inverse``, unnormalised inverse) c2c
+    transform over the last axis of the real planes
     ``xr``, ``xi`` (..., n), or of their rows of n where they come on
     one axis, in three levels of DFT matrix products,
     n = n1 * n2 * n3 = ``factors``: with n = (n2 n3) a + n3 b + c and
@@ -364,9 +365,11 @@ def long_fft(xr, xi, factors, precision='high', natural=True,
     (stacked as one product against [[Fr, -Fi], [Fi, Fr]] a level read
     28.8 ms where this reads 21.2: PERF.md section 6, PR 33).
     ``precision``: 'high' (three bf16 passes of the MXU a
-    product: float32 accuracy) or 'default' (one: the lower-precision
-    control; six, ``HIGHEST``, read 3e-8 for 1.6e-6 at 27.8 ms for
-    22.4 and have no caller).  The leading axes go through a chunk
+    product: float32 accuracy of a spectrum's peaks), 'highest' (six:
+    3e-8 for 1.6e-6, at 27.8 ms for 22.4 a 2^20-point gulp; the
+    coherent dedispersion's, ops.dedisp.transform_path, whose fold
+    sums three passes' error over thousands of samples) or 'default'
+    (one: the lower-precision control).  The leading axes go through a chunk
     at a time (:func:`_chunk_rows`, ``keep`` rows never apart), and
     ``then(yr, yi)``, given the float32 planes (rows, n) of a chunk's
     spectra, is applied to each chunk before the next is begun (a
@@ -375,7 +378,11 @@ def long_fft(xr, xi, factors, precision='high', natural=True,
     (..., n), or ``then``'s results joined along their first axis: in
     frequency order where ``natural``, else as (n1, n2, n3) flattened,
     k1 major, which saves the reordering for a caller that sums
-    spectra before it needs their order."""
+    spectra before it needs their order.  The inverse is cuFFT's
+    unnormalised one, the conjugate of the forward transform of the
+    conjugate: the same matrices, the imaginary plane negated on the
+    way in and on the way out (elementwise, fused into the products'
+    operands), so that ``then`` is given the inverse's planes."""
     import jax
     import jax.numpy as jnp
     n1, n2, n3 = factors
@@ -388,6 +395,7 @@ def long_fft(xr, xi, factors, precision='high', natural=True,
         raise ValueError('long_fft: %d points do not factor as %r'
                          % (xr.shape[-1], (factors,)))
     prec = {'high': jax.lax.Precision.HIGH,
+            'highest': jax.lax.Precision.HIGHEST,
             'default': jax.lax.Precision.DEFAULT}[precision]
     f1, t1, f2, t2, f3 = _long_consts(tuple(factors))
 
@@ -405,11 +413,15 @@ def long_fft(xr, xi, factors, precision='high', natural=True,
         """(rows, n) planes -> the chunk's spectra, or then() of them."""
         ar = xr.astype(jnp.float32).reshape(-1, n1, n2, n3)
         ai = xi.astype(jnp.float32).reshape(-1, n1, n2, n3)
+        if inverse:
+            ai = -ai
         ar, ai = twiddle(*level('ka,rabc->rkbc', f1, ar, ai), t1)
         ar, ai = twiddle(*level('kb,rabc->rakc', f2, ar, ai), t2)
         ar, ai = level('kc,rabc->rkba' if natural else 'kc,rabc->rabk',
                        f3, ar, ai)
         ar, ai = ar.reshape(-1, n), ai.reshape(-1, n)
+        if inverse:
+            ai = -ai
         return (ar, ai) if then is None else then(ar, ai)
 
     nrow = int(np.prod(lead, dtype=np.int64))

@@ -76,7 +76,7 @@ import functools
 
 import numpy as np
 
-__all__ = ['fused_spectrometer', 'long_spectrometer',
+__all__ = ['fused_spectrometer', 'long_spectrometer', 'coherent_fold',
            'spectrometer_oracle',
            'spectrometer_accuracy', 'choose_precision',
            'spectrometer_mode', 'resolve_transpose']
@@ -458,6 +458,118 @@ def long_spectrometer(volt, factors, precision='high'):
     out = long_fft(re, im, factors,
                    precision=precision, then=stokes, keep=2)
     return out.reshape(lead + (4, nfft))
+
+
+#: the spectra of one chunk of a coherent fold (ops.fft.long_fft's
+#: rows, float32 planes) hold at most this many bytes: two channels of
+#: a 2^16-point, 16-window block.  On the v5e, with no fold, its
+#: transforms at six bf16 passes took 56.9 ms a gulp in chunks of one
+#: channel and 55.6 in chunks of two; chunks of four were timed at three
+#: passes only (64.6 ms), so two against four at six passes is not
+#: measured (PERF.md section 6, guppi-fold)
+_FOLD_CHUNK_BYTES = 1 << 25
+
+
+def coherent_fold(volt, starts, chirp_re, chirp_im, shape, nfft, overlap,
+                  rule, factors=None, precision='highest'):
+    """ci8 dual-pol voltages -> folded Stokes profiles, one program: a
+    pulsar-timing backend's coherent mode, the semantics of
+    CoherentDedispersionStage -> DetectStage('stokes') -> FoldStage.
+
+    ``volt``: the gulp's int16 words on one axis (low byte re, as a
+    gulp on one device is held), or the int8 pairs (..., 2); ``shape``
+    its logical (T, nchan, 2 pol, nsamp).  ``starts`` (T,) int32: each
+    frame's start residue (ops.fold.residues), an argument, so that a
+    gulp at any phase runs the same program.  ``chirp_re``,
+    ``chirp_im`` (nchan, nfft) float32: the chirp (ops.dedisp.chirp),
+    arguments too.  A chunk is a few channels of one frame (as many as
+    ``_FOLD_CHUNK_BYTES`` of spectra hold), both polarisations, sliced
+    where they lie: their overlap-save windows go forward through the
+    transform (ops.fft.long_fft at ``factors``, else jnp.fft), are
+    multiplied by their channel's chirp, go back through the inverse,
+    lose half their ``overlap`` at each end (ops.dedisp.head: the
+    chirp's response reaches both ways) and are detected to Stokes
+    I, Q, U, V before the next chunk is begun: the spectra and the
+    dedispersed voltages never reach HBM whole.  The Stokes series do
+    (as large as the voltages would be), and are folded a frame at a
+    time, all channels together (ops.fold.fold_turns, fold_bins): a
+    fold a chunk at a time cut every rotation out of every channel
+    alone, 17 600 small device programs a gulp at the deployment's
+    size, and took 16.4 ms where this takes 8.8 (PERF.md section 6,
+    guppi-fold).  Returns (T, nchan, 4, nbin) float32."""
+    import jax
+    import jax.numpy as jnp
+    from .dedisp import head, window_count
+    from .fft import long_fft
+    from .fold import fold_bins, fold_turns
+    ntime, nchan, npol, nsamp = shape
+    if npol != 2:
+        raise ValueError('coherent_fold: expected dual-pol voltages')
+    step = nfft - overlap
+    nwin = window_count(nsamp, nfft, overlap)
+    new = nwin * step
+    kept = slice(head(overlap), head(overlap) + step)
+    per_chan = npol * nwin
+    fit = max(_FOLD_CHUNK_BYTES // (8 * per_chan * nfft), 1)
+    chans = next(c for c in range(min(fit, nchan), 0, -1) if nchan % c == 0)
+    rows = chans * per_chan
+    chunk = chans * npol * nsamp
+    nchunk = ntime * nchan // chans
+    words = volt.dtype == jnp.int16
+    if words:
+        flat = volt.reshape(-1)
+    else:
+        pairs = volt.reshape(nchunk, chans, npol, nsamp, 2)
+
+    def planes(k):
+        if words:
+            w = jax.lax.dynamic_slice_in_dim(flat, k * chunk, chunk)
+            re = ((w << 8) >> 8).astype(jnp.int8)
+            im = (w >> 8).astype(jnp.int8)
+        else:
+            v = jax.lax.dynamic_index_in_dim(pairs, k, keepdims=False)
+            re, im = v[..., 0], v[..., 1]
+        return (p.astype(jnp.float32).reshape(chans, npol, nsamp)
+                for p in (re, im))
+
+    def transform(ar, ai, inverse):
+        if factors is not None:
+            return long_fft(ar, ai, factors, precision=precision,
+                            keep=rows, inverse=inverse)
+        z = jax.lax.complex(ar, ai)
+        z = jnp.fft.ifft(z, axis=-1) * nfft if inverse \
+            else jnp.fft.fft(z, axis=-1)
+        return jnp.real(z), jnp.imag(z)
+
+    def one(k):
+        re, im = planes(k)
+        # the overlap-save windows, cut where they lie: (rows, nfft)
+        wr, wi = (jnp.stack([p[..., w * step:w * step + nfft]
+                             for w in range(nwin)], axis=2)
+                  .reshape(rows, nfft) for p in (re, im))
+        yr, yi = transform(wr, wi, False)
+        first = (k * chans) % nchan
+        hr, hi = (jax.lax.dynamic_slice_in_dim(h, first, chans)[:, None]
+                  for h in (chirp_re, chirp_im))
+        yr = yr.reshape(chans, per_chan, nfft)
+        yi = yi.reshape(chans, per_chan, nfft)
+        zr, zi = transform((yr * hr - yi * hi).reshape(rows, nfft),
+                           (yr * hi + yi * hr).reshape(rows, nfft), True)
+        xr_, yr_, xi_, yi_ = (
+            p.reshape(chans, npol, nwin, nfft)[..., kept]
+            .reshape(chans, npol, new)[:, q]
+            for p, q in ((zr, 0), (zr, 1), (zi, 0), (zi, 1)))
+        xx = xr_ * xr_ + xi_ * xi_
+        yy = yr_ * yr_ + yi_ * yi_
+        xyr = xr_ * yr_ + xi_ * yi_       # x * conj(y)
+        xyi = xi_ * yr_ - xr_ * yi_
+        return jnp.stack([xx + yy, xx - yy, 2.0 * xyr, -2.0 * xyi], axis=1)
+
+    stokes = jax.lax.map(one, jnp.arange(nchunk)) \
+        .reshape(ntime, nchan * 4, new)
+    return jnp.stack([fold_bins(fold_turns(stokes[t], starts[t], rule),
+                                rule) for t in range(ntime)]) \
+        .reshape(ntime, nchan, 4, rule.nbin)
 
 
 def spectrometer_oracle(volt, rfactor=4):

@@ -31,6 +31,7 @@ __all__ = ['Stage', 'FftStage', 'DetectStage', 'ReduceStage',
            'ScrunchStage', 'MapStage', 'BeamformStage',
            'QuantizeStage', 'CorrelateStage', 'AccumulateStage',
            'FdmtStage', 'MatchedFilterStage', 'ThresholdStage',
+           'CoherentDedispersionStage', 'FoldStage',
            'chain_overlap_nframe']
 
 
@@ -64,6 +65,17 @@ class Stage(object):
     #: stages today.
     overlap_nframe = 0
 
+    #: Arguments of the gulp's program besides the gulp.  A stage whose
+    #: ``build`` function takes more than the gulp, ``fn(x, *operands)``,
+    #: says here how many (:meth:`gulp_operands` gives them for each
+    #: gulp: a fold's phase, from where the gulp lies in its sequence,
+    #: or a dedispersion's chirp, the same device arrays every gulp of
+    #: a sequence).  They are arguments of the one program, not
+    #: constants folded into it, so no gulp recompiles and no executable
+    #: holds a table; such a chain runs one gulp a program on one device
+    #: (no macro-gulp batch, no segment, no mesh).
+    n_gulp_operands = 0
+
     def transform_header(self, hdr):
         return hdr
 
@@ -72,6 +84,11 @@ class Stage(object):
         taxis=int, reim=bool).  Return fn(jax array) -> jax array in
         device representation."""
         raise NotImplementedError
+
+    def gulp_operands(self, frame_offset, nframe):
+        """The ``n_gulp_operands`` arrays, host or device, of the gulp of
+        ``nframe`` frames from frame ``frame_offset`` of its sequence."""
+        return ()
 
     def output_nframe(self, input_nframe):
         num, den = self.nframe_ratio
@@ -993,6 +1010,174 @@ class ThresholdStage(Stage):
         return fn
 
 
+class CoherentDedispersionStage(Stage):
+    """Coherent dedispersion by overlap-save (ops.dedisp), over the last
+    axis of complex voltages whose axes include 'freq': a frame is a
+    block of ``overlap + nwin * (nfft - overlap)`` samples of every
+    channel, its first ``overlap`` history only (GUPPI's coherent mode
+    writes every RAW block with its predecessor's tail in front), and
+    becomes the ``nwin * (nfft - overlap)`` new samples, dedispersed at
+    ``dm`` (pc cm^-3), from its sample ``overlap // 2`` on: the chirp's
+    response reaches both ways in time, so half the overlap is dropped
+    at each end of a window (ops.dedisp), and the output's time scale
+    starts there.  Each frame stands alone, so the stage is frame-local
+    (no lookahead, batch-safe).
+
+    The channels' centres and width come from the header's 'freq'
+    scale ([centre of channel 0, channel width], in its units, MHz by
+    default); the chirp (ops.dedisp.chirp) is made once a sequence on
+    the host in float64 and is an argument of the gulp's program
+    (:meth:`gulp_operands`: two float32 planes, (nchan, nfft), on the
+    device once a sequence).  Output
+    complex64.  Fused with DetectStage('stokes') and a FoldStage on
+    ci8 input, the chain runs as one program with no spectrum in HBM
+    (:func:`match_coherent_fold`)."""
+
+    batch_safe = True
+    n_gulp_operands = 2
+
+    def __init__(self, dm, nfft, overlap):
+        self.dm = float(dm)
+        self.nfft = int(nfft)
+        self.overlap = int(overlap)
+        if not 0 < self.overlap < self.nfft:
+            raise ValueError('overlap must lie in (0, nfft)')
+
+    def transform_header(self, hdr):
+        from .ops.dedisp import chirp, head, window_count
+        itensor = hdr['_tensor']
+        itype = DataType(itensor['dtype'])
+        if not itype.is_complex:
+            raise TypeError('coherent dedispersion requires complex '
+                            'voltages, got %s' % itensor['dtype'])
+        labels = itensor.get('labels') or []
+        if 'freq' not in labels:
+            raise KeyError("coherent dedispersion requires a 'freq' axis, "
+                           "got labels %r" % (labels,))
+        shape = itensor['shape']
+        if shape[-1] == -1:
+            raise KeyError('the last axis is the frame axis; reshape the '
+                           'stream first (views.split_axis)')
+        self.chan_axis = labels.index('freq')
+        self.nout = window_count(int(shape[-1]), self.nfft, self.overlap) \
+            * (self.nfft - self.overlap)
+        nchan = int(shape[self.chan_axis])
+        f0, df = itensor['scales'][self.chan_axis]
+        units = itensor.get('units')
+        funit = (units[self.chan_axis] if units else None) or 'MHz'
+        f0 = convert_units(f0, funit, 'MHz')
+        df = convert_units(df, funit, 'MHz')
+        self.chirp = chirp(self.dm, f0 + df * np.arange(nchan), df,
+                           self.nfft)
+        self._operands = None
+        ohdr = deepcopy(hdr)
+        otensor = ohdr['_tensor']
+        otensor['dtype'] = 'cf32'
+        otensor['shape'][-1] = self.nout
+        if 'scales' in otensor:
+            # the first output is the block's sample head(overlap)
+            t0, dt = otensor['scales'][-1]
+            otensor['scales'][-1] = [t0 + head(self.overlap) * dt, dt]
+        # the new samples a frame makes, every channel and polarisation
+        self.frame_samples = int(np.prod([n for n in otensor['shape']
+                                          if n != -1]))
+        return ohdr
+
+    def gulp_operands(self, frame_offset, nframe):
+        if self._operands is None:
+            import jax.numpy as jnp
+            self._operands = (
+                jnp.asarray(self.chirp.real.astype(np.float32)),
+                jnp.asarray(self.chirp.imag.astype(np.float32)))
+        return self._operands
+
+    def build(self, in_meta):
+        from .ops.dedisp import dedisperse, transform_paths
+        pre = _complexify_fn(in_meta)
+        nfft, overlap, axis = self.nfft, self.overlap, self.chan_axis
+
+        def fn(x, chirp_re, chirp_im):
+            return dedisperse(pre(x), chirp_re, chirp_im, nfft, overlap,
+                              axis)
+        fn.impl_info = transform_paths(nfft)
+        return fn
+
+
+class FoldStage(Stage):
+    """Fold the last axis of a real time series (detected voltages:
+    Stokes I, Q, U, V) into ``nbin`` phase bins of a pulsar's period,
+    ``period_num / period_den`` samples, by the integer rule of
+    ops.fold: absolute sample n falls in bin floor(nbin * ((period_den
+    * n) mod period_num) / period_num).  Sample j of frame f is n = f *
+    nsamp + j + origin, the origin the header's time of the frame's
+    first sample in samples (the last axis's scale, [t0, dt]: t0 / dt,
+    0 without a scale), so that n counts from the stream's time zero
+    (guppi-fold's header puts it at the first new sample of its RAW
+    blocks, so the first dedispersed sample, half an overlap before it,
+    is n = -4096).  Output: the frame's profiles, the last axis
+    'phase' of ``nbin``, float32, to be summed over sub-integrations
+    downstream (``accumulate``).
+
+    Each gulp's start residues are computed on the host, exactly
+    (:meth:`gulp_operands`, in a ``fold.phase`` span), and are an
+    argument of the gulp's program, so gulps at every phase run one
+    compiled program.  A frame's profile depends on where the frame
+    lies, so the stage is not time-concat equivariant."""
+
+    n_gulp_operands = 1
+
+    def __init__(self, nbin, period_num, period_den):
+        self.nbin = int(nbin)
+        self.period_num = int(period_num)
+        self.period_den = int(period_den)
+
+    def transform_header(self, hdr):
+        from .ops.fold import FoldRule
+        itensor = hdr['_tensor']
+        itype = DataType(itensor['dtype'])
+        if itype.is_complex:
+            raise TypeError('fold requires a detected (real) series, '
+                            'got %s' % itensor['dtype'])
+        shape = itensor['shape']
+        if shape.index(-1) != 0 or shape[-1] == -1:
+            raise KeyError('fold requires the frame axis first and the '
+                           'samples folded last')
+        self.rule = FoldRule(shape[-1], self.nbin, self.period_num,
+                             self.period_den)
+        t0, dt = (itensor.get('scales') or [[0, 0]])[-1]
+        self.origin = int(round(t0 / dt)) if dt else 0
+        ohdr = deepcopy(hdr)
+        otensor = ohdr['_tensor']
+        otensor['dtype'] = 'f32'
+        otensor['shape'][-1] = self.nbin
+        if 'labels' in otensor:
+            otensor['labels'][-1] = 'phase'
+        if 'scales' in otensor:
+            otensor['scales'][-1] = [0, 1. / self.nbin]
+        if 'units' in otensor:
+            otensor['units'][-1] = None
+        return ohdr
+
+    def gulp_operands(self, frame_offset, nframe):
+        from .ops.fold import residues
+        from .telemetry import spans
+        with spans.timed('fold.phase', 'compute', frame=int(frame_offset)):
+            return (residues(frame_offset, nframe, self.rule.nsamp,
+                             self.period_num, self.period_den,
+                             self.origin),)
+
+    def build(self, in_meta):
+        import jax
+        import jax.numpy as jnp
+        from .ops.fold import fold
+        rule = self.rule
+
+        def fn(x, starts):
+            return jax.vmap(lambda xt, r: fold(xt, r, rule))(
+                x.astype(jnp.float32), starts)
+        return fn
+
+
 def match_beamformer(stages, headers, shape, dtype):
     """Recognize the quantized beamform-and-detect pattern —
     BeamformStage (per-pol, dual pol; one weight set or a set per
@@ -1121,7 +1306,6 @@ def compose_stages(stages, headers, shape, dtype, substitute=True):
     {'impl': 'xla-fused'}).
     """
     import jax
-    from functools import reduce as _reduce
     if substitute:
         # check the whole-chain substitutions first: when one matches,
         # the per-stage functions below would be built only to be
@@ -1131,11 +1315,14 @@ def compose_stages(stages, headers, shape, dtype, substitute=True):
             plan = match_long_spectrometer(stages, headers, shape, dtype)
         if plan is None:
             plan = match_beamformer(stages, headers, shape, dtype)
+        if plan is None:
+            plan = match_coherent_fold(stages, headers, shape, dtype)
         if plan is not None:
             return plan, plan.info
     fns = []
     info = {'impl': 'xla-fused'}
     cur = jax.ShapeDtypeStruct(tuple(shape), dtype)
+    taxis = headers[0]['_tensor']['shape'].index(-1)
     for stage, ihdr in zip(stages, headers[:-1]):
         idt = DataType(ihdr['_tensor']['dtype'])
         meta = {'shape': list(cur.shape), 'dtype': idt,
@@ -1144,9 +1331,70 @@ def compose_stages(stages, headers, shape, dtype, substitute=True):
         fns.append(fn)
         if getattr(fn, 'impl_info', None):
             info['fft'] = fn.impl_info
-        cur = jax.eval_shape(fn, cur)
-    composed = lambda x: _reduce(lambda v, f: f(v), fns, x)
+        cur = jax.eval_shape(fn, cur, *stage.gulp_operands(
+            0, cur.shape[taxis]))
+    counts = [stage.n_gulp_operands for stage in stages]
+
+    def composed(x, *operands):
+        # ``operands``: every stage's gulp operands in the chain's order
+        for fn, n in zip(fns, counts):
+            x, operands = fn(x, *operands[:n]), operands[n:]
+        return x
     return composed, info
+
+
+def match_coherent_fold(stages, headers, shape, dtype):
+    """Recognize a pulsar-timing backend's coherent mode --
+    CoherentDedispersionStage -> DetectStage('stokes', pol) ->
+    FoldStage on ci8 dual-pol input (time, freq, pol, fine_time) --
+    and return ops.spectrometer.coherent_fold as a
+    :class:`SpectrometerPlan`: one program a gulp that takes a few
+    channels of a frame at a time through the forward transform, the
+    chirp, the inverse and the detection, so that neither the spectra
+    nor the dedispersed voltages reach HBM whole, then folds the
+    Stokes of all channels at once.  Its transforms take
+    the path their length selects (ops.fft.fft_path: three levels of
+    DFT matrices past 16384 points, forward and inverse).  The plan
+    starts from the gulp's int16 words or its pairs and takes the
+    stages' gulp operands, the chirp's planes and the gulp's start
+    residues, as arguments."""
+    if len(stages) != 3:
+        return None
+    c, d, f = stages
+    if not (isinstance(c, CoherentDedispersionStage)
+            and isinstance(d, DetectStage) and isinstance(f, FoldStage)):
+        return None
+    if headers[0]['_tensor']['dtype'] != 'ci8' or str(dtype) != 'int8':
+        return None
+    if len(shape) != 5 or shape[2] != 2 or shape[-1] != 2 or \
+            c.chan_axis != 1:
+        return None
+    if d.mode != 'stokes' or d.axis_index != 2 or d.npol != 2:
+        return None
+    if f.rule.nsamp != c.nout:
+        return None
+    from .ops.dedisp import transform_paths
+    from .ops import spectrometer as spec
+    path = transform_paths(c.nfft)
+    if path['path'] == 'dftmm' or path['inverse']['path'] != path['path']:
+        return None
+    factors = tuple(path['factors']) if path['path'] == 'long' else None
+    logical = tuple(int(n) for n in shape[:-1])
+    nfft, overlap, rule = c.nfft, c.overlap, f.rule
+    precision = path.get('precision', 'highest')
+
+    def run(x, chirp_re, chirp_im, starts):
+        return spec.coherent_fold(x, starts, chirp_re, chirp_im, logical,
+                                  nfft, overlap, rule, factors=factors,
+                                  precision=precision)
+    return SpectrometerPlan(run, {
+        'impl': 'coherent-fold',
+        'fft': path,
+        'dm': c.dm,
+        'overlap': overlap,
+        'nbin': f.nbin,
+        'period': [f.period_num, f.period_den],
+    }, words=run)
 
 
 class SpectrometerPlan(object):
@@ -1168,8 +1416,8 @@ class SpectrometerPlan(object):
         #: its constants (blocks/fused.py)
         self.bound = bound
 
-    def __call__(self, x):
-        return self.fn(x)
+    def __call__(self, x, *operands):
+        return self.fn(x, *operands)
 
 
 def from_words(composed, shape):
@@ -1183,7 +1431,7 @@ def from_words(composed, shape):
         return fn
     from .words import pairs_of
     shape = tuple(shape)
-    return lambda w: composed(pairs_of(w, shape))
+    return lambda w, *operands: composed(pairs_of(w, shape), *operands)
 
 
 def match_spectrometer(stages, headers, shape, dtype):

@@ -129,10 +129,11 @@ def test_path_is_chosen_from_the_shape_and_the_documented_variables(
     assert F.fft_path((1, 4, 2, 1 << 15), [3]) == long
     assert F.fft_path((1, 4, 2, 1 << 15), [-1]) == long
     assert F.fft_path((16384, 2, 4096), [2]) == {'path': 'xla'}
-    # not the last axis, several axes, the inverse, double precision
+    # the inverse as the forward (coherent dedispersion's second
+    # transform); not the last axis, several axes, double precision
+    assert F.fft_path((2, 1 << 15), [1], inverse=True) == long
     assert F.fft_path((4, 1 << 15, 2), [1]) == {'path': 'xla'}
     assert F.fft_path((1 << 15, 1 << 15), [0, 1]) == {'path': 'xla'}
-    assert F.fft_path((2, 1 << 15), [1], inverse=True) == {'path': 'xla'}
     assert F.fft_path((2, 1 << 15), [1], dtype='complex128') == \
         {'path': 'xla'}
     monkeypatch.setenv('BF_FFT_DFT_DTYPE', 'bf16')

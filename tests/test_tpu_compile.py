@@ -392,3 +392,67 @@ def test_cut_of_a_beamform_tab_product_is_words_in_one_program(one_chip):
     sizes = [int(np.prod([int(n) for n in shape.split(',')])) * 4
              for shape in re.findall(r'u32\[([\d,]+)\]', entry)]
     assert max(sizes) == 4 * piece
+
+
+# ---------------------------------------------------------------------------
+# guppi-fold: coherent dedispersion and fold of one node's 64 channels
+# ---------------------------------------------------------------------------
+
+#: the guppi-fold cell's gulp: one RAW block of 64 channels x 2 pol,
+#: 8192 samples of overlap and 917 504 new; its product
+_FOLD_GULP = (1, 64, 2, 925696)
+_FOLD_PRODUCT = (1, 64, 4, 2048)
+
+
+def test_coherent_fold_program_of_a_guppi_fold_gulp(one_chip):
+    """[CoherentDedispersionStage, DetectStage('stokes'), FoldStage] as
+    FusedBlock composes it, at the deployment's shape, from the gulp's
+    int16 words on one axis: the one program, both 2^16-point
+    transforms on the three-level path (four products a level, three
+    levels, two transforms, six bf16 passes a product), no FFT call,
+    no complex type, a loop over the channels; the chirp's two planes
+    and the start residue are ARGUMENTS, the stages' gulp operands in
+    the chain's order (the chirp's 33.5 MB folded in as constants would
+    be in the executable, and a residue as a constant would compile
+    every gulp);
+    temporaries the Stokes series, folded all channels together, and
+    the rotations cut from them, under 2.4 GB, not a gulp's spectra or
+    dedispersed voltages (8.6 GB and 0.94 GB as float32 planes)."""
+    import jax
+    from bifrost_tpu.stages import (CoherentDedispersionStage, DetectStage,
+                                    FoldStage, walk_headers, compose_stages)
+    hdr = {'_tensor': {'shape': [-1] + list(_FOLD_GULP[1:]), 'dtype': 'ci8',
+                       'labels': ['time', 'freq', 'pol', 'fine_time'],
+                       'scales': [[0, 1], [1100.78125, 1.5625], [0, 1],
+                                  [0, 0.64e-6]],
+                       'units': [None, 'MHz', None, 's']}}
+    stages = [CoherentDedispersionStage(297.5, 65536, 8192),
+              DetectStage('stokes'), FoldStage(2048, 335923, 100)]
+    plan, info = compose_stages(stages, walk_headers(stages, hdr),
+                                _FOLD_GULP + (2,), np.dtype('int8'))
+    assert info['impl'] == 'coherent-fold'
+    path = {'path': 'long', 'factors': [32, 16, 128],
+            'precision': 'highest', 'nfft': [65536]}
+    assert info['fft'] == dict(path, inverse=path)
+    operands = [op for s in stages for op in s.gulp_operands(0, 1)]
+    nword = int(np.prod(_FOLD_GULP))
+    args = [jax.ShapeDtypeStruct((nword,), np.int16, sharding=one_chip)] + \
+        [jax.ShapeDtypeStruct(op.shape, op.dtype, sharding=one_chip)
+         for op in operands]
+    assert [a.shape for a in args[1:]] == [(64, 65536), (64, 65536), (1,)]
+    comp = jax.jit(plan.words).trace(*args).lower().compile()
+    text, mem = comp.as_text(), comp.memory_analysis()
+    assert 's16[%d]{0:T(1024)(128)(2,1)} parameter(0)' % nword in text
+    assert re.search(r's32\[1\]\{0[^}]*\} parameter\(3\)', text)
+    entry = text[text.index('ENTRY'):]
+    assert entry.count('f32[64,65536]{1,0:T(8,128)} parameter(') == 2
+    assert ' fft(' not in text and 'c64' not in text
+    assert text.count('convolution(') >= 24
+    assert text.count('operand_precision={highest,highest}') >= 24
+    assert re.search(r'while\(', text)
+    assert mem.output_size_in_bytes == int(np.prod(_FOLD_PRODUCT)) * 4
+    assert mem.argument_size_in_bytes >= nword * 2 + 2 * 64 * 65536 * 4
+    # the Stokes series (0.94 GB) and the rotations cut from them
+    # (0.95 GB): 1.89 GB as compiled
+    stokes = 64 * 4 * 917504 * 4
+    assert stokes < mem.temp_size_in_bytes < 5 * stokes // 2
