@@ -715,10 +715,14 @@ class FusedBlock(TransformBlock):
         ``dedisp.long_gulps``: of them, those whose transforms, the
         forward AND the inverse (``impl_info['fft']['inverse']``, which
         ``spectrometer.long_gulps`` does not read), took the three-level
-        path (ops.fft.long_fft); ``fold.samples``: where the chain folds
-        them too, the samples it dedispersed, every channel and
-        polarisation, of their ``nframe`` frames (as many as the blocks
-        hold new ones: each sample of the stream once).  All counted at 0 too, so that a reader finds the
+        path (ops.fft.long_fft); ``dedisp.level_order_gulps``: of them,
+        those whose two transforms passed the spectrum between them in
+        the levels' order, no digit reversal (``'order': 'levels'`` in
+        both records, ops.dedisp.transform_path); ``fold.samples``:
+        where the chain folds them too, the samples it dedispersed,
+        every channel and polarisation, of their ``nframe`` frames (as
+        many as the blocks hold new ones: each sample of the stream
+        once).  All counted at 0 too, so that a reader finds the
         counters."""
         stage = self._dedisp_stage
         if stage is None:
@@ -729,8 +733,11 @@ class FusedBlock(TransformBlock):
         counters.inc('dedisp.gulps', ngulps)
         counters.inc('dedisp.fused_gulps', ngulps if info.get('impl')
                      == 'coherent-fold' else 0)
+        inv = fft.get('inverse') or {}
         counters.inc('dedisp.long_gulps', ngulps if fft.get('path') ==
-                     (fft.get('inverse') or {}).get('path') == 'long'
+                     inv.get('path') == 'long' else 0)
+        counters.inc('dedisp.level_order_gulps', ngulps if
+                     fft.get('order') == inv.get('order') == 'levels'
                      else 0)
         if self._folds:
             counters.inc('fold.samples', int(nframe) * stage.frame_samples)

@@ -32,7 +32,15 @@ output goes on from there: no sample is lost or made twice.
 The chirp is made here once, on the host, in float64 (its phase runs
 to thousands of radians at a timing array's DMs), and reaches the
 device as two float32 planes that are ARGUMENTS of the gulp's program,
-not constants folded into it.
+not constants folded into it.  Between the two transforms a window's
+spectrum is only multiplied by the chirp, element by element, so it is
+held in whatever order the forward transform leaves it in: on the
+three-level path (ops.fft.long_fft) the levels' own order, (k1, k2,
+k3) flattened, which the inverse takes as it is, and the chirp is
+permuted to that order once a sequence, on the host
+(:func:`in_transform_order`); on jnp.fft's path frequency order.  The
+path record (:func:`transform_path`) decides both, and says which
+(``'order': 'levels'``).
 """
 
 from __future__ import annotations
@@ -40,7 +48,8 @@ from __future__ import annotations
 import numpy as np
 
 __all__ = ['KDM', 'chirp', 'chirp_span_samples', 'window_count', 'head',
-           'windows', 'transform_path', 'transform_paths', 'dedisperse']
+           'windows', 'transform_path', 'transform_paths',
+           'in_transform_order', 'dedisperse']
 
 #: dispersion constant: the delay of frequency f (MHz) is
 #: KDM * DM * f^-2 seconds (Hankins & Rickett 1975)
@@ -99,12 +108,16 @@ def transform_path(nfft, inverse=False):
     (rms, TPU v5e) that is the same in every sample, so a fold over
     thousands of samples does not average it away: the profile read
     5.5e-5 from float64, six passes 3e-7 (PERF.md section 6, guppi-fold).
-    ``BF_FFT_DFT_DTYPE=bf16`` (one pass, the control) is kept."""
+    ``BF_FFT_DFT_DTYPE=bf16`` (one pass, the control) is kept.  On the
+    three-level path the spectrum between the transforms is in the
+    levels' order (``'order': 'levels'``; module docstring)."""
     from .fft import fft_path
     path = dict(fft_path((int(nfft),), [0], inverse=inverse),
                 nfft=[int(nfft)])
     if path.get('precision') == 'high':
         path['precision'] = 'highest'
+    if path['path'] == 'long':
+        path['order'] = 'levels'
     return path
 
 
@@ -113,6 +126,20 @@ def transform_paths(nfft):
     the forward one's path, and the inverse's under ``'inverse'``."""
     return dict(transform_path(nfft),
                 inverse=transform_path(nfft, inverse=True))
+
+
+def in_transform_order(h, nfft):
+    """The chirp ``h`` (nchan, nfft), made in frequency order
+    (:func:`chirp`), in the order the spectrum is held in between the
+    transforms of :func:`transform_path`: the levels' (k1, k2, k3),
+    k = k1 + n1 k2 + n1 n2 k3, where it says ``'order': 'levels'``,
+    else as it is."""
+    path = transform_path(nfft)
+    if path.get('order') != 'levels':
+        return h
+    n1, n2, n3 = path['factors']
+    return h.reshape(-1, n3, n2, n1).transpose(0, 3, 2, 1) \
+        .reshape(h.shape)
 
 
 def windows(x, nfft, overlap):
@@ -128,7 +155,8 @@ def windows(x, nfft, overlap):
 def dedisperse(x, chirp_re, chirp_im, nfft, overlap, chan_axis):
     """Complex voltages ``x`` (..., nsamp), channels on ``chan_axis``
     (not the last), through the whole overlap-save (module docstring),
-    each transform by the path :func:`transform_path` gives ->
+    each transform by the path :func:`transform_path` gives, the chirp's
+    planes in its order (:func:`in_transform_order`) ->
     (..., nwin * (nfft - overlap)) complex64.  The stage
     form: the spectra and the dedispersed voltages are arrays of the
     program, written whole."""
@@ -148,7 +176,8 @@ def dedisperse(x, chirp_re, chirp_im, nfft, overlap, chan_axis):
             return fftn_dispatch(z, [-1], inverse=inverse)
         return jax.lax.complex(*long_fft(
             jnp.real(z), jnp.imag(z), tuple(path['factors']),
-            precision=path['precision'], inverse=inverse))
+            precision=path['precision'], inverse=inverse,
+            natural=path['order'] != 'levels'))
     y = transform(transform(w.astype(jnp.complex64), False) * h, True)
     first = head(overlap)
     return y[..., first:first + step].reshape(

@@ -375,14 +375,25 @@ def long_fft(xr, xi, factors, precision='high', natural=True,
     spectra, is applied to each chunk before the next is begun (a
     spectrometer's detection: the spectra never reach HBM whole); its
     result's leading axis must be the chunk's.  Returns the planes
-    (..., n), or ``then``'s results joined along their first axis: in
-    frequency order where ``natural``, else as (n1, n2, n3) flattened,
-    k1 major, which saves the reordering for a caller that sums
-    spectra before it needs their order.  The inverse is cuFFT's
+    (..., n), or ``then``'s results joined along their first axis.
+
+    ``natural`` says which order the spectrum's side is in, in both
+    directions: frequency order where it is set, else the order the
+    three levels make, (k1, k2, k3) flattened, k1 major (level order),
+    which spares the digit reversal, a relayout of the spectrum, for a
+    caller that sums spectra, or multiplies them element by element
+    by a filter held in that order, before it needs their order.  The
+    forward returns the spectrum so; the inverse takes it so and
+    returns time order either way.  The inverse is cuFFT's
     unnormalised one, the conjugate of the forward transform of the
     conjugate: the same matrices, the imaginary plane negated on the
     way in and on the way out (elementwise, fused into the products'
-    operands), so that ``then`` is given the inverse's planes."""
+    operands), so that ``then`` is given the inverse's planes.  From
+    level order it runs the forward's levels transposed, the last
+    first: the DFT matrix is symmetric, so F = L1 T1 L2 T2 L3 P^T
+    (L a level's product, T a twiddle, P the digit reversal) and
+    the inverse of a level-ordered y is conj(L1 T1 L2 T2 L3 conj(y)),
+    with no reversal at all."""
     import jax
     import jax.numpy as jnp
     n1, n2, n3 = factors
@@ -415,10 +426,16 @@ def long_fft(xr, xi, factors, precision='high', natural=True,
         ai = xi.astype(jnp.float32).reshape(-1, n1, n2, n3)
         if inverse:
             ai = -ai
-        ar, ai = twiddle(*level('ka,rabc->rkbc', f1, ar, ai), t1)
-        ar, ai = twiddle(*level('kb,rabc->rakc', f2, ar, ai), t2)
-        ar, ai = level('kc,rabc->rkba' if natural else 'kc,rabc->rabk',
-                       f3, ar, ai)
+        if inverse and not natural:
+            # from (k1, k2, k3): k3 -> c, k2 -> b, k1 -> a, in place
+            ar, ai = twiddle(*level('kc,rabc->rabk', f3, ar, ai), t2)
+            ar, ai = twiddle(*level('kb,rabc->rakc', f2, ar, ai), t1)
+            ar, ai = level('ka,rabc->rkbc', f1, ar, ai)
+        else:
+            ar, ai = twiddle(*level('ka,rabc->rkbc', f1, ar, ai), t1)
+            ar, ai = twiddle(*level('kb,rabc->rakc', f2, ar, ai), t2)
+            ar, ai = level('kc,rabc->rkba' if natural
+                           else 'kc,rabc->rabk', f3, ar, ai)
         ar, ai = ar.reshape(-1, n), ai.reshape(-1, n)
         if inverse:
             ai = -ai

@@ -482,7 +482,11 @@ def coherent_fold(volt, starts, chirp_re, chirp_im, shape, nfft, overlap,
     frame's start residue (ops.fold.residues), an argument, so that a
     gulp at any phase runs the same program.  ``chirp_re``,
     ``chirp_im`` (nchan, nfft) float32: the chirp (ops.dedisp.chirp),
-    arguments too.  A chunk is a few channels of one frame (as many as
+    arguments too, in the order the spectrum is held in between the
+    transforms (ops.dedisp.in_transform_order): the three levels' own
+    where ``factors`` is given (the forward leaves it so and the inverse
+    takes it so: no digit reversal either way), else frequency order.
+    A chunk is a few channels of one frame (as many as
     ``_FOLD_CHUNK_BYTES`` of spectra hold), both polarisations, sliced
     where they lie: their overlap-save windows go forward through the
     transform (ops.fft.long_fft at ``factors``, else jnp.fft), are
@@ -534,8 +538,9 @@ def coherent_fold(volt, starts, chirp_re, chirp_im, shape, nfft, overlap,
 
     def transform(ar, ai, inverse):
         if factors is not None:
+            # the spectrum stays in the levels' order, as the chirp is
             return long_fft(ar, ai, factors, precision=precision,
-                            keep=rows, inverse=inverse)
+                            natural=False, keep=rows, inverse=inverse)
         z = jax.lax.complex(ar, ai)
         z = jnp.fft.ifft(z, axis=-1) * nfft if inverse \
             else jnp.fft.fft(z, axis=-1)

@@ -1028,7 +1028,8 @@ class CoherentDedispersionStage(Stage):
     default); the chirp (ops.dedisp.chirp) is made once a sequence on
     the host in float64 and is an argument of the gulp's program
     (:meth:`gulp_operands`: two float32 planes, (nchan, nfft), on the
-    device once a sequence).  Output
+    device once a sequence, in the order the transforms hold the
+    spectrum in, ops.dedisp.in_transform_order).  Output
     complex64.  Fused with DetectStage('stokes') and a FoldStage on
     ci8 input, the chain runs as one program with no spectrum in HBM
     (:func:`match_coherent_fold`)."""
@@ -1086,9 +1087,10 @@ class CoherentDedispersionStage(Stage):
     def gulp_operands(self, frame_offset, nframe):
         if self._operands is None:
             import jax.numpy as jnp
-            self._operands = (
-                jnp.asarray(self.chirp.real.astype(np.float32)),
-                jnp.asarray(self.chirp.imag.astype(np.float32)))
+            from .ops.dedisp import in_transform_order
+            h = in_transform_order(self.chirp, self.nfft)
+            self._operands = (jnp.asarray(h.real.astype(np.float32)),
+                              jnp.asarray(h.imag.astype(np.float32)))
         return self._operands
 
     def build(self, in_meta):

@@ -192,6 +192,55 @@ def test_long_fft_inverse_against_float64(n):
     assert rel_err(np.asarray(y), want) < 2e-6
 
 
+def test_chirp_in_the_levels_order_dedisperses_as_in_frequency_order():
+    """On the three-level path the spectrum stays in the levels' order
+    between the transforms and the chirp is held in it
+    (ops.dedisp.in_transform_order, from the path record): two windows
+    through that overlap-save give the series that jnp.fft's gives with
+    the chirp in frequency order, and float64's; on jnp.fft's path the
+    chirp is as it was made."""
+    import jax
+    import jax.numpy as jnp
+    from bifrost_tpu.ops import dedisp
+    n, chan = 1 << 15, 2
+    path = dedisp.transform_path(n)
+    assert path['order'] == dedisp.transform_path(n, True)['order'] == \
+        'levels'
+    n1, n2, n3 = path['factors']
+    h = dedisp.chirp(DM, [F0 + DF * chan], DF, n)
+    held = dedisp.in_transform_order(h, n)
+    k1, k2, k3 = np.unravel_index(np.arange(n), (n1, n2, n3))
+    assert np.array_equal(held[0], h[0, k1 + n1 * k2 + n1 * n2 * k3])
+    assert 'order' not in dedisp.transform_path(4096)
+    short = dedisp.chirp(DM, [F0], DF, 4096)
+    assert np.array_equal(dedisp.in_transform_order(short, 4096), short)
+    rng = np.random.default_rng(17)
+    w = rng.integers(-64, 64, (2, n)) + 1j * rng.integers(-64, 64, (2, n))
+    want = np.fft.ifft(np.fft.fft(w, axis=-1) * _chirp(chan, n), axis=-1) * n
+
+    def levels(wr, wi, hr, hi):
+        def t(ar, ai, inverse):
+            return F.long_fft(ar, ai, (n1, n2, n3), precision='highest',
+                              natural=False, inverse=inverse)
+        yr, yi = t(wr, wi, False)
+        return t(yr * hr - yi * hi, yr * hi + yi * hr, True)
+
+    def frequency(z, hz):
+        return jnp.fft.ifft(jnp.fft.fft(z, axis=-1) * hz, axis=-1) * n
+    planes = [jnp.asarray(p, jnp.float32)
+              for p in (w.real, w.imag, held.real, held.imag)]
+    zr, zi = jax.jit(levels)(*planes)
+    got = np.asarray(zr).astype(np.float64) + 1j * np.asarray(zi)
+    xla = np.asarray(jax.jit(frequency)(jnp.asarray(w, jnp.complex64),
+                                        jnp.asarray(h, jnp.complex64)))
+    assert rel_err(got, want) < 2e-6 and rel_err(xla, want) < 2e-6
+    assert rel_err(got, xla) < 4e-6
+    # the chirp left in frequency order is another filter
+    zr, zi = jax.jit(levels)(*planes[:2], *(jnp.asarray(p, jnp.float32)
+                                           for p in (h.real, h.imag)))
+    assert rel_err(np.asarray(zr) + 1j * np.asarray(zi), want) > 0.1
+
+
 @pytest.mark.parametrize('substitute', [True, False],
                          ids=['fused', 'stages'])
 @pytest.mark.parametrize('nfft,overlap', [(1 << 15, 4096), (4096, 512)],
@@ -208,6 +257,10 @@ def test_chain_against_the_float64_reference(nfft, overlap, substitute):
                              ntime=2)
     path = 'long' if nfft > F.LONG_NFFT else 'xla'
     assert info['fft']['path'] == info['fft']['inverse']['path'] == path
+    # the spectrum between the transforms, and the chirp, in the
+    # levels' order on the three-level path
+    assert info['fft'].get('order') == info['fft']['inverse'].get('order') \
+        == ('levels' if path == 'long' else None)
     assert info['impl'] == ('coherent-fold' if substitute else 'xla-fused')
     raw = _voltages((2, nchan, 2, nsamp, 2), seed=nfft)
     (starts,) = stages[2].gulp_operands(7, 2)
@@ -393,6 +446,7 @@ def test_pipeline_against_the_reference(form):
     assert counters.get('dedisp.fused_gulps') == (6 if form == 'fused'
                                                   else 0)
     assert counters.get('dedisp.long_gulps') == 0       # 4096 points
+    assert counters.get('dedisp.level_order_gulps') == 0
     # the fold counts where it folds what the block dedispersed
     assert counters.get('fold.samples') == \
         (6 * nchan * 2 * new if form == 'fused' else 0)

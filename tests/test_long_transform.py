@@ -56,6 +56,41 @@ def test_long_fft_against_float64(factors, natural):
     assert np.max(np.abs(got - want)) / np.max(np.abs(want)) < 2e-6
 
 
+@pytest.mark.parametrize('precision', ['high', 'highest'])
+@pytest.mark.parametrize('natural', [True, False],
+                         ids=['frequency_order', 'k1_major'])
+@pytest.mark.parametrize('factors', [(4, 8, 16), (32, 16, 128)],
+                         ids=lambda f: 'x'.join(map(str, f)))
+def test_long_fft_inverse_from_either_order(factors, natural, precision):
+    """The inverse takes the spectrum in the order ``natural`` names,
+    frequency or the levels' own (as the forward leaves it), and
+    returns numpy's ifft times n in time order either way; the inverse
+    of the forward's level-ordered spectrum is the voltages times n."""
+    import jax
+    import jax.numpy as jnp
+    n = int(np.prod(factors))
+    n1, n2, n3 = factors
+    rng = np.random.default_rng(n + 1)
+    y = rng.standard_normal((3, n)) + 1j * rng.standard_normal((3, n))
+    want = np.fft.ifft(y, axis=-1) * n
+    if not natural:             # Y[k1 + n1 k2 + n1 n2 k3] at [k1, k2, k3]
+        y = y.reshape(3, n3, n2, n1).transpose(0, 3, 2, 1).reshape(3, n)
+
+    def inv(a, b):
+        return F.long_fft(a, b, factors, precision=precision,
+                          natural=natural, inverse=True)
+    zr, zi = jax.jit(inv)(jnp.asarray(y.real, jnp.float32),
+                          jnp.asarray(y.imag, jnp.float32))
+    got = np.asarray(zr).astype(np.float64) + 1j * np.asarray(zi)
+    assert np.max(np.abs(got - want)) / np.max(np.abs(want)) < 2e-6
+    x = rng.integers(-64, 64, (3, n)) + 1j * rng.integers(-64, 64, (3, n))
+    back = jax.jit(lambda a, b: inv(*F.long_fft(
+        a, b, factors, precision=precision, natural=natural)))(
+        jnp.asarray(x.real, jnp.float32), jnp.asarray(x.imag, jnp.float32))
+    got = np.asarray(back[0]).astype(np.float64) + 1j * np.asarray(back[1])
+    assert np.max(np.abs(got - n * x)) / (n * np.max(np.abs(x))) < 2e-6
+
+
 @pytest.mark.parametrize('nrow,keep,want', [(12, 1, 4), (12, 2, 4),
                                             (12, 3, 3), (10, 1, 2),
                                             (6, 6, 6), (3, 1, 3)])

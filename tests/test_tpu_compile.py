@@ -41,6 +41,24 @@ def _compiled_cut(one_chip, shapes, dtype, *static):
     return xfer._cut_fn.lower(args, 0, *static).compile()
 
 
+def _loop_copies(text):
+    """Names of the ``copy`` instructions in the body of the program's
+    largest while loop (a chunk loop's)."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        m = re.match(r'(?:ENTRY )?%([\w.\-]+) .*\{$', line)
+        if m:
+            name, comps[m.group(1)] = m.group(1), []
+        elif line == '}':
+            name = None
+        elif name:
+            comps[name].append(line)
+    bodies = re.findall(r' while\(.*?body=%([\w.\-]+)', text)
+    body = max((comps[b] for b in bodies), key=len)
+    return [re.match(r'\s*%([\w.\-]+) = ', line).group(1)
+            for line in body if ' copy(' in line]
+
+
 def _one_axis_of_words(text, count, nbytes):
     """The program's ``count`` outputs are ONE axis of uint32 words of
     ``nbytes`` each, laid out as the host holds them (``T(1024)``), and
@@ -114,6 +132,10 @@ def test_cut_of_a_gpuspec_hsr_product(one_chip):
 #: the gpuspec-hsr cell's gulp in device representation, and its product
 _HSR_GULP = (1, 64, 2, 1 << 20, 2)
 _HSR_PRODUCT = (1, 64, 4, 1 << 20)
+#: the copies of the long spectrometer's chunk loop, by name, as the
+#: libtpu of jax 0.9.0 compiles it
+HSR_LOOP_COPIES = {'words': ['copy.%d' % k for k in range(48, 54)],
+                   'pairs': ['copy.%d' % k for k in range(56, 62)]}
 
 
 @pytest.mark.parametrize('form', ['pairs', 'words'])
@@ -153,6 +175,9 @@ def test_long_transform_chain_of_a_gpuspec_hsr_gulp(one_chip, form):
     assert ' fft(' not in text and 'c64' not in text
     assert text.count('convolution(') >= 12     # four products a level
     assert re.search(r'while\(', text)          # the loop over chunks
+    # the forward in frequency order, as before the coherent fold kept
+    # its spectra in the levels' order: the same relayouts, by name
+    assert sorted(_loop_copies(text)) == HSR_LOOP_COPIES[form]
     assert mem.output_size_in_bytes == int(np.prod(_HSR_PRODUCT)) * 4
     # 0.67 GB as compiled from the pairs; from the words 0.40: the
     # planes are made on one axis and a chunk is sliced where it lies
@@ -432,7 +457,7 @@ def test_coherent_fold_program_of_a_guppi_fold_gulp(one_chip):
                                 _FOLD_GULP + (2,), np.dtype('int8'))
     assert info['impl'] == 'coherent-fold'
     path = {'path': 'long', 'factors': [32, 16, 128],
-            'precision': 'highest', 'nfft': [65536]}
+            'precision': 'highest', 'nfft': [65536], 'order': 'levels'}
     assert info['fft'] == dict(path, inverse=path)
     operands = [op for s in stages for op in s.gulp_operands(0, 1)]
     nword = int(np.prod(_FOLD_GULP))
@@ -450,6 +475,12 @@ def test_coherent_fold_program_of_a_guppi_fold_gulp(one_chip):
     assert text.count('convolution(') >= 24
     assert text.count('operand_precision={highest,highest}') >= 24
     assert re.search(r'while\(', text)
+    # the spectrum stays in the levels' order between the transforms:
+    # no digit reversal, which put it in frequency order with k1 (32
+    # wide) minor, padded to 128 lanes, four such copies a chunk and
+    # the inverse's input folded back, 40 copies in the loop
+    assert 'f32[64,128,16,32]' not in text
+    assert len(_loop_copies(text)) <= 36
     assert mem.output_size_in_bytes == int(np.prod(_FOLD_PRODUCT)) * 4
     assert mem.argument_size_in_bytes >= nword * 2 + 2 * 64 * 65536 * 4
     # the Stokes series (0.94 GB) and the rotations cut from them
