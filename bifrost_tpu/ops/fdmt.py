@@ -25,8 +25,15 @@ from .common import as_jax
 __all__ = ['Fdmt', 'fdmt_numpy', 'KDM', 'fdmt_gate_rtol']
 
 #: per-step budget for the Pallas scalar-prefetch delay tables; steps
-#: beyond this run the XLA gather instead (SMEM is 1 MiB total)
+#: beyond this run :func:`_xla_merge_step` instead (SMEM is 1 MiB total)
 SMEM_TABLE_BUDGET = 256 * 1024
+
+#: per-step budget for the Pallas step's VMEM blocks: a subband's two
+#: input rows and its output, each of all its delays, double-buffered.
+#: Steps beyond it run :func:`_xla_merge_step` instead: Mosaic's scoped
+#: VMEM limit is 16 MiB, which the fifth step of a 512-channel,
+#: 4096-delay plan over 6144 frames passes by 128 KiB
+VMEM_BLOCK_BUDGET = 12 << 20
 
 #: dispersion constant, MHz^2 s / (pc cm^-3): delay(f) =
 #: KDM * DM * f^-2 for f in MHz (reference:
@@ -58,27 +65,76 @@ def _cff(f1, f2, exponent):
     return abs(f1 ** exponent - f2 ** exponent)
 
 
-def _xla_merge_step(state, step, sgn, T_logical):
-    """One FDMT merge step as XLA gathers, shared by the pure-XLA core
-    and the Pallas core's SMEM-overflow fallback.  ``state`` may be
-    time-padded: the validity mask uses ``T_logical`` while the gather
-    clip uses the padded extent (pad values never reach [0, T))."""
+def _shift(x, k, sgn):
+    """``x[..., t + sgn * k]`` at ``[..., t]`` along the last axis, zero
+    where that falls outside it: a static roll, masked.  The same shift
+    as a slice and a pad, or as zeros joined to a slice, came out wrong
+    on a TPU v5e (libtpu 0.0.34) in the fifth merge step of a
+    512-channel, 4096-delay plan over 6144 frames, at tile edges, where
+    the masked roll is exact (PERF.md section 6, bl-frb-search)."""
     import jax.numpy as jnp
-    Tp = state.shape[2]
-    t = jnp.arange(Tp)
-    lo = state[step.rows_lo]
-    hi = state[step.rows_hi]
-    d1 = jnp.asarray(step.d1)
-    d2 = jnp.asarray(step.d2)
-    pt = jnp.asarray(step.passthrough)
-    nout = d1.shape[0]
-    rows = jnp.arange(nout)[:, None, None]
-    tshift = t[None, None, :] + sgn * d1[:, :, None]
-    ok = (tshift >= 0) & (tshift <= T_logical - 1)
-    tshift = jnp.clip(tshift, 0, Tp - 1)
-    a = lo[rows, d1[:, :, None], t[None, None, :]]
-    b = hi[rows, d2[:, :, None], tshift] * ok
-    return jnp.where(pt[:, None, None], a, a + b)
+    n = x.shape[-1]
+    if k == 0:
+        return x
+    if k >= n:
+        return jnp.zeros_like(x)
+    t = jnp.arange(n)
+    ok = (t >= k) if sgn < 0 else (t < n - k)
+    return jnp.where(ok, jnp.roll(x, k if sgn < 0 else -k, axis=-1), 0.0)
+
+
+def _init_state(x, nd_init, sgn):
+    """The first level, ``A[c, d, t] = sum_{i <= d} x[c, t + sgn * i]``
+    (zero outside [0, T)): nd_init static shifts of the gulp added in
+    turn, no gather."""
+    import jax.numpy as jnp
+    rows = [x]
+    for i in range(1, nd_init):
+        rows.append(rows[-1] + _shift(x, i, sgn))
+    return jnp.stack(rows, axis=1)
+
+
+def _shift_rows(b, shifts, sgn):
+    """``b[r, t + sgn * shifts[r]]`` at ``[r, t]``, zero where that falls
+    outside the row; ``shifts`` are host integers, one a row: a static
+    shift of all rows by each power of two that some row's shift holds,
+    kept in the rows whose shift has that bit.  On the TPU a per-row
+    time shift as a gather (one index an element) ran at a fraction of
+    a percent of the memory's speed (PERF.md section 6, bl-frb-search);
+    this is a pass over the rows a bit."""
+    import jax.numpy as jnp
+    shifts = np.asarray(shifts, np.int64)
+    k = 1
+    while k <= (shifts.max() if shifts.size else 0):
+        on = (shifts & k) != 0
+        if on.any():
+            b = jnp.where(jnp.asarray(on)[:, None], _shift(b, k, sgn), b)
+        k <<= 1
+    return b
+
+
+def _xla_merge_step(state, step, sgn):
+    """One FDMT merge step in XLA, shared by the pure-XLA core and the
+    Pallas core's fallback: the rows each output row adds taken whole
+    (``take`` along the rows: a row a slice), the second's per-row time
+    shift as static shifts (:func:`_shift_rows`).  ``state`` may be
+    time-padded (the Pallas core's lanes): positive delays read past
+    the logical end, where the padding holds zeros at every level (a
+    forward shift never carries data into it), and negative delays
+    read backwards and never reach it, so no mask is needed."""
+    import jax.numpy as jnp
+    nchan, nd_cur, Tp = state.shape
+    nout, nd_out = step.d1.shape
+    flat = state.reshape(nchan * nd_cur, Tp)
+    lo = (step.rows_lo[:, None] * nd_cur + step.d1).ravel()
+    hi = (step.rows_hi[:, None] * nd_cur + step.d2).ravel()
+    a = jnp.take(flat, jnp.asarray(lo, np.int32), axis=0)
+    b = _shift_rows(jnp.take(flat, jnp.asarray(hi, np.int32), axis=0),
+                    step.d1.ravel(), sgn)
+    pt = np.repeat(step.passthrough, nd_out)
+    if pt.any():
+        b = jnp.where(jnp.asarray(pt)[:, None], 0.0, b)
+    return (a + b).reshape(nout, nd_out, Tp)
 
 
 class _Step(object):
@@ -176,7 +232,6 @@ class Fdmt(object):
 
     # -- single-gulp cores -------------------------------------------------
     def _core_jax(self, negative_delays):
-        import jax.numpy as jnp
         plan = self._plan
         nd_init = plan['nd_init']
         steps = plan['steps']
@@ -185,18 +240,9 @@ class Fdmt(object):
 
         def core(x):
             # x: (nchan, T) float
-            nchan, T = x.shape
-            t = jnp.arange(T)
-            # init: A[c, d, t] = sum_{i<=d} x[c, t + sgn*i]
-            idx = jnp.clip(t[None, :] + sgn * jnp.arange(nd_init)[:, None],
-                           0, T - 1)
-            # zero outside the valid range rather than clamping values in
-            pad_ok = (t[None, :] + sgn * jnp.arange(nd_init)[:, None] >= 0)\
-                & (t[None, :] + sgn * jnp.arange(nd_init)[:, None] <= T - 1)
-            terms = x[:, idx] * pad_ok[None, :, :]
-            state = jnp.cumsum(terms, axis=1)   # (nchan, nd_init, T)
+            state = _init_state(x, nd_init, sgn)   # (nchan, nd_init, T)
             for step in steps:
-                state = _xla_merge_step(state, step, sgn, T)
+                state = _xla_merge_step(state, step, sgn)
             return state[0, :max_delay, :]
         return core
 
@@ -255,14 +301,9 @@ class Fdmt(object):
                else np.arange(max_delay)).astype(np.int32)
 
         def core(x):
-            nchan, T = x.shape
+            T = x.shape[1]
             t = jnp.arange(T)
-            d = jnp.arange(nd_init)[:, None]
-            ti = t[None, :] + sgn * d
-            ok = (ti >= 0) & (ti <= T - 1)
-            state = jnp.cumsum(x[:, jnp.clip(ti, 0, T - 1)] * ok[None],
-                               axis=1)
-            state = state.reshape(-1, T)
+            state = _init_state(x, nd_init, sgn).reshape(-1, T)
             for sel_a, sel_b, segs, pt in sched:
                 a = jnp.take(state, jnp.asarray(sel_a), axis=0)
                 b0 = jnp.take(state, jnp.asarray(sel_b), axis=0)
@@ -295,26 +336,25 @@ class Fdmt(object):
         max_delay = plan['max_delay']
         sgn = -1 if negative_delays else +1
 
-        # Scalar-prefetch delay tables live in SMEM; steps whose tables
-        # exceed SMEM_TABLE_BUDGET (huge-nchan plans) fall back to
-        # _xla_merge_step for that step only.
+        # Scalar-prefetch delay tables live in SMEM, a subband's rows in
+        # VMEM; steps whose tables exceed SMEM_TABLE_BUDGET (huge-nchan
+        # plans) or whose rows exceed VMEM_BLOCK_BUDGET (many delays
+        # over long gulps) fall back to _xla_merge_step for that step
+        # only.
         def core(x):
             nchan, T = x.shape
             Tp = -(-T // 128) * 128
-            t = jnp.arange(T)
-            idx = jnp.clip(t[None, :] + sgn * jnp.arange(nd_init)[:, None],
-                           0, T - 1)
-            pad_ok = (t[None, :] + sgn * jnp.arange(nd_init)[:, None] >= 0)\
-                & (t[None, :] + sgn * jnp.arange(nd_init)[:, None] <= T - 1)
-            terms = x[:, idx] * pad_ok[None, :, :]
-            state = jnp.cumsum(terms, axis=1)   # (nchan, nd_init, T)
+            state = _init_state(x, nd_init, sgn)   # (nchan, nd_init, T)
             if Tp != T:
                 state = jnp.pad(state, ((0, 0), (0, 0), (0, Tp - T)))
             nchan_cur = nchan
             for step in steps:
                 table_bytes = (2 * step.d1.size + len(step.passthrough)) * 4
-                if table_bytes > SMEM_TABLE_BUDGET:
-                    state = _xla_merge_step(state, step, sgn, T)
+                block_bytes = 2 * 4 * Tp * (2 * state.shape[1]
+                                            + step.nd_out)
+                if table_bytes > SMEM_TABLE_BUDGET or \
+                        block_bytes > VMEM_BLOCK_BUDGET:
+                    state = _xla_merge_step(state, step, sgn)
                 else:
                     fn = _pk.fdmt_step(step.d1, step.d2,
                                        step.passthrough.astype(np.int32),
@@ -386,6 +426,13 @@ class Fdmt(object):
             if name in cands:
                 self._core_locked = name
                 return cands[name]()
+            if name == 'gated':
+                # a core the gate excluded computes a wrong product: the
+                # heuristic's pick below would run it all the same
+                raise RuntimeError(
+                    'FDMT: every core departs from the float64 oracle '
+                    'by more than rtol %g at shape %s' %
+                    (fdmt_gate_rtol(), tuple(shape)))
         self.chosen_core = 'rolls' if 'rolls' in cands else 'xla'
         return cands[self.chosen_core]()
 
@@ -437,7 +484,7 @@ class Fdmt(object):
         scale = float(np.max(np.abs(ref))) or 1.0
         rtol = fdmt_gate_rtol()
         fns = {}
-        had_errors = False
+        had_errors = gated = False
         for name, factory in cands.items():
             try:
                 fn = jax.jit(factory())
@@ -450,8 +497,10 @@ class Fdmt(object):
                 continue
             if float(np.max(np.abs(y - ref))) / scale <= rtol:
                 fns[name] = fn
+            else:
+                gated = True
         if not fns:
-            return 'none'
+            return 'gated' if gated else 'none'
         winner, ms, _err = mprobe.select('fdmt', key, fns,
                                          lambda: (xj,),
                                          persist=not had_errors)

@@ -2079,8 +2079,19 @@ class ReadSpan(_SpanAPI):
                 shape = (t['ringlet_shape'] + [nframe] + t['frame_shape'])
                 return _whole(device_rep_zeros(shape, t['dtype']))
 
-            self._data = self._ring._storage.get(
+            data = self._ring._storage.get(
                 self._begin, self._nbyte, t['frame_nbyte'], zeros_fn)
+            from .devrep import device_rep_dtype
+            shape = tuple(self.shape) + \
+                ((2,) if device_rep_dtype(t['dtype'])[1] else ())
+            if tuple(data.shape) != shape and \
+                    data.size == int(np.prod(shape, dtype=np.int64)):
+                # a view that merges, splits or drops axes
+                # (bifrost_tpu.views) changes the header alone: the
+                # writer's array takes the shape the reader's header
+                # gives it, its elements in the same order
+                data = data.reshape(shape)
+            self._data = data
         else:
             self._data = self._host_view(writeable=False)
         return self._data

@@ -162,9 +162,14 @@ class FftStage(Stage):
                 otensor['units'][ax] = transform_units(
                     otensor['units'][ax], -1)
             if 'scales' in otensor:
-                otensor['scales'][ax][0] = 0
                 scale = otensor['scales'][ax][1]
-                otensor['scales'][ax][1] = 1. / (scale * length)
+                step = 1. / (scale * length)
+                # a shifted forward spectrum starts at its most
+                # negative frequency, as FftShiftStage's header says
+                shifted = self.apply_fftshift and not self.inverse \
+                    and self.mode == 'c2c'
+                otensor['scales'][ax] = [-(length // 2) * step if shifted
+                                         else 0, step]
             if 'labels' in otensor and self.axis_labels != [None]:
                 otensor['labels'][ax] = self.axis_labels[i]
         self._oshape_tpl = list(otensor['shape'])

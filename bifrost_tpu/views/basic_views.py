@@ -163,7 +163,12 @@ def merge_axes(block, axis1, axis2, label=None):
             if not np.isclose(scale1, n * scale2):
                 raise ValueError("Scales of merge axes do not line up: "
                                  "%s != %s" % (scale1, n * scale2))
-            tensor['scales'][ax1][1] = scale2
+            # element (i1, i2) lies at start1 + i1 scale1 + start2 +
+            # i2 scale2: the merged axis starts at the sum
+            tensor['scales'][ax1] = [
+                tensor['scales'][ax1][0] + convert_units(
+                    tensor['scales'][ax2][0], tensor['units'][ax2],
+                    tensor['units'][ax1]), scale2]
             del tensor['scales'][ax2]
             del tensor['units'][ax2]
         if 'labels' in tensor:

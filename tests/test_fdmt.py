@@ -124,6 +124,28 @@ def test_fdmt_pallas_smem_fallback_step_interpret(monkeypatch):
     assert err < 1e-5
 
 
+def test_fdmt_pallas_vmem_fallback_step_interpret(monkeypatch):
+    """Steps whose rows (two inputs and the output, all delays,
+    double-buffered) exceed the VMEM budget run the XLA gather, as a
+    512-channel, 4096-delay plan over 6144 frames needs from its fifth
+    step on; the mix must stay exact."""
+    import jax
+    import jax.numpy as jnp
+    from bifrost_tpu.ops import fdmt as fdmt_mod
+    rng = np.random.RandomState(5)
+    x = rng.randn(16, 100).astype(np.float32)
+    plan = fdmt_mod.Fdmt().init(16, 12, 1400.0, 0.1)
+    for neg in (False, True):
+        ref = plan._core_numpy(x.astype(np.float64), neg)
+        # the first steps' rows fit, the later steps' do not
+        for budget in (0, 8 * 128 * 12):
+            monkeypatch.setattr(fdmt_mod, 'VMEM_BLOCK_BUDGET', budget)
+            out = np.asarray(jax.jit(plan._core_pallas(
+                neg, interpret=True))(jnp.asarray(x)))
+            err = np.abs(out - ref).max() / (np.abs(ref).max() + 1e-9)
+            assert err < 1e-5, (neg, budget)
+
+
 def test_rolls_core_matches_oracle():
     """The static-roll core (BF_FDMT_IMPL=rolls) is exact against the
     numpy oracle across shapes, tails, and both delay signs."""
@@ -179,6 +201,23 @@ def test_probe_selects_measured_winner(monkeypatch, tmp_path):
         assert plan2.chosen_core == plan.chosen_core
     else:
         assert plan2.chosen_core in ('xla', 'rolls', 'pallas')
+
+
+def test_probe_refuses_when_the_gate_excludes_every_core(monkeypatch,
+                                                        tmp_path):
+    """A core the oracle gate excludes computes a wrong product: where
+    the gate excludes every candidate, picking one stops with an error
+    instead of running the heuristic's choice."""
+    from bifrost_tpu.ops import mprobe
+    monkeypatch.setenv('BF_FDMT_PROBE', '1')
+    monkeypatch.setenv('BF_CACHE_DIR', str(tmp_path))
+    monkeypatch.setattr(mprobe, '_cache', {})
+    plan = Fdmt().init(16, 8, 1400.0, -0.1)
+    monkeypatch.setattr(plan, '_candidate_cores', lambda neg: {
+        'xla': lambda: (lambda x: 2.0 * plan._core_jax(neg)(x)),
+        'rolls': lambda: (lambda x: plan._core_jax_rolls(neg)(x) + 1.0)})
+    with pytest.raises(RuntimeError, match='every core departs'):
+        plan._pick_core(False, shape=(16, 128))
 
 
 def test_probe_off_keeps_heuristic(monkeypatch):

@@ -487,3 +487,33 @@ def test_coherent_fold_program_of_a_guppi_fold_gulp(one_chip):
     # (0.95 GB): 1.89 GB as compiled
     stokes = 64 * 4 * 917504 * 4
     assert stokes < mem.temp_size_in_bytes < 5 * stokes // 2
+
+
+@pytest.mark.parametrize('core', ['xla', 'pallas'])
+def test_fdmt_program_of_a_bl_frb_search_gulp(one_chip, core):
+    """The FDMT block's program with negative delays at the deployment's
+    shape, 512 channels of [4096 held ‖ 2048 new] filterbank samples
+    over 4096 delays, with each core the race may take: one module
+    named for it (``jit_fdmt_history``, which a device trace shows), the
+    gulp's DM-time plane and the next history out.  Pallas's steps
+    whose rows pass the VMEM budget run ``_xla_merge_step`` (three
+    kernels, then six row-take steps): all nine as kernels, Mosaic
+    refused the fourth (16.12 MiB of a 16 MiB scoped limit)."""
+    import jax
+    from bifrost_tpu.blocks.fdmt import history_program
+    from bifrost_tpu.ops.fdmt import Fdmt
+    plan = Fdmt().init(512, 4096, 1100.0, 2.9296875 / 8)
+    make = {'xla': plan._core_jax, 'pallas': plan._core_pallas}[core]
+    prog = jax.jit(history_program(make(True), 4096))
+    args = [jax.ShapeDtypeStruct((512, n), np.float32, sharding=one_chip)
+            for n in (4096, 2048)]
+    comp = prog.trace(*args).lower().compile()
+    text, mem = comp.as_text(), comp.memory_analysis()
+    assert text.startswith('HloModule jit_fdmt_history')
+    assert text.count('custom_call_target="tpu_custom_call"') == \
+        (3 if core == 'pallas' else 0)
+    # the two arrays, and the tuple's table of them
+    assert 0 <= mem.output_size_in_bytes - (4096 * 2048 + 512 * 4096) * 4 \
+        <= 1024
+    # the levels' rows over 6144 frames: about 100 MB a level
+    assert mem.temp_size_in_bytes < 1.5e9
