@@ -189,7 +189,9 @@ class FusedBlock(TransformBlock):
         self._published_key = None
         self._donate_on = None
         from ..stages import (BeamformStage, CoherentDedispersionStage,
-                              FoldStage)
+                              FoldStage, opens_with_short_transform)
+        self._channelises = opens_with_short_transform(self.stages,
+                                                       self._headers[0])
         self._beam_stage = next((st for st in self.stages
                                  if isinstance(st, BeamformStage)), None)
         self._dedisp_stage = next(
@@ -382,7 +384,8 @@ class FusedBlock(TransformBlock):
                 def build_local(local_shape):
                     fn, info = compose_stages(self.stages,
                                               self._headers,
-                                              local_shape, dtype)
+                                              local_shape, dtype,
+                                              mesh=mesh)
                     self._local_info = info
                     return fn
                 self._local_info = {}
@@ -575,7 +578,7 @@ class FusedBlock(TransformBlock):
 
             def per_shape(shape):
                 fn, info = compose_stages(self.stages, self._headers,
-                                          shape, dtype)
+                                          shape, dtype, mesh=self.mesh)
                 info_box.update(info)
                 return fn
 
@@ -664,8 +667,8 @@ class FusedBlock(TransformBlock):
 
     def _count_transformed(self, ngulps):
         """``spectrometer.gulps``: gulps that went through a chain
-        with a transform in it (the whole-chain kernel, or an FftStage
-        whose path the executed plan recorded);
+        with a transform in it (a whole-chain kernel or plan with one,
+        or an FftStage whose path the executed plan recorded);
         ``spectrometer.long_gulps``: those whose transform took the
         three-level path (ops.fft.long_fft);
         ``spectrometer.word_gulps``: those whose program started from
@@ -673,7 +676,8 @@ class FusedBlock(TransformBlock):
         too so that a reader finds the counter."""
         info = self.impl_info or {}
         fft = info.get('fft')
-        if fft is None and info.get('impl') != 'pallas-spectrometer':
+        if fft is None and info.get('impl') not in ('pallas-spectrometer',
+                                                    'row-channeliser'):
             return
         from ..telemetry import counters
         counters.inc('spectrometer.gulps', ngulps)
@@ -706,6 +710,22 @@ class FusedBlock(TransformBlock):
                      stage.engine.ops_per_frame(
                          shape[1], stage.npol if stage.mode == 'perpol'
                          else 1))
+
+    def _count_channelised(self, ngulps):
+        """``channelise.gulps``: gulps of a chain that opens with a
+        transform of a ci8 gulp's last axis of a length that divides
+        128 (stages.opens_with_short_transform);
+        ``channelise.row_gulps``: of them, those that ran the row
+        channeliser (stages.match_channeliser: the gulp's words as the
+        rows they lie in, no relayout of the gulp).  Both counted at 0
+        too, so that a reader finds the counters."""
+        if not self._channelises:
+            return
+        info = self.impl_info or {}
+        from ..telemetry import counters
+        counters.inc('channelise.gulps', ngulps)
+        counters.inc('channelise.row_gulps', ngulps if info.get('impl')
+                     == 'row-channeliser' else 0)
 
     def _count_dedispersed(self, ngulps, nframe):
         """``dedisp.gulps``: gulps that went through a chain with a
@@ -757,6 +777,7 @@ class FusedBlock(TransformBlock):
             ngulps = max(1, -(-ispan.nframe // self._macro_gulp_in))
             self._count_transformed(ngulps)
             self._count_beamformed(ngulps, ispan.nframe)
+            self._count_channelised(ngulps)
             return
         # a ci8 gulp on one device: the plan starts from its words
         words = self.mesh is None
@@ -771,6 +792,7 @@ class FusedBlock(TransformBlock):
                                          gulp=gulp), owned=True)
         self._count_transformed(1)
         self._count_beamformed(1, ispan.nframe)
+        self._count_channelised(1)
         self._count_dedispersed(1, ispan.nframe)
 
 

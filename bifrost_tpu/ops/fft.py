@@ -223,6 +223,15 @@ def fft_impl_choice():
     return os.environ.get('BF_FFT_IMPL', '').strip().lower()
 
 
+def dft_bf16():
+    """BF_FFT_DFT_DTYPE=bf16: the matrix forms' lower-precision switch,
+    one bf16 pass a product where float32 accuracy takes three or
+    six (the control of the cells whose transforms are products)."""
+    import os
+    return os.environ.get('BF_FFT_DFT_DTYPE', '').strip().lower() \
+        in ('bf16', 'bfloat16')
+
+
 def fft_path(shape, axes, inverse=False, dtype='complex64'):
     """What :func:`fftn_dispatch` runs for a transform of ``axes`` of
     an array of ``shape``, as a record a block can publish: from the
@@ -231,7 +240,6 @@ def fft_path(shape, axes, inverse=False, dtype='complex64'):
     forward or inverse, of one last axis whose length takes three
     levels (:func:`long_factors`); ``'dftmm'`` where BF_FFT_IMPL
     forces the two-level matrix form; else ``'xla'`` (jnp.fft)."""
-    import os
     if fft_impl_choice() == 'dftmm':
         return {'path': 'dftmm'}
     ndim = len(shape)
@@ -239,12 +247,8 @@ def fft_path(shape, axes, inverse=False, dtype='complex64'):
     factors = long_factors(shape[axes[0]]) if axes == [ndim - 1] else None
     if factors is None or str(dtype) != 'complex64':
         return {'path': 'xla'}
-    # BF_FFT_DFT_DTYPE=bf16 is the matrix forms' lower-precision
-    # switch: one bf16 pass a product where float32 accuracy takes three
-    bf16 = os.environ.get('BF_FFT_DFT_DTYPE', '').strip().lower() \
-        in ('bf16', 'bfloat16')
     return {'path': 'long', 'factors': list(factors),
-            'precision': 'default' if bf16 else 'high'}
+            'precision': 'default' if dft_bf16() else 'high'}
 
 
 def fftn_dispatch(x, axes, inverse=False):

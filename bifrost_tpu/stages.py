@@ -1299,7 +1299,8 @@ def walk_headers(stages, hdr):
     return headers
 
 
-def compose_stages(stages, headers, shape, dtype, substitute=True):
+def compose_stages(stages, headers, shape, dtype, substitute=True,
+                   mesh=None):
     """Build the one-gulp device function for a stage chain.
 
     This is the SINGLE chain constructor: FusedBlock compiles exactly
@@ -1310,7 +1311,9 @@ def compose_stages(stages, headers, shape, dtype, substitute=True):
     Returns ``(fn, info)`` where info records the path fn executes
     ({'impl': 'pallas-spectrometer', ...} when the whole-chain kernel
     substitution applies and ``substitute`` is True, else
-    {'impl': 'xla-fused'}).
+    {'impl': 'xla-fused'}).  ``mesh``: the mesh the chain runs
+    across, ``shape`` then a shard's; the plans that take a gulp on one
+    device alone do not match there.
     """
     import jax
     if substitute:
@@ -1324,6 +1327,8 @@ def compose_stages(stages, headers, shape, dtype, substitute=True):
             plan = match_beamformer(stages, headers, shape, dtype)
         if plan is None:
             plan = match_coherent_fold(stages, headers, shape, dtype)
+        if plan is None:
+            plan = match_channeliser(stages, headers, shape, dtype, mesh)
         if plan is not None:
             return plan, plan.info
     fns = []
@@ -1565,3 +1570,73 @@ def match_long_spectrometer(stages, headers, shape, dtype):
         'impl': 'long-spectrometer',
         'fft': dict(path, nfft=[int(shape[-2])]),
     }, words=words)
+
+
+def opens_with_short_transform(stages, hdr):
+    """True where the chain's first stage is a transform of a ci8
+    gulp's last axis (``hdr``, the chain's input header, after the
+    stages' transform_header) whose length divides 128: a row of a
+    gulp's words on one device holds whole ones (ops.channelise)."""
+    if not stages or not isinstance(stages[0], FftStage) or \
+            hdr['_tensor']['dtype'] != 'ci8':
+        return False
+    logical = hdr['_tensor']['shape']
+    f = stages[0]
+    return f.axes == [len(logical) - 1] and 128 % logical[-1] == 0
+
+
+def match_channeliser(stages, headers, shape, dtype, mesh=None):
+    """Recognize a short channeliser -- FftStage(c2c forward, the last
+    axis, a length that divides 128, shifted or not) ->
+    DetectStage('stokes_i', pol) -> ReduceStage(frame axis, r, 'sum')
+    [-> TransposeStage] on ci8 dual-pol input, the frame axis first,
+    on one device (``mesh`` None) -- and return
+    ops.channelise.row_channeliser as a :class:`SpectrometerPlan`: the
+    gulp's words taken as the rows of 128 they lie in, every transform
+    of a row one block-diagonal real product on the MXU, the shift in
+    the matrix's columns, the squares summed over the r spectra, the
+    polarisations added on the sums, the product laid out as the
+    chain's last header says.  No array with the transform's points
+    minor, and no relayout of the gulp.  Its precision is float32's
+    (the matrix as three bf16 parts against the voltages, which bf16
+    holds exactly: three passes), one pass under BF_FFT_DFT_DTYPE=bf16
+    as the other matrix forms (ops.fft.dft_bf16).  The plan starts
+    from the gulp's int16 words or its pairs."""
+    if mesh is not None or len(stages) not in (3, 4):
+        return None
+    f, d, r = stages[:3]
+    t = stages[3] if len(stages) == 4 else None
+    if not (isinstance(d, DetectStage) and isinstance(r, ReduceStage)
+            and (t is None or isinstance(t, TransposeStage))):
+        return None
+    if not opens_with_short_transform(stages, headers[0]) or \
+            str(dtype) != 'int8':
+        return None
+    nd = len(shape) - 1                 # logical rank: (re, im) is last
+    if shape[-1] != 2 or headers[0]['_tensor']['shape'].index(-1) != 0:
+        return None
+    if f.mode != 'c2c' or f.inverse:
+        return None
+    if d.mode != 'stokes_i' or d.npol != 2 or \
+            not 0 < d.axis_index < nd - 1:
+        return None
+    if r.op != 'sum' or r.axis != r.frame_axis or not r.factor or \
+            shape[0] % r.factor:
+        return None
+    from .ops import channelise
+    from .ops.fft import dft_bf16
+    logical = tuple(int(n) for n in shape[:-1])
+    nspec, pol, shift = r.factor, d.axis_index, bool(f.apply_fftshift)
+    precision = 'default' if dft_bf16() else 'split'
+    axes = None if t is None else list(t.axes)
+
+    def fn(x):
+        y = channelise.row_channeliser(x, logical, nspec, pol, shift,
+                                       precision)
+        return y if axes is None else y.transpose(axes)
+    return SpectrometerPlan(fn, {
+        'impl': 'row-channeliser',
+        'nfft': logical[-1],
+        'shift': shift,
+        'precision': precision,
+    }, words=fn)

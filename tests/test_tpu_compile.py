@@ -517,3 +517,41 @@ def test_fdmt_program_of_a_bl_frb_search_gulp(one_chip, core):
         <= 1024
     # the levels' rows over 6144 frames: about 100 MB a level
     assert mem.temp_size_in_bytes < 1.5e9
+
+
+def test_channeliser_program_of_a_bl_frb_search_gulp(one_chip):
+    """The FRB search's front ([FftStage('fine_time', shifted),
+    DetectStage('stokes_i'), ReduceStage('time', 128), TransposeStage])
+    as FusedBlock composes it, at the deployment's shape, from the
+    gulp's int16 words on one axis: the row channeliser, the words
+    taken as the rows of 128 they lie in (one block-diagonal product a
+    chunk of rows, three bf16 passes), no FFT call, no array with the
+    transform's 8 points minor, which the ``xla-fused`` composition
+    padded 16 times in 128-lane tiles (``u16[262144,64,2,8]``, 8.6 GB)
+    and turned time-minor, 9.13 GB of temporaries; the 4 MB product
+    in the corner turn's order."""
+    import jax
+    from bifrost_tpu.stages import (DetectStage, FftStage, ReduceStage,
+                                    TransposeStage, compose_stages,
+                                    from_words, walk_headers)
+    hdr = {'_tensor': {'shape': [-1, 64, 2, 8], 'dtype': 'ci8',
+                       'labels': ['time', 'freq', 'pol', 'fine_time'],
+                       'scales': [[0, 1]] * 4, 'units': [None] * 4}}
+    stages = [FftStage('fine_time', axis_labels='fine_freq',
+                       apply_fftshift=True),
+              DetectStage('stokes_i'), ReduceStage('time', 128),
+              TransposeStage(['pol', 'freq', 'fine_freq', 'time'])]
+    shape = (262144, 64, 2, 8, 2)
+    fn, info = compose_stages(stages, walk_headers(stages, hdr), shape,
+                              np.dtype('int8'))
+    assert info == {'impl': 'row-channeliser', 'nfft': 8, 'shift': True,
+                    'precision': 'split'}
+    arg = jax.ShapeDtypeStruct((1 << 28,), np.int16, sharding=one_chip)
+    comp = jax.jit(from_words(fn, shape)).trace(arg).lower().compile()
+    text, mem = comp.as_text(), comp.memory_analysis()
+    assert 's16[268435456]{0:T(1024)(128)(2,1)} parameter(0)' in text
+    assert '[262144,64,2,8]' not in text
+    assert ' fft(' not in text and 'c64' not in text
+    assert re.search(r'ROOT .*= f32\[1,64,8,2048\]', text)
+    assert mem.output_size_in_bytes == 64 * 8 * 2048 * 4
+    assert mem.temp_size_in_bytes < 3e9
