@@ -742,8 +742,11 @@ class FusedBlock(TransformBlock):
         where the chain folds them too, the samples it dedispersed,
         every channel and polarisation, of their ``nframe`` frames (as
         many as the blocks hold new ones: each sample of the stream
-        once).  All counted at 0 too, so that a reader finds the
-        counters."""
+        once); ``fold.gulps``: those gulps; ``fold.kernel_gulps``: of
+        them, those whose plan cut the rotations with the kernel that
+        reads the series once (``'fold': 'kernel'`` in the record,
+        ops.fold.cut_path).  All counted at 0 too, so that a reader
+        finds the counters."""
         stage = self._dedisp_stage
         if stage is None:
             return
@@ -761,6 +764,9 @@ class FusedBlock(TransformBlock):
                      else 0)
         if self._folds:
             counters.inc('fold.samples', int(nframe) * stage.frame_samples)
+            counters.inc('fold.gulps', ngulps)
+            counters.inc('fold.kernel_gulps', ngulps if info.get('fold')
+                         == 'kernel' else 0)
 
     def on_data(self, ispan, ospan):
         if self._gulp_batch_active > 1 and self._macro_gulp_in:

@@ -495,12 +495,15 @@ def coherent_fold(volt, starts, chirp_re, chirp_im, shape, nfft, overlap,
     chirp's response reaches both ways) and are detected to Stokes
     I, Q, U, V before the next chunk is begun: the spectra and the
     dedispersed voltages never reach HBM whole.  The Stokes series do
-    (as large as the voltages would be), and are folded a frame at a
-    time, all channels together (ops.fold.fold_turns, fold_bins): a
-    fold a chunk at a time cut every rotation out of every channel
-    alone, 17 600 small device programs a gulp at the deployment's
-    size, and took 16.4 ms where this takes 8.8 (PERF.md section 6,
-    guppi-fold).  Returns (T, nchan, 4, nbin) float32."""
+    (as large as the voltages would be), a chunk's as its channels' and
+    Stokes' rows, so that the loop's output is the fold's (nchan * 4,
+    new) rows as they lie, and are folded a frame at a time, all
+    channels together (ops.fold.fold_turns, fold_bins; on a TPU one
+    kernel that reads the series once, ops.fold.cut_path): a fold a
+    chunk at a time cut every rotation out of every channel alone,
+    17 600 small device programs a gulp at the deployment's size, and
+    took 16.4 ms where this takes 8.8 (PERF.md section 6, guppi-fold).
+    Returns (T, nchan, 4, nbin) float32."""
     import jax
     import jax.numpy as jnp
     from .dedisp import head, window_count
@@ -568,7 +571,9 @@ def coherent_fold(volt, starts, chirp_re, chirp_im, shape, nfft, overlap,
         yy = yr_ * yr_ + yi_ * yi_
         xyr = xr_ * yr_ + xi_ * yi_       # x * conj(y)
         xyi = xi_ * yr_ - xr_ * yi_
-        return jnp.stack([xx + yy, xx - yy, 2.0 * xyr, -2.0 * xyi], axis=1)
+        # rows channel-major, then Stokes: the fold's rows as they lie
+        return jnp.stack([xx + yy, xx - yy, 2.0 * xyr, -2.0 * xyi],
+                         axis=1).reshape(chans * 4, new)
 
     stokes = jax.lax.map(one, jnp.arange(nchunk)) \
         .reshape(ntime, nchan * 4, new)

@@ -1180,8 +1180,9 @@ class FoldStage(Stage):
         rule = self.rule
 
         def fn(x, starts):
-            return jax.vmap(lambda xt, r: fold(xt, r, rule))(
-                x.astype(jnp.float32), starts)
+            # a loop over the frames: the kernel's cut is not batched
+            return jax.lax.map(lambda a: fold(a[0], a[1], rule),
+                               (x.astype(jnp.float32), starts))
         return fn
 
 
@@ -1364,7 +1365,8 @@ def match_coherent_fold(stages, headers, shape, dtype):
     channels of a frame at a time through the forward transform, the
     chirp, the inverse and the detection, so that neither the spectra
     nor the dedispersed voltages reach HBM whole, then folds the
-    Stokes of all channels at once.  Its transforms take
+    Stokes of all channels at once (the record's 'fold' says which cut,
+    ops.fold.cut_path).  Its transforms take
     the path their length selects (ops.fft.fft_path: three levels of
     DFT matrices past 16384 points, forward and inverse).  The plan
     starts from the gulp's int16 words or its pairs and takes the
@@ -1386,6 +1388,7 @@ def match_coherent_fold(stages, headers, shape, dtype):
     if f.rule.nsamp != c.nout:
         return None
     from .ops.dedisp import transform_paths
+    from .ops.fold import cut_path
     from .ops import spectrometer as spec
     path = transform_paths(c.nfft)
     if path['path'] == 'dftmm' or path['inverse']['path'] != path['path']:
@@ -1406,6 +1409,7 @@ def match_coherent_fold(stages, headers, shape, dtype):
         'overlap': overlap,
         'nbin': f.nbin,
         'period': [f.period_num, f.period_den],
+        'fold': cut_path(logical[1] * 4, rule),
     }, words=run)
 
 

@@ -361,6 +361,82 @@ def test_fold_bins_are_the_integer_rule(rule):
         assert np.array_equal(got.sum(axis=1), np.ones(nsamp))
 
 
+#: the kernel's cut at small shapes: (nsamp, nbin, pnum, pden) with a
+#: turn of 337 samples (not a multiple of 128, a window of 512) and one
+#: of 256 (a multiple, a window of 384), a few dozen rotations each
+_CUT_RULES = {'turn_337': (8192, 256, 1009, 3),
+              'turn_256': (6400, 128, 2555, 10)}
+
+
+def _cut_cases():
+    for name, (nsamp, nbin, pnum, pden) in _CUT_RULES.items():
+        seeded = np.random.default_rng(pnum).integers(2, pnum - 1, 2)
+        for rows in (8, 16):
+            for r0 in (0, 1, pnum - 1) + tuple(int(r) for r in seeded):
+                yield pytest.param(name, rows, r0,
+                                   id='%s-rows%d-r%d' % (name, rows, r0))
+
+
+@pytest.mark.parametrize('rule,rows,r0', _cut_cases())
+def test_kernel_cut_is_the_xla_cut_and_the_rule(rule, rows, r0):
+    """The kernel that cuts the rotations from the series where it lies
+    (ops.fold._kernel_cut, interpreted here) gives the XLA cut's
+    partial rotations and, through fold_bins, the integer rule's
+    profiles, to float32's rounding, from start residues 0, 1, pnum - 1
+    and two seeded: the first rotation begins before the series (its
+    first sample lies before it from a residue of pden or more), the
+    last runs past its end, and the last windows are clipped to the
+    series' end."""
+    import jax.numpy as jnp
+    nsamp, nbin, pnum, pden = _CUT_RULES[rule]
+    r = FOLD.FoldRule(nsamp, nbin, pnum, pden)
+    W = FOLD._window(r)
+    assert nsamp % 128 == 0 and W % 128 == 0 and W >= r.turn + 127
+    assert (r.turn % 128 == 0) == (rule == 'turn_256')
+    start, w = FOLD._turn_weights(np.int32(r0), r, W)
+    start = np.asarray(start)
+    assert (start[0] < 0) == (r0 >= pden) and start[-1] + r.turn > nsamp
+    assert start[-1] > nsamp - W        # a window clipped to the end
+    x = np.random.default_rng(r0).standard_normal((rows, nsamp)) \
+        .astype(np.float32)
+    got = np.asarray(FOLD._kernel_cut(jnp.asarray(x), jnp.asarray(start), w,
+                                      r, interpret=True))
+    assert got.shape == (r.nclass, rows, W)
+    assert not got[..., r.turn:].any()
+    xla = np.asarray(FOLD.fold_turns(jnp.asarray(x), np.int32(r0), r))
+    np.testing.assert_allclose(got[..., :r.turn], xla, rtol=1e-6,
+                               atol=1e-5)
+    prof = np.asarray(FOLD.fold_bins(jnp.asarray(got[..., :r.turn]), r))
+    b = FOLD.phase_bins(r0, nsamp, nbin, pnum, pden)
+    want = np.stack([np.bincount(b, weights=row, minlength=nbin)
+                     for row in x.astype(np.float64)])
+    assert rel_err(prof, want) < 1e-6
+
+
+@pytest.mark.parametrize('cut,nchan,on_tpu', [
+    ('kernel', 2, True), ('xla', 3, True), ('xla', 2, False)],
+    ids=['kernel', 'rows_not_tiles_of_eight', 'off_the_chip'])
+def test_the_plan_names_the_cut_it_runs(cut, nchan, on_tpu, monkeypatch):
+    """The plan's record says which cut folds the gulp
+    (ops.fold.cut_path): the kernel on a TPU (the backend's answer
+    steered here, the kernel interpreted), the XLA cut where the
+    Stokes rows, four a channel, do not come in tiles of eight, or off
+    the chip; each chain is within TOL of float64."""
+    import functools
+    import jax
+    monkeypatch.setattr(FOLD, '_on_tpu', lambda: on_tpu)
+    monkeypatch.setattr(FOLD, '_kernel_cut',
+                        functools.partial(FOLD._kernel_cut, interpret=True))
+    nfft, overlap = 4096, 512
+    nsamp = overlap + 2 * (nfft - overlap)
+    stages, fn, info = _plan(nfft, overlap, nchan, nsamp, True)
+    assert info['impl'] == 'coherent-fold' and info['fold'] == cut
+    assert FOLD.cut_path(nchan * 4, stages[2].rule) == cut
+    raw = _voltages((1, nchan, 2, nsamp, 2), seed=nchan)
+    got = np.asarray(jax.jit(fn)(raw, *_operands(stages, 3, 1)))
+    assert rel_err(got, reference(raw, [3], nfft, overlap)) < TOL
+
+
 def test_residues_are_exact_far_into_a_stream():
     """A frame's start residue from its offset in Python's integers,
     however far into the stream (int64 would not hold pden * n)."""
@@ -450,6 +526,9 @@ def test_pipeline_against_the_reference(form):
     # the fold counts where it folds what the block dedispersed
     assert counters.get('fold.samples') == \
         (6 * nchan * 2 * new if form == 'fused' else 0)
+    # off the chip the fold cuts with XLA (ops.fold.cut_path)
+    assert counters.get('fold.gulps') == (6 if form == 'fused' else 0)
+    assert counters.get('fold.kernel_gulps') == 0
     assert acc.impl_info['impl'] == ('coherent-fold' if form == 'fused'
                                      else 'xla-fused')
 

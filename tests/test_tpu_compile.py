@@ -429,7 +429,7 @@ _FOLD_GULP = (1, 64, 2, 925696)
 _FOLD_PRODUCT = (1, 64, 4, 2048)
 
 
-def test_coherent_fold_program_of_a_guppi_fold_gulp(one_chip):
+def test_coherent_fold_program_of_a_guppi_fold_gulp(one_chip, monkeypatch):
     """[CoherentDedispersionStage, DetectStage('stokes'), FoldStage] as
     FusedBlock composes it, at the deployment's shape, from the gulp's
     int16 words on one axis: the one program, both 2^16-point
@@ -439,13 +439,19 @@ def test_coherent_fold_program_of_a_guppi_fold_gulp(one_chip):
     and the start residue are ARGUMENTS, the stages' gulp operands in
     the chain's order (the chirp's 33.5 MB folded in as constants would
     be in the executable, and a residue as a constant would compile
-    every gulp);
-    temporaries the Stokes series, folded all channels together, and
-    the rotations cut from them, under 2.4 GB, not a gulp's spectra or
-    dedispersed voltages (8.6 GB and 0.94 GB as float32 planes)."""
+    every gulp).  The fold reads the Stokes series where the loop wrote
+    them (ops.fold.cut_path, steered to the chip's choice here: off
+    the chip the backend is the CPU's): one kernel, and no relayout of
+    the series from the loop's four-row tiles, no padded copy and no
+    array of its 275 rotations, which took 10.4 ms of the v5e's
+    56.35 a gulp; temporaries the Stokes series and little more, not
+    a gulp's spectra or dedispersed voltages (8.6 GB and 0.94 GB as
+    float32 planes)."""
     import jax
+    from bifrost_tpu.ops import fold
     from bifrost_tpu.stages import (CoherentDedispersionStage, DetectStage,
                                     FoldStage, walk_headers, compose_stages)
+    monkeypatch.setattr(fold, '_on_tpu', lambda: True)
     hdr = {'_tensor': {'shape': [-1] + list(_FOLD_GULP[1:]), 'dtype': 'ci8',
                        'labels': ['time', 'freq', 'pol', 'fine_time'],
                        'scales': [[0, 1], [1100.78125, 1.5625], [0, 1],
@@ -455,7 +461,7 @@ def test_coherent_fold_program_of_a_guppi_fold_gulp(one_chip):
               DetectStage('stokes'), FoldStage(2048, 335923, 100)]
     plan, info = compose_stages(stages, walk_headers(stages, hdr),
                                 _FOLD_GULP + (2,), np.dtype('int8'))
-    assert info['impl'] == 'coherent-fold'
+    assert info['impl'] == 'coherent-fold' and info['fold'] == 'kernel'
     path = {'path': 'long', 'factors': [32, 16, 128],
             'precision': 'highest', 'nfft': [65536], 'order': 'levels'}
     assert info['fft'] == dict(path, inverse=path)
@@ -481,12 +487,20 @@ def test_coherent_fold_program_of_a_guppi_fold_gulp(one_chip):
     # the inverse's input folded back, 40 copies in the loop
     assert 'f32[64,128,16,32]' not in text
     assert len(_loop_copies(text)) <= 36
+    # the loop writes the fold's rows in eight-row tiles, and the kernel
+    # reads them there: no four-row Stokes, no pad or reshape to a
+    # (256, ...) series, no array of the rotations
+    assert 'f32[32,2,4,917504]' not in text
+    assert 'f32[275,256,3360]' not in text
+    assert not re.search(r'= f32\[256,\d+\]\{[^}]*\} (pad|reshape)\(',
+                         text)
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert mem.output_size_in_bytes == int(np.prod(_FOLD_PRODUCT)) * 4
     assert mem.argument_size_in_bytes >= nword * 2 + 2 * 64 * 65536 * 4
-    # the Stokes series (0.94 GB) and the rotations cut from them
-    # (0.95 GB): 1.89 GB as compiled
+    # the Stokes series (0.94 GB) and little else: 1.89 GB with the
+    # rotations cut by XLA
     stokes = 64 * 4 * 917504 * 4
-    assert stokes < mem.temp_size_in_bytes < 5 * stokes // 2
+    assert stokes < mem.temp_size_in_bytes < 5 * stokes // 4
 
 
 @pytest.mark.parametrize('core', ['xla', 'pallas'])
